@@ -49,4 +49,4 @@ bench:
 	python bench.py
 
 clean:
-	rm -f native/libketoingest.so native/libketomux.so
+	rm -f native/libketoingest.so native/libketomux.so native/libketopack.so

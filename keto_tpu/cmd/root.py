@@ -62,7 +62,7 @@ def serve(config):
 
     cfg = Config(config_file=config)
     profiling.attach(cfg.get("profiling", ""))  # reference main.go:25-28
-    registry = Registry(cfg)
+    registry = Registry(cfg, use_default_compile_cache=True)
     daemon = Daemon(registry)
     # SIGTERM/SIGINT → drain in-flight requests (serve.drain_timeout_s)
     # behind a NOT_SERVING readiness flip, then exit — rolling restarts

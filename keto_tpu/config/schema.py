@@ -248,7 +248,7 @@ CONFIG_SCHEMA = {
                 "compile_cache_dir": {
                     "type": "string",
                     "default": "",
-                    "description": "Persistent XLA compilation cache directory (jax compilation_cache_dir). When set, compiled kernels survive process restarts — and boot warms the full slice-width ladder (BFS + label kernels) ahead of traffic, so the multi-second warmup/compile cost is paid once per binary instead of once per boot. Empty disables both.",
+                    "description": "Persistent XLA compilation cache directory (jax compilation_cache_dir). When set, compiled kernels survive process restarts — and boot warms the full slice-width ladder (BFS + label kernels) ahead of traffic, so the multi-second warmup/compile cost is paid once per binary instead of once per boot. The JAX_COMPILATION_CACHE_DIR environment variable takes precedence over this option and has the same two effects. With neither set, `keto-tpu serve` still caches under the fixed `.jax_cache/` directory of the checkout but skips the boot warm-up; daemons embedded in-process use no persistent cache (keto_tpu/driver/compile_cache.py).",
                 },
                 "device_build_enabled": {
                     "type": "boolean",
@@ -460,7 +460,12 @@ CONFIG_SCHEMA = {
             "additionalProperties": False,
             "description": "TPU check-engine tuning; no reference analog (the reference engine has no knobs).",
             "properties": {
-                "backend": {"type": "string", "enum": ["tpu", "oracle", "auto"], "default": "auto"},
+                "backend": {
+                    "type": "string",
+                    "enum": ["tpu", "oracle", "auto"],
+                    "default": "auto",
+                    "description": "Check engine: auto serves from the device engine on whatever backend JAX selects (a TPU when one is attached, else XLA's CPU backend) when the store supports snapshots; tpu is the same engine but refuses to start unless JAX's first device is a TPU (the boot error names the platform found); oracle forces the host recursive reference engine.",
+                },
                 "batch_size": {"type": "integer", "default": 4096},
                 "it_cap": {
                     "type": "integer",
@@ -470,7 +475,7 @@ CONFIG_SCHEMA = {
                 "peel_seed_cap": {
                     "type": "number",
                     "default": 4.0,
-                    "description": "Max host-propagated seeds a peeled node may expand to; raise on local hardware with fast host-device links.",
+                    "description": "Max host-propagated seeds a peeled node may expand to. Higher values peel more nodes out of the device kernel (smaller bitmaps, fewer gather rows) at the price of more host-computed seed entries shipped per batch; the best value for a directly attached chip is not measured.",
                 },
                 "batch_window_ms": {"type": "number", "default": 1.0},
                 "sync_rebuild_budget_s": {

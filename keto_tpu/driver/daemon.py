@@ -301,9 +301,15 @@ class Daemon:
         if not hasattr(engine, "snapshot"):
             return
 
-        warm_widths = bool(
-            self.registry.config().get("serve.compile_cache_dir", "")
-        ) and hasattr(engine, "warm_compile")
+        # the ladder warm-up answers an explicit request for a persistent
+        # cache (serve.compile_cache_dir or JAX_COMPILATION_CACHE_DIR) —
+        # not the mere existence of the CLI's default directory
+        from keto_tpu.driver import compile_cache
+
+        _, cache_requested = compile_cache.resolve(
+            str(self.registry.config().get("serve.compile_cache_dir", "") or "")
+        )
+        warm_widths = cache_requested and hasattr(engine, "warm_compile")
 
         def run():
             try:

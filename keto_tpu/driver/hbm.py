@@ -99,15 +99,19 @@ def device_budget_bytes(
 ) -> int:
     """The auto budget: the first local device's ``memory_stats()``
     ``bytes_limit`` minus headroom, or ``FALLBACK_BUDGET_BYTES`` when the
-    backend exposes no stats. ``deterministic`` (lockstep meshes) skips
+    backend exposes no stats (the CPU backend; a TPU without stats raises
+    instead). ``deterministic`` (lockstep meshes) skips
     the per-host probe entirely — hosts could report different limits,
     and ladder decisions must derive from replicated state only."""
     if deterministic:
         return FALLBACK_BUDGET_BYTES
+    platform = "unknown"
     try:
         import jax
 
-        stats = jax.local_devices()[0].memory_stats() or {}
+        device = jax.local_devices()[0]
+        platform = device.platform
+        stats = device.memory_stats() or {}
         limit = int(stats.get("bytes_limit") or 0)
         if limit > 0:
             return max(1, int(limit * (1.0 - headroom_frac)))
@@ -115,6 +119,13 @@ def device_budget_bytes(
         _log.info(
             "device memory stats unavailable; auto budget falls back to "
             "%d bytes", FALLBACK_BUDGET_BYTES, exc_info=True,
+        )
+    if platform == "tpu":
+        # the fallback is sized for backends that keep no HBM (CPU); on a
+        # TPU it would be a budget invented for a chip nobody asked
+        raise RuntimeError(
+            "TPU device reports no memory_stats() bytes_limit: the HBM "
+            "budget cannot be derived — set serve.hbm_budget_bytes"
         )
     return FALLBACK_BUDGET_BYTES
 

@@ -24,9 +24,23 @@ from keto_tpu.x.logging import new_logger
 
 
 class Registry:
-    def __init__(self, config: Config, network_id: str = "default"):
+    def __init__(
+        self,
+        config: Config,
+        network_id: str = "default",
+        *,
+        use_default_compile_cache: bool = False,
+    ):
         self._config = config
         self._network_id = network_id
+        # the CLI ``serve`` path sets this: with no directory requested,
+        # the persistent compilation cache falls back to the fixed
+        # in-checkout path (keto_tpu/driver/compile_cache.py) instead of
+        # staying off, as it does for in-process daemons
+        self._use_default_compile_cache = use_default_compile_cache
+        #: platform / device_kind / count of the devices the check engine
+        #: runs on, set when the device engine is built (keto_device_info)
+        self._device_info: Optional[dict] = None
         self._lock = threading.RLock()  # guards: _singletons, _promoted
         self._singletons: dict[str, Any] = {}
         # fleet promotion flag: a process booted as serve.role=replica
@@ -627,24 +641,27 @@ class Registry:
             backend = self._config.get("engine.backend", "auto")
             store = self.relation_tuple_manager()
             if backend != "oracle" and hasattr(store, "snapshot_rows"):
+                self._device_info = self._select_device(backend)
                 # persistent XLA compilation cache: compiled kernel
                 # geometries survive restarts, so the boot warmup
                 # (Daemon._warm_snapshot → engine.warm_compile) hits disk
                 # instead of recompiling the whole width ladder
-                cc_dir = str(self._config.get("serve.compile_cache_dir", "") or "")
-                if cc_dir:
-                    try:
-                        import jax
+                from keto_tpu.driver import compile_cache
 
-                        jax.config.update("jax_compilation_cache_dir", cc_dir)
-                        jax.config.update(
-                            "jax_persistent_cache_min_compile_time_secs", 0.0
+                try:
+                    cc_dir = compile_cache.configure(
+                        str(self._config.get("serve.compile_cache_dir", "") or ""),
+                        allow_default=self._use_default_compile_cache,
+                    )
+                    if cc_dir:
+                        self.logger().info(
+                            "persistent compilation cache: %s", cc_dir
                         )
-                    except Exception:
-                        self.logger().warning(
-                            "persistent compilation cache unavailable; "
-                            "continuing without it", exc_info=True,
-                        )
+                except Exception:
+                    self.logger().warning(
+                        "persistent compilation cache unavailable; "
+                        "continuing without it", exc_info=True,
+                    )
                 from keto_tpu.check.tpu_engine import TpuCheckEngine
 
                 # multi-chip serving (keto_tpu/parallel/sharded.py): a
@@ -764,6 +781,33 @@ class Registry:
             return CheckEngine(store)
 
         return build()
+
+    def _select_device(self, backend: str) -> dict:
+        """Name the devices the engine will run on and hold
+        ``engine.backend: tpu`` to its word: on any other platform that
+        setting is a boot error, not a quiet CPU-backend deployment.
+        ``auto`` takes whatever JAX found."""
+        import jax
+
+        devices = jax.devices()
+        info = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+        }
+        if backend == "tpu" and info["platform"] != "tpu":
+            raise RuntimeError(
+                f'engine.backend is "tpu" but JAX found platform '
+                f'{info["platform"]!r} ({info["count"]} x {info["device_kind"]}); '
+                f'refusing to serve checks from it. Use engine.backend: '
+                f'"auto" to accept whatever backend JAX selects.'
+            )
+        self.logger().info(
+            "check engine devices: platform=%s device_kind=%s count=%d "
+            "(engine.backend=%s)",
+            info["platform"], info["device_kind"], info["count"], backend,
+        )
+        return info
 
     def expand_depth(self, requested: int) -> int:
         """Clamp a request's max-depth to the configured global cap
@@ -1318,6 +1362,21 @@ class Registry:
                 "Always 1; the version label identifies the running build.",
                 ("version",),
             ).set((VERSION,), 1)
+
+            def device_info():
+                d = self._device_info
+                if d is None:
+                    return [(("none", "none"), 0.0)]
+                return [((d["platform"], d["device_kind"]), float(d["count"]))]
+
+            m.register_callback(
+                "keto_device_info", "gauge",
+                "Devices the check engine runs on as JAX reports them: "
+                "platform and device_kind of the first device, valued with "
+                "the device count (platform=none, 0 until a device engine "
+                "is built — e.g. the oracle backend).",
+                device_info, ("platform", "device_kind"),
+            )
             # engine slice service times: the SAME numbers the adaptive
             # stream-width controller steers by, mirrored from the
             # engine's DurationStats (attached in permission_engine())

@@ -30,15 +30,9 @@ def _backend_initialized() -> bool:
     point, platform/device-count configuration is dead weight — the
     backend snapshotted the flags — so ``init_distributed`` must fail
     loudly instead of silently no-opping into a mis-provisioned mesh."""
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        probe = getattr(xla_bridge, "backends_are_initialized", None)
-        if probe is not None:
-            return bool(probe())
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:
-        return False
+    return bool(xla_bridge.backends_are_initialized())
 
 
 def init_distributed(

@@ -347,7 +347,6 @@ def sharded_check_step(
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     S1, S2, SA, _B = sizes
@@ -455,7 +454,7 @@ def sharded_check_step(
         return jnp.concatenate([packed, tail])
 
     ov_spec = None if ov_nbrs is None else P(GRAPH_AXIS)
-    return shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=(
@@ -466,7 +465,7 @@ def sharded_check_step(
             ov_spec,
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(bucket_nbrs, bucket_dst, entries, ov_nbrs, ov_dst)
 
 
@@ -486,9 +485,9 @@ def sharded_label_step(
     on every shard — the one-shot pair-row exchange — and the compare +
     bit packing run replicated. Output ``uint32[W]`` (no iteration
     tail — there is no iteration), bit-identical to ``label_step``."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     Pn = n_pairs
@@ -525,12 +524,12 @@ def sharded_label_step(
             (ans << bits).reshape(W, 32), np.uint32(0), lax.bitwise_or, (1,)
         )
 
-    return shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(out_lab, in_lab, entries)
 
 
@@ -577,9 +576,9 @@ def sharded_label_sweep_step(
     the wave sequence — and therefore the stored entry set — is
     bit-identical to the single-device sweep; the wave loop stays on
     host because the builder meters budgets and transfers per wave."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def f(b_nbrs, b_dst, v, x, s, c):
@@ -612,7 +611,7 @@ def sharded_label_sweep_step(
         # [g, rps, Wt] — the same layout the next wave feeds back in
         return v2[None], x2[None], s2[None], active, visits
 
-    return shard_map(
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=(
@@ -624,7 +623,7 @@ def sharded_label_sweep_step(
             P(GRAPH_AXIS),
         ),
         out_specs=(P(GRAPH_AXIS), P(GRAPH_AXIS), P(GRAPH_AXIS), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(nbrs, dst, V, X, S, cov)
 
 

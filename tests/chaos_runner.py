@@ -86,7 +86,10 @@ def main() -> int:
     ap.add_argument("--arm-on-file-spec", default="")
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # chaos daemons always serve from XLA's CPU backend: parents (tests,
+    # bench.py's replica section) may hold a chip, which belongs to one
+    # process at a time
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     from keto_tpu.config.provider import Config
     from keto_tpu.driver.daemon import Daemon
