@@ -1,0 +1,1 @@
+from benchmarks.layer_util import serve_overhead_ms as read  # noqa: F401

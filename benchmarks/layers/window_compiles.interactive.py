@@ -1,0 +1,1 @@
+from benchmarks.layer_util import window_compiles as read  # noqa: F401
