@@ -68,6 +68,9 @@ from keto_tpu.x import faults
 from keto_tpu.x.errors import ErrNamespaceUnknown, KetoError
 from keto_tpu.x.retry import retry_call
 from keto_tpu.x.supervise import SupervisedTask
+from keto_tpu.x.timeline import (
+    DEVICE_WAIT, FILL, LAUNCH, PACK, RESOLVE, dispatch_clock,
+)
 from keto_tpu.x.telemetry import DurationStats, MaintenanceStats
 
 _log = logging.getLogger("keto_tpu.check")
@@ -3924,11 +3927,15 @@ class TpuCheckEngine:
         it = iter(tuples_iter)
         max_iters = 0
         t_prev_ready = time.perf_counter()
+        # the calling thread's state clock: the batcher's collector has
+        # one, every other caller gets the no-op
+        clk = dispatch_clock()
 
         def slices():
             off = 0
             while True:
                 cap = min(bound, ctrl.cap()) if ctrl is not None else bound
+                clk.enter(RESOLVE)  # pulling the caller's tuples is part of it
                 batch = list(itertools.islice(it, cap))
                 if not batch:
                     return
@@ -3956,6 +3963,7 @@ class TpuCheckEngine:
             # a truncated frontier re-runs exactly, mid-stream
             nonlocal max_iters, t_prev_ready
             _seq, off, dev, host_ans, nq, chunk, leases, n_ent, t_disp = rec
+            clk.enter(DEVICE_WAIT)
             try:
                 out, iters, truncated = self._unpack_slice(dev, host_ans, nq)
             finally:
@@ -3963,6 +3971,7 @@ class TpuCheckEngine:
                 # will be re-answered elsewhere): the H2D staging copy is
                 # over, the buffers may be re-leased
                 self._stage_release(leases)
+            clk.enter(FILL)
             if dev is not None and not (
                 isinstance(dev, _HybridSlice) and dev.bfs_dev is None
             ):
@@ -3974,6 +3983,7 @@ class TpuCheckEngine:
                     ),
                 )
                 iters = max(iters, redo_iters)
+                clk.enter(FILL)  # the re-run moved the clock through a round of its own
             max_iters = max(max_iters, iters)
             now = time.perf_counter()
             # the service time attributable to THIS slice: dispatch→ready
@@ -4167,8 +4177,10 @@ class TpuCheckEngine:
         landed, ``n_entries`` feeds the controller's entry-cost model."""
         cap_q = self._slice_cap(snap)
         n = len(tuples)
+        clk = dispatch_clock()
         for s0 in range(0, n, cap_q):
             s1 = min(s0 + cap_q, n)
+            clk.enter(RESOLVE)
             sd, tg, multi = self._resolve_bulk(snap, tuples[s0:s1])
             nq = s1 - s0
             W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
@@ -4464,6 +4476,8 @@ class TpuCheckEngine:
             # the eviction ladder dropped the labels between routing and
             # dispatch (concurrent OOM containment): BFS answers instead
             return self._device_batch(snap, sd, tg, multi, i0, i1, W, it_cap=it_cap)
+        clk = dispatch_clock()
+        clk.enter(PACK)
         packed, host_ans = pack_chunk(
             snap, sd, tg, multi, i0, i1, W, native=self._native_pack
         )
@@ -4576,6 +4590,7 @@ class TpuCheckEngine:
                         np.concatenate([pq, np.zeros(pad, np.int64)]),
                     ]
                 ).astype(np.int32)
+            clk.enter(LAUNCH)
             dl = self._labels_dev(snap)
             if self._sharded:
                 # row-sharded label arrays + replicated pairs: the kernel
@@ -4645,6 +4660,8 @@ class TpuCheckEngine:
         copy may complete asynchronously, so earlier reuse could corrupt
         an in-flight slice."""
         faults.check("device-exec")
+        clk = dispatch_clock()
+        clk.enter(PACK)
         packed, host_ans = pack_chunk(
             snap, sd, tg, multi, i0, i1, force_W, native=self._native_pack
         )
@@ -4672,6 +4689,7 @@ class TpuCheckEngine:
             if stg is not None:
                 leases.append(stg)
         buf, sizes = pack_entries(packed, out=stg)
+        clk.enter(LAUNCH)
         ov = snap.device_overlay
 
         def put_entries():
@@ -4733,6 +4751,7 @@ class TpuCheckEngine:
         entries, sizes = shard_mod.route_entries(
             spec, packed, B, out_alloc=out_alloc
         )
+        dispatch_clock().enter(LAUNCH)
         ebuf = jax.device_put(entries, self._shard_stack_sharding)
         ov = snap.device_shard_overlay
         dev = self._guard_alloc(

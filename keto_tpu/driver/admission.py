@@ -34,6 +34,9 @@ import threading
 import time
 from typing import Optional
 
+#: what can trip a decrease, in the order ``tick`` asks
+SIGNALS = ("slice_p99", "queue_delay", "stall")
+
 
 class AdmissionController:
     """AIMD concurrency limiter for the batch check lane.
@@ -64,7 +67,7 @@ class AdmissionController:
         self._increase = int(increase) if increase else max(16, self.max_window // 64)
         self._interval_s = float(interval_s)
         self._time = time_fn
-        self._lock = threading.Lock()  # guards: window, _last_tick, _seen, _rate, _consec_over, last_p99_ms, last_queue_delay_ms, decreases, increases
+        self._lock = threading.Lock()  # guards: window, _last_tick, _seen, _rate, _consec_over, last_p99_ms, last_queue_delay_ms, decreases, decreases_by_signal, increases
         #: admitted batch-lane window (tuples queued); starts open — the
         #: first overloaded tick shrinks it, idle ticks recover it
         self.window = self.max_window
@@ -76,7 +79,22 @@ class AdmissionController:
         self.last_p99_ms = 0.0
         self.last_queue_delay_ms = 0.0
         self.decreases = 0
+        #: the signal that tripped each decrease (sums to ``decreases``)
+        self.decreases_by_signal = {signal: 0 for signal in SIGNALS}
         self.increases = 0
+        self._delay_hist = None
+
+    def attach_queue_delay_histogram(self, histogram) -> None:
+        """Mirror the queue-delay estimate of every evaluated tick into
+        ``histogram`` (seconds, no labels): how near the budget the
+        estimate runs, not only its last value."""
+        self._delay_hist = histogram
+
+    @property
+    def rate_tuples_per_s(self) -> float:
+        """The EWMA of dispatch throughput the queue-delay estimate
+        divides by; 0 before the first round."""
+        return self._rate or 0.0
 
     # -- signals --------------------------------------------------------------
 
@@ -117,22 +135,28 @@ class AdmissionController:
                 queue_delay_ms = backlog / self._rate * 1e3
                 self.last_queue_delay_ms = queue_delay_ms
 
-            overloaded = (p99 is not None and p99 > self.budget_ms) or (
-                queue_delay_ms is not None and queue_delay_ms > self.budget_ms
-            )
-            if p99 is None and queue_delay_ms is None and backlog > self.window:
+            tripped: Optional[str] = None
+            if p99 is not None and p99 > self.budget_ms:
+                tripped = "slice_p99"
+            elif queue_delay_ms is not None and queue_delay_ms > self.budget_ms:
+                tripped = "queue_delay"
+            elif p99 is None and queue_delay_ms is None and backlog > self.window:
                 # stalled device: backlog grows but nothing lands to
                 # measure — treat silence plus a deep queue as overload
-                overloaded = True
+                tripped = "stall"
 
-            if overloaded:
+            if tripped is not None:
                 self.window = max(self.min_window, int(self.window * self._decrease))
                 self.decreases += 1
+                self.decreases_by_signal[tripped] += 1
                 self._consec_over += 1
             else:
                 self.window = min(self.max_window, self.window + self._increase)
                 self.increases += 1
                 self._consec_over = 0
+        # outside the lock: the histogram has its own
+        if queue_delay_ms is not None and self._delay_hist is not None:
+            self._delay_hist.observe((), queue_delay_ms * 1e-3)
 
     # -- decisions ------------------------------------------------------------
 

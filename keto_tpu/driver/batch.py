@@ -51,7 +51,9 @@ from typing import TYPE_CHECKING, Optional, Sequence
 from keto_tpu.relationtuple.model import RelationTuple
 from keto_tpu.x import faults
 from keto_tpu.x.errors import ErrDeadlineExceeded, ErrTooManyRequests, KetoError
-from keto_tpu.x.timeline import current_timeline
+from keto_tpu.x.timeline import (
+    FILL, RESOLVE, TAKE, DispatchClock, bind_dispatch_clock, current_timeline,
+)
 
 if TYPE_CHECKING:
     from keto_tpu.driver.admission import AdmissionController
@@ -155,6 +157,9 @@ class CheckBatcher:
         self.admission_shed_count = 0
         #: requests dropped at dispatch because their deadline had passed
         self.deadline_drop_count = 0
+        #: the collector thread's state clock (x/timeline.DispatchClock):
+        #: written by that thread alone, read by the /metrics bridge
+        self.clock = DispatchClock()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # in-flight accounting for graceful drain: accepted requests whose
@@ -540,7 +545,10 @@ class CheckBatcher:
         Engines advertising ``STREAM_INFO`` additionally yield a
         per-slice info record (width / BFS steps / label-vs-BFS route /
         halo rounds+bytes / service time), which is stamped onto every
-        rider's request timeline as its ``device`` stage."""
+        rider's request timeline as its ``device`` stage.
+
+        The engine moves this thread's state clock through resolve / pack
+        / launch / device_wait and leaves it in ``fill`` when it yields."""
         emitted: list = []  # stream offset -> (item, idx), built at pull time
 
         def live_tuples():
@@ -642,7 +650,10 @@ class CheckBatcher:
         return segments
 
     def _loop(self) -> None:
+        clock = self.clock
+        bind_dispatch_clock(clock)  # the engine's transition sites find it
         while not self._stop.is_set():
+            clock.idle()
             with self._cond:
                 if not self._queued():
                     # bounded wait so stop() always terminates the loop
@@ -658,6 +669,7 @@ class CheckBatcher:
                     if remaining <= 0:
                         break
                     self._cond.wait(timeout=remaining)
+                clock.enter(TAKE)
                 segments = self._take_locked()
                 self._current_round = [item for item, _, _ in segments]
                 backlog = self._lane_tuples[BATCH]
@@ -668,6 +680,8 @@ class CheckBatcher:
             if self.admission is not None:
                 self.admission.tick(backlog=backlog)
             n_tuples = sum(count for _, _, count in segments)
+            clock.round(n_tuples, backlog)
+            clock.enter(RESOLVE)
             t0 = time.monotonic()
             try:
                 faults.check("check-dispatch")
@@ -682,6 +696,7 @@ class CheckBatcher:
                             [item.tuples[idx] for item, idx in emitted],
                             at_leasts, latests,
                         )
+                        clock.enter(FILL)
                         for (item, idx), allowed in zip(emitted, results):
                             self._fill(item, idx, bool(allowed), token)
             except Exception as e:

@@ -94,6 +94,29 @@ RUNGS = ("staging", "labels", "reverse", "warm-ladder", "overlay-budget",
          "tenant-lru")
 
 
+#: ``keto_device_memory_bytes{kind}`` <- the key of ``memory_stats()``
+_MEMORY_KINDS = (
+    ("in_use", "bytes_in_use"), ("peak", "peak_bytes_in_use"), ("limit", "bytes_limit"),
+)
+
+
+def device_memory_rows() -> list[tuple[tuple[str, str], float]]:
+    """``((device id, kind), bytes)`` for every local device, straight
+    from the runtime's ``memory_stats()`` — what the ledger above cannot
+    see (on a mesh, everything not row-sharded sits on device 0). Empty
+    where the backend keeps no stats (the CPU backend)."""
+    import jax
+
+    rows = []
+    for device in jax.local_devices():
+        stats = device.memory_stats() or {}
+        rows += [
+            ((str(device.id), kind), float(stats[key]))
+            for kind, key in _MEMORY_KINDS if key in stats
+        ]
+    return rows
+
+
 def device_budget_bytes(
     headroom_frac: float = DEFAULT_HEADROOM_FRAC, deterministic: bool = False
 ) -> int:

@@ -647,7 +647,14 @@ class Registry:
                 # (Daemon._warm_snapshot → engine.warm_compile) hits disk
                 # instead of recompiling the whole width ladder
                 from keto_tpu.driver import compile_cache
+                from keto_tpu.x import profiling
 
+                # both before the first kernel compiles: what compiling
+                # costs is counted from the runtime, and a profiler
+                # session — whoever opens it — is seen by the dispatch
+                # thread's state clock
+                compile_cache.install_listener()
+                profiling.install_trace_hook()
                 try:
                     cc_dir = compile_cache.configure(
                         str(self._config.get("serve.compile_cache_dir", "") or ""),
@@ -945,6 +952,11 @@ class Registry:
                         self._config.get("serve.admission_min_window", 64)
                     ),
                     max_window=max_pending,
+                )
+                # declared with the registry (metrics()); None when
+                # metrics are off
+                admission.attach_queue_delay_histogram(
+                    self.metrics().family("keto_admission_queue_delay_seconds")
                 )
             b = CheckBatcher(
                 engine,
@@ -1410,6 +1422,13 @@ class Registry:
                 ("stage",),
             )
             m.histogram(
+                "keto_admission_queue_delay_seconds",
+                "Queue delay the admission controller estimated at "
+                "each evaluated tick (batch-lane backlog over its "
+                "EWMA of dispatch throughput): how near the latency "
+                "budget the estimate runs.",
+            )
+            m.histogram(
                 "keto_replication_apply_delay_seconds",
                 "Replica mode: wall time from the primary's commit to "
                 "the change being visible through this replica's 412 "
@@ -1523,6 +1542,103 @@ class Registry:
             "Slice service-time p99 the admission controller last judged "
             "(same DurationStats the stream width controller steers by).",
             admission_attr("last_p99_ms", 1e-3),
+        )
+
+        from keto_tpu.driver.admission import SIGNALS
+
+        def admission_decreases():
+            b = self.peek("check_batcher")
+            a = getattr(b, "admission", None) if b is not None else None
+            by = getattr(a, "decreases_by_signal", {}) if a is not None else {}
+            return [((signal,), float(by.get(signal, 0))) for signal in SIGNALS]
+
+        m.register_callback(
+            "keto_admission_decreases_total", "counter",
+            "Multiplicative decreases of the admission window, by the "
+            "signal that tripped: slice_p99 (slow slices), queue_delay "
+            "(backlog over throughput past the budget), stall (a deep "
+            "queue and nothing landing).",
+            admission_decreases, ("signal",),
+        )
+        m.register_callback(
+            "keto_admission_increases_total", "counter",
+            "Additive increases of the admission window (healthy ticks).",
+            admission_attr("increases"),
+        )
+        m.register_callback(
+            "keto_admission_rate_tuples_per_second", "gauge",
+            "The admission controller's EWMA of dispatch throughput over "
+            "rounds — the divisor of its queue-delay estimate.",
+            admission_attr("rate_tuples_per_s"),
+        )
+
+        from keto_tpu.x.timeline import DISPATCH_STATES
+
+        def clock_snapshot():
+            b = self.peek("check_batcher")
+            clock = getattr(b, "clock", None) if b is not None else None
+            if clock is None:
+                return [0.0] * len(DISPATCH_STATES), 0
+            return clock.snapshot()
+
+        m.register_callback(
+            "keto_dispatch_thread_seconds_total", "counter",
+            "Wall time of the batcher's one dispatch thread by state; the "
+            "states are exclusive and sum to the thread's life. "
+            "wait_work aside, this is what a round costs the host.",
+            lambda: [
+                ((state,), seconds)
+                for state, seconds in zip(DISPATCH_STATES, clock_snapshot()[0])
+            ],
+            ("state",),
+        )
+        m.register_callback(
+            "keto_dispatch_rounds_total", "counter",
+            "Dispatch rounds the batcher's collector has taken off the lanes.",
+            lambda: [((), float(clock_snapshot()[1]))],
+        )
+
+        def compile_counts(i):
+            def read():
+                from keto_tpu.driver.compile_cache import COMPILES
+
+                yield (), float(COMPILES.snapshot()[i])
+
+            return read
+
+        m.register_callback(
+            "keto_compile_seconds_total", "counter",
+            "Seconds in XLA backend compiles since the device engine was "
+            "built (jax.monitoring; loading a program from the persistent "
+            "cache counts its retrieval time).",
+            compile_counts(0),
+        )
+        m.register_callback(
+            "keto_compiles_total", "counter",
+            "XLA backend compile requests (jax.monitoring), programs "
+            "loaded from the persistent cache included.",
+            compile_counts(1),
+        )
+        m.register_callback(
+            "keto_compile_cache_hits_total", "counter",
+            "Compile requests the persistent compilation cache answered.",
+            compile_counts(2),
+        )
+
+        def device_memory():
+            rows = []
+            if self._device_info is not None:
+                from keto_tpu.driver.hbm import device_memory_rows
+
+                rows = device_memory_rows()
+            return rows or [(("none", "in_use"), 0.0)]
+
+        m.register_callback(
+            "keto_device_memory_bytes", "gauge",
+            "Device memory per local device as the runtime's "
+            "memory_stats() reports it: kind is in_use, peak or limit "
+            "(device=none, 0 where the backend keeps no stats).",
+            device_memory, ("device", "kind"),
         )
 
         def maintenance_raw():

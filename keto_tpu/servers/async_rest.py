@@ -25,6 +25,7 @@ import asyncio
 import json
 import logging
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from http import HTTPStatus
 from typing import Optional
@@ -156,13 +157,11 @@ class AsyncRestServer:
         """Wait (from any thread) until no request exchange is mid-flight
         — every accepted request has had its response flushed. True when
         idle within ``timeout_s``."""
-        import time as _time
-
-        deadline = _time.monotonic() + max(0.0, timeout_s)
-        while _time.monotonic() < deadline:
+        deadline = time.monotonic() + max(0.0, timeout_s)
+        while time.monotonic() < deadline:
             if self._active == 0:
                 return True
-            _time.sleep(0.01)
+            time.sleep(0.01)
         return self._active == 0
 
     def stop(self) -> None:
@@ -238,6 +237,9 @@ class AsyncRestServer:
                     )
                     return
                 body = await reader.readexactly(length) if length else b""
+                # stage pool_wait starts here, on the event loop's clock
+                # reading; the handler's pool thread ends it
+                t_read = time.perf_counter()
                 parts = urlsplit(target)
                 query = parse_qs(parts.query, keep_blank_values=True)
                 close = (
@@ -266,14 +268,17 @@ class AsyncRestServer:
                 streamed = False
                 try:
                     pool = self._batch_pool if is_batch else self._pool
-                    status, payload, extra = await asyncio.get_running_loop().run_in_executor(
-                        pool, self.app.handle, method, parts.path, query, body,
-                        headers,
+                    status, payload, extra, t_handled = (
+                        await asyncio.get_running_loop().run_in_executor(
+                            pool, self.app.handle_timed, t_read, method,
+                            parts.path, query, body, headers,
+                        )
                     )
                     if isinstance(payload, StreamBody):
                         streamed = True
                     else:
                         await self._write_response(writer, status, payload, extra, close)
+                        self.app.note_written(t_handled)
                 finally:
                     self._active -= 1
                     if is_batch:

@@ -167,6 +167,11 @@ class RestApp:
             "carries a trace_id exemplar.",
             ("role", "method", "route"),
         )
+        # the listener's two stages around a timeline (x/timeline
+        # LISTENER_STAGES) go straight into the stage histogram the
+        # recorder mirrors timelines into (declared with the registry;
+        # None when metrics are off)
+        self._stage_hist = m.family("keto_timeline_stage_duration_seconds")
 
     # -- dispatch ------------------------------------------------------------
 
@@ -182,11 +187,29 @@ class RestApp:
         ``headers`` are the request headers, lowercase-keyed (deadline
         propagation, trace context); absent for callers that don't carry
         them."""
+        return self.handle_timed(None, method, path, query, body, headers)[:3]
+
+    def handle_timed(
+        self,
+        t_read: Optional[float],
+        method: str,
+        path: str,
+        query: dict[str, list[str]],
+        body: bytes,
+        headers: Optional[dict[str, str]] = None,
+    ):
+        """``handle`` for a listener that keeps time: ``t_read`` is its
+        ``perf_counter()`` reading once head and body were read, and the
+        wait from there to here (for a pool thread) is observed as stage
+        ``pool_wait``. Returns ``(status, payload, headers, t_handled)``;
+        the listener hands ``t_handled`` to ``note_written`` once the
+        response is flushed. 0.0 where no timeline was recorded (health,
+        scrapes): nothing is observed for those."""
         # request span + usage counter + metrics (health endpoints
         # excluded), matching the reference's middleware placement
         # (registry_default.go:288-300)
         if path.startswith("/health/"):
-            return self._route(method, path, query, body, headers)
+            return (*self._route(method, path, query, body, headers), 0.0)
         hdrs = headers or {}
         route = normalize_route(path)
         # correlation: echo the caller's request id or mint one; join the
@@ -225,7 +248,8 @@ class RestApp:
                 # stamp request_id/trace_id onto the record, same ids as
                 # the span and the response headers
                 self._log.debug("%s %s %s -> %d", self.role, method, path, status)
-        dur_s = time.perf_counter() - t0
+        t_handled = time.perf_counter()
+        dur_s = t_handled - t0
         self._req_count.inc((self.role, method, route, str(status)))
         self._req_latency.observe((self.role, method, route), dur_s, trace_id=trace_id)
         resp_headers = dict(resp_headers)
@@ -242,7 +266,22 @@ class RestApp:
                 resp_headers.setdefault(
                     "Server-Timing", recorder.server_timing(tl)
                 )
-        return status, payload, resp_headers
+            if self._stage_hist is not None:
+                if t_read is not None:
+                    self._stage_hist.observe(("pool_wait",), t0 - t_read)
+                return status, payload, resp_headers, t_handled
+        return status, payload, resp_headers, 0.0
+
+    def note_written(self, t_handled: float) -> None:
+        """The listener flushed the response of a request whose
+        ``handle_timed`` returned ``t_handled``: stage ``encode_write``
+        (the hand-back to the listener, ``json.dumps``, the write). It
+        ends after the timeline's ``deliver``, so it goes straight into
+        the histogram."""
+        if t_handled:
+            self._stage_hist.observe(
+                ("encode_write",), time.perf_counter() - t_handled
+            )
 
     def note_listener_shed(self, method: str, path: str) -> None:
         """Record a listener-level 429 (shed on the event loop before any
@@ -786,11 +825,22 @@ class RestApp:
             resp_headers["X-Keto-Snaptoken"] = resp["snaptoken"]
         return 200, resp, resp_headers
 
+    @staticmethod
+    def _stamp_decoded() -> None:
+        """Stage ``decode`` ends here: the body or query is relation
+        tuples now. One stamp, whatever the batch size."""
+        from keto_tpu.x.timeline import current_timeline
+
+        tl = current_timeline()
+        if tl is not None:
+            tl.stamp("decode")
+
     def _get_check(self, query, headers=None):
         try:
             tuple_ = RelationTuple.from_url_query(query)
         except ErrNilSubject:
             raise ErrBadRequest("Subject has to be specified.") from None
+        self._stamp_decoded()
         return self._check(tuple_, query, headers)
 
     def _post_check(self, body: bytes, query, headers=None):
@@ -798,7 +848,9 @@ class RestApp:
             obj = json.loads(body or b"{}")
         except json.JSONDecodeError as e:
             raise ErrBadRequest(f"Unable to decode JSON payload: {e}") from None
-        return self._check(RelationTuple.from_json(obj), query, headers)
+        tuple_ = RelationTuple.from_json(obj)
+        self._stamp_decoded()
+        return self._check(tuple_, query, headers)
 
     def _post_check_batch(self, body: bytes, query, headers=None):
         """Many checks in one request: ``{"tuples": [...]}`` →
@@ -828,6 +880,7 @@ class RestApp:
                 f"{MAX_BATCH_CHECK}); split the request"
             )
         tuples = [RelationTuple.from_json(t) for t in raw]
+        self._stamp_decoded()
         at_least, latest = self._consistency_from(query)
         rep = scope.replica_controller()
         if rep is not None:
@@ -1130,8 +1183,10 @@ def _make_handler(app: RestApp):
                 length = int(self.headers.get("Content-Length") or 0)
                 body = self.rfile.read(length) if length else b""
                 req_headers = {k.lower(): v for k, v in self.headers.items()}
-                status, payload, headers = app.handle(
-                    method, parts.path, query, body, req_headers
+                # no pool on this backend: the connection's own thread
+                # handles, so there is no pool_wait to observe
+                status, payload, headers, t_handled = app.handle_timed(
+                    None, method, parts.path, query, body, req_headers
                 )
                 if isinstance(payload, StreamBody):
                     self._serve_stream(status, payload, headers)
@@ -1149,6 +1204,7 @@ def _make_handler(app: RestApp):
                 self.end_headers()
                 if data:
                     self.wfile.write(data)
+                app.note_written(t_handled)
             finally:
                 with self.server.active_lock:
                     self.server.active_count -= 1

@@ -41,7 +41,8 @@ from typing import Any, Iterator, Optional
 #: canonical stage names, in pipeline order (attrs ride the device stage:
 #: width / bfs_steps / route / halo_rounds / halo_bytes / service_ms)
 STAGES = (
-    "arrival",    # request decoded, correlation ids bound (timeline birth)
+    "arrival",    # correlation ids bound on a handler thread (timeline birth)
+    "decode",     # body / query parsed into relation tuples
     "admit",      # passed the admission window / lane-capacity door
     "shed",       # refused at the door instead (terminal with admit)
     "cache_hit",  # answered from the replica check cache (no dispatch)
@@ -53,6 +54,27 @@ STAGES = (
     "explain",    # witness reconstructed + verified (carries route/verified)
     "deliver",    # response handed back to the serving layer
 )
+
+#: listener-side label values of the stage histogram, observed by the
+#: REST layer around the timeline (they lie outside its ``total``):
+#: ``pool_wait`` is head+body read on the event loop -> the handler
+#: entered on a pool thread; ``encode_write`` is the handler returning ->
+#: the response encoded and flushed
+LISTENER_STAGES = ("pool_wait", "encode_write")
+
+#: the dispatch thread's states. The thread is in exactly one at any
+#: time, so their seconds sum to its wall time
+DISPATCH_STATES = (
+    "wait_work",    # on the batcher's condition: for items, or the window
+    "take",         # packing a round off the lanes
+    "resolve",      # pulling the round's tuples, tuples -> node ids
+    "pack",         # native or Python pack, staging
+    "launch",       # H2D + the jitted call returning
+    "device_wait",  # blocked on a slice's result
+    "fill",         # futures, timelines, audit sample
+)
+WAIT_WORK, TAKE, RESOLVE, PACK, LAUNCH, DEVICE_WAIT, FILL = range(7)
+_SPAN_NAMES = tuple(f"keto.dispatch.{s}" for s in DISPATCH_STATES)
 
 #: cap on stamps one timeline may hold — a 64k-tuple batch riding many
 #: sub-slices must not grow an unbounded stamp list (the flag records
@@ -69,6 +91,109 @@ def current_timeline() -> Optional["Timeline"]:
     seam the batcher/engine stamp through without threading a recorder
     handle down the call stack."""
     return _current_tl.get()
+
+
+class DispatchClock:
+    """Where the dispatch thread's wall time goes, by state.
+
+    Only the owning thread calls ``idle``/``round``/``enter``; a
+    transition is one ``perf_counter`` read and one float add, whatever
+    the round's size. ``snapshot`` is for the scraper, lock-free: a
+    scrape that lands inside a transition may miss that one interval
+    until the next scrape, it never counts one twice.
+
+    While a ``jax.profiler`` session is open (``session.open``, read
+    once per loop pass in ``idle``) each state is also a
+    ``TraceAnnotation`` named ``keto.dispatch.<state>`` carrying the
+    round's ``tuples``, ``slices`` launched so far and ``lane_depth``:
+    contiguous spans on the device trace's clock, so an idle gap of the
+    device is named by what its one feeder was doing."""
+
+    __slots__ = (
+        "seconds", "rounds", "_state", "_t", "_session", "_tracing", "_ann",
+        "_tuples", "_slices", "_lane_depth",
+    )
+
+    def __init__(self, session=None):
+        if session is None:
+            from keto_tpu.x.profiling import SESSION as session
+        self.seconds = [0.0] * len(DISPATCH_STATES)
+        self.rounds = 0
+        self._state = WAIT_WORK
+        self._t = time.perf_counter()
+        self._session = session
+        self._tracing = False
+        self._ann = None
+        self._tuples = self._slices = self._lane_depth = 0
+
+    def enter(self, state: int) -> None:
+        now = time.perf_counter()
+        # _t moves first: snapshot() retries when it sees _t change
+        t, self._t = self._t, now
+        self.seconds[self._state] += now - t
+        self._state = state
+        if self._tracing or self._ann is not None:
+            self._annotate(state)
+
+    def idle(self) -> None:
+        """Top of a loop pass: back to ``wait_work``."""
+        self._tracing = self._session.open
+        self.enter(WAIT_WORK)
+
+    def round(self, tuples: int, lane_depth: int) -> None:
+        """A round was taken: what its spans will say of it."""
+        self.rounds += 1
+        self._tuples, self._slices, self._lane_depth = tuples, 0, lane_depth
+
+    def _annotate(self, state: int) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._tracing:
+            if state == LAUNCH:
+                self._slices += 1
+            self._ann = self._session.annotation(
+                _SPAN_NAMES[state], tuples=self._tuples, slices=self._slices,
+                lane_depth=self._lane_depth,
+            )
+            self._ann.__enter__()
+
+    def snapshot(self) -> tuple[list[float], int]:
+        """``(seconds by state, rounds)`` with the state in progress
+        counted up to now."""
+        for _ in range(4):
+            t, state = self._t, self._state
+            seconds = list(self.seconds)
+            if self._t == t:
+                break
+        seconds[state] += max(0.0, time.perf_counter() - t)
+        return seconds, self.rounds
+
+
+class _NoClock:
+    """What threads other than a dispatch thread get: the engine's
+    transition sites stay unconditional."""
+
+    __slots__ = ()
+
+    def enter(self, state: int) -> None:
+        pass
+
+
+_NO_CLOCK = _NoClock()
+_dispatch = threading.local()
+
+
+def bind_dispatch_clock(clock: Optional[DispatchClock]) -> None:
+    """Make ``clock`` the calling thread's (the batcher's collector
+    binds its own when it starts)."""
+    _dispatch.clock = clock
+
+
+def dispatch_clock():
+    """The calling thread's state clock — a no-op one off the dispatch
+    thread (library callers, warm-up, explain)."""
+    return getattr(_dispatch, "clock", None) or _NO_CLOCK
 
 
 class Timeline:
@@ -347,6 +472,11 @@ NOOP = TimelineRecorder(enabled=False)
 
 __all__ = [
     "STAGES",
+    "LISTENER_STAGES",
+    "DISPATCH_STATES",
+    "DispatchClock",
+    "bind_dispatch_clock",
+    "dispatch_clock",
     "MAX_STAMPS",
     "Timeline",
     "TimelineRecorder",

@@ -1,0 +1,595 @@
+"""One clock for the dispatch thread, the REST pool and the admission
+controller (keto_tpu/x/timeline.py ``DispatchClock``, x/profiling.py's
+trace hook, servers/{rest,async_rest}.py's listener stages,
+driver/admission.py's signals, driver/compile_cache.py's listener).
+
+The cost rule is tested by counting: nothing here may read the clock once
+per tuple, and no annotation object may exist while no profiler session
+is open."""
+
+import json
+import random
+import threading
+import time
+import urllib.error
+import urllib.request
+
+import numpy as np
+import pytest
+
+from keto_tpu.driver.admission import SIGNALS
+from keto_tpu.driver.batch import CheckBatcher
+from keto_tpu.relationtuple.model import RelationTuple, SubjectID
+from keto_tpu.x import profiling
+from keto_tpu.x.metrics import parse_exposition
+from keto_tpu.x.timeline import (
+    DEVICE_WAIT, DISPATCH_STATES, FILL, LAUNCH, LISTENER_STAGES, PACK, RESOLVE, STAGES,
+    TAKE, WAIT_WORK, DispatchClock, TimelineRecorder, dispatch_clock,
+)
+
+
+def _tuples(n):
+    return [
+        RelationTuple(namespace="docs", object=f"o{i}", relation="view",
+                      subject=SubjectID(f"u{i}"))
+        for i in range(n)
+    ]
+
+
+class StreamStub:
+    """The engine's streaming contract with the engine's transition sites:
+    one slice per round, six transitions per slice, whatever its width."""
+
+    def batch_check_stream_with_token(self, tuples_iter, ordered=False, **_kw):
+        def gen():
+            clk = dispatch_clock()
+            clk.enter(RESOLVE)
+            n = len(list(tuples_iter))
+            clk.enter(RESOLVE)
+            clk.enter(PACK)
+            clk.enter(LAUNCH)
+            clk.enter(DEVICE_WAIT)
+            clk.enter(FILL)
+            yield 0, np.ones(n, dtype=bool)
+
+        return gen(), 7
+
+
+class FakeSession:
+    """A ``ProfilerSession`` whose annotations are counted, not traced."""
+
+    def __init__(self):
+        self.open = False
+        self.made = []
+        self.events = []
+
+    def annotation(self, name, **args):
+        self.made.append((name, args))
+        session = self
+
+        class Span:
+            def __enter__(self):
+                session.events.append(("enter", name))
+
+            def __exit__(self, *exc):
+                session.events.append(("exit", name))
+
+        return Span()
+
+
+class CountingClock(DispatchClock):
+    __slots__ = ("idles",)
+
+    def __init__(self, session):
+        super().__init__(session)
+        self.idles = 0
+
+    def idle(self):
+        self.idles += 1
+        super().idle()
+
+
+def _count_clock_reads(monkeypatch):
+    reads = {}
+    real = time.perf_counter
+
+    def counting():
+        name = threading.current_thread().name
+        reads[name] = reads.get(name, 0) + 1
+        return real()
+
+    monkeypatch.setattr(time, "perf_counter", counting)
+    return reads
+
+
+def _drive(n_tuples, monkeypatch, requests=3):
+    """``requests`` sequential batch calls of ``n_tuples``, one round each.
+    Returns clock reads per round on the dispatch thread (loop passes
+    taken out: an idle pass is one read, however long the test sleeps),
+    clock reads per request on the caller's thread, and the session."""
+    session = FakeSession()
+    rec = TimelineRecorder()
+    b = CheckBatcher(StreamStub(), batch_size=8192, batch_sub_slice=8192, window_ms=0.1)
+    b.clock = CountingClock(session)
+    tuples = _tuples(n_tuples)
+    reads = _count_clock_reads(monkeypatch)
+    b.start()
+    try:
+        me = threading.current_thread().name
+        before = reads.get(me, 0)
+        for _ in range(requests):
+            tl = rec.begin("POST /check/batch")
+            with rec.activate(tl):
+                out = b.check_batch(tuples, lane="batch")
+            rec.finish(tl, status=200)
+            assert out == [True] * n_tuples
+        caller = reads.get(me, 0) - before
+    finally:
+        b.stop()
+    monkeypatch.undo()
+    assert b.clock.rounds == requests
+    per_round = (reads["check-batcher"] - b.clock.idles) / requests
+    return per_round, caller / requests, session
+
+
+# -- (a) the cost rule -----------------------------------------------------------
+
+
+def test_clock_reads_per_round_and_request_do_not_grow_with_batch_size(monkeypatch):
+    small = _drive(64, monkeypatch)
+    large = _drive(4096, monkeypatch)
+    assert small[0] == large[0], "clock reads per dispatch round depend on its size"
+    assert small[1] == large[1], "clock reads per request depend on its size"
+    # take + resolve + the stub's six, and the timeline's pack/dispatch/land
+    assert small[0] == 2 + 6 + 3
+
+
+@pytest.mark.parametrize("n_tuples", [64, 4096])
+def test_no_annotation_is_built_while_no_session_is_open(monkeypatch, n_tuples):
+    _, _, session = _drive(n_tuples, monkeypatch)
+    assert session.made == []
+
+
+def test_annotations_follow_the_session_flag(monkeypatch):
+    session = FakeSession()
+    b = CheckBatcher(StreamStub(), batch_size=8192, batch_sub_slice=8192, window_ms=0.1)
+    b.clock = DispatchClock(session)
+    b.start()
+    try:
+        b.check_batch(_tuples(32), lane="batch")
+        assert session.made == []
+        session.open = True
+        time.sleep(0.3)  # the collector re-reads the flag at its next loop pass
+        b.check_batch(_tuples(32), lane="batch")
+        time.sleep(0.05)
+        names = [name for name, _ in session.made]
+        for state in DISPATCH_STATES:
+            assert f"keto.dispatch.{state}" in names
+        launch = next(args for name, args in session.made if name.endswith(".launch"))
+        assert launch == {"tuples": 32, "slices": 1, "lane_depth": 0}
+        session.open = False
+        time.sleep(0.3)
+        made = len(session.made)
+        b.check_batch(_tuples(32), lane="batch")
+        assert len(session.made) == made
+    finally:
+        b.stop()
+    # every span was closed before the next opened, and none is left open
+    assert session.events[0][0] == "enter"
+    for (a, _), (b_, _) in zip(session.events, session.events[1:]):
+        assert a != b_
+    assert session.events[-1][0] == "exit"
+
+
+# -- (b) the state clock ----------------------------------------------------------
+
+
+def test_clock_unit_accumulates_by_state():
+    clock = DispatchClock(FakeSession())
+    t0 = time.perf_counter()
+    clock.enter(TAKE)
+    time.sleep(0.02)
+    clock.enter(PACK)
+    time.sleep(0.01)
+    seconds, rounds = clock.snapshot()
+    wall = time.perf_counter() - t0
+    assert rounds == 0
+    assert seconds[TAKE] == pytest.approx(0.02, abs=0.01)
+    assert seconds[PACK] >= 0.01  # the state in progress is counted up to now
+    assert sum(seconds) == pytest.approx(wall, rel=0.01, abs=2e-4)
+    clock.round(10, 3)
+    assert clock.snapshot()[1] == 1
+
+
+def test_states_sum_to_the_threads_wall_time_and_wait_work_grows_when_idle():
+    t0 = time.perf_counter()
+    b = CheckBatcher(StreamStub(), batch_size=8192, batch_sub_slice=8192, window_ms=0.1)
+    b.start()
+    try:
+        for _ in range(5):
+            b.check_batch(_tuples(512), lane="batch")
+        first, _ = b.clock.snapshot()
+        time.sleep(0.3)
+        second, rounds = b.clock.snapshot()
+        wall = time.perf_counter() - t0
+    finally:
+        b.stop()
+    assert rounds == 5
+    assert sum(second) == pytest.approx(wall, rel=0.01)
+    assert second[WAIT_WORK] - first[WAIT_WORK] == pytest.approx(0.3, abs=0.03)
+    for state in (TAKE, RESOLVE, PACK, LAUNCH, DEVICE_WAIT, FILL):
+        assert second[state] == first[state], DISPATCH_STATES[state]
+        assert second[state] > 0
+
+
+def test_threads_other_than_the_collector_get_the_noop_clock():
+    clk = dispatch_clock()
+    clk.enter(PACK)  # must not raise, must not be a DispatchClock
+    assert not isinstance(clk, DispatchClock)
+
+
+def test_snapshot_under_a_racing_writer_never_counts_an_interval_twice():
+    """More writers' transitions than the scraper can see apart: the sum
+    of a snapshot may miss the interval in flight, it never exceeds the
+    clock's age."""
+    import sys
+
+    clock = DispatchClock(FakeSession())
+    born = time.perf_counter()
+    stop = threading.Event()
+
+    def writer():
+        i = 0
+        while not stop.is_set():
+            clock.enter(i % len(DISPATCH_STATES))
+            i += 1
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t = threading.Thread(target=writer, daemon=True)
+    t.start()
+    try:
+        deadline = time.monotonic() + 0.5
+        last = 0.0
+        while time.monotonic() < deadline:
+            total = sum(clock.snapshot()[0])
+            age = time.perf_counter() - born
+            assert total <= age + 1e-4
+            assert total >= last - 1e-4 or total >= age - 0.05
+            last = total
+    finally:
+        stop.set()
+        t.join(timeout=5)
+        sys.setswitchinterval(old)
+    assert not t.is_alive()
+
+
+# -- a live daemon: /metrics, the REST stages, the engine's transition sites -----
+
+NAMESPACES = [{"id": 0, "name": "docs"}, {"id": 1, "name": "groups"}]
+
+
+def _boot(**overrides):
+    from keto_tpu.config.provider import Config
+    from keto_tpu.driver.daemon import Daemon
+    from keto_tpu.driver.registry import Registry
+
+    cfg = Config(overrides={
+        "namespaces": NAMESPACES, "dsn": "memory",
+        "serve.read.port": 0, "serve.write.port": 0, **overrides,
+    })
+    d = Daemon(Registry(cfg))
+    d.serve_all(block=False)
+    for body in (
+        {"namespace": "groups", "object": "g", "relation": "member", "subject_id": "ann"},
+        {"namespace": "docs", "object": "readme", "relation": "view",
+         "subject_set": {"namespace": "groups", "object": "g", "relation": "member"}},
+    ):
+        urllib.request.urlopen(urllib.request.Request(
+            f"http://127.0.0.1:{d.write_port}/relation-tuples",
+            data=json.dumps(body).encode(), method="PUT",
+            headers={"Content-Type": "application/json"},
+        ), timeout=10)
+    return d
+
+
+@pytest.fixture(scope="module")
+def daemon():
+    d = _boot()
+    yield d
+    d.shutdown()
+
+
+@pytest.fixture(scope="module")
+def threading_daemon():
+    d = _boot(**{"serve.http_backend": "threading"})
+    yield d
+    d.shutdown()
+
+
+def _request(port, path, body=None):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}{path}",
+        data=None if body is None else json.dumps(body).encode(),
+        method="GET" if body is None else "POST",
+        headers={"Content-Type": "application/json"},
+    )
+    try:
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            return resp.status, resp.read(), dict(resp.headers)
+    except urllib.error.HTTPError as e:
+        return e.code, e.read(), dict(e.headers)
+
+
+def _scrape(d):
+    _, raw, _ = _request(d.read_port, "/metrics")
+    return parse_exposition(raw.decode())
+
+
+def _value(families, family, sample=None, **labels):
+    sample = sample or family
+    return sum(
+        v for name, have, v in families[family]["samples"]
+        if name == sample and all(have.get(k) == want for k, want in labels.items())
+    )
+
+
+def _batch(d, n=40):
+    tuples = [
+        {"namespace": "docs", "object": "readme", "relation": "view",
+         "subject_id": "ann" if i % 2 else f"nobody-{i}"}
+        for i in range(n)
+    ]
+    status, raw, headers = _request(d.read_port, "/check/batch", {"tuples": tuples})
+    assert status == 200
+    assert json.loads(raw)["results"] == [bool(i % 2) for i in range(n)]
+    return headers
+
+
+def _stage_counts(d, stages, deadline_s=5.0):
+    """``encode_write`` is observed after the response is flushed: poll."""
+    deadline = time.monotonic() + deadline_s
+    while True:
+        fams = _scrape(d)
+        counts = {
+            s: _value(fams, "keto_timeline_stage_duration_seconds",
+                      "keto_timeline_stage_duration_seconds_count", stage=s)
+            for s in stages
+        }
+        if all(counts.values()) or time.monotonic() > deadline:
+            return counts
+        time.sleep(0.05)
+
+
+def _server_timing(headers):
+    entries = [e.strip().split(";dur=") for e in headers["Server-Timing"].split(",")]
+    return {name: float(ms) for name, ms in entries}
+
+
+# -- (d) the REST stages ------------------------------------------------------------
+
+
+def test_batch_call_over_async_rest_feeds_the_three_new_stages(daemon):
+    before = _stage_counts(daemon, (), 0)
+    assert before == {}
+    headers = _batch(daemon)
+    counts = _stage_counts(daemon, ("pool_wait", "decode", "encode_write"))
+    assert all(c >= 1 for c in counts.values()), counts
+    timing = _server_timing(headers)
+    total = timing.pop("total")
+    # the listener's stages lie outside the timeline; decode is inside it
+    assert "decode" in timing and not set(LISTENER_STAGES) & set(timing)
+    for stage in ("admit", "pack", "dispatch", "device", "land", "deliver"):
+        assert stage in timing
+    # each entry is rounded to 0.01 ms
+    assert sum(timing.values()) == pytest.approx(total, abs=0.005 * (len(timing) + 1))
+
+
+def test_single_get_check_stamps_decode(daemon):
+    status, _, headers = _request(
+        daemon.read_port, "/check?namespace=docs&object=readme&relation=view&subject_id=ann"
+    )
+    assert status == 200
+    timing = _server_timing(headers)
+    assert list(timing)[:2] == ["decode", "admit"]
+
+
+def test_threading_backend_has_no_pool_and_observes_no_pool_wait(threading_daemon):
+    _batch(threading_daemon)
+    counts = _stage_counts(threading_daemon, ("decode", "encode_write"))
+    assert all(c >= 1 for c in counts.values()), counts
+    assert _stage_counts(threading_daemon, ("pool_wait",), 0) == {"pool_wait": 0}
+
+
+def test_scrapes_and_health_checks_are_not_observed_as_stages(daemon):
+    _batch(daemon)
+    first = _stage_counts(daemon, ("pool_wait", "encode_write"))
+    for _ in range(3):
+        _request(daemon.read_port, "/health/ready")
+        _scrape(daemon)
+    time.sleep(0.1)
+    assert _stage_counts(daemon, ("pool_wait", "encode_write")) == first
+
+
+def test_stage_names_are_declared():
+    assert "decode" in STAGES and STAGES.index("decode") == STAGES.index("admit") - 1
+    assert LISTENER_STAGES == ("pool_wait", "encode_write")
+    assert not set(LISTENER_STAGES) & set(STAGES)
+
+
+# -- (b) on /metrics, with the real engine's transition sites ------------------------
+
+
+@pytest.mark.parametrize("state", DISPATCH_STATES)
+def test_every_dispatch_state_is_on_metrics_and_accrues_under_traffic(daemon, state):
+    _batch(daemon)
+    fams = _scrape(daemon)
+    assert fams["keto_dispatch_thread_seconds_total"]["type"] == "counter"
+    assert _value(fams, "keto_dispatch_thread_seconds_total", state=state) > 0
+    assert _value(fams, "keto_dispatch_rounds_total") >= 1
+
+
+def test_dispatch_states_of_a_window_sum_to_its_length(daemon):
+    def read():
+        t = time.perf_counter()
+        fams = _scrape(daemon)
+        return t, _value(fams, "keto_dispatch_thread_seconds_total")
+
+    t0, s0 = read()
+    for _ in range(5):
+        _batch(daemon, 200)
+    time.sleep(0.5)
+    t1, s1 = read()
+    # the scrape itself takes a few ms between the host's reading and the clock's
+    assert s1 - s0 == pytest.approx(t1 - t0, rel=0.02, abs=0.02)
+
+
+# -- (c) a real profiler session on the CPU backend -------------------------------
+
+
+def test_profiler_capture_holds_contiguous_dispatch_spans_on_one_thread(daemon, tmp_path):
+    import jax
+
+    assert profiling.install_trace_hook() is profiling.SESSION
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    assert not profiling.SESSION.open
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        assert profiling.SESSION.open
+        time.sleep(0.3)  # an idle loop pass reads the flag
+        for _ in range(4):
+            _batch(daemon, 100)
+        time.sleep(0.3)
+    finally:
+        jax.profiler.stop_trace()
+    assert not profiling.SESSION.open
+    _batch(daemon)  # closes the last span; opens none
+
+    found = sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(found[-1]))
+    threads = []  # one line per thread; their names need not differ
+    for plane in data.planes:
+        for line in plane.lines:
+            spans = sorted(
+                (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name, dict(ev.stats))
+                for ev in line.events if ev.name.startswith("keto.dispatch.")
+            )
+            if spans:
+                threads.append(spans)
+    for spans in threads:
+        covered = spans[-1][1] - spans[0][0]
+        gaps = 0.0
+        for (_, end, _, _), (start, _, _, _) in zip(spans, spans[1:]):
+            assert start >= end - 1000, "dispatch spans overlap"  # 1 us of rounding
+            gaps += max(0.0, start - end)
+        assert gaps < 0.02 * covered, "dispatch spans are not contiguous"
+    # another daemon of this module idles beside this one: its collector
+    # waits for work on a thread of its own, and only waits
+    busy = [spans for spans in threads if any(n.endswith(".launch") for _, _, n, _ in spans)]
+    assert len(busy) == 1, "the rounds' spans lie on more than one thread"
+    spans = busy[0]
+    assert {name for _, _, name, _ in spans} == {f"keto.dispatch.{s}" for s in DISPATCH_STATES}
+    launch = next(stats for _, _, name, stats in spans if name.endswith(".launch"))
+    assert launch["tuples"] == 100 and launch["slices"] >= 1 and "lane_depth" in launch
+
+
+def test_trace_hook_is_installed_once_and_wraps_both_functions():
+    import jax
+
+    profiling.install_trace_hook()
+    start, stop = jax.profiler.start_trace, jax.profiler.stop_trace
+    profiling.install_trace_hook()
+    assert jax.profiler.start_trace is start and jax.profiler.stop_trace is stop
+    assert hasattr(start, "__wrapped__") and hasattr(stop, "__wrapped__")
+    assert profiling.SESSION.annotation is jax.profiler.TraceAnnotation
+
+
+def test_a_start_trace_that_fails_leaves_the_session_closed(tmp_path):
+    import jax
+
+    profiling.install_trace_hook()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with pytest.raises(Exception):
+            jax.profiler.start_trace(str(tmp_path))  # one session at a time
+        assert profiling.SESSION.open  # the first one still is
+    finally:
+        jax.profiler.stop_trace()
+    assert not profiling.SESSION.open
+
+
+# -- admission and compiles on /metrics ((e)'s replay: tests/test_admission_replay.py) --
+
+
+def test_admission_families_on_metrics(daemon):
+    _batch(daemon)
+    fams = _scrape(daemon)
+    signals = {have["signal"] for _, have, _ in
+               fams["keto_admission_decreases_total"]["samples"]}
+    assert signals == set(SIGNALS)
+    assert fams["keto_admission_queue_delay_seconds"]["type"] == "histogram"
+    assert fams["keto_admission_increases_total"]["type"] == "counter"
+    assert _value(fams, "keto_admission_rate_tuples_per_second") > 0
+
+
+# -- (f) compiles and device memory, from the runtime --------------------------------
+
+
+def test_compile_listener_counts_a_fresh_compile_and_nothing_on_a_second_call():
+    import jax
+    import jax.numpy as jnp
+
+    from keto_tpu.driver import compile_cache
+
+    counts = compile_cache.install_listener()
+    assert compile_cache.install_listener() is counts  # registered once
+
+    salt = random.random()  # a program this process has not compiled
+
+    @jax.jit
+    def fresh(x):
+        return x * salt + 25.0
+
+    x = jnp.ones(7)  # an eager op is a program of its own: compiled before the count
+    s0, n0, _ = counts.snapshot()
+    fresh(x).block_until_ready()
+    s1, n1, _ = counts.snapshot()
+    assert n1 == n0 + 1 and s1 > s0
+    fresh(x).block_until_ready()
+    assert counts.snapshot()[:2] == (s1, n1)
+
+
+def test_compile_and_memory_families_on_metrics(daemon):
+    _batch(daemon)
+    fams = _scrape(daemon)
+    assert _value(fams, "keto_compiles_total") >= 1  # the daemon's own kernels
+    assert _value(fams, "keto_compile_seconds_total") > 0
+    assert fams["keto_compile_cache_hits_total"]["type"] == "counter"
+    # the CPU backend keeps no memory stats: the family is there, valued 0
+    rows = fams["keto_device_memory_bytes"]["samples"]
+    assert rows and all(set(have) == {"device", "kind"} for _, have, _ in rows)
+
+
+def test_device_memory_rows_read_every_local_device(monkeypatch):
+    import jax
+
+    from keto_tpu.driver import hbm
+
+    class Dev:
+        def __init__(self, id_, stats):
+            self.id, self._stats = id_, stats
+
+        def memory_stats(self):
+            return self._stats
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [
+        Dev(0, {"bytes_in_use": 5, "peak_bytes_in_use": 9, "bytes_limit": 100, "other": 1}),
+        Dev(1, {"bytes_in_use": 2}),
+        Dev(2, None),
+    ])
+    assert hbm.device_memory_rows() == [
+        (("0", "in_use"), 5.0), (("0", "peak"), 9.0), (("0", "limit"), 100.0),
+        (("1", "in_use"), 2.0),
+    ]
