@@ -61,6 +61,9 @@ from jax import lax
 
 from keto_tpu import namespace as namespace_pkg
 from keto_tpu.check import native_pack
+from keto_tpu.check.frame import (
+    DEAD, NO_TARGET, SPECIAL, QueryBatch, QueryFrame, as_tuples, pick_tuples,
+)
 from keto_tpu.driver.hbm import HbmGovernor, MemoryPressure, is_resource_exhausted
 from keto_tpu.graph.snapshot import WILDCARD, GraphSnapshot
 from keto_tpu.relationtuple.model import RelationTuple, SubjectID, SubjectSet
@@ -1842,12 +1845,12 @@ class TpuCheckEngine:
             return
         rng = self._audit_rng
         rate = self._audit_rate
-        picked = False
-        for i, rt in enumerate(tuples):
-            if rng.random() < rate:
+        # the draw comes first: a framed batch builds only the sampled
+        # queries as objects
+        idx = [i for i in range(len(tuples)) if rng.random() < rate]
+        if idx:
+            for i, rt in zip(idx, pick_tuples(tuples, idx, "audit")):
                 self._audit_pending.append((rt, bool(decisions[i]), token))
-                picked = True
-        if picked:
             self._audit_task.kick()
 
     def _audit_pass(self) -> None:
@@ -3324,18 +3327,74 @@ class TpuCheckEngine:
     # -- resolution ----------------------------------------------------------
 
     def _resolve_bulk(
-        self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]
+        self, snap: GraphSnapshot, tuples
     ) -> tuple[np.ndarray, np.ndarray, dict]:
         """Resolve every query to device rows (see ``_resolve_bulk_py`` for
         the result contract). Literal queries go through the C++ intern
         tables in one bulk call when the native library provides it;
         wildcard/pattern/unknown-namespace queries and the pure-Python
-        interner use the host loop."""
+        interner use the host loop.
+
+        ``tuples`` is a list of ``RelationTuple`` or a ``QueryBatch``
+        (keto_tpu/check/frame.py): framed ranges bring their records with
+        them and are resolved without a loop over tuples. Where the
+        records cannot be trusted against this snapshot (see
+        ``_frame_blocker``) the batch is turned into objects and takes
+        the list's path."""
+        if isinstance(tuples, QueryBatch):
+            why = self._frame_blocker(snap, tuples)
+            if why is None:
+                got = self._resolve_records(snap, tuples, *self._records_of(snap, tuples))
+                if got is not None:
+                    return got
+                why = "rejected"
+            tuples = tuples.tuples(why)
         if hasattr(snap.interned, "resolve_queries"):
             got = self._resolve_bulk_native(snap, tuples)
             if got is not None:
                 return got
         return self._resolve_bulk_py(snap, tuples)
+
+    def _frame_blocker(self, snap: GraphSnapshot, batch: QueryBatch) -> Optional[str]:
+        """Why ``batch``'s framed records cannot be resolved as they are
+        against ``snap`` (None: they can). Read off the snapshot and the
+        frames, never off a setting: an interner without the bulk entry
+        point; a namespace named "" (the framer assumes there is none);
+        a frame whose namespace ids came from a manager that is no longer
+        the current one (hot reload between framing and resolve)."""
+        if not hasattr(snap.interned, "resolve_queries"):
+            return "no_native"
+        if snap.wild_ns_ids:
+            return "wild_ns"
+        nm = self._nm()
+        for src, _a, _b in batch.parts:
+            if isinstance(src, QueryFrame) and src.manager is not nm:
+                return "reload"
+        return None
+
+    def _records_of(self, snap: GraphSnapshot, batch: QueryBatch):
+        """``batch`` as one buffer of query records plus the indices the
+        records cannot speak for: ``(buf, special, dead, no_target)``. A
+        framed part contributes a slice of its buffer and its flags; a
+        part that is a list goes through the framing loop."""
+        bufs: list[bytes] = []
+        marked: tuple[list, list, list] = ([], [], [])  # special, dead, no_target
+        base = 0
+        for src, a, b in batch.parts:
+            if isinstance(src, QueryFrame):
+                off = src.off
+                bufs.append(src.buf[int(off[a]) : int(off[b])])
+                fl = src.flags[a:b]
+                if fl.any():
+                    for k, flag in enumerate((SPECIAL, DEAD, NO_TARGET)):
+                        marked[k].extend((np.flatnonzero(fl == flag) + base).tolist())
+            else:
+                buf, *lists = self._frame_tuples(snap, src[a:b])
+                bufs.append(buf)
+                for k, idxs in enumerate(lists):
+                    marked[k].extend(i + base for i in idxs)
+            base += b - a
+        return (b"".join(bufs), *marked)
 
     def _resolve_bulk_native(
         self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]
@@ -3344,8 +3403,12 @@ class TpuCheckEngine:
         in one C++ pass; route the rest through the per-query Python path.
         Returns None when the buffer framing is unsafe (separator bytes in
         strings) — callers fall back to the pure host loop."""
-        n = len(tuples)
-        nl = snap.num_live
+        return self._resolve_records(snap, tuples, *self._frame_tuples(snap, tuples))
+
+    def _frame_tuples(self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]):
+        """The framing loop: ``tuples`` as query records, and the indices
+        whose record is a placeholder. Returns ``(buf, special, dead,
+        no_target)``."""
         wild_ids = snap.wild_ns_ids
         nm = self._nm()
         ns_cache: dict = {}
@@ -3420,7 +3483,19 @@ class TpuCheckEngine:
             else:
                 dead.append(i)  # nil subject → denied
                 ap(_PLACEHOLDER)
-        buf = b"".join(parts)
+        return b"".join(parts), special, dead, no_target
+
+    def _resolve_records(
+        self, snap: GraphSnapshot, queries, buf: bytes,
+        special: list[int], dead: list[int], no_target: list[int],
+    ):
+        """Resolve ``len(queries)`` query records in one C++ pass and patch
+        in what the records could not say. ``queries`` (a list or a
+        ``QueryBatch``) is only asked for the tuples at ``special`` and,
+        on a snapshot with nodes the C++ tables do not know, at the
+        misses. None when the buffer's framing is unsafe."""
+        n = len(queries)
+        nl = snap.num_live
         # separator bytes inside strings corrupt framing — detectable as a
         # field-count mismatch, same check as the ingest path
         if buf.count(b"\x1f") != 6 * n or buf.count(b"\x1e") != n:
@@ -3444,7 +3519,9 @@ class TpuCheckEngine:
             tg[np.asarray(no_target)] = -1
         multi: dict = {}
         if special:
-            self._resolve_specials(snap, tuples, special, sd, tg, multi)
+            self._resolve_specials(
+                snap, pick_tuples(queries, special, "special"), special, sd, tg, multi
+            )
         if (
             snap.ov_set_ids
             or snap.ov_leaf_ids
@@ -3464,7 +3541,9 @@ class TpuCheckEngine:
                 if int(i) not in done
             ]
             if miss:
-                s1, t1, m1 = self._resolve_bulk_py(snap, [tuples[i] for i in miss])
+                s1, t1, m1 = self._resolve_bulk_py(
+                    snap, pick_tuples(queries, miss, "overlay")
+                )
                 for j, i in enumerate(miss):
                     sd[i] = s1[j]
                     tg[i] = t1[j]
@@ -3529,22 +3608,22 @@ class TpuCheckEngine:
             return ov_set.get(skey, -1) if ov_set else -1
         return None  # nil subject → denied
 
-    def _resolve_specials(self, snap, tuples, indices, sd, tg, multi):
+    def _resolve_specials(self, snap, picked, indices, sd, tg, multi):
         """Wildcard/pattern queries, resolved in bulk: namespace names go
         through one cache, starts through the snapshot's family-grouped
         sorted indexes (``GraphSnapshot.resolve_starts_bulk`` — one
         vectorized searchsorted pass per pattern family instead of a
-        per-query probe), subjects literally. Results splice into the
-        caller's bulk arrays."""
+        per-query probe), subjects literally. ``picked[k]`` is the tuple
+        of query ``indices[k]``; results splice into the caller's bulk
+        arrays."""
         _ns = self._ns_resolver()
-        live: list[int] = []
+        live: list[tuple] = []
         pats: list[tuple] = []
-        for i in indices:
-            rt = tuples[i]
+        for i, rt in zip(indices, picked):
             ns_id = _ns(rt.namespace)
             if ns_id is None:
                 continue  # unknown namespace → denied
-            live.append(i)
+            live.append((i, rt))
             pats.append((ns_id, rt.object, rt.relation))
         if not live:
             return
@@ -3552,10 +3631,10 @@ class TpuCheckEngine:
         ni = snap.num_int
         sbase = snap.sink_base
         nl = snap.num_live
-        for i, starts in zip(live, starts_l):
+        for (i, rt), starts in zip(live, starts_l):
             if starts.size == 0:
                 continue  # no matching start node → denied
-            t = self._subject_target(snap, tuples[i], _ns)
+            t = self._subject_target(snap, rt, _ns)
             if t is None:
                 continue  # nil subject / unknown subject namespace → denied
             if 0 <= t < nl or (t >= nl and snap.is_answerable_target(t)):
@@ -3629,7 +3708,9 @@ class TpuCheckEngine:
                 tg[i] = t
             sd[i] = start_dev
         if special:
-            self._resolve_specials(snap, tuples, special, sd, tg, multi)
+            self._resolve_specials(
+                snap, [tuples[i] for i in special], special, sd, tg, multi
+            )
         return sd, tg, multi
 
     # -- public API ----------------------------------------------------------
@@ -3924,7 +4005,16 @@ class TpuCheckEngine:
         lockstep = self._lockstep_verify
         if lockstep:
             from keto_tpu.parallel.lockstep import verify_lockstep
-        it = iter(tuples_iter)
+        # a source that cuts its own slices (the batcher's round: ranges
+        # of items, framed or not) hands over up to ``cap`` queries a call,
+        # as a list or a QueryBatch; any other iterable is pulled per tuple
+        take = getattr(tuples_iter, "take", None)
+        if take is None:
+            it = iter(tuples_iter)
+
+            def take(cap):
+                return list(itertools.islice(it, cap))
+
         max_iters = 0
         t_prev_ready = time.perf_counter()
         # the calling thread's state clock: the batcher's collector has
@@ -3936,12 +4026,13 @@ class TpuCheckEngine:
             while True:
                 cap = min(bound, ctrl.cap()) if ctrl is not None else bound
                 clk.enter(RESOLVE)  # pulling the caller's tuples is part of it
-                batch = list(itertools.islice(it, cap))
+                batch = take(cap)
                 if not batch:
                     return
                 if lockstep:
                     # per stream slice, BEFORE any dispatch (same contract
                     # as batch_check_with_token): divergence fails loudly
+                    batch = as_tuples(batch, "lockstep")
                     verify_lockstep(
                         snap.snapshot_id, batch, shards=self._shard_count
                     )
@@ -3978,7 +4069,7 @@ class TpuCheckEngine:
                 self.bfs_steps_stats.observe(float(iters))
             if truncated:
                 out, redo_iters = self._run_exact(
-                    snap, chunk, it_cap=min(
+                    snap, as_tuples(chunk, "truncated"), it_cap=min(
                         max(self._it_cap * 8, 8), self._cap_limit(snap)
                     ),
                 )
@@ -4152,13 +4243,15 @@ class TpuCheckEngine:
     def _dispatch_slices(
         self,
         snap: GraphSnapshot,
-        tuples: Sequence[RelationTuple],
+        tuples,
         it_cap: Optional[int] = None,
     ):
-        """Resolve + pack + dispatch ``tuples`` in ``_slice_cap`` query
-        slices, yielding ``[dev_out | None, host_ans, nq, chunk_tuples]``
-        records as each slice is enqueued (the device chews on earlier
-        slices meanwhile; chunk_tuples lets a truncated slice re-run).
+        """Resolve + pack + dispatch ``tuples`` (a list of
+        ``RelationTuple`` or a ``QueryBatch``; both are cut by range, never
+        walked) in ``_slice_cap`` query slices, yielding
+        ``[dev_out | None, host_ans, nq, chunk_tuples]`` records as each
+        slice is enqueued (the device chews on earlier slices meanwhile;
+        chunk_tuples lets a truncated slice re-run).
 
         A slice whose resolved fan-out exceeds the entry budget (wildcard
         patterns, high-out-degree static starts) is sub-chunked so entry

@@ -1797,6 +1797,25 @@ class Registry:
             native_pack_paths, ("path",),
         )
 
+        # /check/batch query frames (keto_tpu/check/frame.py): how often
+        # the framed path engages, and how often a frame's tuples had to
+        # be turned into objects after all
+        from keto_tpu.check import frame as check_frame
+
+        check_frame.check_frame_metrics(m)
+        m.register_callback(
+            "keto_check_frame_materialized_total", "counter",
+            "Times a framed /check/batch body's tuples were decoded into "
+            "objects after all (once a call or a slice, never per tuple), "
+            "by why: oracle (CPU engine or degraded mode), retry, "
+            "truncated (exact re-run), audit, lockstep, special (pattern "
+            "queries), overlay (nodes newer than the intern tables), "
+            "reload (namespaces changed after framing), wild_ns, "
+            "no_native, rejected.",
+            lambda: [((why,), float(n)) for why, n in sorted(check_frame.MATERIALIZED.items())],
+            ("why",),
+        )
+
         # streaming snapshot build (keto_tpu/graph/stream_build.py): the
         # live pipeline phase plus cumulative ingest counters, read from
         # the engine's BuildProgress at scrape time — a multi-minute

@@ -100,6 +100,16 @@ def load_library() -> Optional[ctypes.CDLL]:
             lib.graph_resolve_queries.argtypes = [
                 p, ctypes.c_char_p, c, c, ctypes.POINTER(c), ctypes.POINTER(c),
             ]
+        if hasattr(lib, "check_frame_body"):
+            # /check/batch query framer (native/ingest.cpp)
+            lib.check_frame_table_new.restype = p
+            lib.check_frame_table_new.argtypes = [ctypes.c_char_p, c]
+            lib.check_frame_table_free.argtypes = [p]
+            lib.check_frame_body.restype = c
+            lib.check_frame_body.argtypes = [
+                p, ctypes.c_char_p, c, c, p, c, ctypes.POINTER(c), c,
+                ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(c),
+            ]
         for fn in ("graph_resolve_leaf", "graph_obj_code", "graph_rel_code"):
             getattr(lib, fn).restype = c
             getattr(lib, fn).argtypes = [p, ctypes.c_char_p, c]
@@ -140,6 +150,76 @@ def pack_rows(rows) -> bytes:
     if hasattr(rows[0], "packed"):
         return b"".join(r.packed() for r in rows)
     return b"".join(encode_row(r) for r in rows)
+
+
+#: check_frame_body's decline codes (native/ingest.cpp ``FrameDecline``),
+#: as the ``reason`` label of ``keto_check_frame_declines_total``
+FRAME_DECLINES = {-1: "shape", -2: "escape", -3: "encoding", -4: "size", -5: "capacity"}
+
+
+class FrameTable:
+    """A namespace manager's name -> id table in the form the native
+    query framer reads (``check_frame_table_new``): built once per
+    manager, read-only after, freed with this object."""
+
+    def __init__(self, lib: ctypes.CDLL, handle: int):
+        self._lib = lib
+        self._handle = handle
+
+    def __del__(self):
+        lib = getattr(self, "_lib", None)
+        if lib is not None and self._handle:
+            lib.check_frame_table_free(self._handle)
+            self._handle = None
+
+    @classmethod
+    def build(cls, manager) -> Optional["FrameTable"]:
+        """None when the framer cannot stand in for the object path under
+        this manager: no native library (or one without the entry point,
+        or ``KETO_TPU_NATIVE=0``), a namespace named "" (the wildcard
+        namespace: the engine's pattern resolver owns it), or an id the
+        record format cannot carry."""
+        lib = load_library()
+        if lib is None or not hasattr(lib, "check_frame_body"):
+            return None
+        ids: dict[bytes, int] = {}
+        for ns in manager.namespaces():
+            if ns.name == "" or not 0 <= int(ns.id) < 2**63:
+                return None
+            name = ns.name.encode("utf-8", "surrogatepass")
+            if min(name) >= 0x20:
+                # a name with a control byte can only be written with an
+                # escape, and a body with an escape is never framed
+                ids[name] = int(ns.id)
+        buf = b"".join(b"%b\x1f%d\x1e" % kv for kv in ids.items())
+        handle = lib.check_frame_table_new(buf, len(buf))
+        return cls(lib, handle) if handle else None
+
+    def frame(self, body: bytes, max_tuples: int):
+        """Frame one ``POST /check/batch`` body. Returns ``(buf, off,
+        flags)`` — the query records ``graph_resolve_queries`` parses,
+        ``n + 1`` record offsets and one flag byte a record (0 literal, 1
+        special, 2 dead, 3 no-target) — or the reason it declined, a
+        string. The GIL is released for the pass over the body."""
+        n_body = len(body)
+        # a plain element is at least 58 bytes, and a record is shorter
+        # than the element it came from; the framer checks both anyway
+        cap_n = min(max_tuples, n_body // 50 + 1) + 1
+        out = np.empty(n_body + 16, np.uint8)
+        off = np.empty(cap_n + 1, np.int64)
+        flags = np.empty(cap_n, np.uint8)
+        out_len = ctypes.c_int64()
+        c = ctypes.c_int64
+        n = self._lib.check_frame_body(
+            self._handle, body, n_body, max_tuples,
+            out.ctypes.data, out.size,
+            off.ctypes.data_as(ctypes.POINTER(c)), off.size,
+            flags.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            ctypes.byref(out_len),
+        )
+        if n <= 0:
+            return FRAME_DECLINES.get(int(n), "shape")
+        return out[: out_len.value].tobytes(), off[: n + 1], flags[:n]
 
 
 class NativeInterned:

@@ -76,7 +76,9 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Optional
 from urllib.parse import parse_qs, urlsplit
 
+from keto_tpu.check.frame import QueryFrame, check_frame_metrics
 from keto_tpu.expand.tree import Tree
+from keto_tpu.graph.native import FrameTable
 from keto_tpu.relationtuple.model import (
     RelationQuery,
     RelationTuple,
@@ -172,6 +174,11 @@ class RestApp:
         # recorder mirrors timelines into (declared with the registry;
         # None when metrics are off)
         self._stage_hist = m.family("keto_timeline_stage_duration_seconds")
+        self._batch_tuples, self._frame_declines = check_frame_metrics(m)
+        #: (namespace manager, its table for the native query framer —
+        #: None: this manager's bodies are never framed); rebuilt when a
+        #: reload has put another manager in its place
+        self._frame_table: tuple = (None, None)
 
     # -- dispatch ------------------------------------------------------------
 
@@ -852,13 +859,40 @@ class RestApp:
         self._stamp_decoded()
         return self._check(tuple_, query, headers)
 
+    def _frame_body(self, scope, body: bytes):
+        """``body`` as a ``QueryFrame`` when it has the plain form the
+        native framer takes (native/ingest.cpp ``check_frame_body``), else
+        None — and the body is then decoded as it always was, so every
+        error a request can get comes from there. Counts the decline by
+        reason."""
+        manager = scope.namespace_manager()
+        cached = self._frame_table
+        if cached[0] is not manager:
+            cached = self._frame_table = (manager, FrameTable.build(manager))
+        table = cached[1]
+        if table is None:
+            reason = "unavailable"
+        elif not body or not isinstance(body, bytes):
+            reason = "shape"
+        else:
+            got = table.frame(body, MAX_BATCH_CHECK)
+            if not isinstance(got, str):
+                return QueryFrame(*got, body, manager)
+            reason = got
+        self._frame_declines.inc((reason,))
+        return None
+
     def _post_check_batch(self, body: bytes, query, headers=None):
         """Many checks in one request: ``{"tuples": [...]}`` →
         ``{"results": [bool, ...]}`` in order. Large payloads classify
         into the batcher's BATCH lane (override with ``X-Keto-Priority``)
         and dispatch in bounded sub-slices, so they never convoy
         interactive checks; shed with 429 + Retry-After past the
-        admission window."""
+        admission window.
+
+        A body in the plain form goes to the batcher as one framed buffer
+        of query records, no object per tuple; any other body is decoded
+        into ``RelationTuple``s. The answers are the same either way."""
         scope = self._scope(headers)
         lane_hint = self._lane_from(headers)
         batcher = scope.check_batcher()
@@ -867,6 +901,29 @@ class RestApp:
             # paying the JSON decode — during a brownout the 429s must
             # cost microseconds or the parsing itself becomes the load
             batcher.admission_precheck()
+        tuples = self._frame_body(scope, body)
+        if tuples is None:
+            tuples = self._decode_check_batch(body)
+            self._batch_tuples.inc(("objects",), by=len(tuples))
+        else:
+            self._batch_tuples.inc(("framed",), by=len(tuples))
+        self._stamp_decoded()
+        at_least, latest = self._consistency_from(query)
+        rep = scope.replica_controller()
+        if rep is not None:
+            rep.gate_read(at_least, latest)
+        results, token = batcher.check_batch_with_token(
+            tuples, at_least=at_least, latest=latest,
+            deadline=self._deadline_from(query, headers),
+            lane=lane_hint,
+        )
+        resp_headers = {} if token is None else {"X-Keto-Snaptoken": str(token)}
+        return 200, {"results": results}, resp_headers
+
+    @staticmethod
+    def _decode_check_batch(body: bytes) -> list:
+        """The general decode of a ``/check/batch`` body, and the source
+        of every 400 the endpoint answers."""
         try:
             obj = json.loads(body or b"{}")
         except json.JSONDecodeError as e:
@@ -879,19 +936,7 @@ class RestApp:
                 f"too many tuples in one batch check ({len(raw)} > "
                 f"{MAX_BATCH_CHECK}); split the request"
             )
-        tuples = [RelationTuple.from_json(t) for t in raw]
-        self._stamp_decoded()
-        at_least, latest = self._consistency_from(query)
-        rep = scope.replica_controller()
-        if rep is not None:
-            rep.gate_read(at_least, latest)
-        results, token = batcher.check_batch_with_token(
-            tuples, at_least=at_least, latest=latest,
-            deadline=self._deadline_from(query, headers),
-            lane=lane_hint,
-        )
-        resp_headers = {} if token is None else {"X-Keto-Snaptoken": str(token)}
-        return 200, {"results": [bool(r) for r in results]}, resp_headers
+        return [RelationTuple.from_json(t) for t in raw]
 
     def _get_expand(self, query, headers=None):
         # the reference parses max-depth unconditionally — absent/invalid

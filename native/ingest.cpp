@@ -50,6 +50,7 @@
 #include <string>
 #include <string_view>
 #include <thread>
+#include <unordered_map>
 #include <vector>
 
 namespace {
@@ -623,6 +624,177 @@ void stream_worker(StreamBuilder* sb) {
     }
 }
 
+// ---- /check/batch query framer: helpers (entry points and the contract are
+// at check_frame_body, below) ---------------------------------------------------
+
+enum FrameDecline : int64_t {
+    FRAME_SHAPE = -1,     // not the plain form
+    FRAME_ESCAPE = -2,    // a backslash inside a string
+    FRAME_ENCODING = -3,  // raw control byte or invalid UTF-8 in a string
+    FRAME_SIZE = -4,      // no tuples, or more than max_tuples
+    FRAME_CAPACITY = -5,  // an output array too small (caller's sizing)
+};
+
+struct FrameTable {
+    std::string storage;
+    // name -> decimal id, both views into storage
+    std::unordered_map<std::string_view, std::string_view> entries;
+    const std::string_view* find(std::string_view name) const {
+        auto it = entries.find(name);
+        return it == entries.end() ? nullptr : &it->second;
+    }
+};
+
+struct FrameCur {
+    const unsigned char* p;
+    const unsigned char* end;
+    void ws() {
+        while (p < end && (*p == ' ' || *p == '\t' || *p == '\n' || *p == '\r')) ++p;
+    }
+    // consume one expected byte after optional whitespace
+    bool eat(unsigned char c) {
+        ws();
+        if (p < end && *p == c) {
+            ++p;
+            return true;
+        }
+        return false;
+    }
+    unsigned char peek() {
+        ws();
+        return p < end ? *p : 0;
+    }
+};
+
+inline bool utf8_cont(const unsigned char* q, const unsigned char* end) {
+    return q < end && (*q & 0xC0) == 0x80;
+}
+
+// A JSON string in the plain form; 0 on success, a FrameDecline otherwise.
+int64_t frame_string(FrameCur& c, std::string_view& out) {
+    c.ws();
+    if (c.p >= c.end || *c.p != '"') return FRAME_SHAPE;
+    const unsigned char* q = c.p + 1;
+    const unsigned char* const end = c.end;
+    const unsigned char* const start = q;
+    while (q < end) {
+        unsigned char b = *q;
+        if (b == '"') {
+            out = std::string_view((const char*)start, (size_t)(q - start));
+            c.p = q + 1;
+            return 0;
+        }
+        if (b == '\\') return FRAME_ESCAPE;
+        if (b < 0x20) return FRAME_ENCODING;
+        if (b < 0x80) {
+            ++q;
+            continue;
+        }
+        // strict UTF-8 (RFC 3629): no overlong form, no surrogate, <= U+10FFFF
+        if (b >= 0xC2 && b <= 0xDF) {
+            if (!utf8_cont(q + 1, end)) return FRAME_ENCODING;
+            q += 2;
+        } else if (b >= 0xE0 && b <= 0xEF) {
+            if (!utf8_cont(q + 1, end) || !utf8_cont(q + 2, end)) return FRAME_ENCODING;
+            if (b == 0xE0 && q[1] < 0xA0) return FRAME_ENCODING;
+            if (b == 0xED && q[1] > 0x9F) return FRAME_ENCODING;
+            q += 3;
+        } else if (b >= 0xF0 && b <= 0xF4) {
+            if (!utf8_cont(q + 1, end) || !utf8_cont(q + 2, end) || !utf8_cont(q + 3, end))
+                return FRAME_ENCODING;
+            if (b == 0xF0 && q[1] < 0x90) return FRAME_ENCODING;
+            if (b == 0xF4 && q[1] > 0x8F) return FRAME_ENCODING;
+            q += 4;
+        } else {
+            return FRAME_ENCODING;
+        }
+    }
+    return FRAME_SHAPE;  // unterminated
+}
+
+enum : unsigned { K_NS = 1, K_OBJ = 2, K_REL = 4, K_SID = 8, K_SSET = 16 };
+
+struct FrameElem {
+    std::string_view ns, obj, rel, sid, sns, sobj, srel;
+};
+
+// {"namespace": S, "object": S, "relation": S}, each key exactly once
+int64_t frame_subject_set(FrameCur& c, FrameElem& e) {
+    if (!c.eat('{')) return FRAME_SHAPE;
+    unsigned seen = 0;
+    for (;;) {
+        std::string_view key, val;
+        int64_t rc = frame_string(c, key);
+        if (rc) return rc;
+        if (!c.eat(':')) return FRAME_SHAPE;
+        unsigned bit;
+        if (key == "namespace") bit = K_NS;
+        else if (key == "object") bit = K_OBJ;
+        else if (key == "relation") bit = K_REL;
+        else return FRAME_SHAPE;
+        if (seen & bit) return FRAME_SHAPE;
+        seen |= bit;
+        if ((rc = frame_string(c, val))) return rc;
+        if (bit == K_NS) e.sns = val;
+        else if (bit == K_OBJ) e.sobj = val;
+        else e.srel = val;
+        if (c.eat(',')) continue;
+        if (c.eat('}')) break;
+        return FRAME_SHAPE;
+    }
+    return seen == (K_NS | K_OBJ | K_REL) ? 0 : (int64_t)FRAME_SHAPE;
+}
+
+// one element of "tuples"; sets *is_set for a subject set
+int64_t frame_element(FrameCur& c, FrameElem& e, bool* is_set) {
+    if (!c.eat('{')) return FRAME_SHAPE;
+    unsigned seen = 0;
+    for (;;) {
+        std::string_view key, val;
+        int64_t rc = frame_string(c, key);
+        if (rc) return rc;
+        if (!c.eat(':')) return FRAME_SHAPE;
+        unsigned bit;
+        if (key == "namespace") bit = K_NS;
+        else if (key == "object") bit = K_OBJ;
+        else if (key == "relation") bit = K_REL;
+        else if (key == "subject_id") bit = K_SID;
+        else if (key == "subject_set") bit = K_SSET;
+        else return FRAME_SHAPE;
+        if (seen & bit) return FRAME_SHAPE;
+        seen |= bit;
+        if (bit == K_SSET) {
+            if ((rc = frame_subject_set(c, e))) return rc;
+        } else {
+            if ((rc = frame_string(c, val))) return rc;
+            if (bit == K_NS) e.ns = val;
+            else if (bit == K_OBJ) e.obj = val;
+            else if (bit == K_REL) e.rel = val;
+            else e.sid = val;
+        }
+        if (c.eat(',')) continue;
+        if (c.eat('}')) break;
+        return FRAME_SHAPE;
+    }
+    if (seen == (K_NS | K_OBJ | K_REL | K_SID)) *is_set = false;
+    else if (seen == (K_NS | K_OBJ | K_REL | K_SSET)) *is_set = true;
+    else return FRAME_SHAPE;  // a key missing, or both subjects
+    return 0;
+}
+
+struct FrameOut {
+    char* p;
+    char* end;
+    bool room(size_t n) const { return (size_t)(end - p) >= n; }
+    void put(std::string_view s) {
+        std::memcpy(p, s.data(), s.size());
+        p += s.size();
+    }
+    void put(char ch) { *p++ = ch; }
+};
+
+constexpr std::string_view kFramePlaceholder("0\x1f\x1f\x1f" "1\x1f\x1f\x1f\x1e", 9);
+
 }  // namespace
 
 extern "C" {
@@ -928,6 +1100,158 @@ int64_t graph_resolve_queries(const Graph* g, const char* buf, int64_t len,
         ++i;
     }
     return (i == n && p >= end) ? 0 : -1;
+}
+
+// ---- /check/batch query framer ---------------------------------------------
+//
+// check_frame_body turns the raw body of POST /check/batch into the same
+// 7-field query records graph_resolve_queries parses, without a Python
+// object per tuple (ctypes releases the GIL for the call). It frames the
+// body or it DECLINES; it never reports an error of its own: a declined
+// body is decoded by json.loads + RelationTuple.from_json as before, and
+// every 4xx comes from there. Only the plain form is framed:
+//
+//   {"tuples": [ {"namespace": S, "object": S, "relation": S,
+//                 "subject_id": S | "subject_set": {"namespace": S,
+//                 "object": S, "relation": S}}, ... ]}
+//
+// keys in any order, JSON whitespace anywhere, S a string without a
+// backslash escape, without a raw control byte and in strict UTF-8 (so
+// its bytes are exactly what str.encode() of the decoded value gives,
+// and no 0x1E/0x1F can reach a record). Anything else declines: another
+// top-level key, an unknown, missing or duplicate key, a non-string
+// value, zero tuples or more than max_tuples.
+//
+// Per record one flag byte, mirroring keto_tpu/check/tpu_engine.py
+// _resolve_bulk_native for a snapshot without a namespace named "":
+//   0 literal    the record resolves as written
+//   1 special    empty namespace/object/relation: placeholder record, the
+//                host pattern resolver answers
+//   2 dead       unknown namespace (the tuple's or the subject set's):
+//                placeholder record, denied
+//   3 no-target  subject set with an empty namespace: the start resolves,
+//                the target cannot exist
+//
+// The namespace table (check_frame_table_new) is built once per namespace
+// manager from "name \x1f decimal-id \x1e" records and is read-only after.
+
+FrameTable* check_frame_table_new(const char* buf, int64_t len) {
+    FrameTable* t = new FrameTable();
+    t->storage.assign(buf, (size_t)len);
+    const char* p = t->storage.data();
+    const char* end = p + t->storage.size();
+    while (p < end) {
+        const char* us = (const char*)std::memchr(p, '\x1f', (size_t)(end - p));
+        if (!us) break;
+        const char* rs = (const char*)std::memchr(us + 1, '\x1e', (size_t)(end - us - 1));
+        if (!rs) break;
+        std::string_view name(p, (size_t)(us - p));
+        std::string_view id(us + 1, (size_t)(rs - us - 1));
+        bool ok = !id.empty() && id.size() <= 19;
+        for (char ch : id) ok = ok && ch >= '0' && ch <= '9';
+        if (!ok) break;
+        t->entries.emplace(name, id);
+        p = rs + 1;
+    }
+    if (p != end) {  // malformed table: no table
+        delete t;
+        return nullptr;
+    }
+    return t;
+}
+
+void check_frame_table_free(FrameTable* t) { delete t; }
+
+// Frame `body` into out[0..*out_len) with off[0..n] record offsets and
+// flags[0..n). Returns n > 0, or a negative FrameDecline. off must hold
+// off_cap >= 2 entries, flags off_cap - 1; every write is bounds-checked
+// against out_cap and off_cap.
+int64_t check_frame_body(const FrameTable* t, const char* body, int64_t len,
+                         int64_t max_tuples, char* out, int64_t out_cap,
+                         int64_t* off, int64_t off_cap, uint8_t* flags,
+                         int64_t* out_len) {
+    if (!t || !body || len <= 0 || off_cap < 2 || out_cap < 0) return FRAME_CAPACITY;
+    FrameCur c{(const unsigned char*)body, (const unsigned char*)body + len};
+    FrameOut w{out, out + out_cap};
+    if (!c.eat('{')) return FRAME_SHAPE;
+    std::string_view key;
+    int64_t rc = frame_string(c, key);
+    if (rc) return rc;
+    if (key != "tuples" || !c.eat(':') || !c.eat('[')) return FRAME_SHAPE;
+    if (c.peek() == ']') return FRAME_SIZE;  // the empty array: a 400 of the general path
+    int64_t n = 0;
+    // the previous element's namespace, nearly always this one's too
+    std::string_view last_name;
+    const std::string_view* last_id = nullptr;
+    bool have_last = false;
+    auto ns_id = [&](std::string_view name) -> const std::string_view* {
+        if (have_last && name == last_name) return last_id;
+        last_name = name;
+        last_id = t->find(name);
+        have_last = true;
+        return last_id;
+    };
+    for (;;) {
+        FrameElem e;
+        bool is_set = false;
+        if ((rc = frame_element(c, e, &is_set))) return rc;
+        if (n >= max_tuples) return FRAME_SIZE;
+        if (n + 1 >= off_cap) return FRAME_CAPACITY;
+        off[n] = (int64_t)(w.p - out);
+        uint8_t flag = 0;
+        const std::string_view* id = e.ns.empty() ? nullptr : ns_id(e.ns);
+        const std::string_view* sid = nullptr;
+        if (!e.ns.empty() && !id) {
+            flag = 2;
+        } else if (e.ns.empty() || e.obj.empty() || e.rel.empty()) {
+            flag = 1;
+        } else if (is_set) {
+            if (e.sns.empty()) flag = 3;
+            else if (!(sid = t->find(e.sns))) flag = 2;
+        }
+        if (flag == 1 || flag == 2) {
+            if (!w.room(kFramePlaceholder.size())) return FRAME_CAPACITY;
+            w.put(kFramePlaceholder);
+        } else {
+            size_t need = id->size() + e.obj.size() + e.rel.size() + 8;
+            if (flag == 0 && is_set) need += sid->size() + e.sobj.size() + e.srel.size();
+            else if (flag == 0) need += e.sid.size();
+            if (!w.room(need)) return FRAME_CAPACITY;
+            w.put(*id);
+            w.put('\x1f');
+            w.put(e.obj);
+            w.put('\x1f');
+            w.put(e.rel);
+            w.put('\x1f');
+            if (flag == 0 && is_set) {
+                w.put('0');
+                w.put('\x1f');
+                w.put(*sid);
+                w.put('\x1f');
+                w.put(e.sobj);
+                w.put('\x1f');
+                w.put(e.srel);
+            } else {
+                w.put('1');
+                w.put('\x1f');
+                if (flag == 0) w.put(e.sid);
+                w.put('\x1f');
+                w.put('\x1f');
+            }
+            w.put('\x1e');
+        }
+        flags[n] = flag;
+        ++n;
+        if (c.eat(',')) continue;
+        if (c.eat(']')) break;
+        return FRAME_SHAPE;
+    }
+    if (!c.eat('}')) return FRAME_SHAPE;
+    c.ws();
+    if (c.p != c.end) return FRAME_SHAPE;  // trailing bytes: json.loads' "Extra data"
+    off[n] = (int64_t)(w.p - out);
+    *out_len = off[n];
+    return n;
 }
 
 int64_t graph_obj_code(const Graph* g, const char* s, int64_t len) {

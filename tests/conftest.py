@@ -46,16 +46,16 @@ def pytest_configure(config):
     something else happened to run ``make native`` in the tree before.
     Build them once, in the controlling process and before any xdist
     worker imports a test file (the skip conditions are evaluated at
-    import). Where there is no compiler the tests skip as before."""
+    import). ``make`` goes by time stamps, so a tree whose libraries are
+    older than their sources (an entry point added since) is rebuilt too,
+    and a fresh one costs nothing. Where there is no compiler the tests
+    skip as before."""
     if hasattr(config, "workerinput"):
         return
     import subprocess
     from pathlib import Path
 
     root = Path(__file__).resolve().parents[1]
-    libs = ("libketoingest.so", "libketomux.so", "libketopack.so")
-    if all((root / "native" / lib).is_file() for lib in libs):
-        return
     try:
         subprocess.run(["make", "native"], cwd=root, capture_output=True, timeout=600)
     except (OSError, subprocess.TimeoutExpired):
