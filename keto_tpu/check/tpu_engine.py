@@ -61,6 +61,7 @@ from jax import lax
 
 from keto_tpu import namespace as namespace_pkg
 from keto_tpu.check import native_pack
+from keto_tpu.check.geometry import INLINE, KernelGeometries
 from keto_tpu.check.frame import (
     DEAD, NO_TARGET, SPECIAL, QueryBatch, QueryFrame, as_tuples, pick_tuples,
 )
@@ -639,6 +640,63 @@ def _pad_entries(rows_l, qs_l, B: int, drop_row: int):
     return rows, qs
 
 
+def _pad_packed(packed, sizes: tuple, ni: int):
+    """``pack_chunk``'s seven arrays padded up to ``sizes`` = (S1, S2, SA, B)
+    with the same sentinels ``pack_chunk`` pads with: seed rows that drop,
+    the all-zero answer row, no target."""
+    S1, S2, SA, B = sizes
+
+    def up(a, n, fill):
+        if a.shape[0] == n:
+            return a
+        return np.concatenate([a, np.full(n - a.shape[0], fill, np.int32)])
+
+    e1r, e1q, e2r, e2q, ar, aq, targets = packed
+    return (
+        up(e1r, S1, ni + 1), up(e1q, S1, 0), up(e2r, S2, ni + 1), up(e2q, S2, 0),
+        up(ar, SA, ni), up(aq, SA, 0), up(targets, B, ni),
+    )
+
+
+def _sub_packed(packed, chosen: np.ndarray, B: int, ni: int):
+    """``pack_chunk``'s seven arrays for the queries of a packed chunk that
+    ``chosen`` (bool per query) picks, renumbered 0.. in their order and
+    padded for width ``B``: what packing those queries alone would give,
+    without walking their starts a second time. None when none of them
+    has a seed (as ``pack_chunk``: nothing reaches the device)."""
+    e1r, e1q, e2r, e2q, ar, aq, targets = packed
+    nq = chosen.size
+    place = np.cumsum(chosen) - 1
+
+    def side(rows, qs, pad_row):
+        keep = (rows != pad_row) & (qs < nq)
+        keep[keep] = chosen[qs[keep]]
+        return [rows[keep]], [place[qs[keep]]]
+
+    e1, e2 = side(e1r, e1q, ni + 1), side(e2r, e2q, ni + 1)
+    if not e1[0][0].size and not e2[0][0].size:
+        return None
+    sub_targets = np.full(B, ni, np.int32)
+    picked = targets[:nq][chosen]
+    sub_targets[: picked.size] = picked
+    return (
+        _pad_entries(*e1, B, ni + 1) + _pad_entries(*e2, B, ni + 1)
+        + _pad_entries(*side(ar, aq, ni), B, ni) + (sub_targets,)
+    )
+
+
+def _padding_packed(sizes: tuple, ni: int):
+    """``pack_chunk``'s seven arrays at ``sizes`` = (S1, S2, SA, B) with
+    nothing in them: every seed a dropped row, every answer entry the
+    all-zero row, no target. What a warm-up runs a program on."""
+    S1, S2, SA, B = sizes
+    return (
+        np.full(S1, ni + 1, np.int32), np.zeros(S1, np.int32),
+        np.full(S2, ni + 1, np.int32), np.zeros(S2, np.int32),
+        np.full(SA, ni, np.int32), np.zeros(SA, np.int32), np.full(B, ni, np.int32),
+    )
+
+
 def pack_chunk(
     snap: GraphSnapshot,
     sd: np.ndarray,
@@ -1136,6 +1194,12 @@ class TpuCheckEngine:
         # pulls per convergence observation, adapted to the workload's
         # traversal depth from the iteration counts kernels report back
         self._block_iters = 8
+        # which kernel programs are compiled, so that a served slice pads up
+        # to one that is before it compiles its own on the dispatch thread
+        # (keto_tpu/check/geometry.py); warm_compile settles block_iters, a
+        # static of every one of them, from the snapshot
+        self._geoms = KernelGeometries(self._compile_geometry)
+        self._block_iters_settled = False
         # concurrently in-flight chunks (bounds device bitmap workspaces)
         self._dispatch_window = 16
         # streaming pipeline: the latency-adaptive width controller is
@@ -1601,6 +1665,7 @@ class TpuCheckEngine:
         self._cache_task.stop()
         self._audit_task.stop()
         self._label_build_wait()
+        self._geoms.close()
 
     # -- HBM budget governor (keto_tpu/driver/hbm.py) ------------------------
 
@@ -1779,6 +1844,7 @@ class TpuCheckEngine:
         drop the warm-compiled executables: wide-slice throughput falls,
         decisions do not change (the same kernels at narrower widths)."""
         self._width_trim = max(self._width_trim, len(_WORD_WIDTHS) - 4)
+        self._geoms.reset()
         freed = self.hbm.release("warmup")
         self._last_warm_bytes = max(self._last_warm_bytes, freed)
         kerns: list = [
@@ -3225,6 +3291,125 @@ class TpuCheckEngine:
         the same formula ``_slice_cap`` budgets with)."""
         return (snap.num_int + 1) * 12 * (B // 32)
 
+    def _check_shape(self, snap: GraphSnapshot) -> tuple:
+        """What of the snapshot fixes a ``check_step`` program: row counts
+        and the shapes of the arrays it closes over."""
+        ov = snap.device_overlay
+        return (
+            snap.num_active, snap.num_int, tuple(b.n for b in snap.buckets),
+            tuple(a.shape for a in snap.device_buckets),
+            None if ov is None else (ov[0].shape, ov[1].shape),
+        )
+
+    def _check_fixed(self, it_cap: int) -> tuple:
+        return (it_cap, self._block_iters, self._donate_entries)
+
+    @staticmethod
+    def _label_shape(labs) -> tuple:
+        return (labs[0].shape, labs[1].shape)
+
+    def _label_fixed(self) -> tuple:
+        return (self._donate_entries,)
+
+    def _bitmap_sharding_for(self, B: int):
+        if self._mesh is None:
+            return None
+        if (B // 32) % self._mesh.shape.get("data", 1):
+            return self._bitmap_sharding_rows_only
+        return self._bitmap_sharding
+
+    def _run_check_padding(
+        self, snap: GraphSnapshot, sizes: tuple, it_cap: int, seeds=None
+    ) -> np.ndarray:
+        """One ``check_step`` at ``sizes`` = (S1, S2, SA, B) on entries that
+        are all padding - dropped seed rows, the all-zero answer row - so
+        that the program of these sizes is compiled; with ``seeds``, those
+        interior rows start one query each. Returns the device output."""
+        ni = snap.num_int
+        packed = _padding_packed(sizes, ni)
+        if seeds is not None:
+            packed[0][: seeds.size] = seeds
+            packed[1][: seeds.size] = np.arange(seeds.size)
+        buf, sizes = pack_entries(packed)
+        ov = snap.device_overlay
+        kern = self._entry_kernels()[0]
+        return self._guard_alloc(
+            "warm-compile",
+            lambda: kern(
+                snap.device_buckets,
+                jnp.asarray(buf),
+                ov_nbrs=None if ov is None else ov[0],
+                ov_dst=None if ov is None else ov[1],
+                sizes=sizes,
+                n_active=snap.num_active,
+                n_int=ni,
+                valid_rows=tuple(b.n for b in snap.buckets),
+                it_cap=it_cap,
+                block_iters=self._block_iters,
+                bitmap_sharding=self._bitmap_sharding_for(sizes[3]),
+            ).block_until_ready(),
+        )
+
+    def _run_label_padding(self, labs, sizes: tuple) -> None:
+        """One ``label_step`` at ``sizes`` = (P, B) on pairs of the all-pad
+        row, so that the program of these sizes is compiled."""
+        P, B = sizes
+        ni = labs[0].shape[0] - 1
+        pairs = np.concatenate(
+            [np.full(2 * P, ni, np.int32), np.zeros(P, np.int32)]
+        )
+        kern = self._entry_kernels()[1]
+        self._guard_alloc(
+            "warm-compile",
+            lambda: kern(
+                labs[0], labs[1], jnp.asarray(pairs), n_pairs=P, B=B
+            ).block_until_ready(),
+        )
+
+    def _settle_block_iters(self, snap: GraphSnapshot, B: int) -> None:
+        """``block_iters`` is a static of every ``check_step`` program, so a
+        change recompiles them all: settle it once, before the ladder is
+        warmed, from how deep the snapshot's own device part runs - a BFS
+        from a spread of the interior rows nothing on the device points at
+        (the sources of what the pulls walk) - and leave it there."""
+        self._block_iters_settled = True
+        na, ni = snap.num_active, snap.num_int
+        if na == 0 or ni <= na or not snap.buckets:
+            return
+        seeds = np.unique(
+            np.linspace(na, ni - 1, num=min(B, ni - na)).astype(np.int32)
+        )
+        out = np.asarray(
+            self._run_check_padding(snap, (B, B, B, B), self._it_cap, seeds=seeds)
+        )
+        self._block_iters = max(
+            self._block_iters, min(32, _ceil_pow2(int(out[B // 32]) + 1))
+        )
+
+    def _compile_geometry(self, kernel: str, shape: tuple, fixed: tuple, sizes: tuple) -> bool:
+        """The geometry worker's compile (keto_tpu/check/geometry.py): run
+        the kernel once on padding at ``sizes`` against the current
+        snapshot, if that still has the shape the slice saw."""
+        snap = self._snapshot
+        if self._closing or snap is None:
+            return False
+        if kernel == "check":
+            if self._check_shape(snap) != shape or self._check_fixed(fixed[0]) != fixed:
+                return False
+            self._run_check_padding(snap, sizes, fixed[0])
+            return True
+        labs = self._labels_dev(snap)
+        if labs is None or self._label_shape(labs) != shape or self._label_fixed() != fixed:
+            return False
+        self._run_label_padding(labs, sizes)
+        return True
+
+    def kernel_geometry_counts(self) -> dict:
+        """``{(kernel, met): launched slices}``: how each slice of the
+        single-device path found its program (the
+        ``keto_kernel_geometry_total`` scrape callback)."""
+        return self._geoms.counts()
+
     def warm_compile(self) -> int:
         """Ahead-of-time compile of the slice-width ladder (BFS and
         label kernels) against the current snapshot's geometry, so the
@@ -3236,7 +3421,13 @@ class TpuCheckEngine:
         breach the HBM budget are SKIPPED (never evicted for — warming is
         optional work) and counted in the ``warm_widths_skipped`` gauge /
         ``keto_hbm_warm_widths_skipped``. Returns the number of kernels
-        warmed."""
+        warmed.
+
+        On the single-device path every warmed program is also entered in
+        the engine's geometry set, and from here on a slice whose own
+        program is not compiled pads up to one that is
+        (keto_tpu/check/geometry.py): the ladder's minimum rungs are what
+        there always is to pad up to."""
         snap = self.snapshot()
         # the label kernels warm against the index the overlapped boot
         # build installs onto this snapshot: join it first, or every
@@ -3248,8 +3439,12 @@ class TpuCheckEngine:
         warmed = 0
         skipped = 0
         warm_bytes = 0
-        check_kern, label_kern = self._entry_kernels()
-        for B in self.stream_widths(snap):
+        plain = self._mesh is None  # the path whose geometries are tracked
+        widths = self.stream_widths(snap)
+        if plain and widths:
+            self._settle_block_iters(snap, widths[0])
+        labs = None
+        for B in widths:
             if self._closing:
                 break  # teardown must never race an in-flight compile
             need = self._warm_width_bytes(snap, B)
@@ -3259,37 +3454,20 @@ class TpuCheckEngine:
             # the empty-batch geometry: every entry array at its minimum
             # pad (B), every row a dropped/padded sentinel — the same
             # static shapes a real B-query slice produces
-            e_rows = np.full(B, ni + 1, np.int32)
-            e_q = np.zeros(B, np.int32)
-            a_rows = np.full(B, ni, np.int32)
-            targets = np.full(B, ni, np.int32)
-            packed = (e_rows, e_q, e_rows, e_q, a_rows, e_q, targets)
             if self._sharded and snap.device_shards is not None:
-                dev = self._dispatch_sharded(snap, packed, self._it_cap)
+                dev = self._dispatch_sharded(
+                    snap, _padding_packed((B, B, B, B), ni), self._it_cap
+                )
                 self._guard_alloc(
                     "warm-compile", lambda d=dev: d.dev.block_until_ready()
                 )
             else:
-                buf, sizes = pack_entries(packed)
-                ov = snap.device_overlay
-                self._guard_alloc(
-                    "warm-compile",
-                    lambda: check_kern(
-                        snap.device_buckets,
-                        jnp.asarray(buf),
-                        ov_nbrs=None if ov is None else ov[0],
-                        ov_dst=None if ov is None else ov[1],
-                        sizes=sizes,
-                        n_active=snap.num_active,
-                        n_int=ni,
-                        valid_rows=tuple(b.n for b in snap.buckets),
-                        it_cap=self._it_cap,
-                        block_iters=self._block_iters,
-                        bitmap_sharding=self._bitmap_sharding
-                        if self._mesh is not None and (B // 32) % self._mesh.shape.get("data", 1) == 0
-                        else (self._bitmap_sharding_rows_only if self._mesh is not None else None),
-                    ).block_until_ready(),
-                )
+                self._run_check_padding(snap, (B, B, B, B), self._it_cap)
+                if plain:
+                    self._geoms.add(
+                        "check", self._check_shape(snap),
+                        self._check_fixed(self._it_cap), (B, B, B, B),
+                    )
             warmed += 1
             # one slice runs at a time: the warm family holds the WIDEST
             # warmed width's workspace, not the sum over widths
@@ -3297,13 +3475,12 @@ class TpuCheckEngine:
             self.hbm.register("warmup", warm_bytes)
             labs = self._labels_dev(snap)
             if self._labels_enabled and labs is not None:
-                pairs = np.concatenate(
-                    [np.full(B, ni, np.int32), np.full(B, ni, np.int32),
-                     np.zeros(B, np.int32)]
-                )
                 if self._sharded:
                     from keto_tpu.parallel import sharded as shard_mod
 
+                    pairs = np.concatenate(
+                        [np.full(2 * B, ni, np.int32), np.zeros(B, np.int32)]
+                    )
                     self._guard_alloc(
                         "warm-compile",
                         lambda: shard_mod.label_kernel(self._mesh)(
@@ -3313,14 +3490,16 @@ class TpuCheckEngine:
                         ).block_until_ready(),
                     )
                 else:
-                    self._guard_alloc(
-                        "warm-compile",
-                        lambda: label_kern(
-                            labs[0], labs[1],
-                            jnp.asarray(pairs), n_pairs=B, B=B,
-                        ).block_until_ready(),
-                    )
+                    self._run_label_padding(labs, (B, B))
+                    if plain:
+                        self._geoms.add(
+                            "label", self._label_shape(labs), self._label_fixed(), (B, B)
+                        )
                 warmed += 1
+        if plain:
+            self._geoms.mark_warmed("check", self._check_shape(snap))
+            if self._labels_enabled and labs is not None:
+                self._geoms.mark_warmed("label", self._label_shape(labs))
         self.maintenance.set_gauge("warm_widths_skipped", skipped)
         return warmed
 
@@ -4066,7 +4245,7 @@ class TpuCheckEngine:
             if dev is not None and not (
                 isinstance(dev, _HybridSlice) and dev.bfs_dev is None
             ):
-                self.bfs_steps_stats.observe(float(iters))
+                self._note_bfs_steps(iters)
             if truncated:
                 out, redo_iters = self._run_exact(
                     snap, as_tuples(chunk, "truncated"), it_cap=min(
@@ -4461,7 +4640,7 @@ class TpuCheckEngine:
                 )
                 out[pos : pos + nq] = bits
                 if bfs is not None:
-                    self.bfs_steps_stats.observe(float(it))
+                    self._note_bfs_steps(it)
                 max_iters = max(max_iters, it)
                 if tr:
                     trunc_idx.extend(range(pos, pos + nq))
@@ -4471,12 +4650,21 @@ class TpuCheckEngine:
                     self._bfs_halo(dev),
                 )
                 out[pos : pos + nq] = bits
-                self.bfs_steps_stats.observe(float(it))
+                self._note_bfs_steps(it)
                 max_iters = max(max_iters, it)
                 if tr:
                     trunc_idx.extend(range(pos, pos + nq))
             pos += nq
         return out, max_iters, trunc_idx
+
+    def _note_bfs_steps(self, iters: int) -> None:
+        """One landed slice that ran ``check_step``: its pulls, for bench's
+        percentiles and for ``keto_check_bfs_steps_total`` /
+        ``keto_check_bfs_slices_total``."""
+        self.bfs_steps_stats.observe(float(iters))
+        self.maintenance.incr("bfs_slices")
+        if iters:
+            self.maintenance.incr("bfs_steps", by=int(iters))
 
     def _note_route(self, route: str, nq: int, ms: float) -> None:
         """Record one landed slice's route (label | hybrid | bfs | host |
@@ -4523,6 +4711,10 @@ class TpuCheckEngine:
         # argname, so shrinking it would recompile every kernel geometry for
         # a marginal saving (converged pulls inside a block are lax.cond
         # no-ops) — growing pays one recompile to cut while-loop trips.
+        # warm_compile settles it from the snapshot: growing it later would
+        # recompile, on the serving thread, every program it warmed.
+        if self._block_iters_settled:
+            return
         want = min(32, _ceil_pow2(max_iters + 1))
         if want > self._block_iters:
             self._block_iters = want
@@ -4588,6 +4780,16 @@ class TpuCheckEngine:
         for i in multi:
             if i0 <= i < i1:
                 fallback[i - i0] = True
+        # why each query left the label path, first cause wins
+        # (keto_label_fallbacks_total{reason})
+        reasons = {"multi": int(np.count_nonzero(fallback))}
+
+        def fall_back(reason: str, where) -> None:
+            fresh = np.zeros(nq, bool)
+            fresh[where] = True
+            fresh &= ~fallback
+            reasons[reason] = reasons.get(reason, 0) + int(np.count_nonzero(fresh))
+            fallback[where] = True
 
         # valid (non-padding) entries; e1/e2 pad with row ni+1, a with ni
         m1 = (e1r != ni + 1) & (e1q < nq)
@@ -4600,7 +4802,7 @@ class TpuCheckEngine:
         e1_q_v = e1q[m1].astype(np.int64)
         self_hit = t_int[e1_q_v] & (e1_rows_v == tq[e1_q_v])
         if self_hit.any():
-            fallback[e1_q_v[self_hit]] = True
+            fall_back("self_hit", e1_q_v[self_hit])
 
         # target-side rows per query: the interior target, or the sink
         # answer-gather rows
@@ -4619,7 +4821,7 @@ class TpuCheckEngine:
         n_pairs_q = ns * nr
         over = n_pairs_q > self._LABEL_PAIR_CAP
         if over.any():
-            fallback[over] = True
+            fall_back("pair_cap", over)
         # drop both sides of fallback queries before the join
         keep_s = ~fallback[s_q]
         keep_b = ~fallback[b_q]
@@ -4647,8 +4849,7 @@ class TpuCheckEngine:
             # coverage: a miss on an uncertifiable pair is not a deny
             cert = idx.certifiable(pa, pb)
             if not cert.all():
-                bad = np.unique(pq[~cert])
-                fallback[bad] = True
+                fall_back("uncertifiable", np.unique(pq[~cert]))
                 keep = ~fallback[pq]
                 pa, pb, pq = pa[keep], pb[keep], pq[keep]
         else:
@@ -4658,11 +4859,20 @@ class TpuCheckEngine:
         self.maintenance.incr("label_checks", by=nq - n_fb)
         if n_fb:
             self.maintenance.incr("label_fallbacks", by=n_fb)
+            for reason, count in reasons.items():
+                if count:
+                    self.maintenance.incr(f"label_fallbacks_{reason}", by=count)
 
         ldev = None
         if pa.size:
             faults.check("device-exec")
             P = _entry_pad(B, pa.size)
+            dl = self._labels_dev(snap)
+            lmet = None
+            if self._mesh is None:
+                own = (P, B)
+                lshape, lfixed = self._label_shape(dl), self._label_fixed()
+                (P, B), lmet = self._geoms.meet("label", lshape, lfixed, own)
             pad = P - pa.size
             stg = self._stage_acquire(3 * P) if self._mesh is None else None
             if stg is not None:
@@ -4683,8 +4893,9 @@ class TpuCheckEngine:
                         np.concatenate([pq, np.zeros(pad, np.int64)]),
                     ]
                 ).astype(np.int32)
-            clk.enter(LAUNCH)
-            dl = self._labels_dev(snap)
+            clk.enter(
+                LAUNCH, ("hybrid" if n_fb else "label", "label_step", (P, B), lmet)
+            )
             if self._sharded:
                 # row-sharded label arrays + replicated pairs: the kernel
                 # does the one-shot pair-row exchange internally
@@ -4715,23 +4926,21 @@ class TpuCheckEngine:
                     "label-kernel",
                     lambda: lkern(dl[0], dl[1], put_pairs(), n_pairs=P, B=B),
                 )
+                if lmet == INLINE:
+                    self._geoms.add("label", lshape, lfixed, own)
 
         bfs_dev = None
         bfs_pos = None
         if n_fb:
-            pos = np.nonzero(fallback)[0]
-            gidx = pos + i0
-            sd2 = sd[gidx]
-            tg2 = tg[gidx]
-            multi2 = {
-                j: multi[int(i)] for j, i in enumerate(gidx) if int(i) in multi
-            }
-            W2 = next(w for w in _WORD_WIDTHS if 32 * w >= pos.size)
-            bfs_dev, _, bfs_leases = self._device_batch(
-                snap, sd2, tg2, multi2, 0, pos.size, W2, it_cap=it_cap
-            )
-            leases.extend(bfs_leases)
-            bfs_pos = pos
+            # the fallback queries' entries are in ``packed`` already: the
+            # sub-batch is cut out of it, not walked and packed again
+            bfs_pos = np.nonzero(fallback)[0]
+            W2 = next(w for w in _WORD_WIDTHS if 32 * w >= n_fb)
+            sub = _sub_packed(packed, fallback, 32 * W2, ni)
+            if sub is not None:
+                faults.check("device-exec")
+                bfs_dev, bfs_leases = self._launch_check(snap, sub, it_cap, "hybrid")
+                leases.extend(bfs_leases)
         if ldev is None and bfs_dev is None:
             return None, host_ans, leases
         return _HybridSlice(ldev, bfs_dev, bfs_pos), host_ans, leases
@@ -4746,43 +4955,49 @@ class TpuCheckEngine:
         i1: int,
         force_W: Optional[int] = None,
         it_cap: Optional[int] = None,
+        route: str = "bfs",
     ):
         """Pack + dispatch one sub-chunk. Returns ``(dev, host_ans,
         leases)`` — ``leases`` are pooled staging buffers the caller MUST
         release only after the slice lands (``_stage_release``): the H2D
         copy may complete asynchronously, so earlier reuse could corrupt
-        an in-flight slice."""
+        an in-flight slice. On the single-device path a chunk whose own
+        program is not compiled is padded up to one that is
+        (keto_tpu/check/geometry.py)."""
         faults.check("device-exec")
-        clk = dispatch_clock()
-        clk.enter(PACK)
+        dispatch_clock().enter(PACK)
         packed, host_ans = pack_chunk(
             snap, sd, tg, multi, i0, i1, force_W, native=self._native_pack
         )
-        leases: list = []
         if packed is None:
             # no query in the chunk reaches the device: host_ans is the
             # whole answer
-            return None, host_ans, leases
+            return None, host_ans, []
+        dev, leases = self._launch_check(snap, packed, it_cap, route)
+        return dev, host_ans, leases
+
+    def _launch_check(
+        self, snap: GraphSnapshot, packed, it_cap: Optional[int], route: str
+    ):
+        """Ship one packed chunk to ``check_step``. Returns ``(dev,
+        leases)``."""
+        clk = dispatch_clock()
+        leases: list = []
+        it_cap = it_cap or self._it_cap
         if self._sharded and snap.device_shards is not None:
-            return (
-                self._dispatch_sharded(
-                    snap, packed, it_cap or self._it_cap, leases=leases
-                ),
-                host_ans,
-                leases,
-            )
-        sharding = self._bitmap_sharding
-        if self._mesh is not None:
-            W = packed[-1].shape[0] // 32
-            if W % self._mesh.shape.get("data", 1):
-                sharding = self._bitmap_sharding_rows_only
-        stg = None
+            return self._dispatch_sharded(snap, packed, it_cap, leases=leases), leases
+        stg = met = None
         if self._mesh is None:
+            own = tuple(packed[i].shape[0] for i in (0, 2, 4, 6))
+            shape, fixed = self._check_shape(snap), self._check_fixed(it_cap)
+            use, met = self._geoms.meet("check", shape, fixed, own)
+            if use != own:
+                packed = _pad_packed(packed, use, snap.num_int)
             stg = self._stage_acquire(sum(a.shape[0] for a in packed))
             if stg is not None:
                 leases.append(stg)
         buf, sizes = pack_entries(packed, out=stg)
-        clk.enter(LAUNCH)
+        clk.enter(LAUNCH, (route, "check_step", sizes, met))
         ov = snap.device_overlay
 
         def put_entries():
@@ -4809,12 +5024,14 @@ class TpuCheckEngine:
                 n_active=snap.num_active,
                 n_int=snap.num_int,
                 valid_rows=tuple(b.n for b in snap.buckets),
-                it_cap=it_cap or self._it_cap,
+                it_cap=it_cap,
                 block_iters=self._block_iters,
-                bitmap_sharding=sharding,
+                bitmap_sharding=self._bitmap_sharding_for(sizes[3]),
             ),
         )
-        return dev, host_ans, leases
+        if met == INLINE:
+            self._geoms.add("check", shape, fixed, own)
+        return dev, leases
 
     def _dispatch_sharded(
         self, snap: GraphSnapshot, packed, it_cap: int, leases=None

@@ -1691,8 +1691,65 @@ class Registry:
             "keto_label_checks_total", "counter",
             "Check queries answered by the 2-hop label fast path (path="
             "label) vs routed to the BFS kernel while labels were live "
-            "(path=fallback: wildcards, coverage gaps, self-queries).",
+            "(path=fallback: seeds x target rows past the pair cap, "
+            "coverage gaps, self-queries, wildcard or multi-start subjects; "
+            "keto_label_fallbacks_total says which).",
             label_paths, ("path",),
+        )
+
+        def label_fallback_reasons():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((reason,), float(counters.get(f"label_fallbacks_{reason}", 0)))
+                for reason in ("pair_cap", "uncertifiable", "self_hit", "multi")
+            ]
+
+        m.register_callback(
+            "keto_label_fallbacks_total", "counter",
+            "Check queries that left the label fast path for the BFS kernel, "
+            "by the first cause found: pair_cap (seeds x target rows above "
+            "64 pairs), uncertifiable (a pair the index cannot certify), "
+            "self_hit (a start row that is the target), multi (wildcard or "
+            "multi-start subject).",
+            label_fallback_reasons, ("reason",),
+        )
+
+        def bfs_steps(key):
+            def read():
+                counters, _, _ = maintenance_raw()
+                yield (), float(counters.get(key, 0))
+
+            return read
+
+        m.register_callback(
+            "keto_check_bfs_steps_total", "counter",
+            "Frontier pulls of check_step, summed over the landed BFS and "
+            "hybrid slices (each slice's convergence check included).",
+            bfs_steps("bfs_steps"),
+        )
+        m.register_callback(
+            "keto_check_bfs_slices_total", "counter",
+            "Landed slices that ran check_step (BFS and hybrid routes): "
+            "keto_check_bfs_steps_total over this is the mean pulls a slice.",
+            bfs_steps("bfs_slices"),
+        )
+
+        def kernel_geometries():
+            engine = self.peek("permission_engine")
+            counts = getattr(engine, "kernel_geometry_counts", dict)()
+            return [
+                ((kernel, met), float(counts.get((kernel, met), 0)))
+                for kernel in ("check", "label")
+                for met in ("compiled", "padded_up", "inline_compile")
+            ]
+
+        m.register_callback(
+            "keto_kernel_geometry_total", "counter",
+            "Slices launched on the single-device path, by kernel and by how "
+            "each found its program: compiled (its own sizes), padded_up "
+            "(rode a larger compiled program; its own compiles behind it), "
+            "inline_compile (the launch compiled it, on the dispatch thread).",
+            kernel_geometries, ("kernel", "met"),
         )
 
         def label_coverage():

@@ -105,7 +105,8 @@ class DispatchClock:
     While a ``jax.profiler`` session is open (``session.open``, read
     once per loop pass in ``idle``) each state is also a
     ``TraceAnnotation`` named ``keto.dispatch.<state>`` carrying the
-    round's ``tuples``, ``slices`` launched so far and ``lane_depth``:
+    round's ``tuples``, ``slices`` launched so far and ``lane_depth`` (a
+    ``launch`` also its slice's ``route`` and ``geometry``):
     contiguous spans on the device trace's clock, so an idle gap of the
     device is named by what its one feeder was doing."""
 
@@ -126,14 +127,16 @@ class DispatchClock:
         self._ann = None
         self._tuples = self._slices = self._lane_depth = 0
 
-    def enter(self, state: int) -> None:
+    def enter(self, state: int, note=None) -> None:
+        """``note``, on a ``launch``: ``(route, kernel, sizes, how the slice
+        met its program)``, read only while a profiler session is open."""
         now = time.perf_counter()
         # _t moves first: snapshot() retries when it sees _t change
         t, self._t = self._t, now
         self.seconds[self._state] += now - t
         self._state = state
         if self._tracing or self._ann is not None:
-            self._annotate(state)
+            self._annotate(state, note)
 
     def idle(self) -> None:
         """Top of a loop pass: back to ``wait_work``."""
@@ -145,16 +148,23 @@ class DispatchClock:
         self.rounds += 1
         self._tuples, self._slices, self._lane_depth = tuples, 0, lane_depth
 
-    def _annotate(self, state: int) -> None:
+    def _annotate(self, state: int, note=None) -> None:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
         if self._tracing:
             if state == LAUNCH:
                 self._slices += 1
+            attrs = {}
+            if note is not None:
+                route, kernel, sizes, met = note
+                attrs = {
+                    "route": route,
+                    "geometry": f"{kernel} {'x'.join(map(str, sizes))} {met or 'untracked'}",
+                }
             self._ann = self._session.annotation(
                 _SPAN_NAMES[state], tuples=self._tuples, slices=self._slices,
-                lane_depth=self._lane_depth,
+                lane_depth=self._lane_depth, **attrs,
             )
             self._ann.__enter__()
 
@@ -176,7 +186,7 @@ class _NoClock:
 
     __slots__ = ()
 
-    def enter(self, state: int) -> None:
+    def enter(self, state: int, note=None) -> None:
         pass
 
 
