@@ -9,7 +9,8 @@ a folder its parent's, and groups are subject sets as in rbac-groups.
 
 Per 1,000,000 tuples (every count scales linearly, with floors so that small
 rehearsals still build): 40,000 users; 200 top groups and 1,800 leaf groups,
-each leaf ``member`` of one top group; an ordinary user joins 1-3 leaf groups
+each leaf ``member`` of one top group, nine a top group, dealt in turn; an
+ordinary user joins 1-3 leaf groups
 and 2% of the users ("power users") join 40; 60,000 folders in 600 trees, a
 folder's parent drawn among the earlier folders of depth < 9; every folder has
 ``owner@user``, ``access@(dir#owner)`` and, below a root,
@@ -49,7 +50,7 @@ def build(rng, n_tuples: int) -> Graph:
     scale = n_tuples / SOURCE_TUPLES
     n_users = max(200, int(40_000 * scale))
     n_top = max(20, int(200 * scale))
-    n_leaf = max(40, int(1_800 * scale))
+    n_leaf = max(9 * n_top, int(1_800 * scale))  # nine leaves a top group at every size
     n_dirs = max(120, int(60_000 * scale))
     n_roots = max(4, int(600 * scale))
     staff_grants = max(2, int(150 * scale))
@@ -58,8 +59,11 @@ def build(rng, n_tuples: int) -> Graph:
     rows = g.rows
     group = lambda k: f"group-{k}"  # top groups first, then the leaves
 
-    # groups: each leaf is a member of one top group
-    leaf_top = [rng.randrange(n_top) for _ in range(n_leaf)]
+    # groups: each leaf is a member of one top group, dealt in turn, so that
+    # a top group holds nine at every size (a group of four or fewer is folded
+    # into the host's walk by the engine's peel: the all-staff groups are what
+    # this deployment leaves on the device, and a rehearsal has to have them)
+    leaf_top = [leaf % n_top for leaf in range(n_leaf)]
     top_leaves = {}
     for leaf, top in enumerate(leaf_top):
         top_leaves.setdefault(top, []).append(leaf)

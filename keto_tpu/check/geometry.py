@@ -23,8 +23,11 @@ compiled and asks it before every launch:
   widest warmed rung holds): the launch compiles, as it always did, and is
   counted.
 
-A kernel's set belongs to one snapshot shape; a slice that arrives with
-another shape starts an empty one, not warmed.
+A set belongs to one kernel on one snapshot shape; a slice that arrives with
+a shape not met before starts an empty one, not warmed. The sets of the last
+few shapes are kept, so a deployment that goes back and forth between two
+(an overlay there, then folded away) finds each as it left it, and what the
+worker compiled for a shape that has gone meanwhile lands in that shape's set.
 """
 
 from __future__ import annotations
@@ -43,24 +46,25 @@ COMPILED, PADDED_UP, INLINE = "compiled", "padded_up", "inline_compile"
 class _Family:
     """One kernel's compiled sizes on one snapshot shape."""
 
-    __slots__ = ("shape", "sizes", "warmed")
+    __slots__ = ("sizes", "warmed")
 
-    def __init__(self, shape):
-        self.shape = shape
+    def __init__(self):
         self.sizes: dict[tuple, set] = {}  # fixed -> {sizes}
         self.warmed = False
 
 
 class KernelGeometries:
-    """The compiled sizes per kernel and ``fixed`` statics, for the snapshot
-    shape the kernel last saw. ``compile_fn(kernel, shape, fixed, sizes) ->
-    bool`` runs on the worker thread and compiles that program (False: the
-    shape has gone)."""
+    """The compiled sizes per kernel, snapshot shape and ``fixed`` statics.
+    ``compile_fn(kernel, shape, fixed, sizes) -> bool`` runs on the worker
+    thread and compiles that program (False: the shape has gone)."""
+
+    #: shapes a kernel's sets are kept for, the most recently met last
+    KEEP_SHAPES = 4
 
     def __init__(self, compile_fn: Callable[[str, tuple, tuple, tuple], bool]):
         self._compile_fn = compile_fn
         self._lock = threading.Lock()  # guards everything below
-        self._families: dict[str, _Family] = {}
+        self._families: dict[tuple, _Family] = {}  # (kernel, shape) ->, in order of last use
         self._asked: set = set()  # (kernel, shape, fixed, sizes) ever handed to the worker
         self._inflight = 0  # of those, queued or compiling
         self._counts: Counter = Counter()  # (kernel, met) -> launches
@@ -69,9 +73,11 @@ class KernelGeometries:
         self._closed = False
 
     def _family(self, kernel: str, shape: tuple) -> _Family:
-        fam = self._families.get(kernel)
-        if fam is None or fam.shape != shape:
-            fam = self._families[kernel] = _Family(shape)
+        fam = self._families.pop((kernel, shape), None) or _Family()
+        self._families[(kernel, shape)] = fam
+        mine = [k for k in self._families if k[0] == kernel]
+        for k in mine[: -self.KEEP_SHAPES]:
+            del self._families[k]
         return fam
 
     def add(self, kernel: str, shape: tuple, fixed: tuple, sizes: tuple) -> None:

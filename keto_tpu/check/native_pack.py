@@ -35,7 +35,7 @@ from typing import Optional
 
 import numpy as np
 
-_ABI_VERSION = 1
+_ABI_VERSION = 2
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_checked = False
@@ -95,6 +95,7 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.keto_gather_n.argtypes = [p]
         lib.keto_gather_fetch.argtypes = [p, _I32, _I64]
         lib.keto_gather_free.argtypes = [p]
+        lib.keto_pairs_member.argtypes = [_I32, _I32, c, _I32, _I32, c, _U8]
         _lib = lib
         return _lib
     return None
@@ -193,3 +194,26 @@ def sink_gather(snap, sinks: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     finally:
         lib.keto_gather_free(h)
     return rows, cnts
+
+
+def pairs_member(
+    set_rows: np.ndarray, set_q: np.ndarray, rows: np.ndarray, q: np.ndarray
+) -> np.ndarray:
+    """``bool[len(rows)]``: is the (row, query) pair ``(rows[i], q[i])``
+    among the pairs ``(set_rows[j], set_q[j])``. One hash set off the GIL
+    where the library is there, sorted keys otherwise: the same answer."""
+    lib = load_library()
+    if lib is None:
+        key = (q.astype(np.int64) << 32) | rows.astype(np.int64)
+        return np.isin(key, (set_q.astype(np.int64) << 32) | set_rows.astype(np.int64))
+    set_rows = np.ascontiguousarray(set_rows, np.int32)
+    set_q = np.ascontiguousarray(set_q, np.int32)
+    rows = np.ascontiguousarray(rows, np.int32)
+    q = np.ascontiguousarray(q, np.int32)
+    out = np.zeros(rows.shape[0], np.uint8)
+    lib.keto_pairs_member(
+        _ptr(set_rows, ctypes.c_int32), _ptr(set_q, ctypes.c_int32), set_rows.shape[0],
+        _ptr(rows, ctypes.c_int32), _ptr(q, ctypes.c_int32), rows.shape[0],
+        _ptr(out, ctypes.c_uint8),
+    )
+    return out.view(bool)

@@ -94,20 +94,26 @@ _DEGREE_CHUNK = 1024
 
 
 def _pull(
-    bucket_nbrs: Sequence[jnp.ndarray], bucket_valid_rows: Sequence[int], R: jnp.ndarray
+    bucket_nbrs: Sequence[jnp.ndarray],
+    bucket_valid_rows: Sequence[int],
+    R: jnp.ndarray,
+    row_of=None,
 ) -> jnp.ndarray:
     """One BFS pull step over the active rows.
 
     R: uint32[n_live+1, W] → uint32[n_active, W]. Buckets hold live→live
     edges and are contiguous in device-id order — concatenating per-bucket
-    OR-reductions yields the active prefix with no scatter.
+    OR-reductions yields the active prefix with no scatter. ``row_of``
+    maps a neighbor id to the row of ``R`` that holds it, where ``R`` is
+    not the whole bitmap.
     """
     outs = []
     for nbrs, n_valid in zip(bucket_nbrs, bucket_valid_rows):
         n_pad, cap = nbrs.shape
         acc = None
         for c0 in range(0, cap, _DEGREE_CHUNK):
-            gathered = R[nbrs[:, c0 : c0 + _DEGREE_CHUNK]]  # [n_pad, chunk, W]
+            idx = nbrs[:, c0 : c0 + _DEGREE_CHUNK]
+            gathered = R[idx if row_of is None else row_of(idx)]  # [n_pad, chunk, W]
             part = lax.reduce(gathered, np.uint32(0), lax.bitwise_or, (1,))
             acc = part if acc is None else lax.bitwise_or(acc, part)
         outs.append(acc[:n_valid])
@@ -158,45 +164,69 @@ def check_step(
     q = jnp.arange(B)
     words = q // 32
     bits = (q % 32).astype(jnp.uint32)
-    # per (row, word) slot, masks from distinct queries occupy distinct bits
-    # and per-query row lists are deduplicated on host, so scatter-add
-    # never carries — add on disjoint bits is bitwise OR
-    zero = jnp.zeros((n_int + 1, W), jnp.uint32)
-    # the one-hop term: start bits of static (zero-in-degree) nodes
-    # propagated to their interior out-neighbors on host. These bits are
-    # "reached via ≥ 1 edge" by construction, so they feed R0 and answers.
-    ans_base = zero.at[e2_rows, e2_words].add(e2_masks, mode="drop")
-    R0 = zero.at[e1_rows, e1_words].add(e1_masks, mode="drop") | ans_base
+    # ONE bitmap over the interior rows holds every seed. Per (row, word)
+    # slot, masks from distinct queries occupy distinct bits and per-query
+    # row lists are deduplicated on host, so scatter-add never carries —
+    # add on disjoint bits is bitwise OR. A start row the host walk also
+    # reached is in both lists for its query: the second scatter adds only
+    # the bits the first left clear.
+    R0 = jnp.zeros((n_int + 1, W), jnp.uint32).at[e2_rows, e2_words].add(
+        e2_masks, mode="drop"
+    )
+    have = R0.at[e1_rows, e1_words].get(mode="fill", fill_value=0)
+    R0 = R0.at[e1_rows, e1_words].add(e1_masks & ~have, mode="drop")
     if bitmap_sharding is not None:
         # "data" shards words (embarrassingly parallel); "graph" shards rows
         # and lets the SPMD partitioner insert the per-step all-gather the
         # pull's cross-shard row gathers need
         R0 = lax.with_sharding_constraint(R0, bitmap_sharding)
-        ans_base = lax.with_sharding_constraint(ans_base, bitmap_sharding)
+    # the one-hop term: start bits of static (zero-in-degree) nodes
+    # propagated to their interior out-neighbors on host. These bits are
+    # "reached via ≥ 1 edge" by construction, so one that sits on its
+    # query's target answers it; no bitmap is kept for that, the entry
+    # list is compared with the targets.
+    base_hit = jnp.zeros(B, jnp.uint32).at[e2_q].max(
+        (e2_rows == targets[e2_q]).astype(jnp.uint32)
+    )
 
     if n_active == 0 or not bucket_nbrs:
         # no interior→interior edges: the fixpoint is R0 itself
-        R_fix = R0
+        A_fix = None
         pull_p = jnp.zeros((n_active + 1, W), jnp.uint32)
         iters = jnp.int32(0)
         truncated = jnp.bool_(False)
     else:
-        # Only the active prefix R[:n_active] can change; the in-place .set
-        # on the while-loop carry aliases, so passive rows are never copied.
+        # Only the active prefix R[:n_active] can change, so only it is
+        # carried through the loop: A holds it, plus one all-zero row that
+        # every passive neighbor id is sent to. What the passive
+        # neighbors contribute to a pull is their seed bits, the same in
+        # every step, and is gathered from R0 once (row n_int is all-zero:
+        # active neighbor ids are sent there).
+        def in_A(ids):
+            return jnp.minimum(ids, n_active)
+
+        def in_R0(ids):
+            return jnp.where(ids >= n_active, ids, n_int)
+
+        p_passive = _pull(bucket_nbrs, valid_rows, R0, in_R0)
+        if ov_nbrs is not None:
+            # delta-overlay edges (inserts since the base snapshot
+            # build, keto_tpu/graph/overlay.py): OR the overlay
+            # in-neighbors into their unique destination rows. Inside
+            # the loop, so multi-hop paths through delta edges converge
+            # exactly like base edges.
+            ovo = lax.reduce(R0[in_R0(ov_nbrs)], np.uint32(0), lax.bitwise_or, (1,))
+            p_passive = p_passive.at[ov_dst].set(p_passive[ov_dst] | ovo, mode="drop")
+
         def step(st):
-            R, _, _, it = st
-            p = _pull(bucket_nbrs, valid_rows, R)
+            A, _, _, it = st
+            p = _pull(bucket_nbrs, valid_rows, A, in_A) | p_passive
             if ov_nbrs is not None:
-                # delta-overlay edges (inserts since the base snapshot
-                # build, keto_tpu/graph/overlay.py): OR the overlay
-                # in-neighbors into their unique destination rows. Inside
-                # the loop, so multi-hop paths through delta edges converge
-                # exactly like base edges.
-                ovo = lax.reduce(R[ov_nbrs], np.uint32(0), lax.bitwise_or, (1,))
+                ovo = lax.reduce(A[in_A(ov_nbrs)], np.uint32(0), lax.bitwise_or, (1,))
                 p = p.at[ov_dst].set(p[ov_dst] | ovo, mode="drop")
-            act = R[:n_active]
+            act = A[:n_active]
             nxt = lax.bitwise_or(p, act)
-            return R.at[:n_active].set(nxt), p, jnp.any(nxt != act), it + 1
+            return A.at[:n_active].set(nxt), p, jnp.any(nxt != act), it + 1
 
         # Each while iteration runs a *block* of pulls, each skipped via
         # lax.cond once the fixpoint is reached (monotone bitmaps:
@@ -215,29 +245,33 @@ def check_step(
         # an R0 alias — so even a degenerate caller can't leak start bits
         # (which must never count as "reached via ≥ 1 edge") into answers.
         p0 = jnp.zeros((n_active, W), jnp.uint32)
-        R_fix, p_fix, truncated, iters = lax.while_loop(
+        A0 = jnp.concatenate([R0[:n_active], jnp.zeros((1, W), jnp.uint32)], axis=0)
+        A_fix, p_fix, truncated, iters = lax.while_loop(
             lambda st: st[2] & (st[3] < it_cap),
             block,
-            (R0, p0, jnp.bool_(True), jnp.int32(0)),
+            (A0, p0, jnp.bool_(True), jnp.int32(0)),
         )
         pull_p = jnp.concatenate([p_fix, jnp.zeros((1, W), jnp.uint32)], axis=0)
 
     # interior targets: "reached via ≥ 1 edge" = the pull of the fixpoint —
     # already computed by the converging iteration and carried out of the
     # loop — plus the one-hop term. Passive/absent targets read the padded
-    # all-zero rows.
+    # all-zero row.
     t_act = jnp.where(targets < n_active, targets, n_active)
-    a = pull_p[t_act, words] | ans_base[targets, words]
-    hit = (a >> bits) & jnp.uint32(1)
+    hit = ((pull_p[t_act, words] >> bits) & jnp.uint32(1)) | base_hit
 
     # sink targets: gather each entry's (interior in-neighbor row, query
     # word) from the fixpoint — start bits of the neighbor DO count here
-    # (the neighbor is not the target) — and scatter-OR per query.
-    # Collisions only combine entries of distinct (row, query) pairs: max
-    # on {0,1} is exact.
+    # (the neighbor is not the target) — and scatter-OR per query. A
+    # passive neighbor's fixpoint is its seed bits. Collisions only
+    # combine entries of distinct (row, query) pairs: max on {0,1} is
+    # exact.
     aw = a_q // 32
     ab = (a_q % 32).astype(jnp.uint32)
-    vals = (R_fix[a_rows, aw] >> ab) & jnp.uint32(1)
+    fix = R0[a_rows, aw]
+    if A_fix is not None:
+        fix = jnp.where(a_rows < n_active, A_fix[jnp.minimum(a_rows, n_active), aw], fix)
+    vals = (fix >> ab) & jnp.uint32(1)
     hit = hit.at[a_q].max(vals)
 
     # Single packed output ``uint32[W+2]``: per-query decision bits, then
@@ -393,11 +427,13 @@ _label_kernel_donated = partial(
 
 
 class _HybridSlice:
-    """Device output(s) of one label-routed slice: the label kernel's
-    packed bits for the whole slice, plus — when some queries fell back —
-    a BFS sub-batch output and the slice positions it answers. Quacks
-    like a device array where the streaming pipeline needs it
-    (``copy_to_host_async`` / ``is_ready``)."""
+    """Device output(s) of one slice whose BFS part answers only some of
+    its positions: the label kernel's packed bits for the whole slice (a
+    label-routed slice; None on the BFS route), plus — when some queries
+    fell back, or on the BFS route — a BFS sub-batch output and the slice
+    positions it answers (``device_part``: the queries the host could not
+    answer without the device). Quacks like a device array where the
+    streaming pipeline needs it (``copy_to_host_async`` / ``is_ready``)."""
 
     __slots__ = ("label_dev", "bfs_dev", "bfs_pos")
 
@@ -407,8 +443,8 @@ class _HybridSlice:
         self.bfs_pos = bfs_pos
 
     def parts(self) -> list:
-        # label_dev is None when every query in the chunk fell back to
-        # the BFS sub-batch (no certifiable pair survived routing)
+        # label_dev is None on the BFS route, and where no certifiable pair
+        # survived routing
         out = [] if self.label_dev is None else [self.label_dev]
         if self.bfs_dev is not None:
             out.append(self.bfs_dev)
@@ -658,30 +694,66 @@ def _pad_packed(packed, sizes: tuple, ni: int):
     )
 
 
-def _sub_packed(packed, chosen: np.ndarray, B: int, ni: int):
-    """``pack_chunk``'s seven arrays for the queries of a packed chunk that
-    ``chosen`` (bool per query) picks, renumbered 0.. in their order and
-    padded for width ``B``: what packing those queries alone would give,
-    without walking their starts a second time. None when none of them
-    has a seed (as ``pack_chunk``: nothing reaches the device)."""
+def device_part(snap: GraphSnapshot, packed, host_ans: np.ndarray):
+    """What of a packed chunk ``check_step`` has to see, and what the host
+    can say without it.
+
+    Only the active rows change under the pulls; every other interior row
+    keeps the bits it was seeded with. So a sink target whose answer rows
+    hold one of its query's own seed rows is granted here (**direct**: the
+    set intersection the kernel would otherwise do as a scatter into, and a
+    gather from, a bitmap over all interior rows), and a query gets
+    nothing more from the device unless its target side has an active
+    row: an answer row below ``num_active``, or an active interior target
+    (a passive one is answered by the host walk's own hit alone). The
+    others leave the chunk, so on a graph whose device part is small the
+    kernel runs a narrow sub-batch of few entries; where most rows are
+    active nearly every query stays.
+
+    ORs the direct grants into ``host_ans`` and returns ``(packed, pos)``:
+    the seven arrays of the queries that need the device, renumbered 0..
+    in their order and padded for the narrowest width that holds them, and
+    their positions in the chunk; ``(None, None)`` where none does.
+    """
     e1r, e1q, e2r, e2q, ar, aq, targets = packed
-    nq = chosen.size
-    place = np.cumsum(chosen) - 1
+    ni, na = snap.num_int, snap.num_active
+    nq = host_ans.shape[0]
+    v1, v2, va = e1r != ni + 1, e2r != ni + 1, ar != ni
+    e1r, e1q, e2r, e2q, ar, aq = e1r[v1], e1q[v1], e2r[v2], e2q[v2], ar[va], aq[va]
+    if ar.size:
+        direct = native_pack.pairs_member(
+            np.concatenate([e1r, e2r]), np.concatenate([e1q, e2q]), ar, aq
+        )
+        host_ans[aq[direct]] = True
+    need = targets[:nq] < na
+    need[aq[ar < na]] = True
+    need &= ~host_ans
+    k1, k2 = need[e1q], need[e2q]
+    if not k1.any() and not k2.any():
+        return None, None  # no query is left, or nothing seeds those that are
+    pos = np.nonzero(need)[0]
+    B = 32 * next(w for w in _WORD_WIDTHS if 32 * w >= pos.size)
+    place = np.cumsum(need) - 1
+    ka = need[aq]
+    # one pad for the three entry arrays, B·4^k: how many of its queries a
+    # chunk sends here varies from chunk to chunk, and every combination
+    # of pads is a program of its own to compile
+    E = B
+    while E < max(int(k1.sum()), int(k2.sum()), int(ka.sum())):
+        E *= 4
 
-    def side(rows, qs, pad_row):
-        keep = (rows != pad_row) & (qs < nq)
-        keep[keep] = chosen[qs[keep]]
-        return [rows[keep]], [place[qs[keep]]]
+    def side(rows, q, keep, pad_row):
+        out_r, out_q = np.full(E, pad_row, np.int32), np.zeros(E, np.int32)
+        n = int(keep.sum())
+        out_r[:n], out_q[:n] = rows[keep], place[q[keep]]
+        return out_r, out_q
 
-    e1, e2 = side(e1r, e1q, ni + 1), side(e2r, e2q, ni + 1)
-    if not e1[0][0].size and not e2[0][0].size:
-        return None
     sub_targets = np.full(B, ni, np.int32)
-    picked = targets[:nq][chosen]
-    sub_targets[: picked.size] = picked
+    sub_targets[: pos.size] = targets[pos]
     return (
-        _pad_entries(*e1, B, ni + 1) + _pad_entries(*e2, B, ni + 1)
-        + _pad_entries(*side(ar, aq, ni), B, ni) + (sub_targets,)
+        side(e1r, e1q, k1, ni + 1) + side(e2r, e2q, k2, ni + 1)
+        + side(ar, aq, ka, ni) + (sub_targets,),
+        pos,
     )
 
 
@@ -1199,7 +1271,7 @@ class TpuCheckEngine:
         # (keto_tpu/check/geometry.py); warm_compile settles block_iters, a
         # static of every one of them, from the snapshot
         self._geoms = KernelGeometries(self._compile_geometry)
-        self._block_iters_settled = False
+        self._block_iters_shape: Optional[tuple] = None  # the shape it was settled on
         # concurrently in-flight chunks (bounds device bitmap workspaces)
         self._dispatch_window = 16
         # streaming pipeline: the latency-adaptive width controller is
@@ -3293,13 +3365,19 @@ class TpuCheckEngine:
 
     def _check_shape(self, snap: GraphSnapshot) -> tuple:
         """What of the snapshot fixes a ``check_step`` program: row counts
-        and the shapes of the arrays it closes over."""
-        ov = snap.device_overlay
-        return (
+        and the shapes of the arrays it closes over. Kept on the snapshot
+        for as long as it holds the same device arrays."""
+        bk, ov = snap.device_buckets, snap.device_overlay
+        kept = getattr(snap, "_check_shape_of", None)
+        if kept is not None and kept[0] is bk and kept[1] is ov:
+            return kept[2]
+        shape = (
             snap.num_active, snap.num_int, tuple(b.n for b in snap.buckets),
-            tuple(a.shape for a in snap.device_buckets),
+            tuple(a.shape for a in bk),
             None if ov is None else (ov[0].shape, ov[1].shape),
         )
+        snap._check_shape_of = (bk, ov, shape)
+        return shape
 
     def _check_fixed(self, it_cap: int) -> tuple:
         return (it_cap, self._block_iters, self._donate_entries)
@@ -3368,11 +3446,12 @@ class TpuCheckEngine:
 
     def _settle_block_iters(self, snap: GraphSnapshot, B: int) -> None:
         """``block_iters`` is a static of every ``check_step`` program, so a
-        change recompiles them all: settle it once, before the ladder is
-        warmed, from how deep the snapshot's own device part runs - a BFS
-        from a spread of the interior rows nothing on the device points at
-        (the sources of what the pulls walk) - and leave it there."""
-        self._block_iters_settled = True
+        change recompiles them all: settle it before the ladder is warmed,
+        from how deep the snapshot's own device part runs - a BFS from a
+        spread of the interior rows nothing on the device points at (the
+        sources of what the pulls walk) - and leave it there for as long as
+        snapshots keep this shape (``_after_batch``)."""
+        self._block_iters_shape = self._check_shape(snap)
         na, ni = snap.num_active, snap.num_int
         if na == 0 or ni <= na or not snap.buckets:
             return
@@ -3956,7 +4035,7 @@ class TpuCheckEngine:
             self._note_device_error(e)
             return self._fallback_check(tuples)
         self._note_device_ok()
-        self._after_batch(max_iters)
+        self._after_batch(max_iters, snap)
         self._audit_sample(tuples, out, snap.snapshot_id)
         return out.tolist(), snap.snapshot_id
 
@@ -4265,7 +4344,10 @@ class TpuCheckEngine:
             if dev is None:
                 route = "host"
             elif isinstance(dev, _HybridSlice):
-                route = "label" if dev.bfs_dev is None else "hybrid"
+                route = (
+                    "label" if dev.bfs_dev is None
+                    else "bfs" if dev.label_dev is None else "hybrid"
+                )
             else:
                 route = "bfs"
             if ctrl is not None:
@@ -4366,7 +4448,7 @@ class TpuCheckEngine:
             # land() already released is a no-op here)
             for rec in inflight:
                 self._stage_release(rec[6])
-        self._after_batch(max_iters)
+        self._after_batch(max_iters, snap)
 
     def _slice_cap(self, snap: GraphSnapshot) -> int:
         """Queries per device slice: the widest bitmap the workspace budget
@@ -4419,6 +4501,25 @@ class TpuCheckEngine:
             cnt[m_ans] += sp_[t + 1] - sp_[t]
         return cnt
 
+    @staticmethod
+    def _device_reach(snap: GraphSnapshot) -> Optional[np.ndarray]:
+        """``bool[num_live]``: can the device add anything to the answer of
+        a query with this target - an active interior row, or a sink that
+        gathers its answer from one. Worked out once a snapshot; None where
+        overlay edges into sinks would have to be counted too."""
+        if snap.ov_sink_in or snap.sink_indptr is None:
+            return None
+        reach = getattr(snap, "_device_reach_of", None)
+        if reach is None:
+            reach = np.zeros(snap.num_live, bool)
+            reach[: snap.num_active] = True
+            sink_of = np.repeat(
+                np.arange(snap.sink_indptr.shape[0] - 1), np.diff(snap.sink_indptr)
+            )
+            reach[snap.sink_base + sink_of[snap.sink_indices < snap.num_active]] = True
+            snap._device_reach_of = reach
+        return reach
+
     def _dispatch_slices(
         self,
         snap: GraphSnapshot,
@@ -4465,6 +4566,14 @@ class TpuCheckEngine:
                 if budget is not None:
                     cap_e = min(cap_e, max(B, budget))
             cnt = self._entry_counts(snap, sd, tg, multi)
+            if int(cnt.sum()) > cap_e:
+                reach = self._device_reach(snap)
+                if reach is not None:
+                    # a query whose target side has no row that a pull
+                    # changes sends the device nothing (``device_part``):
+                    # its entries do not count towards a split
+                    known = (tg >= 0) & (tg < snap.num_live)
+                    cnt[known & ~reach[np.where(known, tg, 0)]] = 0
             if int(cnt.sum()) <= cap_e:
                 bounds = [(0, nq)]
             else:
@@ -4705,15 +4814,16 @@ class TpuCheckEngine:
         self._route_slices.clear()
         self._route_queries.clear()
 
-    def _after_batch(self, max_iters: int) -> None:
+    def _after_batch(self, max_iters: int, snap: GraphSnapshot) -> None:
         # adapt the pull-block size so deep workloads converge within few
         # convergence observations. Grow-only: block_iters is a static jit
         # argname, so shrinking it would recompile every kernel geometry for
         # a marginal saving (converged pulls inside a block are lax.cond
         # no-ops) — growing pays one recompile to cut while-loop trips.
-        # warm_compile settles it from the snapshot: growing it later would
-        # recompile, on the serving thread, every program it warmed.
-        if self._block_iters_settled:
+        # Not on the shape warm_compile settled it for: growing it there
+        # would recompile, on the serving thread, every program it warmed.
+        # A snapshot of another shape compiles its programs anyway.
+        if snap.device_buckets is not None and self._block_iters_shape == self._check_shape(snap):
             return
         want = min(32, _ceil_pow2(max_iters + 1))
         if want > self._block_iters:
@@ -4811,49 +4921,60 @@ class TpuCheckEngine:
         )
         b_q = np.concatenate([np.nonzero(t_int)[0], aq[ma].astype(np.int64)])
 
-        # group both sides by query, then cross-join per query
-        so = np.argsort(s_q, kind="stable")
-        s_rows, s_q = s_rows[so], s_q[so]
-        bo = np.argsort(b_q, kind="stable")
-        b_rows, b_q = b_rows[bo], b_q[bo]
+        # count each side per query first: a query over the pair cap takes
+        # neither side into the sort and the cross-join below
         ns = np.bincount(s_q, minlength=nq)
         nr = np.bincount(b_q, minlength=nq)
-        n_pairs_q = ns * nr
-        over = n_pairs_q > self._LABEL_PAIR_CAP
+        over = ns * nr > self._LABEL_PAIR_CAP
         if over.any():
             fall_back("pair_cap", over)
-        # drop both sides of fallback queries before the join
-        keep_s = ~fallback[s_q]
-        keep_b = ~fallback[b_q]
-        s_rows, s_q = s_rows[keep_s], s_q[keep_s]
-        b_rows, b_q = b_rows[keep_b], b_q[keep_b]
-        ns = np.bincount(s_q, minlength=nq) if s_q.size else np.zeros(nq, np.int64)
-        nr = np.bincount(b_q, minlength=nq) if b_q.size else np.zeros(nq, np.int64)
 
-        rep_nr = np.repeat(nr, ns)  # aligned to s_rows
-        total = int(rep_nr.sum())
-        if total:
-            b_starts = np.cumsum(nr) - nr
-            seed_q = s_q
-            base = np.repeat(b_starts[seed_q], rep_nr)
-            csum = np.cumsum(rep_nr) - rep_nr
-            within = np.arange(total) - np.repeat(csum, rep_nr)
-            pa = np.repeat(s_rows, rep_nr)
-            pb = b_rows[base + within]
-            pq = np.repeat(seed_q, rep_nr)
-            # e2-seed == target pairs: already host-granted, reach0 would
-            # double-count the 0-edge path — drop (e1 cases fell back)
-            drop = t_int[pq] & (pa == pb)
-            if drop.any():
-                pa, pb, pq = pa[~drop], pb[~drop], pq[~drop]
-            # coverage: a miss on an uncertifiable pair is not a deny
-            cert = idx.certifiable(pa, pb)
-            if not cert.all():
-                fall_back("uncertifiable", np.unique(pq[~cert]))
-                keep = ~fallback[pq]
-                pa, pb, pq = pa[keep], pb[keep], pq[keep]
-        else:
-            pa = pb = pq = np.zeros(0, np.int64)
+        def rides_whole() -> bool:
+            # a sub-batch as wide as the slice holds the slice: the queries
+            # the label kernel could take ride it too, and the label kernel
+            # is not launched
+            n = int(np.count_nonzero(fallback))
+            return n > 0 and next(w for w in _WORD_WIDTHS if 32 * w >= n) >= W
+
+        pa = pb = pq = np.zeros(0, np.int64)
+        whole = rides_whole()
+        if not whole:
+            keep_s = ~fallback[s_q]
+            keep_b = ~fallback[b_q]
+            s_rows, s_q = s_rows[keep_s], s_q[keep_s]
+            b_rows, b_q = b_rows[keep_b], b_q[keep_b]
+            # group both sides by query, then cross-join per query
+            so = np.argsort(s_q, kind="stable")
+            s_rows, s_q = s_rows[so], s_q[so]
+            bo = np.argsort(b_q, kind="stable")
+            b_rows, b_q = b_rows[bo], b_q[bo]
+            ns = np.bincount(s_q, minlength=nq) if s_q.size else np.zeros(nq, np.int64)
+            nr = np.bincount(b_q, minlength=nq) if b_q.size else np.zeros(nq, np.int64)
+            rep_nr = np.repeat(nr, ns)  # aligned to s_rows
+            total = int(rep_nr.sum())
+            if total:
+                b_starts = np.cumsum(nr) - nr
+                seed_q = s_q
+                base = np.repeat(b_starts[seed_q], rep_nr)
+                csum = np.cumsum(rep_nr) - rep_nr
+                within = np.arange(total) - np.repeat(csum, rep_nr)
+                pa = np.repeat(s_rows, rep_nr)
+                pb = b_rows[base + within]
+                pq = np.repeat(seed_q, rep_nr)
+                # e2-seed == target pairs: already host-granted, reach0 would
+                # double-count the 0-edge path — drop (e1 cases fell back)
+                drop = t_int[pq] & (pa == pb)
+                if drop.any():
+                    pa, pb, pq = pa[~drop], pb[~drop], pq[~drop]
+                # coverage: a miss on an uncertifiable pair is not a deny
+                cert = idx.certifiable(pa, pb)
+                if not cert.all():
+                    fall_back("uncertifiable", np.unique(pq[~cert]))
+                    keep = ~fallback[pq]
+                    pa, pb, pq = pa[keep], pb[keep], pq[keep]
+                    whole = rides_whole()
+        if whole:
+            fall_back("whole_slice", ~fallback)
 
         n_fb = int(np.count_nonzero(fallback))
         self.maintenance.incr("label_checks", by=nq - n_fb)
@@ -4862,6 +4983,10 @@ class TpuCheckEngine:
             for reason, count in reasons.items():
                 if count:
                     self.maintenance.incr(f"label_fallbacks_{reason}", by=count)
+        if whole:
+            faults.check("device-exec")
+            dev, leases = self._launch_check(snap, packed, host_ans, it_cap, "bfs")
+            return dev, host_ans, leases
 
         ldev = None
         if pa.size:
@@ -4932,15 +5057,26 @@ class TpuCheckEngine:
         bfs_dev = None
         bfs_pos = None
         if n_fb:
-            # the fallback queries' entries are in ``packed`` already: the
-            # sub-batch is cut out of it, not walked and packed again
-            bfs_pos = np.nonzero(fallback)[0]
+            pos = np.nonzero(fallback)[0]
+            gidx = pos + i0
+            multi2 = {
+                j: multi[int(i)] for j, i in enumerate(gidx) if int(i) in multi
+            }
             W2 = next(w for w in _WORD_WIDTHS if 32 * w >= n_fb)
-            sub = _sub_packed(packed, fallback, 32 * W2, ni)
-            if sub is not None:
+            clk.enter(PACK)
+            packed2, host2 = pack_chunk(
+                snap, sd[gidx], tg[gidx], multi2, 0, n_fb, W2,
+                native=self._native_pack,
+            )
+            if packed2 is not None:
                 faults.check("device-exec")
-                bfs_dev, bfs_leases = self._launch_check(snap, sub, it_cap, "hybrid")
+                sub, bfs_leases = self._launch_check(
+                    snap, packed2, host2, it_cap, "hybrid", sub_of=pos
+                )
                 leases.extend(bfs_leases)
+                host_ans[pos] |= host2  # what the host granted without the device
+                if sub is not None:
+                    bfs_dev, bfs_pos = sub.bfs_dev, sub.bfs_pos
         if ldev is None and bfs_dev is None:
             return None, host_ans, leases
         return _HybridSlice(ldev, bfs_dev, bfs_pos), host_ans, leases
@@ -4973,19 +5109,30 @@ class TpuCheckEngine:
             # no query in the chunk reaches the device: host_ans is the
             # whole answer
             return None, host_ans, []
-        dev, leases = self._launch_check(snap, packed, it_cap, route)
+        dev, leases = self._launch_check(snap, packed, host_ans, it_cap, route)
         return dev, host_ans, leases
 
     def _launch_check(
-        self, snap: GraphSnapshot, packed, it_cap: Optional[int], route: str
+        self, snap: GraphSnapshot, packed, host_ans: np.ndarray,
+        it_cap: Optional[int], route: str, sub_of: Optional[np.ndarray] = None,
     ):
-        """Ship one packed chunk to ``check_step``. Returns ``(dev,
-        leases)``."""
+        """Ship what of one packed chunk the device has to see
+        (``device_part``: the rest is granted into ``host_ans`` here) to
+        ``check_step``. Returns ``(slice, leases)``: a ``_HybridSlice`` of
+        no label part whose BFS part answers the chunk's positions that
+        needed the device, or None where none does. ``sub_of`` says where
+        the chunk's own queries sit in a wider slice."""
         clk = dispatch_clock()
         leases: list = []
         it_cap = it_cap or self._it_cap
         if self._sharded and snap.device_shards is not None:
-            return self._dispatch_sharded(snap, packed, it_cap, leases=leases), leases
+            dev = self._dispatch_sharded(snap, packed, it_cap, leases=leases)
+            return (dev if sub_of is None else _HybridSlice(None, dev, sub_of)), leases
+        packed, pos = device_part(snap, packed, host_ans)
+        if packed is None:
+            return None, leases
+        if sub_of is not None:
+            pos = sub_of[pos]
         stg = met = None
         if self._mesh is None:
             own = tuple(packed[i].shape[0] for i in (0, 2, 4, 6))
@@ -5031,7 +5178,7 @@ class TpuCheckEngine:
         )
         if met == INLINE:
             self._geoms.add("check", shape, fixed, own)
-        return dev, leases
+        return _HybridSlice(None, dev, pos), leases
 
     def _dispatch_sharded(
         self, snap: GraphSnapshot, packed, it_cap: int, leases=None

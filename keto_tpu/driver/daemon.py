@@ -334,7 +334,8 @@ class Daemon:
                     # replays them from disk before traffic arrives
                     n = engine.warm_compile()
                     self.registry.logger().info(
-                        "width-ladder warmup compiled/loaded %d kernels", n
+                        "width-ladder warmup compiled/loaded %d kernels "
+                        "(block_iters %s)", n, getattr(engine, "_block_iters", "-"),
                     )
             except Exception:
                 stats = getattr(engine, "maintenance", None)

@@ -1701,7 +1701,7 @@ class Registry:
             counters, _, _ = maintenance_raw()
             return [
                 ((reason,), float(counters.get(f"label_fallbacks_{reason}", 0)))
-                for reason in ("pair_cap", "uncertifiable", "self_hit", "multi")
+                for reason in ("pair_cap", "uncertifiable", "self_hit", "multi", "whole_slice")
             ]
 
         m.register_callback(
@@ -1710,7 +1710,9 @@ class Registry:
             "by the first cause found: pair_cap (seeds x target rows above "
             "64 pairs), uncertifiable (a pair the index cannot certify), "
             "self_hit (a start row that is the target), multi (wildcard or "
-            "multi-start subject).",
+            "multi-start subject), whole_slice (none of its own: the slice's "
+            "other queries made a BFS sub-batch as wide as the slice, so the "
+            "whole slice rode it and the label kernel was not launched).",
             label_fallback_reasons, ("reason",),
         )
 

@@ -161,7 +161,7 @@ constexpr int64_t kParallelThreshold = 1 << 16;
 extern "C" {
 
 // ABI version probe: the Python binding refuses a stale .so.
-int64_t keto_pack_version() { return 1; }
+int64_t keto_pack_version() { return 2; }
 
 void* keto_pack_walk(
     const int64_t* fwd_indptr, const int32_t* fwd_indices, int64_t n_base,
@@ -305,5 +305,28 @@ void keto_gather_fetch(void* h, int32_t* rows, int64_t* cnts) {
 }
 
 void keto_gather_free(void* h) { delete static_cast<GatherResult*>(h); }
+
+// Which of the probe (row, query) pairs are among the set's pairs:
+// out[i] = 1 where (probe_rows[i], probe_q[i]) is one of them. What
+// tpu_engine._device_part asks of a packed chunk: is a row that a sink
+// target gathers its answer from one of its query's own seed rows.
+void keto_pairs_member(const int32_t* set_rows, const int32_t* set_q,
+                       int64_t n_set, const int32_t* probe_rows,
+                       const int32_t* probe_q, int64_t n_probe, uint8_t* out) {
+    KeySet set;
+    set.reserve((size_t)n_set);
+    for (int64_t i = 0; i < n_set; ++i)
+        set.insert(((uint64_t)(uint32_t)set_q[i] << 32) | (uint32_t)set_rows[i]);
+    for (int64_t i = 0; i < n_probe; ++i) {
+        uint64_t key = ((uint64_t)(uint32_t)probe_q[i] << 32) | (uint32_t)probe_rows[i];
+        size_t j = KeySet::mix(key) & set.mask;
+        uint8_t hit = 0;
+        while (!set.slots.empty() && set.slots[j]) {
+            if (set.slots[j] == key + 1) { hit = 1; break; }
+            j = (j + 1) & set.mask;
+        }
+        out[i] = hit;
+    }
+}
 
 }  // extern "C"

@@ -54,15 +54,30 @@ def as_query(q):
 
 class Deployment:
     """The generator's graph in a store, with a pool of queries as the bulk
-    traffic builds them and what the reference and the generator say of each."""
+    traffic builds them and what the reference and the generator say of each.
 
-    def __init__(self, seed: int):
+    ``ring``: the top-level groups that hold no folder are also members of
+    one another in a ring. The generator's device part is the all-staff
+    groups and their leaves (the host walk and ``device_part`` answer every
+    query that does not touch them); the ring puts every group on the
+    device, and changes no answer, since membership of a group that holds
+    no folder grants nothing."""
+
+    def __init__(self, seed: int, ring: bool = False):
         self.graph = GEN.build(random.Random(seed), TUPLES)
+        rows = list(self.graph.rows)
+        if ring:
+            holds = {r[5] for r in rows if r[0] == "directories" and r[4] == "groups"}
+            tops = sorted({r[1] for r in rows if r[0] == "groups" and r[4] == "groups"} - holds,
+                          key=lambda g: int(g.split("-")[1]))
+            rows += [("groups", a, "member", None, "groups", b, "member")
+                     for a, b in zip(tops, tops[1:] + tops[:1])]
+        self.ring, self.rows = ring, rows
         self.store = MemoryPersister(namespace_pkg.MemoryManager(NSS))
-        self.store.write_relation_tuples(*map(as_tuple, self.graph.rows))
+        self.store.write_relation_tuples(*map(as_tuple, rows))
         objects = traffic.skewed_objects(seed, self.graph.n_objects, POOL, SKEW)
         self.queries, self.analytic = GEN.queries(self.graph, random.Random(seed + 1), objects)
-        reference = Reference(self.graph.rows)
+        reference = Reference(rows)
         self.reference = [reference.allowed(*q) for q in self.queries]
         self.tuples = [as_query(q) for q in self.queries]
 
@@ -73,14 +88,21 @@ class Deployment:
         return eng
 
 
-@pytest.fixture(scope="module", params=SEEDS)
+@pytest.fixture(scope="module", params=SEEDS + ("ring",), ids=str)
 def deployment(request):
+    if request.param == "ring":
+        return Deployment(SEEDS[0], ring=True)
     return Deployment(request.param)
 
 
 @pytest.fixture(scope="module")
 def one():
     return Deployment(SEEDS[0])
+
+
+@pytest.fixture(scope="module")
+def ringed():
+    return Deployment(SEEDS[0], ring=True)
 
 
 def stream(engine, tuples, width=1024):
@@ -102,6 +124,22 @@ def test_reference_and_analytic_expectation_agree(deployment):
     assert 0.5 < share < 0.95, "the pool should hold grants and denials"
 
 
+def over_the_pair_cap(engine, tuples):
+    """Per query of ``tuples``: do its seed rows times its target-side rows
+    pass the label route's pair cap (what ``_device_batch_labeled`` counts)."""
+    snap = engine.snapshot()
+    sd, tg, multi = engine._resolve_bulk(snap, tuples)
+    n = len(tuples)
+    W = next(w for w in te._WORD_WIDTHS if 32 * w >= n)
+    packed = te.pack_chunk(snap, sd, tg, multi, 0, n, W)[0]
+    e1r, e1q, e2r, e2q, ar, aq, targets = packed
+    ni = snap.num_int
+    ns = (np.bincount(e1q[e1r != ni + 1], minlength=n)[:n]
+          + np.bincount(e2q[e2r != ni + 1], minlength=n)[:n])
+    nr = np.bincount(aq[ar != ni], minlength=n)[:n] + (targets[:n] < ni)
+    return ns * nr > engine._LABEL_PAIR_CAP
+
+
 @pytest.mark.parametrize("route", ["labels_on", "labels_off", "oracle"])
 def test_engine_equals_reference_and_expectation(deployment, route):
     if route == "oracle":
@@ -113,16 +151,51 @@ def test_engine_equals_reference_and_expectation(deployment, route):
             got = stream(engine, deployment.tuples)
             counters = engine.maintenance.snapshot()
             routes = engine.route_slice_counts()
+            assert routes.get("bfs", 0) + routes.get("hybrid", 0) > 0, (
+                f"no slice reached check_step: the test is vacuous ({routes})"
+            )
+            if route == "labels_on":
+                assert counters.get("label_fallbacks_pair_cap", 0) > 0, (
+                    "no check left the label kernel: the test is vacuous"
+                )
+            else:
+                assert not routes.get("hybrid", 0) and not routes.get("label", 0)
+            if route == "labels_on" and deployment.ring:
+                # a slice with a few such queries among ones the label
+                # kernel takes is a hybrid, whatever its sub-chunks
+                over = over_the_pair_cap(engine, deployment.tuples)
+                under = list(np.nonzero(~over)[0][:200])
+                assert len(under) >= 40, "the pool has too few queries under the cap"
+                few = list(np.nonzero(over)[0][:20]) + under  # 20 of 60..220: a sub-batch of one word
+                engine.reset_route_stats()
+                mixed = stream(engine, [deployment.tuples[i] for i in few])
+                assert mixed == [deployment.reference[i] for i in few]
+                assert engine.route_slice_counts().get("hybrid", 0) >= 1, (
+                    "no hybrid slice landed: the test is vacuous"
+                )
         finally:
             engine.close()
-        if route == "labels_on":
-            assert counters.get("label_fallbacks", 0) > 0 and routes.get("hybrid", 0) > 0, (
-                "no check left the label kernel: the test is vacuous"
-            )
-        else:
-            assert routes.get("bfs", 0) > 0 and not routes.get("hybrid", 0)
     assert got == deployment.reference
     assert got == deployment.analytic
+
+
+def test_a_slice_that_mostly_falls_back_rides_check_step_whole(ringed):
+    """A sub-batch as wide as the slice holds the slice: the label kernel is
+    not launched, and the queries it could have taken are counted as handed
+    over, by that cause."""
+    engine = ringed.engine()
+    try:
+        engine.stream_ctrl.entry_budget = lambda: None  # one sub-chunk a slice, as on a fast device
+        seen = launched_sizes(engine)
+        # 512 queries: a slice of the 2,048 width, most of them over the cap
+        assert stream(engine, ringed.tuples[:512], 512) == ringed.reference[:512]
+        assert seen and all(kernel == "check" for kernel, _ in seen), seen
+        counters = engine.maintenance.snapshot()
+        assert counters["label_fallbacks"] == 512 and not counters.get("label_checks")
+        assert counters["label_fallbacks_whole_slice"] == 512 - counters["label_fallbacks_pair_cap"] > 0
+        assert set(engine.route_slice_counts()) == {"bfs"}
+    finally:
+        engine.close()
 
 
 def test_framed_batch_through_rest_gives_the_same_answers(one):
@@ -186,10 +259,49 @@ def test_a_slice_pads_up_only_once_warmed_and_its_own_program_compiles_behind_it
         assert g.meet("check", shape, fixed, huge) == (huge, INLINE)
         # another snapshot shape starts an empty, unwarmed set
         assert g.meet("check", ("other",), fixed, (256,) * 4) == ((256,) * 4, INLINE)
+        # and the first, met again, is as it was left
+        assert g.meet("check", shape, fixed, own) == (own, COMPILED)
         counts = g.counts()
-        assert counts[("check", INLINE)] == 3 and counts[("check", COMPILED)] >= 2
+        assert counts[("check", INLINE)] == 3 and counts[("check", COMPILED)] >= 3
         assert counts[("check", PADDED_UP)] >= 2
     finally:
+        g.close()
+
+
+def test_a_compile_that_ends_after_its_shape_has_gone_leaves_the_new_shape_alone():
+    """The snapshot changes while the worker compiles: what it compiled is
+    entered for the shape it was asked for, and the shape served meanwhile
+    keeps its sizes and stays warmed."""
+    started, release = threading.Event(), threading.Event()
+
+    def compile_fn(kernel, shape, fixed, sizes):
+        started.set()
+        assert release.wait(10)
+        return True
+
+    g = KernelGeometries(compile_fn)
+    old, new, fixed = ("old",), ("new",), ("fixed",)
+    own = (256, 512, 256, 256)
+    try:
+        for shape in (old, new):
+            g.add("check", shape, fixed, (2048,) * 4)
+            g.mark_warmed("check", shape)
+        assert g.meet("check", old, fixed, own) == ((2048,) * 4, PADDED_UP)
+        assert started.wait(10)
+        # the refresh: slices of the new shape are served while the worker compiles
+        g.add("check", new, fixed, (256,) * 4)
+        assert g.meet("check", new, fixed, (256,) * 4) == ((256,) * 4, COMPILED)
+        release.set()
+        deadline = time.monotonic() + 10
+        while g.pending() and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert g.meet("check", new, fixed, (256,) * 4) == ((256,) * 4, COMPILED)
+        assert g.meet("check", new, fixed, (512,) * 4) == ((2048,) * 4, PADDED_UP), "no longer warmed"
+        assert g.meet("check", new, fixed, own)[1] == PADDED_UP, "the old shape's program is not the new one's"
+        assert g.meet("check", old, fixed, own) == (own, COMPILED)
+        assert not g.counts().get(("check", INLINE))
+    finally:
+        release.set()
         g.close()
 
 
@@ -221,7 +333,8 @@ def wait_for_worker(engine, timeout=120.0):
         time.sleep(0.02)
 
 
-def test_after_warm_compile_no_pass_compiles_on_the_calling_thread(one):
+def test_after_warm_compile_no_pass_compiles_on_the_calling_thread(ringed):
+    one = ringed
     """The pin: on the parent (4203fa9) the same passes compile 7 programs on
     the calling thread, ``check_step`` and ``label_step`` at entry pads that
     are not the rung ``warm_compile`` warmed."""
@@ -246,7 +359,24 @@ def test_after_warm_compile_no_pass_compiles_on_the_calling_thread(one):
         engine.close()
 
 
-def test_an_engine_nobody_warmed_compiles_inline_and_says_so(one):
+def test_block_iters_is_left_alone_on_the_warmed_shape_only(ringed):
+    one = ringed
+    engine = one.engine()
+    try:
+        engine.warm_compile()
+        snap, settled = engine.snapshot(), engine._block_iters
+        engine._after_batch(30, snap)
+        assert engine._block_iters == settled, "grew on the shape the ladder was warmed for"
+        # a refresh that changes the shape: its programs compile anyway
+        engine._block_iters_shape = ("another shape",)
+        engine._after_batch(30, snap)
+        assert engine._block_iters == 32
+    finally:
+        engine.close()
+
+
+def test_an_engine_nobody_warmed_compiles_inline_and_says_so(ringed):
+    one = ringed
     engine = one.engine()
     try:
         assert stream(engine, one.tuples[:1024]) == one.reference[:1024]
@@ -277,37 +407,93 @@ def launched_sizes(engine):
     return seen
 
 
-def test_padded_geometry_answers_bit_identically_on_a_rung_boundary(one):
+def test_padded_geometry_answers_bit_identically_on_a_rung_boundary(ringed):
     """Two slices cut on either side of a rung boundary - the longest whose
-    seed entries still fit 2 x B, and one query more - through the exact
-    programs of an engine nobody warmed and the padded ones of a warmed
-    engine: the same bits."""
+    device part still has the entry pads of the one before it, and one query
+    more - through the exact programs of an engine nobody warmed and the
+    padded ones of a warmed engine: the same bits."""
+    one = ringed
     exact = one.engine(labels_enabled=False)
     padded = one.engine(labels_enabled=False)
     try:
         snap = exact.snapshot()
         sd, tg, multi = exact._resolve_bulk(snap, one.tuples)
-        B = 256
 
-        def seeds(n):
-            packed = te.pack_chunk(snap, sd, tg, multi, 0, n, B // 32)[0]
-            return 0 if packed is None else int(np.count_nonzero(packed[2] != snap.num_int + 1))
+        def sizes(n):
+            """The sizes the first ``n`` queries launch at, as one slice."""
+            W = next(w for w in te._WORD_WIDTHS if 32 * w >= n)
+            packed, host_ans = te.pack_chunk(snap, sd, tg, multi, 0, n, W)
+            sub = None if packed is None else te.device_part(snap, packed, host_ans)[0]
+            return None if sub is None else tuple(sub[i].shape[0] for i in (0, 2, 4, 6))
 
-        n = next(n for n in range(2, B + 1) if seeds(n) > 2 * B)
-        assert seeds(n - 1) <= 2 * B < seeds(n)
+        above = lambda sz: sz is not None and max(sz[:3]) > sz[3]  # off the warmed minimum rung
+        n = next(n for n in range(64, 1024) if above(sizes(n - 1)) and sizes(n) != sizes(n - 1))
+        def answer(engine, cut):
+            """The first ``cut`` queries as one chunk, not split by entries."""
+            snap = engine.snapshot()
+            resolved = engine._resolve_bulk(snap, one.tuples)
+            W = next(w for w in te._WORD_WIDTHS if 32 * w >= cut)
+            dev, host_ans, leases = engine._device_batch(snap, *resolved, 0, cut, W)
+            try:
+                return engine._unpack_slice(dev, host_ans, cut)[0].tolist()
+            finally:
+                engine._stage_release(leases)
+
         padded.warm_compile()
-        seen = launched_sizes(padded)
+        own, ran = launched_sizes(exact), launched_sizes(padded)
         for cut in (n - 1, n):
-            want = exact.batch_check(one.tuples[:cut])
-            assert padded.batch_check(one.tuples[:cut]) == want == one.reference[:cut]
-        assert len(seen) == 2 and all(kernel == "check" for kernel, _ in seen)
-        assert all(sizes[3] > B or sizes[1] > 2 * B for _, sizes in seen), (
-            f"the warmed engine ran the slices at their own sizes: {seen}")
+            assert answer(padded, cut) == answer(exact, cut) == one.reference[:cut]
+        assert [sz for _, sz in own] == [sizes(n - 1), sizes(n)]
+        assert all(r != o and all(a >= b for a, b in zip(r, o))
+                   for (_, r), (_, o) in zip(ran, own)), (
+            f"the warmed engine ran the slices at their own sizes: {ran}")
         assert padded.kernel_geometry_counts().get(("check", PADDED_UP), 0) == 2
         assert exact.kernel_geometry_counts() == {("check", INLINE): 2}
     finally:
         exact.close()
         padded.close()
+
+
+@pytest.mark.parametrize("where", ["ringed", "cycle"])
+def test_device_part_grants_what_the_seeds_hold_and_keeps_what_an_active_row_can_change(ringed, where):
+    """``device_part`` against the kernel itself: a chunk run whole through
+    ``check_step`` gives the bits that the host's direct grants and the
+    sub-batch's bits give together. On the ringed deployment every grant is
+    direct and the device part confirms the denials; on a membership cycle
+    the grants come through rows that the pulls change."""
+    if where == "ringed":
+        store, tuples, want = ringed.store, ringed.tuples[:1024], ringed.reference[:1024]
+    else:
+        store = small_store()
+        tuples = [T("files", f"doc-{c}", "access", SubjectID(u))
+                  for c in "gh" for u in ("ann", "bob", "cyd", "dee")] * 8
+        want = [CheckEngine(store).subject_is_allowed(q) for q in tuples]
+    engine = TpuCheckEngine(store, store.namespaces, compact_after_s=3600.0, labels_enabled=False)
+    try:
+        snap = engine.snapshot()
+        n = len(tuples)
+        W = next(w for w in te._WORD_WIDTHS if 32 * w >= n)
+        sd, tg, multi = engine._resolve_bulk(snap, tuples)
+        packed, host_ans = te.pack_chunk(snap, sd, tg, multi, 0, n, W)
+        kw = dict(n_active=snap.num_active, n_int=snap.num_int,
+                  valid_rows=tuple(b.n for b in snap.buckets), it_cap=64)
+        run = lambda pk: np.asarray(te.check_step(
+            snap.device_buckets, te.pack_entries(pk)[0],
+            sizes=tuple(pk[i].shape[0] for i in (0, 2, 4, 6)), **kw))
+        whole = engine._decode_packed(run(packed), host_ans.copy(), n)[0]
+        granted = host_ans.copy()
+        sub, pos = te.device_part(snap, packed, granted)
+        assert sub is not None and 0 < pos.size < n and not granted[pos].any()
+        bits = engine._decode_packed(run(sub), np.zeros(pos.size, bool), pos.size)[0]
+        if where == "ringed":
+            assert granted.sum() > host_ans.sum(), "no direct grant: vacuous"
+        else:
+            assert bits.any(), "the device part granted nothing: vacuous"
+        got = granted.copy()
+        got[pos] |= bits
+        assert got.tolist() == whole.tolist() == want
+    finally:
+        engine.close()
 
 
 # -- why a check leaves the label kernel -----------------------------------------
@@ -368,12 +554,13 @@ def test_each_fallback_reason_is_reached_by_a_query_built_for_it(reason, query, 
         engine.close()
 
 
-def test_new_families_are_on_metrics(one):
+def test_new_families_are_on_metrics(ringed):
+    one = ringed
     reg = Registry(Config(overrides={
         "namespaces": [{"id": n.id, "name": n.name} for n in NSS],
     }))
     try:
-        reg.relation_tuple_manager().write_relation_tuples(*map(as_tuple, one.graph.rows))
+        reg.relation_tuple_manager().write_relation_tuples(*map(as_tuple, one.rows))
         app = RestApp(reg, READ)
         engine = reg.permission_engine()
         engine.labels_settled()  # the build overlaps: the label route has to be live

@@ -255,6 +255,9 @@ def test_router_fallbacks_stay_bit_identical():
         T("x", "c0", "m", SubjectID("alice")),           # unknown namespace
         T("d", "doc", "view", SubjectID("alice")),       # plain deep grant
     ]
+    # wider than one word of queries: a sub-batch as wide as the slice
+    # would take the whole slice to the BFS kernel, the label kernel unused
+    qs += [T("d", "doc", "view", SubjectID("alice"))] * 32
     on = assert_three_way(p, qs)
     assert on.maintenance.snapshot().get("label_fallbacks", 0) > 0
 

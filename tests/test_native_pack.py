@@ -181,6 +181,27 @@ def test_sink_gather_parity(make_persister):
         engine.close()
 
 
+@needs_native
+@pytest.mark.parametrize("seed", range(4))
+def test_pairs_member_parity(seed, monkeypatch):
+    """The hash set behind ``device_part``'s direct grants equals the sorted
+    keys of the numpy arm: empty sets, empty probes, repeated pairs."""
+    rng = np.random.default_rng(seed)
+    for _ in range(40):
+        n, m = (int(x) for x in rng.integers(0, 3000, 2))
+        rows_hi, q_hi = int(rng.choice([4, 300, 2_000_000])), int(rng.choice([32, 2048]))
+        sr, sq = rng.integers(0, rows_hi, n), rng.integers(0, q_hi, n)
+        r, q = rng.integers(0, rows_hi, m), rng.integers(0, q_hi, m)
+        native = native_pack.pairs_member(sr, sq, r, q)
+        with monkeypatch.context() as mp:
+            mp.setattr(native_pack, "load_library", lambda: None)
+            plain = native_pack.pairs_member(sr, sq, r, q)
+        want = np.array([(a, b) in set(zip(sr.tolist(), sq.tolist()))
+                         for a, b in zip(r.tolist(), q.tolist())], bool)
+        assert native.dtype == plain.dtype == bool
+        assert (native == want).all() and (plain == want).all()
+
+
 # -- the amortized seen set ----------------------------------------------------
 
 

@@ -186,6 +186,9 @@ def test_staging_rung_evicts_and_restores(make_persister):
     engine = TpuCheckEngine(p, p.namespaces)
     oracle = CheckEngine(p)
     try:
+        # the label kernel's pairs are what is staged here: this store has
+        # no row a pull can change, so the BFS route alone launches nothing
+        engine.labels_settled()
         expected = [oracle.subject_is_allowed(q) for q in queries[:64]]
         assert engine.batch_check(queries[:64]) == expected
         assert engine.hbm.ledger().get("staging", 0) > 0
