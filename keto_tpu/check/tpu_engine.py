@@ -1212,6 +1212,11 @@ class TpuCheckEngine:
     #: / label-vs-BFS route / halo rounds+bytes, what the CheckBatcher
     #: stamps onto each rider's request timeline (keto_tpu/x/timeline.py)
     STREAM_INFO = True
+    #: capability flag: ``batch_check_stream_with_token`` accepts
+    #: ``launch_mark=True`` (ordered=False only) and yields None once,
+    #: when every slice of the stream is launched and some have not landed
+    #: — the point at which the CheckBatcher may launch its next round
+    STREAM_LAUNCH_MARK = True
 
     def __init__(
         self,
@@ -3761,6 +3766,7 @@ class TpuCheckEngine:
         got = snap.interned.resolve_queries(buf, n)
         if got is None:
             return None
+        dispatch_clock().poll()
         start_raw, sub_raw = got
         r2d = snap.raw2dev
         sd = np.where(start_raw >= 0, r2d[np.clip(start_raw, 0, None)], -1)
@@ -4135,6 +4141,7 @@ class TpuCheckEngine:
         mode: str = "latest",
         ordered: bool = True,
         with_info: bool = False,
+        launch_mark: bool = False,
     ):
         """``batch_check_stream`` plus the deciding snapshot's id, resolved
         eagerly so serving callers can attach the snaptoken to responses
@@ -4148,11 +4155,22 @@ class TpuCheckEngine:
         ``halo_bytes``. The CheckBatcher stamps this onto every rider's
         request timeline.
 
+        ``launch_mark=True`` (requires ``ordered=False``) adds one yield of
+        ``None``: every slice of the stream has been launched and at least
+        one has not landed. Whatever the caller does before it resumes the
+        generator runs while those slices are on the device — the
+        CheckBatcher resolves, packs and launches its next round there.
+        Launch order on the device is the order of the calls, as without
+        the mark (sharded and lockstep engines see the same sequence of
+        programs: only the host's ``device_get`` moves). A stream whose
+        slices all landed as they were launched (host-only slices, the CPU
+        fallback) gives no mark.
+
         In degraded mode the stream is served by the CPU reference engine
         with the same yield contract (see ``batch_check_with_token`` for
         the fallback semantics)."""
-        if with_info and ordered:
-            raise ValueError("with_info requires ordered=False")
+        if (with_info or launch_mark) and ordered:
+            raise ValueError("with_info and launch_mark require ordered=False")
         if self._should_fallback():
             return self._fallback_stream(
                 tuples_iter, ordered=ordered, with_info=with_info
@@ -4160,7 +4178,7 @@ class TpuCheckEngine:
         snap = self._snapshot_for(at_least, mode)
         gen = self._stream(
             snap, tuples_iter, depth=depth, slice_cap=slice_cap,
-            ordered=ordered, with_info=with_info,
+            ordered=ordered, with_info=with_info, launch_mark=launch_mark,
         )
         return self._guard_stream(gen), snap.snapshot_id
 
@@ -4249,7 +4267,7 @@ class TpuCheckEngine:
 
     def _stream(
         self, snap, tuples_iter, *, depth, slice_cap, ordered,
-        with_info: bool = False,
+        with_info: bool = False, launch_mark: bool = False,
     ):
         depth = depth or self._dispatch_window
         bound = self._slice_cap(snap)
@@ -4334,12 +4352,15 @@ class TpuCheckEngine:
                 iters = max(iters, redo_iters)
                 clk.enter(FILL)  # the re-run moved the clock through a round of its own
             max_iters = max(max_iters, iters)
-            now = time.perf_counter()
             # the service time attributable to THIS slice: dispatch→ready
             # when the pipeline ran dry, ready→ready interval when
-            # saturated (both equal the caller-visible inter-yield gap)
-            ms = (now - max(t_disp, t_prev_ready)) * 1e3
-            t_prev_ready = now
+            # saturated (both equal the caller-visible inter-yield gap).
+            # A slice that sat ready while the caller was away on its next
+            # round was served by the time the clock's probe first saw it
+            # so, not by the time the thread came back for it
+            end = seen.pop(_seq, None) or time.perf_counter()
+            ms = max(0.0, end - max(t_disp, t_prev_ready)) * 1e3
+            t_prev_ready = max(t_prev_ready, end)
             stats.observe(ms)
             if dev is None:
                 route = "host"
@@ -4383,8 +4404,22 @@ class TpuCheckEngine:
                 info["halo_bytes"] = int(iters) * halo_src.halo_bytes_per_round
             return off, out, info
 
+        #: seq -> when a slice in flight was first seen ready, noted at the
+        #: clock's transitions while the caller holds the launch mark
+        seen: dict[int, float] = {}
+        away = False
+
+        def seen_ready(now):
+            if not away:
+                return True
+            for rec in inflight:
+                if rec[0] not in seen and self._slice_ready(rec[2]):
+                    seen[rec[0]] = now
+            return len(seen) == len(inflight)
+
         src = slices()
         exhausted = False
+        marked = not launch_mark
         inflight: list = []
         done: dict[int, tuple[int, np.ndarray]] = {}  # landed, awaiting in-order yield
         seq = 0
@@ -4408,6 +4443,14 @@ class TpuCheckEngine:
                     seq += 1
                 if not inflight and exhausted:
                     break
+                if exhausted and not marked:
+                    # launched, not landed: the caller's turn
+                    marked = away = True
+                    clk.watch(seen_ready)
+                    try:
+                        yield None
+                    finally:
+                        away = False
                 # ready-order landing: every finished slice unpacks now — an
                 # early finisher never waits behind a straggler's transfer
                 progressed = False
@@ -4555,6 +4598,7 @@ class TpuCheckEngine:
             s1 = min(s0 + cap_q, n)
             clk.enter(RESOLVE)
             sd, tg, multi = self._resolve_bulk(snap, tuples[s0:s1])
+            clk.poll()
             nq = s1 - s0
             W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
             B = 32 * W
@@ -4876,6 +4920,7 @@ class TpuCheckEngine:
         packed, host_ans = pack_chunk(
             snap, sd, tg, multi, i0, i1, W, native=self._native_pack
         )
+        clk.poll()
         nq = i1 - i0
         leases: list = []
         if packed is None:

@@ -36,6 +36,17 @@ Against the TPU engine the dispatch is STREAMING: each round goes
 through ``batch_check_stream_with_token(ordered=False)`` — the engine's
 latency-adaptive ready-order pipeline — and each caller's future resolves
 the moment its slice lands, re-associated by query offset.
+
+ONE ROUND OF LOOK-AHEAD. A round whose slices are all launched is on the
+device and needs nothing of this thread until it lands. If batch-lane
+work is queued at that point, the collector takes the next round and
+resolves, packs and launches it first, then lands and fills the older
+one, whose result has arrived meanwhile. If none is queued it lands at
+once: a single check is answered sooner by landing than by looking
+ahead. Two rounds are open at most, the one on the device and the one
+being launched behind it (``_loop`` holds one and launches one: there is
+no third to configure): a third would only wait behind two and hold its
+riders' decisions back.
 """
 
 from __future__ import annotations
@@ -121,10 +132,24 @@ class _Round:
     no tuple touched); iterating yields ``RelationTuple``s one by one, for
     the CPU-oracle stream and engines that know nothing of ``take``."""
 
-    __slots__ = ("_expire", "_segments", "_held", "offs", "live", "n")
+    __slots__ = (
+        "_expire", "_segments", "_held", "offs", "live", "n",
+        "segments", "n_tuples", "busy_s", "gen", "token", "want_info",
+    )
 
     def __init__(self, segments, expire):
         self._expire = expire
+        #: as taken off the lanes: what a failed round retries
+        self.segments = segments
+        self.n_tuples = sum(count for _, _, count in segments)
+        #: seconds the dispatch thread spent on THIS round (its launch,
+        #: its landing and fills; not the other open round's launch in
+        #: between): the admission controller's rate is tuples over these
+        self.busy_s = 0.0
+        #: the engine's stream while the round is open, and what it said
+        self.gen = None
+        self.token = None
+        self.want_info = False
         self._segments = iter(segments)
         self._held = None  # the part of a segment a slice had no room for
         #: ``live[k]`` = (item, start, count) entered the stream at ``offs[k]``
@@ -176,6 +201,18 @@ class _Round:
                 return
             src, a, b = part
             yield from as_tuples(src, "oracle")[a:b]
+
+    @property
+    def items(self) -> list:
+        return [item for item, _, _ in self.segments]
+
+    def asked(self) -> tuple[list, list]:
+        """The riders' ``at_least`` floors and ``latest`` flags: what the
+        round's snapshot has to satisfy (``_consistency_kw``)."""
+        return (
+            [item.at_least for item, _, _ in self.segments],
+            [item.latest for item, _, _ in self.segments],
+        )
 
     def riders(self, off: int, nq: int):
         """The segments a landed slice ``[off, off + nq)`` answers, as
@@ -245,8 +282,9 @@ class CheckBatcher:
         self._cond = threading.Condition()  # guards: _lanes, _lane_tuples, _current_round, shed_count, shed_by_lane, admission_shed_count
         self._lanes: dict[str, deque] = {lane: deque() for lane in LANES}
         self._lane_tuples: dict[str, int] = {lane: 0 for lane in LANES}
-        #: items taken into the current dispatch round (failed promptly
-        #: by ``stop`` so no caller ever hangs on a dead collector)
+        #: items taken into the open dispatch rounds, both of them when
+        #: two are (failed promptly by ``stop`` so no caller ever hangs on
+        #: a dead collector)
         self._current_round: list[_Item] = []
         #: requests refused at the door (lane full or admission window)
         self.shed_count = 0
@@ -632,7 +670,7 @@ class CheckBatcher:
             except InvalidStateError:
                 pass  # expired/failed concurrently; caller already has an answer
 
-    def _dispatch_stream(self, segments, at_leasts, latests) -> None:
+    def _launch_stream(self, round_: _Round) -> bool:
         """Streaming dispatch for engines with the ready-order stream API:
         each caller's future resolves the moment ITS slice lands (the
         ``ordered=False`` fast path — re-association is by query offset),
@@ -649,16 +687,32 @@ class CheckBatcher:
 
         The round goes to the engine as it is (``_Round``): ranges of the
         items' lists and frames, cut into slices by count, and each landed
-        slice is written back by range."""
-        round_ = _Round(segments, self._expire)
-        want_info = bool(getattr(self._engine, "STREAM_INFO", False))
-        kw = self._consistency_kw(at_leasts, latests)
-        if want_info:
+        slice is written back by range.
+
+        This half resolves the round's snapshot and runs the stream up to
+        the engine's launch mark (``STREAM_LAUNCH_MARK``): True when the
+        round is on the device and ``_land_stream`` has the rest to do,
+        False when it is over (an engine without the mark, or slices that
+        landed as they were launched)."""
+        engine = self._engine
+        round_.want_info = bool(getattr(engine, "STREAM_INFO", False))
+        kw = self._consistency_kw(*round_.asked())
+        if round_.want_info:
             kw["with_info"] = True
-        gen, token = self._engine.batch_check_stream_with_token(
+        if getattr(engine, "STREAM_LAUNCH_MARK", False):
+            kw["launch_mark"] = True
+        round_.gen, round_.token = engine.batch_check_stream_with_token(
             round_, ordered=False, **kw
         )
-        for rec in gen:
+        return self._land_stream(round_)
+
+    def _land_stream(self, round_: _Round) -> bool:
+        """Fill every slice the stream lands, up to its launch mark (True)
+        or its end (False)."""
+        token, want_info = round_.token, round_.want_info
+        for rec in round_.gen:
+            if rec is None:
+                return True
             off, out = rec[0], rec[1]
             riders = list(round_.riders(off, len(out)))
             if want_info:
@@ -680,6 +734,7 @@ class CheckBatcher:
                 for a in range(0, hi - lo, _FILL_RUN):
                     b = min(a + _FILL_RUN, hi - lo)
                     self._fill(item, idx[a:b], allowed[lo + a : lo + b], token)
+        return False
 
     # -- collector -----------------------------------------------------------
 
@@ -740,17 +795,23 @@ class CheckBatcher:
                 batchq.popleft()
         return segments
 
-    def _loop(self) -> None:
-        clock = self.clock
-        bind_dispatch_clock(clock)  # the engine's transition sites find it
-        while not self._stop.is_set():
-            clock.idle()
-            with self._cond:
+    def _take(self, ahead: bool) -> tuple[list, int]:
+        """The next round's segments and the batch lane's backlog behind
+        them. With nothing open: wait for work, then for the coalescing
+        window. ``ahead``, while a round is on the device: only if
+        batch-lane work is queued, and without a wait — a queue of singles
+        alone is served soonest by landing first, and a coalescing window
+        would be spent with a round's decisions held back."""
+        with self._cond:
+            if ahead:
+                if not self._lane_tuples[BATCH] or self._stop.is_set():
+                    return [], 0
+            else:
                 if not self._queued():
                     # bounded wait so stop() always terminates the loop
                     self._cond.wait(timeout=0.25)
                     if not self._queued():
-                        continue
+                        return [], 0
                 # coalescing window: wait for more arrivals up to
                 # window_ms or a full round — each wait blocks on the
                 # condition for exactly the remaining window, no polling
@@ -760,44 +821,87 @@ class CheckBatcher:
                     if remaining <= 0:
                         break
                     self._cond.wait(timeout=remaining)
-                clock.enter(TAKE)
-                segments = self._take_locked()
-                self._current_round = [item for item, _, _ in segments]
-                backlog = self._lane_tuples[BATCH]
-                # space freed: wake producers blocked on a full lane
-                self._cond.notify_all()
-            if not segments:
-                continue
-            if self.admission is not None:
-                self.admission.tick(backlog=backlog)
-            n_tuples = sum(count for _, _, count in segments)
-            clock.round(n_tuples, backlog)
-            clock.enter(RESOLVE)
-            t0 = time.monotonic()
-            try:
-                faults.check("check-dispatch")
-                at_leasts = [item.at_least for item, _, _ in segments]
-                latests = [item.latest for item, _, _ in segments]
-                if hasattr(self._engine, "batch_check_stream_with_token"):
-                    self._dispatch_stream(segments, at_leasts, latests)
-                else:
-                    # an engine without the stream API: one plain call
-                    # over the round's live tuples, as objects
-                    round_ = _Round(segments, self._expire)
-                    tuples = list(round_)
-                    if tuples:
-                        results, token = self._dispatch(tuples, at_leasts, latests)
-                        clock.enter(FILL)
-                        allowed = [bool(r) for r in results]
-                        for item, idx, lo, hi in round_.riders(0, len(tuples)):
-                            self._fill(item, idx, allowed[lo:hi], token)
-            except Exception as e:
-                self._fail_or_retry(segments, e)
-            finally:
-                if self.admission is not None:
-                    self.admission.observe_round(n_tuples, time.monotonic() - t0)
-                with self._cond:
-                    self._current_round = []
+            self.clock.enter(TAKE)
+            segments = self._take_locked()
+            # beside the items of the round on the device, if one is
+            self._current_round = self._current_round + [
+                item for item, _, _ in segments
+            ]
+            backlog = self._lane_tuples[BATCH]
+            # space freed: wake producers blocked on a full lane
+            self._cond.notify_all()
+        return segments, backlog
+
+    def _loop(self) -> None:
+        clock = self.clock
+        bind_dispatch_clock(clock)  # the engine's transition sites find it
+        on_device: Optional[_Round] = None  # launched, not landed
+        while not self._stop.is_set():
+            if on_device is None:
+                clock.idle()
+            segments, backlog = self._take(ahead=on_device is not None)
+            launched = None
+            if segments:
+                launched = self._launch(segments, backlog, on_device is not None)
+            if on_device is not None:
+                self._land(on_device)
+            on_device = launched
+            with self._cond:
+                self._current_round = on_device.items if on_device else []
+        if on_device is not None:
+            self._land(on_device)  # stopping: what is on the device still lands
+
+    def _launch(self, segments, backlog: int, overlapped: bool) -> Optional[_Round]:
+        """One round from its segments to the device. Returns it while it
+        is open (launched, not landed: the stream's launch mark), None
+        when it is over — landed, expired, failed or retried."""
+        clock = self.clock
+        if self.admission is not None:
+            self.admission.tick(backlog=backlog)
+        round_ = _Round(segments, self._expire)
+        clock.round(round_.n_tuples, backlog, overlapped)
+        clock.enter(RESOLVE)
+        t0 = time.monotonic()
+        is_open = False
+        try:
+            faults.check("check-dispatch")
+            if hasattr(self._engine, "batch_check_stream_with_token"):
+                is_open = self._launch_stream(round_)
+            else:
+                # an engine without the stream API: one plain call
+                # over the round's live tuples, as objects
+                tuples = list(round_)
+                if tuples:
+                    results, token = self._dispatch(tuples, *round_.asked())
+                    clock.enter(FILL)
+                    allowed = [bool(r) for r in results]
+                    for item, idx, lo, hi in round_.riders(0, len(tuples)):
+                        self._fill(item, idx, allowed[lo:hi], token)
+        except Exception as e:
+            self._fail_or_retry(segments, e)
+        round_.busy_s += time.monotonic() - t0
+        if is_open:
+            return round_
+        self._close(round_)
+        return None
+
+    def _land(self, round_: _Round) -> None:
+        """Land and fill an open round; its failure is its own."""
+        t0 = time.monotonic()
+        try:
+            # to the stream's end: a second mark, should an engine give
+            # one, is no second turn
+            while self._land_stream(round_):
+                pass
+        except Exception as e:
+            self._fail_or_retry(round_.segments, e)
+        round_.busy_s += time.monotonic() - t0
+        self._close(round_)
+
+    def _close(self, round_: _Round) -> None:
+        round_.gen = None
+        if self.admission is not None:
+            self.admission.observe_round(round_.n_tuples, round_.busy_s)
 
     def _fail_or_retry(self, segments, exc: Exception) -> None:
         """A failed dispatch retries its unresolved requests ONCE through

@@ -1574,12 +1574,23 @@ class Registry:
 
         from keto_tpu.x.timeline import DISPATCH_STATES
 
-        def clock_snapshot():
+        def batcher_clock():
             b = self.peek("check_batcher")
-            clock = getattr(b, "clock", None) if b is not None else None
+            return getattr(b, "clock", None) if b is not None else None
+
+        def clock_snapshot():
+            clock = batcher_clock()
             if clock is None:
                 return [0.0] * len(DISPATCH_STATES), 0
             return clock.snapshot()
+
+        def rounds_by_overlap():
+            clock = batcher_clock()
+            # overlapped first: a round counted between the two reads
+            # shows as one not overlapped, never as a negative count
+            over = clock.overlapped if clock is not None else 0
+            rounds = clock.rounds if clock is not None else 0
+            return [(("true",), float(over)), (("false",), float(rounds - over))]
 
         m.register_callback(
             "keto_dispatch_thread_seconds_total", "counter",
@@ -1594,8 +1605,10 @@ class Registry:
         )
         m.register_callback(
             "keto_dispatch_rounds_total", "counter",
-            "Dispatch rounds the batcher's collector has taken off the lanes.",
-            lambda: [((), float(clock_snapshot()[1]))],
+            "Dispatch rounds the batcher's collector has taken off the "
+            "lanes; overlapped=\"true\" for a round launched while another "
+            "was still on the device (launched, not landed).",
+            rounds_by_overlap, ("overlapped",),
         )
 
         def compile_counts(i):

@@ -111,8 +111,8 @@ class DispatchClock:
     device is named by what its one feeder was doing."""
 
     __slots__ = (
-        "seconds", "rounds", "_state", "_t", "_session", "_tracing", "_ann",
-        "_tuples", "_slices", "_lane_depth",
+        "seconds", "rounds", "overlapped", "_state", "_t", "_session",
+        "_tracing", "_ann", "_tuples", "_slices", "_lane_depth", "_probes",
     )
 
     def __init__(self, session=None):
@@ -120,6 +120,9 @@ class DispatchClock:
             from keto_tpu.x.profiling import SESSION as session
         self.seconds = [0.0] * len(DISPATCH_STATES)
         self.rounds = 0
+        #: the rounds among ``rounds`` launched while another was open
+        self.overlapped = 0
+        self._probes: list = []
         self._state = WAIT_WORK
         self._t = time.perf_counter()
         self._session = session
@@ -137,15 +140,39 @@ class DispatchClock:
         self._state = state
         if self._tracing or self._ann is not None:
             self._annotate(state, note)
+        if self._probes:
+            self._ask(now)
+
+    def _ask(self, now: float) -> None:
+        self._probes = [p for p in self._probes if not p(now)]
+
+    def watch(self, probe) -> None:
+        """Ask ``probe(now) -> bool`` at every transition and ``poll``
+        from here on, until it answers True. A round left on the device
+        while the thread works on the next one has nobody waiting on it:
+        this is how its slices get the time they were first SEEN ready,
+        one ``is_ready()`` a slice and site while the round is open and
+        nothing otherwise."""
+        self._probes.append(probe)
+
+    def poll(self) -> None:
+        """Inside a long state: ask the probes, if there are any."""
+        if self._probes:
+            self._ask(time.perf_counter())
 
     def idle(self) -> None:
         """Top of a loop pass: back to ``wait_work``."""
         self._tracing = self._session.open
         self.enter(WAIT_WORK)
 
-    def round(self, tuples: int, lane_depth: int) -> None:
-        """A round was taken: what its spans will say of it."""
+    def round(self, tuples: int, lane_depth: int, overlapped: bool = False) -> None:
+        """A round was taken: what its spans will say of it.
+        ``overlapped``: another round is open (launched, not landed)."""
         self.rounds += 1
+        self.overlapped += overlapped
+        # ``idle`` is not passed while rounds follow each other without a
+        # gap: a profiler session opened under load is seen here
+        self._tracing = self._session.open
         self._tuples, self._slices, self._lane_depth = tuples, 0, lane_depth
 
     def _annotate(self, state: int, note=None) -> None:
@@ -187,6 +214,12 @@ class _NoClock:
     __slots__ = ()
 
     def enter(self, state: int, note=None) -> None:
+        pass
+
+    def watch(self, probe) -> None:
+        pass
+
+    def poll(self) -> None:
         pass
 
 

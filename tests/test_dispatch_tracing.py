@@ -429,19 +429,65 @@ def test_every_dispatch_state_is_on_metrics_and_accrues_under_traffic(daemon, st
     assert _value(fams, "keto_dispatch_rounds_total") >= 1
 
 
-def test_dispatch_states_of_a_window_sum_to_its_length(daemon):
+def _states_sum_to_the_window(d, traffic):
+    """The dispatch states' seconds between two scrapes against the wall time
+    between them. A scrape reads the clock somewhere between its request and
+    its reply, so each is bracketed and the window is known to that."""
     def read():
-        t = time.perf_counter()
-        fams = _scrape(daemon)
-        return t, _value(fams, "keto_dispatch_thread_seconds_total")
+        a = time.perf_counter()
+        fams = _scrape(d)
+        return a, time.perf_counter(), fams
 
-    t0, s0 = read()
-    for _ in range(5):
-        _batch(daemon, 200)
+    a0, b0, f0 = read()
+    traffic()
     time.sleep(0.5)
-    t1, s1 = read()
-    # the scrape itself takes a few ms between the host's reading and the clock's
-    assert s1 - s0 == pytest.approx(t1 - t0, rel=0.02, abs=0.02)
+    a1, b1, f1 = read()
+    states = (
+        _value(f1, "keto_dispatch_thread_seconds_total")
+        - _value(f0, "keto_dispatch_thread_seconds_total")
+    )
+    assert a1 - b0 - 0.005 <= states <= b1 - a0 + 0.005
+    return f0, f1
+
+
+def test_dispatch_states_of_a_window_sum_to_its_length(daemon):
+    _states_sum_to_the_window(daemon, lambda: [_batch(daemon, 200) for _ in range(5)])
+
+
+def test_dispatch_states_sum_to_the_window_with_two_rounds_open(daemon):
+    """A body of 5,000 is five rounds of a sub-slice of 1,024: every round
+    but the first is launched while the one before it is out. The states
+    stay exclusive (one thread, one state) and the round counter says which
+    rounds were overlapped."""
+    f0, f1 = _states_sum_to_the_window(
+        daemon, lambda: [_batch(daemon, 5000) for _ in range(4)]
+    )
+
+    def rounds(fams, **labels):
+        return _value(fams, "keto_dispatch_rounds_total", **labels)
+
+    assert {have["overlapped"] for _n, have, _v in f1["keto_dispatch_rounds_total"]["samples"]} == {
+        "true", "false"
+    }
+    taken = rounds(f1) - rounds(f0)
+    overlapped = rounds(f1, overlapped="true") - rounds(f0, overlapped="true")
+    assert taken >= 4 * 5
+    assert overlapped >= 4 * 3  # a call's first round finds nothing out; its last may not either
+    for state in DISPATCH_STATES:
+        assert _value(f1, "keto_dispatch_thread_seconds_total", state=state) >= _value(
+            f0, "keto_dispatch_thread_seconds_total", state=state
+        )
+
+
+def test_singles_alone_never_overlap_a_round(daemon):
+    before = _value(_scrape(daemon), "keto_dispatch_rounds_total", overlapped="true")
+    for i in range(20):
+        status, _raw, _h = _request(
+            daemon.read_port,
+            f"/check?namespace=docs&object=readme&relation=view&subject_id={'ann' if i % 2 else 'bob'}",
+        )
+        assert status in (200, 403)
+    assert _value(_scrape(daemon), "keto_dispatch_rounds_total", overlapped="true") == before
 
 
 # -- (c) a real profiler session on the CPU backend -------------------------------

@@ -341,7 +341,9 @@ def test_controller_bootstrap_feed_and_durable_watermark(tmp_path):
         stub.commit(9, [("insert", T("b", "u2"))])
         stub.commit(11, [("delete", T("a", "u1"))])
         wait_until(lambda: ctl.watermark == 11, what="feed catch-up")
-        assert ctl.durable.load() == 11
+        # the store's watermark moves in apply_commit, the durable file an
+        # fsync later on the feed thread: wait for it, do not race it
+        wait_until(lambda: ctl.durable.load() == 11, what="durable watermark")
         assert store.applied_commits == 2
         from keto_tpu.relationtuple.model import RelationQuery
 
