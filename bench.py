@@ -338,10 +338,10 @@ def stream_pass(engine, snap, queries, tag):
     ``slice_tail`` section aggregates. Returns ``(decisions, metrics)``."""
     import numpy as _np
 
-    for w in engine.stream_widths(snap):
+    for w in engine.dispatch.stream_widths(snap):
         engine.batch_check(queries[:w])
     engine.stream_slice_stats.reset()
-    engine.reset_route_stats()
+    engine.dispatch.reset_route_stats()
     from keto_tpu.check.native_pack import COUNTERS as _pack_counters
 
     pack_before = dict(_pack_counters)
@@ -360,9 +360,9 @@ def stream_pass(engine, snap, queries, tag):
     p50 = steady[len(steady) // 2] * 1e3
     p99 = steady[min(len(steady) - 1, int(len(steady) * 0.99))] * 1e3
     svc = engine.stream_slice_stats.snapshot()
-    ctrl = engine.stream_ctrl.snapshot()
+    ctrl = engine.dispatch.stream_ctrl.snapshot()
     routes = {}
-    for route, r in engine.stream_route_snapshot().items():
+    for route, r in engine.dispatch.stream_route_snapshot().items():
         busy_s = r["mean_ms"] * r["slices"] / 1e3
         routes[route] = {
             **{k: r[k] for k in ("slices", "queries", "p50_ms", "p99_ms")},
@@ -565,7 +565,7 @@ def run_depth_sweep(rng):
 
         def timed_pass(engine):
             engine.batch_check(queries)  # warmup/compile
-            engine.bfs_steps_stats.reset()
+            engine.dispatch.bfs_steps_stats.reset()
             times = []
             got = None
             for _ in range(reps):
@@ -590,7 +590,7 @@ def run_depth_sweep(rng):
         eng_off = TpuCheckEngine(store, store.namespaces, labels_enabled=False)
         eng_off.snapshot()
         got_off, qps_off = timed_pass(eng_off)
-        steps = eng_off.bfs_steps_stats.snapshot()
+        steps = eng_off.dispatch.bfs_steps_stats.snapshot()
 
         oracle = CheckEngine(store)
         sample = queries[:oracle_sample]
@@ -812,7 +812,7 @@ def run_config4(rng):
     build_phases = _build_phase_metrics(engine, n_tuples, ingest_s, snapshot_s)
     log(f"[c4] build phases: {build_phases}")
     hbm_buckets = sum(int(b.nbrs.nbytes) for b in snap.buckets)
-    w_max = engine._slice_cap(snap) // 32
+    w_max = engine.dispatch._slice_cap(snap) // 32
     hbm_bitmaps = 3 * (snap.num_int + 1) * 4 * w_max
     # actual device occupancy when the backend reports memory stats (TPU
     # bytes_in_use) — the host-side estimate stays as the fallback and
@@ -841,7 +841,7 @@ def run_config4(rng):
     engine.labels_settled()  # join the overlapped label build before timing
 
     reps = int(os.environ.get("BENCH_REPS", 3))
-    engine.bfs_steps_stats.reset()
+    engine.dispatch.bfs_steps_stats.reset()
     maint0 = engine.maintenance.snapshot()
     times = []
     got = None
@@ -856,7 +856,7 @@ def run_config4(rng):
     # frontier-hop count per dispatched slice across the timed window —
     # the depth tax the label path removes must be attributable, not
     # inferred from interior_rows (BENCH_r04's gap)
-    bfs_steps = engine.bfs_steps_stats.snapshot()
+    bfs_steps = engine.dispatch.bfs_steps_stats.snapshot()
     maint1 = engine.maintenance.snapshot()
     lab_served = maint1.get("label_checks", 0) - maint0.get("label_checks", 0)
     lab_fell = maint1.get("label_fallbacks", 0) - maint0.get("label_fallbacks", 0)
@@ -2717,7 +2717,7 @@ def run_sharded(rng):
             labels_enabled=False,
         )
         engine.batch_check(queries)  # warmup/compile
-        engine.bfs_steps_stats.reset()
+        engine.dispatch.bfs_steps_stats.reset()
         c0, _, _ = engine.maintenance.raw()
         rounds0 = c0.get("shard_halo_rounds", 0)
         bytes0 = c0.get("shard_halo_bytes", 0)
@@ -2729,7 +2729,7 @@ def run_sharded(rng):
         times.sort()
         sec = times[len(times) // 2]
         mism = sum(g != w for g, w in zip(got[:oracle_sample], want))
-        steps = engine.bfs_steps_stats.snapshot()
+        steps = engine.dispatch.bfs_steps_stats.snapshot()
         c1, _, _ = engine.maintenance.raw()
         spec = engine.snapshot().shard_spec
         # labels-on served-product row (one rep — the contrast, not the

@@ -5,7 +5,7 @@ starts through the forward CSR, (query, row) seen/seed dedup, target-hit
 grants, and the sink answer gather — runs here as one GIL-released C++
 call on the eligible path, so resolve/pack of slice k+2 genuinely
 overlaps device execution of k+1 instead of fighting the GIL. The numpy
-implementation in keto_tpu/check/tpu_engine.py remains the contract
+implementation in keto_tpu/check/pack.py remains the contract
 (bit-identical output, fuzz-compared in tests/test_native_pack.py) and
 the fallback.
 
@@ -19,8 +19,8 @@ insert-only-delta serving state keeps the native path.
 
 Loading is opportunistic: ``load_library()`` returns None (and callers
 fall back to numpy) when the shared object is absent, stale
-(``keto_pack_version`` mismatch), ``KETO_TPU_NATIVE=0``, or
-``KETO_TPU_NATIVE_PACK=0``. Build with ``make native``.
+(``keto_pack_version`` mismatch) or ``KETO_TPU_NATIVE=0`` (every native
+off: a box without a compiler). Build with ``make native``.
 
 ``COUNTERS`` tracks which path packed each chunk; the registry scrapes
 it as ``keto_native_pack_chunks_total{path}``.
@@ -63,8 +63,6 @@ def load_library() -> Optional[ctypes.CDLL]:
         return _lib
     _lib_checked = True
     if os.environ.get("KETO_TPU_NATIVE", "1") == "0":
-        return None
-    if os.environ.get("KETO_TPU_NATIVE_PACK", "1") == "0":
         return None
     for path in _candidate_paths():
         if not path.exists():

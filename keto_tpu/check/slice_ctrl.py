@@ -1,0 +1,245 @@
+"""The slice scheduler of the streaming check pipeline."""
+
+from __future__ import annotations
+
+import collections
+import threading
+from typing import Optional
+
+from keto_tpu.check.pack import _WORD_WIDTHS
+
+
+class StreamSliceController:
+    """Service-time-aware slice scheduler for the streaming pipeline.
+
+    The memory-derived ``_slice_cap`` optimizes pure throughput — the
+    widest bitmap the workspace budget allows — at the price of a long
+    service time per slice (how long on a directly attached chip is not
+    measured). Per-slice timelines
+    (PR 14) showed the residual p99 tail is ROUTE-shaped: label slices
+    finish in single-digit ms while a BFS slice of the same width pays
+    tens of hops, so one reactive width shared by all routes lets the
+    occasional deep slice blow a 10–25× p99/p50 spread. This controller
+    therefore keeps a **predicted-service-time model** fit online from
+    the per-slice ``(width, route, bfs_steps, entries, service_ms)``
+    stats the stream already records, and schedules with it three ways:
+
+    - **width planning** (``cap()``): the widest compiled ladder width
+      (``32·_WORD_WIDTHS`` — adapting never compiles a new kernel) whose
+      PREDICTED service time stays at or below ``target_ms``, where the
+      prediction is pessimistic over the routes seen recently — one slow
+      BFS observation immediately narrows the next slices instead of
+      waiting for the shared EWMA to catch up. The original reactive
+      narrow-fast / re-widen-slow ladder walk is retained underneath as
+      a safety net for cost regimes the model has not seen;
+    - **pre-dispatch splitting** (``entry_budget()``): the model's
+      ms-per-device-entry estimate converts ``target_ms`` into a device
+      entry budget, and ``_dispatch_slices`` splits a predicted-slow
+      chunk (wildcard fanout, deep host walks) into sub-slices BEFORE
+      dispatch — the ready-order window then interleaves them with fast
+      slices, so a monster chunk never serializes the stream;
+    - **tail guard**: the observed p99/p50 ratio of recent slices is
+      checked against ``tail_ratio`` (config ``serve.stream_tail_ratio``)
+      and a multiplicative guard scales both the planned width and the
+      entry budget down while the tail is blown, recovering gradually —
+      the direct control loop for the bench's slice-tail gate.
+
+    ``floor`` bounds narrowing so a latency spike cannot collapse
+    throughput (2048 queries/slice keeps > 50k checks/s even at 25
+    slices/s).
+    """
+
+    #: widen when observed ms < WIDEN_FRAC · target, ``patience`` times in a row
+    WIDEN_FRAC = 0.5
+    #: narrow when observed ms > NARROW_FRAC · target
+    NARROW_FRAC = 1.25
+    #: a route binds the pessimistic prediction for this many slices
+    #: after it was last observed
+    ROUTE_RECENCY = 64
+    #: recompute the tail guard every this many observations
+    TAIL_EVERY = 32
+
+    def __init__(
+        self,
+        target_ms: float = 40.0,
+        floor: int = 2048,
+        patience: int = 2,
+        tail_ratio: float = 5.0,
+    ):
+        self._ladder = [32 * w for w in _WORD_WIDTHS]
+        self.target_ms = float(target_ms)
+        self.tail_ratio = float(tail_ratio)
+        self._lo = next(
+            (i for i, c in enumerate(self._ladder) if c >= floor),
+            len(self._ladder) - 1,
+        )
+        self._patience = patience
+        self._lock = threading.Lock()
+        # start two rungs under the top: wide enough that a fast link is
+        # near peak throughput from slice one, narrow enough that the
+        # first observations on a slow link land near the target
+        self._i = max(self._lo, len(self._ladder) - 3)
+        self._good = 0
+        self._ewma_ms_per_q: Optional[float] = None
+        #: per-route cost model: route → {per_q, per_entry, bfs_steps,
+        #: last_seen} (EWMAs; last_seen is a slice counter)
+        self._routes: dict[str, dict] = {}
+        self._slices = 0
+        self._ring: collections.deque = collections.deque(maxlen=256)
+        self._guard = 1.0
+        self._tail_p50 = 0.0
+        self._tail_p99 = 0.0
+
+    def _recent_locked(self):
+        horizon = self._slices - self.ROUTE_RECENCY
+        return [
+            st for st in self._routes.values() if st["last_seen"] >= horizon
+        ]
+
+    def _model_cap_locked(self) -> Optional[int]:
+        """Widest ladder width whose predicted service time (pessimistic
+        per-query cost over recently seen routes, scaled by the tail
+        guard) fits the target; None before any observation."""
+        recent = self._recent_locked()
+        per_q = max((st["per_q"] for st in recent), default=None)
+        if per_q is None or per_q <= 0:
+            return None
+        limit = self.target_ms * self._guard / per_q
+        want = self._ladder[self._lo]
+        for c in self._ladder:
+            if c <= limit:
+                want = max(want, c)
+        return want
+
+    def cap(self) -> int:
+        """Per-slice query cap for the NEXT slice: the reactive ladder
+        rung bounded by the model's predicted-service-time width (always
+        a compiled ladder width)."""
+        with self._lock:
+            cap = self._ladder[self._i]
+            m = self._model_cap_locked()
+            return cap if m is None else max(self._ladder[self._lo], min(cap, m))
+
+    def entry_budget(self) -> Optional[int]:
+        """Device entries one sub-chunk may carry before its predicted
+        service time overshoots the target — the pre-dispatch split
+        bound ``_dispatch_slices`` applies. None before the model has an
+        entry-cost estimate."""
+        with self._lock:
+            recent = self._recent_locked()
+            per_e = max(
+                (st["per_entry"] for st in recent if st["per_entry"] > 0),
+                default=None,
+            )
+            if per_e is None:
+                return None
+            return max(256, int(self.target_ms * self._guard / per_e))
+
+    def observe(
+        self,
+        nq: int,
+        ms: float,
+        route: str = "bfs",
+        bfs_steps: int = 0,
+        entries: Optional[int] = None,
+    ) -> None:
+        """Feed one slice's service time: dispatch→ready when the pipeline
+        ran dry, ready→ready interval when saturated. ``route``/
+        ``bfs_steps``/``entries`` (from the stream's per-slice info) fit
+        the per-route model; plain ``observe(nq, ms)`` still steers the
+        reactive ladder alone."""
+        if nq <= 0:
+            return
+        per_q = ms / nq
+        with self._lock:
+            self._slices += 1
+            st = self._routes.get(route)
+            if st is None:
+                st = {"per_q": per_q, "per_entry": 0.0, "bfs_steps": 0.0,
+                      "last_seen": 0, "n": 0}
+                self._routes[route] = st
+            else:
+                # asymmetric EWMA: a slowdown bumps the predicted cost
+                # HARD (the very next cap()/entry_budget() narrows —
+                # that is the tail control), while a speedup also decays
+                # fast so a cleared spike doesn't pin throughput low
+                old = st["per_q"]
+                st["per_q"] = (
+                    0.5 * old + 0.5 * per_q
+                    if per_q >= old
+                    else 0.3 * old + 0.7 * per_q
+                )
+            if entries:
+                pe = ms / max(1, entries)
+                old = st["per_entry"]
+                if old <= 0:
+                    st["per_entry"] = pe
+                else:
+                    st["per_entry"] = (
+                        0.5 * old + 0.5 * pe
+                        if pe >= old
+                        else 0.3 * old + 0.7 * pe
+                    )
+            st["bfs_steps"] = 0.7 * st["bfs_steps"] + 0.3 * float(bfs_steps)
+            st["last_seen"] = self._slices
+            st["n"] += 1
+            self._ring.append(ms)
+            if self._slices % self.TAIL_EVERY == 0:
+                self._retune_tail_locked()
+            e = self._ewma_ms_per_q
+            self._ewma_ms_per_q = per_q if e is None else 0.7 * e + 0.3 * per_q
+            cap = self._ladder[self._i]
+            if ms > self.NARROW_FRAC * self.target_ms:
+                want = self._lo
+                for k in range(self._i, self._lo - 1, -1):
+                    if self._ladder[k] * per_q <= self.target_ms:
+                        want = k
+                        break
+                self._i = min(self._i, max(self._lo, want))
+                self._good = 0
+            elif ms < self.WIDEN_FRAC * self.target_ms and nq >= cap:
+                self._good += 1
+                if self._good >= self._patience and self._i + 1 < len(self._ladder):
+                    self._i += 1
+                    self._good = 0
+            else:
+                self._good = 0
+
+    def _retune_tail_locked(self) -> None:
+        vals = sorted(self._ring)
+        if len(vals) < 8:
+            return
+        self._tail_p50 = vals[len(vals) // 2]
+        self._tail_p99 = vals[min(len(vals) - 1, int(len(vals) * 0.99))]
+        blown = (
+            self._tail_p50 > 0
+            and self._tail_p99 > self.tail_ratio * self._tail_p50
+            and self._tail_p99 > self.target_ms
+        )
+        if blown:
+            self._guard = max(0.25, self._guard * 0.5)
+        else:
+            self._guard = min(1.0, self._guard * 1.1)
+
+    def snapshot(self) -> dict:
+        """Controller state for introspection (bench, /debug)."""
+        with self._lock:
+            return {
+                "cap": self._ladder[self._i],
+                "target_ms": self.target_ms,
+                "ewma_ms_per_query": self._ewma_ms_per_q,
+                "model_cap": self._model_cap_locked(),
+                "tail_ratio": self.tail_ratio,
+                "tail_guard": self._guard,
+                "tail_p50_ms": round(self._tail_p50, 3),
+                "tail_p99_ms": round(self._tail_p99, 3),
+                "routes": {
+                    r: {
+                        "per_q_ms": round(st["per_q"], 6),
+                        "per_entry_ms": round(st["per_entry"], 6),
+                        "bfs_steps": round(st["bfs_steps"], 2),
+                        "slices": st["n"],
+                    }
+                    for r, st in self._routes.items()
+                },
+            }

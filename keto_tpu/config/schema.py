@@ -65,16 +65,6 @@ CONFIG_SCHEMA = {
                     "default": 5.0,
                     "description": "Slice-tail bound the streaming controller steers toward: when the observed per-slice service-time p99 exceeds this multiple of p50 (and the p99 is over the slice target), the controller's tail guard multiplicatively tightens both the planned slice width and the pre-dispatch entry budget until the tail recovers. The bench's slice_tail section and the tail-smoke CI gate grade against the same ratio.",
                 },
-                "native_pack_enabled": {
-                    "type": "boolean",
-                    "default": True,
-                    "description": "Native (C++) pack walk for the check hot path: the host-side frontier expansion, seen/seed dedup, and sink answer gathers run as one GIL-released call into native/libketopack.so (threaded CSR gathers), so packing slice k+2 overlaps device execution of k+1 instead of fighting the GIL. Bit-identical to the numpy path by contract (fuzz-compared in CI); snapshots with host-visible overlay state (tombstones, overlay adjacency) always use the numpy path. false — or a missing/stale library — pins numpy everywhere.",
-                },
-                "staging_enabled": {
-                    "type": "boolean",
-                    "default": True,
-                    "description": "Persistent entry staging for slice dispatch: packed entry arrays concatenate into pooled per-geometry host buffers (leased until their slice lands, so reuse can never alias an in-flight transfer) and — on backends that implement XLA buffer donation (TPU/GPU) — ship through donated kernel arguments so the device-side staging allocation aliases into the kernel output instead of allocating fresh per slice. Pool bytes ride the HBM governor's 'staging' ledger tag and are the FIRST eviction-ladder rung (dropping them costs only per-slice allocation churn). false pins per-slice allocation + device_put.",
-                },
                 "overlay_edge_budget": {
                     "type": "integer",
                     "default": 4096,

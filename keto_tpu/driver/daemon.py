@@ -335,7 +335,7 @@ class Daemon:
                     n = engine.warm_compile()
                     self.registry.logger().info(
                         "width-ladder warmup compiled/loaded %d kernels "
-                        "(block_iters %s)", n, getattr(engine, "_block_iters", "-"),
+                        "(block_iters %s)", n, engine.dispatch._block_iters,
                     )
             except Exception:
                 stats = getattr(engine, "maintenance", None)

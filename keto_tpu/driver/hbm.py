@@ -75,7 +75,7 @@ RESTORE_FRAC = 0.7
 #: around the dispatches and released before the result installs;
 #: "staging" is the
 #: persistent entry-staging pool behind the donated dispatch buffers,
-#: keto_tpu/check/tpu_engine.py _StagingPool — reconciled against the
+#: keto_tpu/check/pack.py _StagingPool — reconciled against the
 #: pool's own accounting at every scrape)
 TAGS = ("snapshot", "overlay", "labels", "reverse", "warmup", "build",
         "staging")

@@ -752,12 +752,6 @@ class Registry:
                     build_chunk_rows=int(
                         self._config.get("serve.build_chunk_rows", 262144)
                     ),
-                    native_pack_enabled=bool(
-                        self._config.get("serve.native_pack_enabled", True)
-                    ),
-                    staging_enabled=bool(
-                        self._config.get("serve.staging_enabled", True)
-                    ),
                     stream_tail_ratio=float(
                         self._config.get("serve.stream_tail_ratio", 5.0)
                     ),

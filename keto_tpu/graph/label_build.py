@@ -7,7 +7,7 @@ PR 8 capped landmarks at min(num_int, 128k) and why coverage degrades on
 exactly the huge deep graphs where the BFS fallback hurts most. This
 module rebuilds construction as **W-landmark-wide bit-packed frontier
 waves** through the same dense gather-OR pull the check kernels use
-(keto_tpu/check/tpu_engine.py ``check_step``, and the halo-exchange
+(keto_tpu/check/kernels.py ``check_step``, and the halo-exchange
 structure of ``parallel/sharded.py`` in sharded mode):
 
 - the batch's W landmark BFSs run simultaneously as one ``uint32[n+1,

@@ -1,6 +1,6 @@
 """Pruned-landmark 2-hop reachability labels: O(1)-step checks at any depth.
 
-The BFS check kernel (keto_tpu/check/tpu_engine.py) pays one TPU step per
+The BFS check kernel (keto_tpu/check/kernels.py) pays one TPU step per
 frontier hop, so deep grant chains (team forests, org hierarchies) tax
 every check with their depth: BENCH_r04's depth-8 config runs ~60k
 checks/s against ~215k on the shallow graph. This module precomputes a

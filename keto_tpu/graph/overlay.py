@@ -17,7 +17,7 @@ snapshot:
     engine's batch-setup propagation;
   * interior source → active-interior destination → the **overlay ELL**: a
     tiny ``[K, C]`` gather matrix applied as an extra scatter-OR stage in
-    every BFS pull (tpu_engine.check_step), so multi-hop paths through delta
+    every BFS pull (check/kernels.py check_step), so multi-hop paths through delta
     edges converge exactly like base edges;
   * interior source → sink destination → answer-gather overlay
     (``ov_sink_in``);

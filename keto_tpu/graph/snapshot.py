@@ -1,6 +1,6 @@
 """Device-friendly graph snapshot: bucketed reverse-ELL adjacency.
 
-The TPU check kernel (keto_tpu/check/tpu_engine.py) runs breadth-first
+The TPU check kernel (keto_tpu/check/kernels.py) runs breadth-first
 reachability as a **pull**: per step, every node ORs the reached-bitmaps of
 its *in*-neighbors. A pull step is gather-only — TPUs gather well but
 serialize scatters with colliding indices, so the layout makes the inner
@@ -265,7 +265,7 @@ class GraphSnapshot:
     # static→x edges extend the host one-hop adjacency, new edges into
     # sinks extend the answer gathers, and new interior→interior edges form
     # a tiny device-side "overlay ELL" applied as an extra scatter stage in
-    # every BFS pull (tpu_engine.check_step).
+    # every BFS pull (check/kernels.py check_step).
     ov_set_ids: Optional[dict] = None  # (ns_id, obj, rel) → overlay dev id
     ov_leaf_ids: Optional[dict] = None  # subject str → overlay dev id
     ov_class: Optional[dict] = None  # overlay dev id → "static" | "sink"

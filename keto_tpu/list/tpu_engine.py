@@ -52,7 +52,7 @@ import numpy as np
 from jax import lax
 
 from keto_tpu import namespace as namespace_pkg
-from keto_tpu.check.tpu_engine import _pull
+from keto_tpu.check.kernels import pull
 from keto_tpu.graph.snapshot import GraphSnapshot
 from keto_tpu.list.engine import (
     ListEngine,
@@ -86,7 +86,7 @@ def list_step(
 ) -> jnp.ndarray:
     """Reachability fixpoint over one list layout: per step the
     bucket-covered prefix ORs its gathered neighbors (the check kernel's
-    ``_pull``), then overlay edges OR into their destination rows —
+    ``pull``), then overlay edges OR into their destination rows —
     inside the loop, so multi-hop paths through delta edges converge
     exactly like base edges. Returns the full fixpoint bitmap (the
     listing's answer IS the reached set, so the whole bitmap ships
@@ -98,7 +98,7 @@ def list_step(
         R, _, it = st
         Rn = R
         if bucket_nbrs and n_active:
-            p = _pull(bucket_nbrs, valid_rows, R)
+            p = pull(bucket_nbrs, valid_rows, R)
             Rn = Rn.at[:n_active].set(Rn[:n_active] | p)
         if ov_nbrs is not None:
             ovo = lax.reduce(Rn[ov_nbrs], np.uint32(0), lax.bitwise_or, (1,))

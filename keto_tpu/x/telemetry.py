@@ -20,7 +20,7 @@ class DurationStats:
 
     The streaming check pipeline records every slice's service time here,
     and every consumer reads the SAME numbers: the engine's adaptive
-    slice-width controller (keto_tpu/check/tpu_engine.py), bench.py's
+    slice-width controller (keto_tpu/check/slice_ctrl.py), bench.py's
     per-config ``stream_slice_*`` report, and operator introspection — so
     the latency the controller steers by is exactly the latency the
     benchmark grades."""

@@ -1049,7 +1049,7 @@ int64_t graph_resolve_leaf(const Graph* g, const char* s, int64_t len) {
 // out_start[i] = LHS set id or -1, out_sub[i] = subject raw id (leaves
 // offset by num_sets, matching edge dst encoding) or -1. Returns 0 on
 // success, -1 on a malformed buffer. Wildcard/pattern queries never
-// reach this path (keto_tpu/check/tpu_engine.py routes them to the
+// reach this path (keto_tpu/check/dispatch.py routes them to the
 // host-side pattern resolver).
 int64_t graph_resolve_queries(const Graph* g, const char* buf, int64_t len,
                               int64_t n, int64_t* out_start, int64_t* out_sub) {
@@ -1122,7 +1122,7 @@ int64_t graph_resolve_queries(const Graph* g, const char* buf, int64_t len,
 // top-level key, an unknown, missing or duplicate key, a non-string
 // value, zero tuples or more than max_tuples.
 //
-// Per record one flag byte, mirroring keto_tpu/check/tpu_engine.py
+// Per record one flag byte, mirroring keto_tpu/check/dispatch.py
 // _resolve_bulk_native for a snapshot without a namespace named "":
 //   0 literal    the record resolves as written
 //   1 special    empty namespace/object/relation: placeholder record, the

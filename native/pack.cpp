@@ -1,6 +1,6 @@
 // Native batch-setup pack walk: the host side of the check hot path.
 //
-// keto_tpu/check/tpu_engine.py:pack_chunk expands host-propagated starts
+// keto_tpu/check/pack.py:pack_chunk expands host-propagated starts
 // (static, peeled-interior, overlay nodes) through the forward CSR until
 // every path either seeds the device bitmap (interior rows), decides a
 // query on host (a traversed edge landing on its target), or dies out.

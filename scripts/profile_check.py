@@ -19,8 +19,9 @@ sys.path.insert(0, ".")
 from bench import build_workload, make_queries  # noqa: E402
 
 from keto_tpu import namespace as namespace_pkg  # noqa: E402
-from keto_tpu.check import tpu_engine as te  # noqa: E402
-from keto_tpu.check.tpu_engine import TpuCheckEngine, pack_chunk  # noqa: E402
+from keto_tpu.check.kernels import _check_kernel  # noqa: E402
+from keto_tpu.check.pack import _WORD_WIDTHS, pack_chunk, pack_entries  # noqa: E402
+from keto_tpu.check.tpu_engine import TpuCheckEngine  # noqa: E402
 from keto_tpu.persistence.memory import MemoryPersister  # noqa: E402
 
 
@@ -56,7 +57,7 @@ def main():
         queries_fn = lambda: make_queries(rng, n_checks, doc_grant, n_users, user_reaches, member_of, T)  # noqa: E731
     store = MemoryPersister(nm)
     store.write_relation_tuples(*tuples)
-    mb = int(os.environ.get("PROF_MAX_BATCH", 32 * te._WORD_WIDTHS[-1]))
+    mb = int(os.environ.get("PROF_MAX_BATCH", 32 * _WORD_WIDTHS[-1]))
     budget = int(float(os.environ.get("PROF_MEM_GB", "6")) * (1 << 30))
     engine = TpuCheckEngine(store, store.namespaces, max_batch=mb, mem_budget_bytes=budget)
     snap = engine.snapshot()
@@ -68,20 +69,20 @@ def main():
 
     # warmup / compile
     t0 = time.perf_counter()
-    engine.batch_check(queries[: engine._max_batch])
-    log(f"warmup {time.perf_counter()-t0:.1f}s  block_iters={engine._block_iters}")
+    engine.batch_check(queries[: engine.dispatch._max_batch])
+    log(f"warmup {time.perf_counter()-t0:.1f}s  block_iters={engine.dispatch._block_iters}")
 
     # --- stage 1: resolve ---
     t0 = time.perf_counter()
-    sd, tg, multi = engine._resolve_bulk(snap, queries)
+    sd, tg, multi = engine.dispatch._resolve_bulk(snap, queries)
     t_resolve = time.perf_counter() - t0
     log(f"resolve_bulk: {t_resolve*1e3:.0f} ms ({n_checks/t_resolve:,.0f} q/s), multi={len(multi)}")
 
     # --- stage 2: pack all chunks (host only) ---
-    cap = engine._slice_cap(snap)
+    cap = engine.dispatch._slice_cap(snap)
     log(f"slice cap {cap} queries (W={cap // 32})")
     bounds = [(i, min(i + cap, n_checks)) for i in range(0, n_checks, cap)]
-    W = next(w for w in te._WORD_WIDTHS if 32 * w >= min(cap, n_checks))
+    W = next(w for w in _WORD_WIDTHS if 32 * w >= min(cap, n_checks))
     t0 = time.perf_counter()
     packs = [pack_chunk(snap, sd, tg, multi, a, b, W) for a, b in bounds]
     t_pack = time.perf_counter() - t0
@@ -94,15 +95,15 @@ def main():
     packs = [(p, h) for p, h in packs if p is not None]
     for (packed, host_ans) in packs:
         t0 = time.perf_counter()
-        buf, sizes = te.pack_entries(packed)
+        buf, sizes = pack_entries(packed)
         entries = jnp.asarray(buf)
         t_xfer += time.perf_counter() - t0
         t0 = time.perf_counter()
-        out = te._check_kernel(
+        out = _check_kernel(
             snap.device_buckets, entries, sizes=sizes,
             n_active=snap.num_active, n_int=snap.num_int,
             valid_rows=tuple(b.n for b in snap.buckets),
-            it_cap=engine._it_cap, block_iters=engine._block_iters,
+            it_cap=engine.dispatch._it_cap, block_iters=engine.dispatch._block_iters,
             bitmap_sharding=None,
         )
         t_disp += time.perf_counter() - t0
@@ -118,18 +119,18 @@ def main():
         log("no device chunks; skipping device-only stage")
         return
     packed, _ = packs[0]
-    buf, sizes = te.pack_entries(packed)
+    buf, sizes = pack_entries(packed)
     dev_entries = jax.device_put(jnp.asarray(buf))
     jax.block_until_ready(dev_entries)
     reps = max(4, len(packs))
     t0 = time.perf_counter()
     outs = []
     for _ in range(reps):
-        outs.append(te._check_kernel(
+        outs.append(_check_kernel(
             snap.device_buckets, dev_entries, sizes=sizes,
             n_active=snap.num_active, n_int=snap.num_int,
             valid_rows=tuple(b.n for b in snap.buckets),
-            it_cap=engine._it_cap, block_iters=engine._block_iters,
+            it_cap=engine.dispatch._it_cap, block_iters=engine.dispatch._block_iters,
             bitmap_sharding=None,
         ))
     jax.block_until_ready(outs)

@@ -109,7 +109,7 @@ def main() -> int:
     from bench import log
     from keto_tpu.check import native_pack
     from keto_tpu.check.engine import CheckEngine
-    from keto_tpu.check.tpu_engine import pack_chunk
+    from keto_tpu.check.pack import pack_chunk
     from keto_tpu.config.provider import Config
     from keto_tpu.driver.daemon import Daemon
     from keto_tpu.driver.registry import Registry
@@ -173,7 +173,7 @@ def main() -> int:
         # the controller steers and /metrics exposes)
         svc = engine.stream_slice_stats.snapshot()
         ratio = (svc["p99_ms"] / svc["p50_ms"]) if svc["p50_ms"] else 0.0
-        ctrl = engine.stream_ctrl.snapshot()
+        ctrl = engine.dispatch.stream_ctrl.snapshot()
         log(
             f"[tail-smoke] slices={svc['count']} p50={svc['p50_ms']:.2f}ms "
             f"p99={svc['p99_ms']:.2f}ms ratio={ratio:.2f} "
@@ -199,7 +199,7 @@ def main() -> int:
                                 relation=t["relation"],
                                 subject=SubjectID(t["subject_id"]))
                   for t in workload(rng)]
-            sd, tg, multi = engine._resolve_bulk(snap, qs)
+            sd, tg, multi = engine.dispatch._resolve_bulk(snap, qs)
             pn, hn = pack_chunk(snap, sd, tg, multi, 0, len(qs), native=True)
             pp, hp = pack_chunk(snap, sd, tg, multi, 0, len(qs), native=False)
             if (hn != hp).any() or (pn is None) != (pp is None):
@@ -211,7 +211,7 @@ def main() -> int:
                         break
 
         # staging ledger reconciles with the pool, zero leases leaked
-        st = engine.staging_snapshot()
+        st = engine.dispatch.staging_snapshot()
         led = engine.hbm.ledger().get("staging", 0)
         if st["leased"] != 0:
             problems.append(f"{st['leased']} staging leases outlived their slices")

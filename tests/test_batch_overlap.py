@@ -1,6 +1,6 @@
 """One round of look-ahead in the batcher's collector (keto_tpu/driver/batch.py
 ``_loop`` / ``_take_ahead`` / ``_launch`` / ``_land``, with the seam it needs in
-the engine: check/tpu_engine.py ``_stream``'s launch mark and x/timeline.py
+the engine: check/dispatch.py ``_stream``'s launch mark and x/timeline.py
 ``DispatchClock.watch``).
 
 Against a fake stream engine whose rounds land on command, and against the real
@@ -20,6 +20,7 @@ import pytest
 
 from keto_tpu import namespace as namespace_pkg
 from keto_tpu.check.frame import QueryFrame
+from keto_tpu.check.dispatch import CheckDispatch
 from keto_tpu.check.tpu_engine import TpuCheckEngine
 from keto_tpu.driver import batch as batch_mod
 from keto_tpu.driver.admission import AdmissionController
@@ -426,8 +427,8 @@ def test_the_engines_stream_gives_its_mark_once_between_launch_and_land(world):
     tuples = world.queries(24, rng)
     eng.batch_check(tuples[:1])
     seen_ready = []
-    real_ready = TpuCheckEngine._slice_ready
-    eng._slice_ready = lambda dev: seen_ready.append(dev is not None) or real_ready(dev)
+    real_ready = CheckDispatch._slice_ready
+    eng.dispatch._slice_ready = lambda dev: seen_ready.append(dev is not None) or real_ready(dev)
     gen, token = eng.batch_check_stream_with_token(iter(tuples), ordered=False, launch_mark=True)
     recs = list(gen)
     assert recs[0] is None and all(r is not None for r in recs[1:])
@@ -564,7 +565,7 @@ def test_a_device_fault_with_two_rounds_open_reaches_no_caller(world):
     assert res["first"][0] == world.expected(first)
     assert res["second"][0] == world.expected(second)
     assert (b.clock.rounds, b.clock.overlapped) == (2, 1)
-    assert eng.staging_snapshot().get("leased", 0) == 0  # nothing left leased behind
+    assert eng.dispatch.staging_snapshot().get("leased", 0) == 0  # nothing left leased behind
 
 
 # -- the clocks: round n+1's host work is not round n's service time --------------------
@@ -683,13 +684,13 @@ def _scripted_slice_ms(world, monkeypatch, look_ahead, device_s, step_s, rounds=
     eng.batch_check(tuples[:8])  # snapshot built, programs compiled: outside the script
     # the label index comes up behind the first batch; until then a slice of
     # this tiny graph is answered on the host and has no device time to script
-    wait_for(lambda: eng._labels_usable(eng.snapshot()), msg="label route up")
+    wait_for(lambda: eng.dispatch._labels_usable(eng.snapshot()), msg="label route up")
     eng.batch_check(tuples[:8])
     script = [5000.0]
     monkeypatch.setattr(time, "perf_counter", lambda: script[0])
     ready_at = {}
-    real_dispatch = eng._dispatch_slices
-    real_unpack = eng._unpack_slice
+    real_dispatch = eng.dispatch._dispatch_slices
+    real_unpack = eng.dispatch._unpack_slice
 
     def dispatching(snap, batch, it_cap=None):
         for rec in real_dispatch(snap, batch, it_cap):
@@ -702,9 +703,9 @@ def _scripted_slice_ms(world, monkeypatch, look_ahead, device_s, step_s, rounds=
         script[0] = max(script[0], ready_at.get(id(dev), 0.0))
         return real_unpack(dev, host_ans, nq)
 
-    monkeypatch.setattr(eng, "_dispatch_slices", dispatching)
-    monkeypatch.setattr(eng, "_unpack_slice", unpacking)
-    monkeypatch.setattr(eng, "_slice_ready", lambda dev: dev is None or script[0] >= ready_at[id(dev)])
+    monkeypatch.setattr(eng.dispatch, "_dispatch_slices", dispatching)
+    monkeypatch.setattr(eng.dispatch, "_unpack_slice", unpacking)
+    monkeypatch.setattr(eng.dispatch, "_slice_ready", lambda dev: dev is None or script[0] >= ready_at[id(dev)])
     monkeypatch.setattr(eng, "STREAM_LAUNCH_MARK", look_ahead, raising=False)
     _, before = eng.stream_slice_stats.tail(0)
     b = _batcher(eng)

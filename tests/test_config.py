@@ -116,7 +116,7 @@ def test_engine_config_keys_are_wired():
         }
     )
     reg = Registry(cfg)
-    assert reg.permission_engine()._it_cap == 77
+    assert reg.permission_engine().dispatch._it_cap == 77
     # requests asking for 0 or more than the cap get the cap
     assert reg.expand_depth(0) == 3
     assert reg.expand_depth(2) == 2

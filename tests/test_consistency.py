@@ -105,7 +105,10 @@ def test_serving_mode_catches_up_via_delta():
         T("g", "team", "member", SubjectID("alice")),
     )
     engine = TpuCheckEngine(p, p.namespaces)
-    engine.snapshot()
+    # the first snapshot's label build installs its index under the engine's
+    # lock, on a thread of its own, and the serving path never waits for
+    # that lock (it serves the snapshot it has): let the build land first
+    engine.labels_settled()
     engine._last_full_build_s = 60.0
     p.write_relation_tuples(T("g", "team", "member", SubjectID("bob")))
     p.delete_relation_tuples(T("g", "team", "member", SubjectID("alice")))

@@ -24,7 +24,7 @@ from benchmarks.reference import Reference  # noqa: E402
 from benchmarks.run import load_module  # noqa: E402
 from keto_tpu import namespace as namespace_pkg  # noqa: E402
 from keto_tpu.check import CheckEngine  # noqa: E402
-from keto_tpu.check import tpu_engine as te  # noqa: E402
+from keto_tpu.check import kernels, pack  # noqa: E402
 from keto_tpu.check.geometry import COMPILED, INLINE, PADDED_UP, KernelGeometries  # noqa: E402
 from keto_tpu.check.tpu_engine import TpuCheckEngine  # noqa: E402
 from keto_tpu.config.provider import Config  # noqa: E402
@@ -128,16 +128,16 @@ def over_the_pair_cap(engine, tuples):
     """Per query of ``tuples``: do its seed rows times its target-side rows
     pass the label route's pair cap (what ``_device_batch_labeled`` counts)."""
     snap = engine.snapshot()
-    sd, tg, multi = engine._resolve_bulk(snap, tuples)
+    sd, tg, multi = engine.dispatch._resolve_bulk(snap, tuples)
     n = len(tuples)
-    W = next(w for w in te._WORD_WIDTHS if 32 * w >= n)
-    packed = te.pack_chunk(snap, sd, tg, multi, 0, n, W)[0]
+    W = next(w for w in pack._WORD_WIDTHS if 32 * w >= n)
+    packed = pack.pack_chunk(snap, sd, tg, multi, 0, n, W)[0]
     e1r, e1q, e2r, e2q, ar, aq, targets = packed
     ni = snap.num_int
     ns = (np.bincount(e1q[e1r != ni + 1], minlength=n)[:n]
           + np.bincount(e2q[e2r != ni + 1], minlength=n)[:n])
     nr = np.bincount(aq[ar != ni], minlength=n)[:n] + (targets[:n] < ni)
-    return ns * nr > engine._LABEL_PAIR_CAP
+    return ns * nr > engine.dispatch._LABEL_PAIR_CAP
 
 
 @pytest.mark.parametrize("route", ["labels_on", "labels_off", "oracle"])
@@ -167,7 +167,7 @@ def test_engine_equals_reference_and_expectation(deployment, route):
                 under = list(np.nonzero(~over)[0][:200])
                 assert len(under) >= 40, "the pool has too few queries under the cap"
                 few = list(np.nonzero(over)[0][:20]) + under  # 20 of 60..220: a sub-batch of one word
-                engine.reset_route_stats()
+                engine.dispatch.reset_route_stats()
                 mixed = stream(engine, [deployment.tuples[i] for i in few])
                 assert mixed == [deployment.reference[i] for i in few]
                 assert engine.route_slice_counts().get("hybrid", 0) >= 1, (
@@ -185,7 +185,7 @@ def test_a_slice_that_mostly_falls_back_rides_check_step_whole(ringed):
     over, by that cause."""
     engine = ringed.engine()
     try:
-        engine.stream_ctrl.entry_budget = lambda: None  # one sub-chunk a slice, as on a fast device
+        engine.dispatch.stream_ctrl.entry_budget = lambda: None  # one sub-chunk a slice, as on a fast device
         seen = launched_sizes(engine)
         # 512 queries: a slice of the 2,048 width, most of them over the cap
         assert stream(engine, ringed.tuples[:512], 512) == ringed.reference[:512]
@@ -328,7 +328,7 @@ COMPILES = backend_compiles_by_thread()
 
 def wait_for_worker(engine, timeout=120.0):
     deadline = time.monotonic() + timeout
-    while engine._geoms.pending():
+    while engine.dispatch.geoms.pending():
         assert time.monotonic() < deadline, "the geometry worker did not finish"
         time.sleep(0.02)
 
@@ -341,7 +341,7 @@ def test_after_warm_compile_no_pass_compiles_on_the_calling_thread(ringed):
     engine = one.engine()
     try:
         engine.warm_compile()
-        settled = engine._block_iters
+        settled = engine.dispatch._block_iters
         me = threading.current_thread().name
         before = COMPILES.get(me, 0)
         for width in (1024, 256, 1024):
@@ -354,7 +354,7 @@ def test_after_warm_compile_no_pass_compiles_on_the_calling_thread(ringed):
         # what the worker compiled is ridden from here on, still without a compile here
         assert stream(engine, one.tuples) == one.reference
         assert COMPILES.get(me, 0) == before
-        assert engine._block_iters == settled, "block_iters moved after the warm-up"
+        assert engine.dispatch._block_iters == settled, "block_iters moved after the warm-up"
     finally:
         engine.close()
 
@@ -364,13 +364,13 @@ def test_block_iters_is_left_alone_on_the_warmed_shape_only(ringed):
     engine = one.engine()
     try:
         engine.warm_compile()
-        snap, settled = engine.snapshot(), engine._block_iters
-        engine._after_batch(30, snap)
-        assert engine._block_iters == settled, "grew on the shape the ladder was warmed for"
+        snap, settled = engine.snapshot(), engine.dispatch._block_iters
+        engine.dispatch._after_batch(30, snap)
+        assert engine.dispatch._block_iters == settled, "grew on the shape the ladder was warmed for"
         # a refresh that changes the shape: its programs compile anyway
-        engine._block_iters_shape = ("another shape",)
-        engine._after_batch(30, snap)
-        assert engine._block_iters == 32
+        engine.dispatch._block_iters_shape = ("another shape",)
+        engine.dispatch._after_batch(30, snap)
+        assert engine.dispatch._block_iters == 32
     finally:
         engine.close()
 
@@ -383,7 +383,7 @@ def test_an_engine_nobody_warmed_compiles_inline_and_says_so(ringed):
         counts = engine.kernel_geometry_counts()
         assert counts.get(("check", INLINE), 0) >= 1
         assert not counts.get(("check", PADDED_UP)) and not counts.get(("label", PADDED_UP))
-        assert engine._geoms.pending() == 0 and engine._geoms._worker is None
+        assert engine.dispatch.geoms.pending() == 0 and engine.dispatch.geoms._worker is None
     finally:
         engine.close()
 
@@ -392,7 +392,7 @@ def launched_sizes(engine):
     """Record the sizes every kernel launch of ``engine`` from this thread
     runs at (the geometry worker's compiles go through the same kernels)."""
     seen = []
-    check, label = engine._entry_kernels()
+    check, label = engine.dispatch._entry_kernels()
     me = threading.current_thread()
 
     def spy(name, kern):
@@ -403,7 +403,7 @@ def launched_sizes(engine):
 
         return call
 
-    engine._entry_kernels = lambda: (spy("check", check), spy("label", label))
+    engine.dispatch._entry_kernels = lambda: (spy("check", check), spy("label", label))
     return seen
 
 
@@ -417,13 +417,13 @@ def test_padded_geometry_answers_bit_identically_on_a_rung_boundary(ringed):
     padded = one.engine(labels_enabled=False)
     try:
         snap = exact.snapshot()
-        sd, tg, multi = exact._resolve_bulk(snap, one.tuples)
+        sd, tg, multi = exact.dispatch._resolve_bulk(snap, one.tuples)
 
         def sizes(n):
             """The sizes the first ``n`` queries launch at, as one slice."""
-            W = next(w for w in te._WORD_WIDTHS if 32 * w >= n)
-            packed, host_ans = te.pack_chunk(snap, sd, tg, multi, 0, n, W)
-            sub = None if packed is None else te.device_part(snap, packed, host_ans)[0]
+            W = next(w for w in pack._WORD_WIDTHS if 32 * w >= n)
+            packed, host_ans = pack.pack_chunk(snap, sd, tg, multi, 0, n, W)
+            sub = None if packed is None else pack.device_part(snap, packed, host_ans)[0]
             return None if sub is None else tuple(sub[i].shape[0] for i in (0, 2, 4, 6))
 
         above = lambda sz: sz is not None and max(sz[:3]) > sz[3]  # off the warmed minimum rung
@@ -431,13 +431,13 @@ def test_padded_geometry_answers_bit_identically_on_a_rung_boundary(ringed):
         def answer(engine, cut):
             """The first ``cut`` queries as one chunk, not split by entries."""
             snap = engine.snapshot()
-            resolved = engine._resolve_bulk(snap, one.tuples)
-            W = next(w for w in te._WORD_WIDTHS if 32 * w >= cut)
-            dev, host_ans, leases = engine._device_batch(snap, *resolved, 0, cut, W)
+            resolved = engine.dispatch._resolve_bulk(snap, one.tuples)
+            W = next(w for w in pack._WORD_WIDTHS if 32 * w >= cut)
+            dev, host_ans, leases = engine.dispatch._device_batch(snap, *resolved, 0, cut, W)
             try:
-                return engine._unpack_slice(dev, host_ans, cut)[0].tolist()
+                return engine.dispatch._unpack_slice(dev, host_ans, cut)[0].tolist()
             finally:
-                engine._stage_release(leases)
+                engine.dispatch._stage_release(leases)
 
         padded.warm_compile()
         own, ran = launched_sizes(exact), launched_sizes(padded)
@@ -472,19 +472,19 @@ def test_device_part_grants_what_the_seeds_hold_and_keeps_what_an_active_row_can
     try:
         snap = engine.snapshot()
         n = len(tuples)
-        W = next(w for w in te._WORD_WIDTHS if 32 * w >= n)
-        sd, tg, multi = engine._resolve_bulk(snap, tuples)
-        packed, host_ans = te.pack_chunk(snap, sd, tg, multi, 0, n, W)
+        W = next(w for w in pack._WORD_WIDTHS if 32 * w >= n)
+        sd, tg, multi = engine.dispatch._resolve_bulk(snap, tuples)
+        packed, host_ans = pack.pack_chunk(snap, sd, tg, multi, 0, n, W)
         kw = dict(n_active=snap.num_active, n_int=snap.num_int,
                   valid_rows=tuple(b.n for b in snap.buckets), it_cap=64)
-        run = lambda pk: np.asarray(te.check_step(
-            snap.device_buckets, te.pack_entries(pk)[0],
+        run = lambda pk: np.asarray(kernels.check_step(
+            snap.device_buckets, pack.pack_entries(pk)[0],
             sizes=tuple(pk[i].shape[0] for i in (0, 2, 4, 6)), **kw))
-        whole = engine._decode_packed(run(packed), host_ans.copy(), n)[0]
+        whole = engine.dispatch._decode_packed(run(packed), host_ans.copy(), n)[0]
         granted = host_ans.copy()
-        sub, pos = te.device_part(snap, packed, granted)
+        sub, pos = pack.device_part(snap, packed, granted)
         assert sub is not None and 0 < pos.size < n and not granted[pos].any()
-        bits = engine._decode_packed(run(sub), np.zeros(pos.size, bool), pos.size)[0]
+        bits = engine.dispatch._decode_packed(run(sub), np.zeros(pos.size, bool), pos.size)[0]
         if where == "ringed":
             assert granted.sum() > host_ans.sum(), "no direct grant: vacuous"
         else:
@@ -575,7 +575,7 @@ def test_new_families_are_on_metrics(ringed):
         assert value('keto_kernel_geometry_total{kernel="check",met="padded_up"}') == 0
         # at this size the device part has no row that can change, so a
         # slice may converge in 0 pulls
-        assert value("keto_check_bfs_slices_total") == engine.bfs_steps_stats.snapshot()["count"] >= 1
-        assert value("keto_check_bfs_steps_total") == sum(engine.bfs_steps_stats.tail(4096)[0])
+        assert value("keto_check_bfs_slices_total") == engine.dispatch.bfs_steps_stats.snapshot()["count"] >= 1
+        assert value("keto_check_bfs_steps_total") == sum(engine.dispatch.bfs_steps_stats.tail(4096)[0])
     finally:
         reg.close()

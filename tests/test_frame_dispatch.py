@@ -1,6 +1,6 @@
 """A framed ``/check/batch`` body on its way through the batcher and the
 engine (keto_tpu/check/frame.py, driver/batch.py ``_Round``,
-check/tpu_engine.py ``_resolve_bulk``): rounds that mix singles with a
+check/dispatch.py ``_resolve_bulk``): rounds that mix singles with a
 framed sub-slice, a frame cut over several rounds, deadlines, the retry
 path, engines that want objects, a namespace reload between framing and
 resolve, and the range fill."""
@@ -289,13 +289,13 @@ def test_namespace_reload_between_framing_and_resolve(make_persister):
         if not hasattr(snap.interned, "resolve_queries"):
             pytest.skip("native interner not in use")
         stale = make_frame(old, queries)
-        assert eng._frame_blocker(snap, QueryBatch([(stale, 0, 2)])) == "reload"
+        assert eng.dispatch._frame_blocker(snap, QueryBatch([(stale, 0, 2)])) == "reload"
         gen, _ = eng.batch_check_stream_with_token(_OneBatch(QueryBatch([(stale, 0, 2)])), ordered=True)
         assert np.concatenate(list(gen)).tolist() == [True, False]
         assert frame_mod.MATERIALIZED == {"reload": 1}
         # framed under the current manager it resolves as it is
         fresh = make_frame(new, queries)
-        assert eng._frame_blocker(snap, QueryBatch([(fresh, 0, 2)])) is None
+        assert eng.dispatch._frame_blocker(snap, QueryBatch([(fresh, 0, 2)])) is None
         gen, _ = eng.batch_check_stream_with_token(_OneBatch(QueryBatch([(fresh, 0, 2)])), ordered=True)
         assert np.concatenate(list(gen)).tolist() == [True, False]
         assert frame_mod.MATERIALIZED == {"reload": 1}

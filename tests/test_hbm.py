@@ -205,11 +205,11 @@ def test_tiny_budget_walks_every_rung_with_decision_parity(make_persister):
             "staging", "labels", "reverse", "warm-ladder", "overlay-budget",
         ]
         assert snap["forced_allocs"] >= 1
-        assert engine._staging_suspended
+        assert engine.dispatch._staging_suspended
         assert engine._labels_suspended
         assert engine._snapshot.labels is None
         # rung 2 trimmed the compile-width ladder
-        assert len(engine._word_widths()) < 7
+        assert len(engine.dispatch._word_widths()) < 7
         # rung 3 shrank the overlay budget below the configured value
         assert engine._max_overlay_edges < engine._configured_overlay_budget
         # ladder decisions changed no answers (again, post-eviction)
@@ -234,7 +234,7 @@ def test_rungs_walk_stepwise_and_recover_when_pressure_clears(make_persister):
         engine.hbm.set_budget_bytes(resident - led.get("staging", 0) - 1)
         assert engine.hbm.plan(led["snapshot"], what="test swap")
         assert engine.hbm.rung_depth >= 2
-        assert engine._staging_suspended
+        assert engine.dispatch._staging_suspended
         assert engine._labels_suspended
         assert engine.batch_check(queries) == expected
 
@@ -398,11 +398,11 @@ def test_warm_compile_skips_widths_over_budget(make_persister):
     try:
         engine.batch_check(queries[:8])
         snap = engine._snapshot
-        all_widths = engine.stream_widths(snap)
+        all_widths = engine.dispatch.stream_widths(snap)
         assert len(all_widths) > 1
         # budget: residency plus the SMALLEST width's workspace only —
         # warming must stop there and count the skipped rungs
-        smallest = engine._warm_width_bytes(snap, all_widths[0])
+        smallest = engine.dispatch._warm_width_bytes(snap, all_widths[0])
         engine.hbm.set_budget_bytes(engine.hbm.resident_bytes() + smallest)
         warmed = engine.warm_compile()
         assert warmed >= 1

@@ -23,7 +23,8 @@ import pytest
 
 from keto_tpu.check import native_pack
 from keto_tpu.check.engine import CheckEngine
-from keto_tpu.check.tpu_engine import TpuCheckEngine, _SortedSeen, pack_chunk
+from keto_tpu.check.pack import _SortedSeen, pack_chunk
+from keto_tpu.check.tpu_engine import TpuCheckEngine
 from keto_tpu.relationtuple.model import RelationTuple, SubjectID, SubjectSet
 
 
@@ -90,7 +91,7 @@ def test_native_pack_byte_parity_fuzz(make_persister, seed):
     try:
         snap = engine.snapshot()
         assert native_pack.walk_eligible(snap)
-        sd, tg, multi = engine._resolve_bulk(snap, queries)
+        sd, tg, multi = engine.dispatch._resolve_bulk(snap, queries)
         for i0, i1 in [(0, len(queries)), (17, 130), (60, 61)]:
             pn, hn = pack_chunk(snap, sd, tg, multi, i0, i1, native=True)
             pp, hp = pack_chunk(snap, sd, tg, multi, i0, i1, native=False)
@@ -146,11 +147,12 @@ def test_overlay_state_routes_to_numpy(make_persister):
 
 
 @needs_native
-def test_native_pack_env_disable(make_persister, monkeypatch):
-    """KETO_TPU_NATIVE_PACK=0 pins the numpy path without changing
-    answers (the engine flag seam does the same)."""
+def test_native_pack_unavailable_pins_numpy(make_persister, monkeypatch):
+    """With no library to load (what ``KETO_TPU_NATIVE=0`` or a box without
+    a compiler leaves) the engine packs with numpy, and answers the same."""
     p, queries = _fuzz_store(make_persister, seed=2, n_tuples=80, chain=5)
-    engine = TpuCheckEngine(p, p.namespaces, native_pack_enabled=False)
+    monkeypatch.setattr(native_pack, "available", lambda: False)
+    engine = TpuCheckEngine(p, p.namespaces)
     oracle = CheckEngine(p)
     try:
         before = native_pack.COUNTERS["native"]
@@ -261,7 +263,7 @@ def test_deep_chain_pack_completes(make_persister):
     try:
         snap = engine.snapshot()
         q = [T("a", "c0", "r0", SubjectID("u"))]
-        sd, tg, multi = engine._resolve_bulk(snap, q)
+        sd, tg, multi = engine.dispatch._resolve_bulk(snap, q)
         packed, host_ans = pack_chunk(snap, sd, tg, multi, 0, 1, native=False)
         # the chain is peeled/static-heavy: the walk decides it on host
         # or seeds the bitmap — either way it must agree with native

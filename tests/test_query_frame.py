@@ -250,15 +250,15 @@ def assert_frame_equals_decode(engine, body: bytes, got) -> None:
     buf, off, flags = got
     tuples = decode(body)
     n = len(tuples)
-    want_buf, special, dead, no_target = eng._frame_tuples(snap, tuples)
+    want_buf, special, dead, no_target = eng.dispatch._frame_tuples(snap, tuples)
     assert buf == want_buf
     assert flags.tolist() == flags_of(n, special, dead, no_target).tolist()
     assert off[0] == 0 and off[-1] == len(buf) and len(off) == n + 1
     ends = [i + 1 for i, b in enumerate(buf) if b == 0x1E]
     assert off[1:].tolist() == ends
     framed = QueryFrame(buf, off, flags, body, MANAGER)
-    sd, tg, multi = eng._resolve_bulk(snap, QueryBatch([(framed, 0, n)]))
-    sd_o, tg_o, multi_o = eng._resolve_bulk_native(snap, tuples)
+    sd, tg, multi = eng.dispatch._resolve_bulk(snap, QueryBatch([(framed, 0, n)]))
+    sd_o, tg_o, multi_o = eng.dispatch._resolve_bulk_native(snap, tuples)
     assert np.array_equal(sd, sd_o) and np.array_equal(tg, tg_o)
     assert multi.keys() == multi_o.keys()
     for i in multi:
@@ -267,7 +267,7 @@ def assert_frame_equals_decode(engine, body: bytes, got) -> None:
     # a cut in the middle resolves to the same rows
     if n > 2:
         a, b = n // 3, n - 1
-        sd_c, tg_c, _ = eng._resolve_bulk(snap, QueryBatch([(framed, a, b)]))
+        sd_c, tg_c, _ = eng.dispatch._resolve_bulk(snap, QueryBatch([(framed, a, b)]))
         assert np.array_equal(sd_c, sd_o[a:b]) and np.array_equal(tg_c, tg_o[a:b])
 
 

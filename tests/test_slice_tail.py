@@ -6,22 +6,22 @@ a staging buffer is only re-leased after the slice that shipped it has
 LANDED — across the ordered and ``ordered=False`` offset fast paths,
 across a mid-stream width switch, and across an HBM eviction of the
 staging rung mid-stream, every decision must still match the CPU
-reference oracle. The donated kernel variants are forced on
-(``KETO_TPU_DONATE=1``) so the donation call path executes even on
-backends where XLA ignores the donation.
+reference oracle. The donated kernel variants are forced on (the test
+patches ``kernels._donation_default``) so the donation call path executes
+even on backends where XLA ignores the donation.
 """
 
 import random
+import warnings
 
 import numpy as np
 import pytest
 
 from keto_tpu.check.engine import CheckEngine
-from keto_tpu.check.tpu_engine import (
-    StreamSliceController,
-    TpuCheckEngine,
-    _StagingPool,
-)
+from keto_tpu.check import kernels
+from keto_tpu.check.pack import _StagingPool
+from keto_tpu.check.slice_ctrl import StreamSliceController
+from keto_tpu.check.tpu_engine import TpuCheckEngine
 from keto_tpu.relationtuple.model import RelationTuple, SubjectID, SubjectSet
 
 
@@ -95,19 +95,21 @@ def test_staging_reuse_never_corrupts_decisions(
     a forced mid-stream width switch, and a mid-stream eviction (then
     restore) of the staging rung — every decision matches the oracle
     and no lease leaks."""
-    monkeypatch.setenv("KETO_TPU_DONATE", "1")
+    monkeypatch.setattr(kernels, "_donation_default", lambda: True)
+    # the CPU backend ignores the donation and says so once a geometry
+    warnings.filterwarnings("ignore", message="Some donated buffers were not usable")
     p, queries = _mixed_depth_store(make_persister, seed=seed)
     engine = TpuCheckEngine(p, p.namespaces, max_batch=64)
     oracle = CheckEngine(p)
     try:
-        assert engine._donate_entries
+        assert engine.dispatch._donate_entries
         expected = [oracle.subject_is_allowed(q) for q in queries]
         n = len(queries)
         hooks = {
             # mid-stream width switch: one fake monster-slow observation
             # narrows the controller's next planned width immediately
-            n // 4: lambda: engine.stream_ctrl.observe(
-                engine.stream_ctrl.cap(), 100_000.0
+            n // 4: lambda: engine.dispatch.stream_ctrl.observe(
+                engine.dispatch.stream_ctrl.cap(), 100_000.0
             ),
             # mid-stream staging eviction: rung 0 drops the pool; later
             # slices fall back to per-slice buffers
@@ -128,10 +130,10 @@ def test_staging_reuse_never_corrupts_decisions(
             for off, out in gen:
                 got[off : off + len(out)] = out.tolist()
         assert got == expected
-        st = engine.staging_snapshot()
+        st = engine.dispatch.staging_snapshot()
         assert st["leased"] == 0, "a staging lease outlived its slice"
         # the ledger's staging tag reconciles with the pool's accounting
-        assert engine.hbm.ledger().get("staging", 0) == engine._staging.bytes()
+        assert engine.hbm.ledger().get("staging", 0) == engine.dispatch._staging.bytes()
     finally:
         engine.close()
 
@@ -148,8 +150,8 @@ def test_abandoned_stream_releases_leases(make_persister):
         )
         next(gen)  # at least one slice landed, several more in flight
         gen.close()
-        assert engine.staging_snapshot()["leased"] == 0
-        assert engine.hbm.ledger().get("staging", 0) == engine._staging.bytes()
+        assert engine.dispatch.staging_snapshot()["leased"] == 0
+        assert engine.hbm.ledger().get("staging", 0) == engine.dispatch._staging.bytes()
         # and the engine still serves correctly afterwards
         oracle = CheckEngine(p)
         assert engine.batch_check(queries[:32]) == [
@@ -193,26 +195,30 @@ def test_staging_rung_evicts_and_restores(make_persister):
         assert engine.batch_check(queries[:64]) == expected
         assert engine.hbm.ledger().get("staging", 0) > 0
         assert engine.hbm.evict_one(reason="test") == "staging"
-        assert engine._staging_suspended
+        assert engine.dispatch._staging_suspended
         assert engine.hbm.ledger().get("staging", 0) == 0
         assert engine.batch_check(queries[:64]) == expected
         # suspended: the pool must not refill
         assert engine.hbm.ledger().get("staging", 0) == 0
         engine.hbm.maybe_restore()
-        assert not engine._staging_suspended
+        assert not engine.dispatch._staging_suspended
         assert engine.batch_check(queries[:64]) == expected
         assert engine.hbm.ledger().get("staging", 0) > 0
     finally:
         engine.close()
 
 
-def test_staging_disabled_engine_uses_no_pool(make_persister):
+def test_staging_suspended_engine_uses_no_pool(make_persister):
+    """An engine whose staging rung was shed before its first slice ships
+    every slice from a buffer of its own: no pool, nothing on the ledger."""
     p, queries = _mixed_depth_store(make_persister, seed=6)
-    engine = TpuCheckEngine(p, p.namespaces, staging_enabled=False)
+    engine = TpuCheckEngine(p, p.namespaces)
     try:
+        engine.labels_settled()  # a refresh that lands walks the ladder back up
+        assert engine.hbm.evict_one(reason="test") == "staging"
         engine.batch_check(queries[:64])
         assert engine.hbm.ledger().get("staging", 0) == 0
-        assert engine.staging_snapshot()["bytes"] == 0
+        assert engine.dispatch.staging_snapshot()["bytes"] == 0
     finally:
         engine.close()
 
@@ -271,16 +277,19 @@ def test_predicted_slow_chunks_split_before_dispatch(make_persister, monkeypatch
     try:
         snap = engine.snapshot()
         batch = queries[:128]
-        n_default = sum(1 for _ in engine._dispatch_slices(snap, batch))
+        n_default = sum(1 for _ in engine.dispatch._dispatch_slices(snap, batch))
         monkeypatch.setattr(
-            engine.stream_ctrl, "entry_budget", lambda: 64
+            engine.dispatch.stream_ctrl, "entry_budget", lambda: 64
         )
-        recs = list(engine._dispatch_slices(snap, batch))
+        recs = list(engine.dispatch._dispatch_slices(snap, batch))
         assert len(recs) > n_default, "entry budget did not split the chunk"
+        for rec in recs:
+            engine.dispatch._stage_release(rec[4])
         # every sub-slice stayed within ~the budget floor geometry and
-        # the reassembled decisions still match the oracle
-        out, _iters, trunc = engine._collect(recs, len(batch))
-        assert not trunc
+        # the decisions of the drained stream still match the oracle
+        before = sum(engine.route_slice_counts().values())
+        out, _iters = engine.dispatch._run_exact(snap, batch)
+        assert sum(engine.route_slice_counts().values()) - before == len(recs)
         assert out.tolist() == [oracle.subject_is_allowed(q) for q in batch]
     finally:
         engine.close()
@@ -299,8 +308,8 @@ def test_batcher_consults_planned_slice_width(make_persister):
         b = CheckBatcher(engine, batch_size=8192, batch_sub_slice=4096)
         # narrow the planned width to the controller floor (2048): one
         # huge observation — now narrower than the configured sub-slice
-        engine.stream_ctrl.observe(engine.stream_ctrl.cap(), 1_000_000.0)
-        cap = engine.stream_ctrl.cap()
+        engine.dispatch.stream_ctrl.observe(engine.dispatch.stream_ctrl.cap(), 1_000_000.0)
+        cap = engine.dispatch.stream_ctrl.cap()
         assert cap < 4096
         big = (queries * 20)[: cap + 1000]
         item = _Item(big, Future(), None, False, None, BATCH)

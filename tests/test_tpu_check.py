@@ -247,10 +247,10 @@ def test_bulk_resolve_native_parity(make_persister, seed):
         queries.append(
             T(rng.choice(ns_names + ["nope"]), rng.choice(objects), rng.choice(relations), sub)
         )
-    got_n = tpu._resolve_bulk_native(snap, queries)
+    got_n = tpu.dispatch._resolve_bulk_native(snap, queries)
     assert got_n is not None
     sd_n, tg_n, multi_n = got_n
-    sd_p, tg_p, multi_p = tpu._resolve_bulk_py(snap, queries)
+    sd_p, tg_p, multi_p = tpu.dispatch._resolve_bulk_py(snap, queries)
     assert np.array_equal(sd_n, sd_p)
     assert np.array_equal(tg_n, tg_p)
     assert multi_n.keys() == multi_p.keys()
@@ -280,12 +280,12 @@ def test_bulk_resolve_wild_subject_namespace_parity(make_persister):
         T("ns0", "o0", "r1", SubjectSet("", "o5", "r0")),  # divergent shape
         T("ns0", "o0", "r1", SubjectID("u1")),
     ]
-    sd_p, tg_p, multi_p = tpu._resolve_bulk_py(snap, queries)
+    sd_p, tg_p, multi_p = tpu.dispatch._resolve_bulk_py(snap, queries)
     # the pure-Python contract: literal start resolves to a single row
     # (never the -2 multi sentinel) with a reachable target
     assert sd_p[0] >= 0 and tg_p[0] >= 0 and 0 not in multi_p
     if hasattr(snap.interned, "resolve_queries"):
-        got = tpu._resolve_bulk_native(snap, queries)
+        got = tpu.dispatch._resolve_bulk_native(snap, queries)
         assert got is not None
         sd_n, tg_n, multi_n = got
         assert np.array_equal(sd_n, sd_p)
@@ -308,10 +308,10 @@ def test_bulk_resolve_wild_subject_no_empty_namespace(make_persister):
     tpu = TpuCheckEngine(p, p.namespaces)
     snap = tpu.snapshot()
     queries = [T("ns0", "o0", "r1", SubjectSet("", "o5", "r0"))]
-    sd_p, tg_p, _ = tpu._resolve_bulk_py(snap, queries)
+    sd_p, tg_p, _ = tpu.dispatch._resolve_bulk_py(snap, queries)
     assert sd_p[0] >= 0 and tg_p[0] == -1
     if hasattr(snap.interned, "resolve_queries"):
-        got = tpu._resolve_bulk_native(snap, queries)
+        got = tpu.dispatch._resolve_bulk_native(snap, queries)
         assert got is not None
         sd_n, tg_n, _ = got
         assert np.array_equal(sd_n, sd_p)
@@ -408,8 +408,8 @@ def test_it_cap_truncation_rerun_exact(make_persister):
     # and this test exists to exercise the BFS truncation retry ladder
     engine = TpuCheckEngine(p, p.namespaces, it_cap=1, labels_enabled=False)
     rungs = []
-    orig = engine._run_exact
-    engine._run_exact = lambda s, t, it_cap=None: (
+    orig = engine.dispatch._run_exact
+    engine.dispatch._run_exact = lambda s, t, it_cap=None: (
         rungs.append(it_cap), orig(s, t, it_cap=it_cap)
     )[1]
     queries = [
@@ -543,7 +543,7 @@ def test_stream_ready_order_preserves_order_under_skew(make_persister, pattern):
     rng = random.Random(5)
     ready = {"never": lambda dev: False, "always": lambda dev: True,
              "random": lambda dev: rng.random() < 0.5}[pattern]
-    engine._slice_ready = ready  # instance seam shadows the staticmethod
+    engine.dispatch._slice_ready = ready  # instance seam shadows the staticmethod
     slices = list(engine.batch_check_stream(iter(queries), depth=3))
     assert len(slices) > 3
     assert np.concatenate(slices).tolist() == want
@@ -559,7 +559,7 @@ def test_stream_unordered_reassociates_by_offset(make_persister):
     engine = TpuCheckEngine(p, p.namespaces, max_batch=32)
     want = engine.batch_check(queries)
     rng = random.Random(9)
-    engine._slice_ready = lambda dev: rng.random() < 0.5
+    engine.dispatch._slice_ready = lambda dev: rng.random() < 0.5
     got = np.zeros(len(queries), dtype=bool)
     seen = 0
     for off, out in engine.batch_check_stream(iter(queries), depth=3, ordered=False):
@@ -584,7 +584,7 @@ def test_stream_adaptive_controller_converges():
     """The width controller narrows under slow slices (multiplicatively,
     to the rung its per-query cost predicts) and re-widens rung by rung
     once full-width slices show headroom again."""
-    from keto_tpu.check.tpu_engine import StreamSliceController
+    from keto_tpu.check.slice_ctrl import StreamSliceController
 
     ctrl = StreamSliceController(target_ms=40.0, floor=32, patience=1)
     top = 32 * 4096
