@@ -968,8 +968,9 @@ class CheckDispatch:
     def _slices(self, snap, take, bound, ctrl, it_cap):
         """The launching side of a stream: cut up to the controller's cap
         off the source, resolve, pack and launch it, and yield one
-        ``(offset, dev, host_ans, nq, chunk, leases, n_entries)`` a launched
-        slice."""
+        ``(offset, dev, host_ans, nq, chunk, leases, n_entries, full_take)``
+        a launched slice; ``full_take``: the take it was cut from filled
+        the cap (``StreamSliceController.observe``)."""
         lockstep = self._lockstep_verify
         if lockstep:
             from keto_tpu.parallel.lockstep import verify_lockstep
@@ -991,14 +992,15 @@ class CheckDispatch:
             if snap.n_nodes == 0 or snap.n_edges == 0:
                 yield (
                     off, None, np.zeros(len(batch), dtype=bool),
-                    len(batch), batch, [], 0,
+                    len(batch), batch, [], 0, False,
                 )
                 off += len(batch)
                 continue
+            full = len(batch) >= cap
             for dev, host_ans, nq, chunk, leases, n_ent in (
                 self._dispatch_slices(snap, batch, it_cap=it_cap)
             ):
-                yield off, dev, host_ans, nq, chunk, leases, n_ent
+                yield off, dev, host_ans, nq, chunk, leases, n_ent, full
                 off += nq
 
     def _stream(
@@ -1041,7 +1043,7 @@ class CheckDispatch:
             # unpack one slice (blocks iff its transfer hasn't finished);
             # a truncated frontier re-runs exactly, mid-stream
             nonlocal max_iters, t_prev_ready
-            _seq, off, dev, host_ans, nq, chunk, leases, n_ent, t_disp = rec
+            _seq, off, dev, host_ans, nq, chunk, leases, n_ent, full, t_disp = rec
             clk.enter(DEVICE_WAIT)
             try:
                 out, iters, truncated = self._unpack_slice(dev, host_ans, nq)
@@ -1100,7 +1102,8 @@ class CheckDispatch:
                 route = "bfs"
             if ctrl is not None:
                 ctrl.observe(
-                    nq, ms, route=route, bfs_steps=int(iters), entries=n_ent
+                    nq, ms, route=route, bfs_steps=int(iters), entries=n_ent,
+                    full_take=full,
                 )
             self._note_route(route, nq, ms)
             if not truncated:  # the re-run's own slices were sampled as they landed
@@ -1161,12 +1164,12 @@ class CheckDispatch:
                     if nxt is None:
                         exhausted = True
                         break
-                    off, dev, host_ans, nq, chunk, leases, n_ent = nxt
+                    off, dev, host_ans, nq, chunk, leases, n_ent, full = nxt
                     if dev is not None:
                         dev.copy_to_host_async()
                     inflight.append((
                         seq, off, dev, host_ans, nq, chunk, leases, n_ent,
-                        time.perf_counter(),
+                        full, time.perf_counter(),
                     ))
                     seq += 1
                 if not inflight and exhausted:

@@ -142,12 +142,17 @@ class StreamSliceController:
         route: str = "bfs",
         bfs_steps: int = 0,
         entries: Optional[int] = None,
+        full_take: bool = False,
     ) -> None:
         """Feed one slice's service time: dispatch→ready when the pipeline
         ran dry, ready→ready interval when saturated. ``route``/
         ``bfs_steps``/``entries`` (from the stream's per-slice info) fit
         the per-route model; plain ``observe(nq, ms)`` still steers the
-        reactive ladder alone."""
+        reactive ladder alone. ``full_take``: the slice is a sub-chunk of
+        a take that filled the cap and was cut by the entry budget — it
+        speaks for the cap's width like a slice of ``cap`` queries (a
+        graph whose takes at one rung are always cut could otherwise
+        never widen again after one slow slice)."""
         if nq <= 0:
             return
         per_q = ms / nq
@@ -197,7 +202,7 @@ class StreamSliceController:
                         break
                 self._i = min(self._i, max(self._lo, want))
                 self._good = 0
-            elif ms < self.WIDEN_FRAC * self.target_ms and nq >= cap:
+            elif ms < self.WIDEN_FRAC * self.target_ms and (nq >= cap or full_take):
                 self._good += 1
                 if self._good >= self._patience and self._i + 1 < len(self._ladder):
                     self._i += 1

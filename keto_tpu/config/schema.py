@@ -98,7 +98,7 @@ CONFIG_SCHEMA = {
                 "batch_sub_slice": {
                     "type": "integer",
                     "default": 1024,
-                    "description": "Priority lanes: at most this many batch-lane tuples join one dispatch round, so a monster batch request is served in bounded sub-slices that interleave with interactive checks instead of owning the device for its full width. An interactive check arriving mid-burst waits at most one sub-slice, not the whole batch.",
+                    "description": "Priority lanes: while interactive checks are about (one is queued when a dispatch round is taken, or one rode that round or the one before it) at most this many batch-lane tuples join a round, so a monster batch request is served in bounded sub-slices that interleave with interactive checks instead of owning the device for its full width: it is the most batch-lane work that may stand between an interactive check and its round. While the interactive lane is quiet a round takes batch-lane work up to serve.batch_size, and the first interactive check to arrive rides the next round taken.",
                 },
                 "admission_enabled": {
                     "type": "boolean",

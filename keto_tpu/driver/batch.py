@@ -17,12 +17,17 @@ batcher therefore keeps TWO lanes:
 - ``interactive`` — single checks and small batches (≤
   ``interactive_max_tuples``): packed into the **next** dispatch round
   ahead of all queued batch work.
-- ``batch`` — pre-batched chunks: dispatched in bounded **sub-slices**
-  (≤ ``batch_sub_slice`` tuples per round), so a monster request
-  interleaves with the interactive lane instead of owning the device
-  for its full width. A small reserve (``batch_reserve_share`` of the
-  round) keeps the batch lane from starving when interactive traffic
-  alone can fill every round.
+- ``batch`` — pre-batched chunks. While interactive work is about (an
+  item queued at the take, or one that rode this round or the one before
+  it, which may still be on the device) they are dispatched in bounded
+  **sub-slices** (≤ ``batch_sub_slice`` tuples per round), so a monster
+  request interleaves with the interactive lane instead of owning the
+  device for its full width. While the interactive lane is quiet there
+  is nobody to interleave with, and a round takes batch-lane work up to
+  its own cap (``batch_size``): what a round costs the dispatch thread
+  is mostly paid per round, not per tuple. A small reserve
+  (``batch_reserve_share`` of the round) keeps the batch lane from
+  starving when interactive traffic alone can fill every round.
 
 Lane choice: explicit (``lane=``, from the REST ``X-Keto-Priority``
 header / gRPC ``x-keto-priority`` metadata) or by size. ADMISSION
@@ -278,6 +283,9 @@ class CheckBatcher:
         self._interactive_max_tuples = max(1, interactive_max_tuples)
         self._sub_slice = max(1, batch_sub_slice or max(1, batch_size // 4))
         self._batch_reserve = max(1, int(batch_size * batch_reserve_share))
+        #: whether the round taken last carried interactive work (it may
+        #: still be on the device); the collector thread's alone
+        self._inter_rode = False
         self.admission = admission
         self._cond = threading.Condition()  # guards: _lanes, _lane_tuples, _current_round, shed_count, shed_by_lane, admission_shed_count
         self._lanes: dict[str, deque] = {lane: deque() for lane in LANES}
@@ -404,8 +412,9 @@ class CheckBatcher:
         lane: Optional[str] = None,
     ) -> list[bool]:
         """Pre-batched requests ride the lanes like everything else: big
-        chunks land in the batch lane and dispatch in bounded sub-slices
-        that interleave with interactive work."""
+        chunks land in the batch lane and dispatch a round's worth at a
+        time, in bounded sub-slices that interleave with interactive work
+        while there is any."""
         return self.check_batch_with_token(
             tuples, timeout, at_least=at_least, latest=latest, deadline=deadline,
             lane=lane,
@@ -744,8 +753,11 @@ class CheckBatcher:
     def _take_locked(self) -> list:  # holds: _cond
         """Pack one dispatch round (called under ``_cond``): interactive
         items first — every one of them rides the NEXT round — then batch
-        lane work up to ``batch_sub_slice``, taking *partial* chunks so a
-        monster batch request interleaves instead of convoying. A reserve
+        lane work, taking *partial* chunks. While interactive work is
+        about (queued here, or aboard this round or the one before it)
+        that is at most ``batch_sub_slice``, so a monster batch request
+        interleaves instead of convoying; while the lane is quiet it is
+        the round's own cap, since nobody is there to convoy. A reserve
         keeps the batch lane moving when interactive traffic alone could
         fill every round. Returns ``[(item, start, count), ...]``."""
         segments = []
@@ -764,7 +776,17 @@ class CheckBatcher:
             if item.tl is not None:
                 item.tl.stamp("pack")  # queue wait ended here
             n += item.n
-        batch_cap = min(cap - n, self._sub_slice)
+        # the sub-slice is what may stand between an interactive item and
+        # its round, so it binds only while there are such items: one
+        # aboard this round or still queued behind it, or one aboard the
+        # round before, which may be on the device and lands only after
+        # this one's launch. A quiet lane lets the round fill: the thread
+        # pays most of a round per round, not per tuple
+        busy = bool(segments or inter)
+        batch_cap = cap - n
+        if busy or self._inter_rode:
+            batch_cap = min(batch_cap, self._sub_slice)
+        self._inter_rode = busy
         # service-time-aware sub-slicing: the engine's slice controller
         # predicts how many queries fit one target-latency slice for the
         # routes currently in play — a batch sub-slice wider than that

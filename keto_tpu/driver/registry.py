@@ -1605,6 +1605,19 @@ class Registry:
             rounds_by_overlap, ("overlapped",),
         )
 
+        def round_tuples():
+            clock = batcher_clock()
+            yield (), float(clock.round_tuples if clock is not None else 0)
+
+        m.register_callback(
+            "keto_dispatch_round_tuples_total", "counter",
+            "Tuples the dispatch rounds took off the lanes. Over "
+            "keto_dispatch_rounds_total (both values) it is the mean "
+            "round's width: what the per-round cost of the dispatch "
+            "thread is spread over.",
+            round_tuples,
+        )
+
         def compile_counts(i):
             def read():
                 from keto_tpu.driver.compile_cache import COMPILES

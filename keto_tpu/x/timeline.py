@@ -111,7 +111,7 @@ class DispatchClock:
     device is named by what its one feeder was doing."""
 
     __slots__ = (
-        "seconds", "rounds", "overlapped", "_state", "_t", "_session",
+        "seconds", "rounds", "overlapped", "round_tuples", "_state", "_t", "_session",
         "_tracing", "_ann", "_tuples", "_slices", "_lane_depth", "_probes",
     )
 
@@ -122,6 +122,8 @@ class DispatchClock:
         self.rounds = 0
         #: the rounds among ``rounds`` launched while another was open
         self.overlapped = 0
+        #: the tuples those rounds took off the lanes, all together
+        self.round_tuples = 0
         self._probes: list = []
         self._state = WAIT_WORK
         self._t = time.perf_counter()
@@ -170,6 +172,7 @@ class DispatchClock:
         ``overlapped``: another round is open (launched, not landed)."""
         self.rounds += 1
         self.overlapped += overlapped
+        self.round_tuples += tuples
         # ``idle`` is not passed while rounds follow each other without a
         # gap: a profiler session opened under load is seen here
         self._tracing = self._session.open

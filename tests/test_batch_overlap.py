@@ -7,8 +7,10 @@ Against a fake stream engine whose rounds land on command, and against the real
 engine on the CPU: the order of launches and landings, the counter that says
 how often a round was launched behind another, answers and snaptokens against
 the serial loop (the same engine with ``STREAM_LAUNCH_MARK`` off), failure and
-``stop()`` with two rounds open, a round's own snapshot, and the two clocks that
-must not count the host's work on round n+1 as round n's service time."""
+``stop()`` with two rounds open, a round's own snapshot, the two clocks that
+must not count the host's work on round n+1 as round n's service time, and how
+wide a round is taken: up to the round's own cap while the interactive lane is
+quiet, cut at the sub-slice from the first single on (``_take_locked``)."""
 
 import json
 import random
@@ -137,7 +139,10 @@ def _call(b, res, key, tuples, **kw):
 
 
 def _batcher(engine, **kw):
-    kw = {"batch_size": 32, "window_ms": 0.0, "batch_sub_slice": 8, **kw}
+    """Rounds of 8 whatever the lanes hold: the round's cap is the sub-slice.
+    (With a larger ``batch_size`` a quiet interactive lane lets a round take
+    batch-lane work up to that: the tests of the take, further down.)"""
+    kw = {"batch_size": 8, "window_ms": 0.0, "batch_sub_slice": 8, **kw}
     return CheckBatcher(engine, **kw)
 
 
@@ -145,7 +150,7 @@ def test_with_batch_work_queued_the_next_round_launches_before_this_one_lands():
     eng = GatedEngine()
     b = _batcher(eng)
     res = {}
-    tuples = [_q(i) for i in range(32)]  # four rounds of a sub-slice of 8
+    tuples = [_q(i) for i in range(32)]  # four rounds of 8
     t = _call(b, res, "a", tuples, lane=BATCH, timeout=30)
     wait_for(lambda: b.lane_depths[BATCH] == 32, msg="queued")
     b.start()
@@ -217,7 +222,7 @@ def test_a_single_that_arrives_while_a_round_is_out_rides_the_look_ahead_round()
     work queued the next round launches before this one lands, and a single
     queued by then is at its head."""
     eng = GatedEngine(hold=True)
-    b = _batcher(eng)
+    b = _batcher(eng, batch_size=32)
     res = {}
     bulk = _call(b, res, "bulk", [_q(i) for i in range(16)], lane=BATCH, timeout=30)
     single = _call(b, res, "single", [_q(3)], lane=INTERACTIVE, timeout=30)
@@ -236,6 +241,59 @@ def test_a_single_that_arrives_while_a_round_is_out_rides_the_look_ahead_round()
         b.stop()
     assert res["single"] == ([True], 100)
     assert res["bulk"][0] == [_want(_q(i)) for i in range(16)]
+
+
+# -- how much batch-lane work a round takes ---------------------------------------------
+# (the take itself, scripted segment by segment: tests/test_overload.py)
+
+
+@pytest.mark.parametrize("call, want", [(4096, [4096]), (10000, [4096, 4096, 1808])])
+def test_on_a_quiet_interactive_lane_a_call_rides_rounds_of_the_rounds_own_cap(call, want):
+    eng = GatedEngine()
+    b = CheckBatcher(eng, batch_size=4096, window_ms=0.0, batch_sub_slice=1024)
+    res = {}
+    tuples = [_q(i) for i in range(call)]
+    t = _call(b, res, "a", tuples, lane=BATCH, timeout=60)
+    wait_for(lambda: b.lane_depths[BATCH] == call, msg="queued")
+    b.start()
+    try:
+        t.join(timeout=60)
+    finally:
+        b.stop()
+    assert res["a"][0] == [_want(x) for x in tuples]
+    assert [len(eng.launched[n]) for n in range(len(want))] == want
+    assert (b.clock.rounds, b.clock.round_tuples) == (len(want), call)
+    assert b.clock.overlapped == len(want) - 1
+
+
+def test_the_first_single_after_a_quiet_spell_rides_the_next_round_and_narrows_the_two_after():
+    """Quiet -> busy -> quiet, with the collector running: two wide rounds, a
+    single arrives while they are open, and from the next round taken batch-lane
+    work is cut at the sub-slice until a round and the one before it carried no
+    interactive item."""
+    eng = GatedEngine(hold=True)
+    b = _batcher(eng, batch_size=32)
+    res = {}
+    tuples = [_q(i) for i in range(96)]
+    bulk = _call(b, res, "bulk", tuples, lane=BATCH, timeout=30)
+    wait_for(lambda: b.lane_depths[BATCH] == 96, msg="queued")
+    b.start()
+    try:
+        # round 0 is out, round 1 launched behind it: the collector waits for round 0
+        wait_for(lambda: eng.n_launched() == 2, msg="two wide rounds open")
+        single = _call(b, res, "single", [_q(3)], lane=INTERACTIVE, timeout=30)
+        wait_for(lambda: b.lane_depths == {INTERACTIVE: 1, BATCH: 32}, msg="single queued")
+        for n in range(5):
+            eng.gate(n).set()
+        bulk.join(timeout=30)
+        single.join(timeout=30)
+    finally:
+        b.stop()
+    assert [len(eng.launched[n]) for n in range(5)] == [32, 32, 1 + 8, 8, 16]
+    assert eng.launched[2][0] == _q(3)  # at the head of the very next round taken
+    assert res["single"] == ([True], 102)
+    assert res["bulk"][0] == [_want(x) for x in tuples]
+    assert (b.clock.rounds, b.clock.round_tuples) == (5, 97)
 
 
 # -- failure and shutdown, per round ---------------------------------------------------
@@ -582,12 +640,14 @@ class ScriptClock:
 
 
 class ScriptedEngine(GatedEngine):
-    """A round costs the host ``host_s`` to launch and the device ``device_s``
-    from then until it is ready; landing waits for what is left of that."""
+    """A round costs the host ``host_s`` and ``per_tuple_s`` a tuple to launch,
+    and the device ``device_s`` from then until it is ready; landing waits for
+    what is left of that."""
 
-    def __init__(self, clock, host_s, device_s, look_ahead):
+    def __init__(self, clock, host_s, device_s, look_ahead, per_tuple_s=0.0):
         super().__init__()
         self.clock, self.host_s, self.device_s = clock, host_s, device_s
+        self.per_tuple_s = per_tuple_s
         self.STREAM_LAUNCH_MARK = look_ahead
 
     def batch_check_stream_with_token(self, source, ordered=False, launch_mark=False, **kw):
@@ -595,7 +655,7 @@ class ScriptedEngine(GatedEngine):
 
         def gen():
             tuples = list(source)
-            clock.now += self.host_s
+            clock.now += self.host_s + self.per_tuple_s * len(tuples)
             ready_at = clock.now + self.device_s
             if launch_mark:
                 yield None
@@ -605,12 +665,16 @@ class ScriptedEngine(GatedEngine):
         return gen(), 1
 
 
-def _scripted_rate(monkeypatch, look_ahead, host_s=0.0040, device_s=0.0004, rounds=40):
+def _scripted_rate(monkeypatch, look_ahead, host_s=0.0040, device_s=0.0004, rounds=40,
+                   batch_size=1024, per_tuple_s=0.0):
+    """The admission controller's rate after one batch-lane call of
+    ``1024 * rounds`` tuples on a quiet interactive lane: rounds of
+    ``batch_size``, so of the sub-slice unless a test asks for wider ones."""
     clock = ScriptClock()
     monkeypatch.setattr(batch_mod, "time", clock)
     ctrl = AdmissionController(target_ms=40.0, min_window=64, max_window=1 << 20)
-    eng = ScriptedEngine(clock, host_s, device_s, look_ahead)
-    b = CheckBatcher(eng, batch_size=4096, window_ms=0.0, batch_sub_slice=1024,
+    eng = ScriptedEngine(clock, host_s, device_s, look_ahead, per_tuple_s)
+    b = CheckBatcher(eng, batch_size=batch_size, window_ms=0.0, batch_sub_slice=1024,
                      max_pending=1 << 20, admission=ctrl)
     res = {}
     t = _call(b, res, "a", [_q(i) for i in range(1024)] * rounds, lane=BATCH, timeout=None)
@@ -621,7 +685,9 @@ def _scripted_rate(monkeypatch, look_ahead, host_s=0.0040, device_s=0.0004, roun
         assert not t.is_alive()
     finally:
         b.stop()
-    assert b.clock.overlapped == (rounds - 1 if look_ahead else 0)
+    n_rounds = -(-1024 * rounds // batch_size)
+    assert (b.clock.rounds, b.clock.round_tuples) == (n_rounds, 1024 * rounds)
+    assert b.clock.overlapped == (n_rounds - 1 if look_ahead else 0)
     return ctrl.rate_tuples_per_s
 
 
@@ -657,6 +723,20 @@ def test_admission_rate_counts_a_wait_the_host_could_not_cover(monkeypatch):
     ahead = _scripted_rate(monkeypatch, look_ahead=True, device_s=device_s)
     assert 1024 / device_s < ahead < 1024 / host_s
     assert ahead == pytest.approx(2 * 1024 / (host_s + device_s), rel=0.25)
+
+
+def test_admission_rate_of_wide_rounds_is_their_tuples_over_the_threads_time(monkeypatch):
+    """A round that costs the thread 1.6 ms and 0.7 ms per 1,024 tuples: 2.3 ms
+    at the sub-slice's width, 4.4 ms at 4,096. On a quiet interactive lane the
+    batcher takes the wide ones, and the controller's rate is their tuples over
+    their time like any round's: it reads 2.1 times higher because it is."""
+    a, b = 0.0016, 0.0007 / 1024
+    device_s = 0.0004
+    narrow = _scripted_rate(monkeypatch, True, a, device_s, per_tuple_s=b)
+    wide = _scripted_rate(monkeypatch, True, a, device_s, per_tuple_s=b, batch_size=4096)
+    assert narrow == pytest.approx(0.8 * 1024 / 0.0023 + 0.2 * 1024 / 0.0027, rel=0.01)
+    assert wide == pytest.approx(0.8 * 4096 / 0.0044 + 0.2 * 4096 / 0.0048, rel=0.01)
+    assert wide / narrow == pytest.approx((4096 / 0.0044) / (1024 / 0.0023), rel=0.03)
 
 
 class SteppingClock(DispatchClock):

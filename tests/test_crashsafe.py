@@ -430,7 +430,9 @@ def test_drain_resolves_both_priority_lanes():
             "serve.write.port": 0,
             "serve.drain_timeout_s": 10.0,
             # a wide coalescing window + small sub-slices keep the batch
-            # chunk spanning several dispatch rounds when the drain hits
+            # chunk spanning several dispatch rounds when the drain hits:
+            # eight with the interactive checks about (64 a round), two
+            # should a round find their lane quiet (256 a round)
             "engine.batch_window_ms": 100.0,
             "engine.batch_size": 256,
             "serve.batch_sub_slice": 64,

@@ -122,8 +122,11 @@ def test_round_mixing_singles_with_a_framed_sub_slice_resolves_as_one_slice(worl
     assert frame_mod.MATERIALIZED == {}  # nobody asked for objects
 
 
-def test_framed_item_cut_over_several_rounds(world):
-    b = CheckBatcher(world.tpu, batch_size=16, window_ms=0.5, batch_sub_slice=7)
+@pytest.mark.parametrize("batch_size, n_rounds", [(7, 8), (16, 4)])
+def test_framed_item_cut_over_several_rounds(world, batch_size, n_rounds):
+    """50 framed tuples on a quiet interactive lane: cut at the round's own
+    cap, be that the sub-slice (7 a round) or wider (16, 16, 16, 2)."""
+    b = CheckBatcher(world.tpu, batch_size=batch_size, window_ms=0.5, batch_sub_slice=7)
     chunk = world.queries(50)
     framed = make_frame(world.manager, chunk)
     b.start()
@@ -133,7 +136,7 @@ def test_framed_item_cut_over_several_rounds(world):
         b.stop()
     assert got == world.expected(chunk)
     assert token == world.tpu.snapshot().snapshot_id
-    assert b.clock.rounds >= 8  # 50 tuples at <= 7 a round
+    assert (b.clock.rounds, b.clock.round_tuples) == (n_rounds, 50)
 
 
 def test_round_take_cuts_across_segments_by_count(world):

@@ -5,7 +5,8 @@ harness is the load half):
 
 - the CheckBatcher's priority lanes pack interactive checks into the
   next dispatch round ahead of queued batch work, and serve monster
-  batch chunks in bounded sub-slices;
+  batch chunks in bounded sub-slices while singles are about (in rounds
+  of the round's own cap while their lane is quiet);
 - the AIMD admission controller shrinks the admitted batch window past
   the latency budget and sheds with growing Retry-After advice —
   interactive is never admission-limited;
@@ -22,6 +23,7 @@ import threading
 import time
 import urllib.error
 import urllib.request
+from concurrent.futures import Future
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
@@ -29,7 +31,7 @@ import pytest
 from keto_tpu import namespace as namespace_pkg
 from keto_tpu.config.provider import Config
 from keto_tpu.driver.admission import AdmissionController
-from keto_tpu.driver.batch import BATCH, INTERACTIVE, CheckBatcher
+from keto_tpu.driver.batch import BATCH, INTERACTIVE, CheckBatcher, _Item
 from keto_tpu.driver.daemon import Daemon
 from keto_tpu.driver.registry import Registry
 from keto_tpu.httpclient import KetoClient, RetryBudget
@@ -78,11 +80,16 @@ class GateEngine:
 def test_interactive_packs_ahead_of_queued_batch():
     """An interactive check that arrives while a monster batch chunk is
     queued rides the NEXT dispatch round, ahead of the remaining batch
-    tuples — and batch work is taken at most one sub-slice per round."""
+    tuples — and with singles about, batch work is taken at most one
+    sub-slice per round."""
     eng = GateEngine()
     b = CheckBatcher(eng, batch_size=8, window_ms=2.0, batch_sub_slice=4)
     b.start()
     try:
+        # round 1 is a single's, and the collector blocks inside it
+        first = threading.Thread(target=lambda: b.check(T("i-0"), timeout=30), daemon=True)
+        first.start()
+        wait_for(lambda: len(eng.calls) == 1, msg="first round dispatched")
         chunk = [T(f"c-{i}") for i in range(12)]
         batch_res = {}
         bt = threading.Thread(
@@ -90,9 +97,8 @@ def test_interactive_packs_ahead_of_queued_batch():
             daemon=True,
         )
         bt.start()
-        wait_for(lambda: len(eng.calls) == 1, msg="first round dispatched")
-        # the collector is blocked inside round 1 (first sub-slice);
-        # an interactive check arrives now
+        wait_for(lambda: b.lane_depths[BATCH] == 12, msg="chunk queued")
+        # an interactive check arrives behind the monster chunk
         inter_res = {}
         it = threading.Thread(
             target=lambda: inter_res.update(r=b.check(T("i-2"), timeout=30)),
@@ -101,15 +107,16 @@ def test_interactive_packs_ahead_of_queued_batch():
         it.start()
         wait_for(lambda: b.lane_depths[INTERACTIVE] == 1, msg="interactive queued")
         eng.release.set()
-        it.join(timeout=10)
-        bt.join(timeout=10)
+        for t in (first, it, bt):
+            t.join(timeout=10)
         assert inter_res["r"] is True  # i-2 → even → allowed
         assert batch_res["r"] == [int(t.object[2:]) % 2 == 0 for t in chunk]
-        # round 1: first sub-slice of the chunk only
-        assert [t.object for t in eng.calls[0]] == ["c-0", "c-1", "c-2", "c-3"]
         # round 2: the interactive tuple is FIRST, ahead of the chunk's
-        # remaining tuples; batch take stays within one sub-slice
-        assert eng.calls[1][0].object == "i-2"
+        # first sub-slice
+        assert [t.object for t in eng.calls[1]] == ["i-2", "c-0", "c-1", "c-2", "c-3"]
+        # and the round behind it stays within one sub-slice too: the one
+        # that carried the single may still be on the device
+        assert [t.object for t in eng.calls[2]] == ["c-4", "c-5", "c-6", "c-7"]
         for call in eng.calls:
             assert sum(1 for t in call if t.object.startswith("c-")) <= 4
     finally:
@@ -117,12 +124,12 @@ def test_interactive_packs_ahead_of_queued_batch():
 
 
 def test_monster_chunk_resolves_across_sub_slices():
-    """A chunk wider than the sub-slice bound is answered correctly and
-    in order across several dispatch rounds."""
+    """A chunk wider than a round is answered correctly and in order
+    across several dispatch rounds."""
     eng = GateEngine(block_first=False)
     b = CheckBatcher(
-        eng, batch_size=8, window_ms=0.5, batch_sub_slice=3,
-        interactive_max_tuples=4,
+        eng, batch_size=3, window_ms=0.5, batch_sub_slice=3,
+        interactive_max_tuples=2,
     )
     b.start()
     try:
@@ -134,6 +141,98 @@ def test_monster_chunk_resolves_across_sub_slices():
         assert all(len(c) <= 3 for c in eng.calls)
     finally:
         b.stop()
+
+
+# the take itself, scripted: no collector thread, one ``_take_locked`` a round
+
+
+def _queue(b, n, lane):
+    item = _Item([T(f"q-{i}") for i in range(n)], Future(), None, False, None, lane)
+    b._enqueue(item)
+    return item
+
+
+def _take(b):
+    """One round off the lanes, as ``[(lane, start, count), ...]``."""
+    with b._cond:
+        return [(item.lane, start, count) for item, start, count in b._take_locked()]
+
+
+def _batch_share(segments):
+    return sum(count for lane, _, count in segments if lane == BATCH)
+
+
+def test_quiet_interactive_lane_takes_batch_work_up_to_the_rounds_cap():
+    b = CheckBatcher(GateEngine(), batch_size=4096, batch_sub_slice=1024)
+    _queue(b, 10000, BATCH)
+    _queue(b, 4096, BATCH)
+    assert _take(b) == [(BATCH, 0, 4096)]
+    assert _take(b) == [(BATCH, 4096, 4096)]
+    # a round is filled across calls, the head call first
+    assert _take(b) == [(BATCH, 8192, 1808), (BATCH, 0, 2288)]
+    assert _take(b) == [(BATCH, 2288, 1808)]
+    assert _take(b) == []
+
+
+def test_with_a_single_in_every_round_batch_work_stays_within_the_sub_slice():
+    b = CheckBatcher(GateEngine(), batch_size=4096, batch_sub_slice=1024)
+    _queue(b, 20000, BATCH)
+    for _ in range(6):
+        _queue(b, 1, INTERACTIVE)
+        segments = _take(b)
+        assert segments[0] == (INTERACTIVE, 0, 1)  # the single rides the next round, at its head
+        assert _batch_share(segments) == 1024
+    # a single every second round keeps them narrow as well
+    for k in range(6):
+        if k % 2:
+            _queue(b, 1, INTERACTIVE)
+        assert _batch_share(_take(b)) == 1024
+
+
+def test_quiet_to_busy_to_quiet_narrows_at_the_first_single_and_widens_two_rounds_later():
+    b = CheckBatcher(GateEngine(), batch_size=4096, batch_sub_slice=1024)
+    _queue(b, 30000, BATCH)
+    assert _batch_share(_take(b)) == 4096  # quiet
+    assert _batch_share(_take(b)) == 4096
+    _queue(b, 1, INTERACTIVE)
+    segments = _take(b)  # the very next round taken carries it
+    assert segments[0] == (INTERACTIVE, 0, 1) and _batch_share(segments) == 1024
+    assert _batch_share(_take(b)) == 1024  # the single's round may still be on the device
+    assert _batch_share(_take(b)) == 4096  # neither this round nor the one before carried one
+    assert _batch_share(_take(b)) == 4096
+
+
+def test_singles_still_queued_after_a_full_interactive_take_keep_the_round_narrow():
+    """More singles than a round holds: what stays queued rides the next
+    round, so neither that round nor this one takes more than the reserve or
+    the sub-slice of batch work."""
+    b = CheckBatcher(GateEngine(), batch_size=16, batch_sub_slice=4, interactive_max_tuples=1)
+    _queue(b, 100, BATCH)
+    for _ in range(20):
+        _queue(b, 1, INTERACTIVE)
+    assert _batch_share(_take(b)) == 2  # 14 singles, then the reserve's room
+    assert b.lane_depths[INTERACTIVE] == 6
+    segments = _take(b)
+    assert len(segments) == 7 and _batch_share(segments) == 4
+    assert _batch_share(_take(b)) == 4
+    assert _batch_share(_take(b)) == 16
+
+
+@pytest.mark.parametrize("ctrl_cap, quiet, busy", [(2500, 2500, 1024), (600, 600, 600), (1 << 20, 4096, 1024)])
+def test_the_slice_controllers_cap_bounds_a_wide_take_too(ctrl_cap, quiet, busy):
+    class Ctrl:
+        def cap(self):
+            return ctrl_cap
+
+    eng = GateEngine()
+    eng.stream_ctrl = Ctrl()
+    b = CheckBatcher(eng, batch_size=4096, batch_sub_slice=1024)
+    _queue(b, 20000, BATCH)
+    assert _batch_share(_take(b)) == quiet
+    _queue(b, 1, INTERACTIVE)
+    assert _batch_share(_take(b)) == busy
+    assert _batch_share(_take(b)) == busy
+    assert _batch_share(_take(b)) == quiet
 
 
 def test_lane_classification_by_size_and_hint():

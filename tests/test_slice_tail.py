@@ -268,6 +268,54 @@ def test_tail_guard_engages_on_blown_ratio():
     assert ctrl.snapshot()["tail_guard"] > snap["tail_guard"]
 
 
+def test_sub_chunks_of_a_full_take_speak_for_the_caps_width():
+    """One slow slice takes the reactive rung to the floor. A graph whose
+    takes at that rung are always cut by the entry budget hands the controller
+    only slices narrower than the cap: they widen it again when they come
+    from a take that filled it, and only then."""
+    ctrl = StreamSliceController(target_ms=40.0)
+    ctrl.observe(4096, 2000.0, route="label", entries=16384)  # a compile, a pause
+    assert ctrl.cap() == 2048
+    for _ in range(8):
+        ctrl.observe(1024, 1.5, route="label", entries=4096)  # a narrow take: says nothing
+    assert ctrl.cap() == 2048
+    for _ in range(2):
+        ctrl.observe(1024, 1.5, route="label", entries=4096, full_take=True)
+    assert ctrl.cap() == 8192
+    # a slow sub-chunk of a full take narrows like any slow slice
+    ctrl.observe(1024, 2000.0, route="label", entries=4096, full_take=True)
+    assert ctrl.cap() == 2048
+
+
+def test_the_stream_tells_the_controller_which_slices_came_from_a_full_take(make_persister, monkeypatch):
+    """``_stream`` cuts up to the controller's cap off its source; whatever the
+    entry budget then makes of a take, each launched slice carries whether the
+    take filled the cap."""
+    p, queries = _mixed_depth_store(make_persister, seed=9)
+    engine = TpuCheckEngine(p, p.namespaces, labels_enabled=False)
+    oracle = CheckEngine(p)
+    try:
+        d = engine.dispatch
+        batch = (queries * 3)[:160]
+        monkeypatch.setattr(d.stream_ctrl, "cap", lambda: 64)
+        monkeypatch.setattr(d.stream_ctrl, "entry_budget", lambda: 64)
+        seen = []
+        real = d.stream_ctrl.observe
+        monkeypatch.setattr(
+            d.stream_ctrl, "observe",
+            lambda nq, ms, **kw: seen.append((nq, kw["full_take"])) or real(nq, ms, **kw),
+        )
+        out = engine.batch_check(batch)
+        assert out == [oracle.subject_is_allowed(q) for q in batch]
+        # 160 queries at a cap of 64: two full takes and a rest of 32
+        full = sum(nq for nq, was_full in seen if was_full)
+        rest = sum(nq for nq, was_full in seen if not was_full)
+        assert (full, rest) == (128, 32)
+        assert sum(1 for _nq, was_full in seen if was_full) > 2  # and the budget cut them
+    finally:
+        engine.close()
+
+
 def test_predicted_slow_chunks_split_before_dispatch(make_persister, monkeypatch):
     """A tiny entry budget splits a resolved chunk into many sub-slices
     (the pre-dispatch half of the tail control), decisions unchanged."""
