@@ -48,7 +48,7 @@ from keto_tpu.check.kernels import (
 )
 from keto_tpu.check.pack import (
     _WORD_WIDTHS, _HybridSlice, _ShardedSlice, _StagingPool, _entry_pad,
-    _pad_packed, _padding_packed, device_part, pack_chunk, pack_entries,
+    _pad_packed, _padding_packed, device_part, hub_usable, pack_chunk, pack_entries,
 )
 from keto_tpu.check.slice_ctrl import StreamSliceController
 from keto_tpu.graph.snapshot import WILDCARD, GraphSnapshot, _ceil_pow2
@@ -336,16 +336,17 @@ class CheckDispatch:
         """What of the snapshot fixes a ``check_step`` program: row counts
         and the shapes of the arrays it closes over. Kept on the snapshot
         for as long as it holds the same device arrays."""
-        bk, ov = snap.device_buckets, snap.device_overlay
+        bk, ov, hub = snap.device_buckets, snap.device_overlay, snap.device_hub
         kept = getattr(snap, "_check_shape_of", None)
-        if kept is not None and kept[0] is bk and kept[1] is ov:
-            return kept[2]
+        if kept is not None and kept[0] is bk and kept[1] is ov and kept[2] is hub:
+            return kept[3]
         shape = (
             snap.num_active, snap.num_int, tuple(b.n for b in snap.buckets),
             tuple(a.shape for a in bk),
             None if ov is None else (ov[0].shape, ov[1].shape),
+            None if hub is None else hub.shape,
         )
-        snap._check_shape_of = (bk, ov, shape)
+        snap._check_shape_of = (bk, ov, hub, shape)
         return shape
 
     def _check_fixed(self, it_cap: int) -> tuple:
@@ -387,6 +388,7 @@ class CheckDispatch:
                 jnp.asarray(buf),
                 ov_nbrs=None if ov is None else ov[0],
                 ov_dst=None if ov is None else ov[1],
+                hub_nbrs=snap.device_hub,
                 sizes=sizes,
                 n_active=snap.num_active,
                 n_int=ni,
@@ -526,12 +528,55 @@ class CheckDispatch:
                             "label", self._label_shape(labs), self._label_fixed(), (B, B)
                         )
                 warmed += 1
+        if plain and labs is not None and self._riders_expected(snap):
+            # hub rows: a chunk's pairs and its riders' entries outgrow the
+            # ladder's own rungs by far, at widths the ladder has. Rungs of
+            # many entries on those widths, so that no such slice rides a
+            # wider bitmap or waits for a program of its own
+            for kernel, sizes in self._hub_rungs(widths):
+                if self._closing:
+                    break
+                if kernel == "label":
+                    self._run_label_padding(labs, sizes)
+                    self.geoms.add(kernel, self._label_shape(labs), self._label_fixed(), sizes)
+                else:
+                    self._run_check_padding(snap, sizes, self._it_cap)
+                    self.geoms.add(
+                        kernel, self._check_shape(snap), self._check_fixed(self._it_cap), sizes
+                    )
+                warmed += 1
         if plain:
             self.geoms.mark_warmed("check", self._check_shape(snap))
             if self._labels_enabled and labs is not None:
                 self.geoms.mark_warmed("label", self._label_shape(labs))
         self.maintenance.set_gauge("warm_widths_skipped", skipped)
         return warmed
+
+    @staticmethod
+    def _hub_rungs(widths: list[int]) -> list[tuple[str, tuple]]:
+        """The programs ``warm_compile`` adds where ``_riders_expected``, as
+        ``(kernel, sizes)``: ``(P, B)`` of ``label`` and ``(E, E, E, B)`` of
+        ``check``.
+        A chunk of B queries brings the label kernel up to the pair cap
+        times B pairs (rungs at 8 B and 32 B on the widths a served take
+        has), and its riders, a third of it on such a graph, a dozen
+        entries each or more (a hub sink's relay rows, the rows of a sink
+        just under a hub's, a seed a grant): ``device_part`` pads them
+        B' x 2^k (rungs at 16, 32 and 64 B' on the width that holds a take's
+        riders, 16,384 and 65,536 entries on the narrower two, which hold
+        what is left of a chunk that was cut)."""
+        rungs = [("label", (k * B, B)) for B in widths[2:4] for k in (8, 32)]
+        rungs += [("check", (E,) * 3 + (B,)) for B in widths[:2] for E in (16384, 65536)]
+        rungs += [("check", (k * B,) * 3 + (B,)) for B in widths[2:3] for k in (16, 32, 64)]
+        return rungs
+
+    @staticmethod
+    def _riders_expected(snap: GraphSnapshot) -> bool:
+        """Will hybrid slices carry riders on this snapshot whatever the
+        traffic? Yes where it has hub sinks (``GraphSnapshot.hub_relays``:
+        an answer gathered from more than twice the rows of the label
+        route's pair cap): a check on one passes the cap with a single seed."""
+        return snap.hub_ptr is not None
 
     # -- resolution ----------------------------------------------------------
 
@@ -1056,7 +1101,7 @@ class CheckDispatch:
             if dev is not None and not (
                 isinstance(dev, _HybridSlice) and dev.bfs_dev is None
             ):
-                self._note_bfs_steps(iters)
+                self._note_bfs_steps(iters, getattr(dev, "bfs_words", 0))
             if truncated:
                 # the cap that can NEVER truncate: monotone bitmaps reach the
                 # fixpoint in at most one pull per active row (each growing
@@ -1272,7 +1317,12 @@ class CheckDispatch:
         m_ans = has_start & (tg >= sbase) & (tg < nl)
         if m_ans.any():
             t = tg[m_ans] - sbase
-            cnt[m_ans] += sp_[t + 1] - sp_[t]
+            rows = sp_[t + 1] - sp_[t]
+            if hub_usable(snap):
+                # a hub sink sends its relay rows, not its rows
+                relays = snap.hub_ptr[t + 1] - snap.hub_ptr[t]
+                rows = np.where(relays > 0, relays, rows)
+            cnt[m_ans] += rows
         return cnt
 
     @staticmethod
@@ -1311,8 +1361,13 @@ class CheckDispatch:
         arrays stay within the {B, 2B, 4B} pad geometries — workload can't
         force unbounded allocations or fresh kernel geometries (a single
         monster query still falls through to ``_entry_pad``'s pow2
-        fallback; there is no smaller unit to split). The budget is the
-        smaller of the geometric 4·B bound and the slice controller's
+        fallback; there is no smaller unit to split). On a snapshot of hub
+        rows (``_riders_expected``) with the label route live the bound is
+        the pair cap times B: that route cuts a chunk by pairs and hands
+        ``check_step`` only the riders, whose entries ``device_part`` packs
+        narrow, so a take is one hybrid slice and not one per 4·B entries;
+        ``warm_compile`` has left rungs for it. The budget is
+        the smaller of that geometric bound and the slice controller's
         PREDICTED-service-time budget (``entry_budget``): a chunk the
         model predicts slow splits BEFORE dispatch, and the stream's
         ready-order window interleaves its sub-slices with fast ones —
@@ -1333,13 +1388,23 @@ class CheckDispatch:
             nq = s1 - s0
             W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
             B = 32 * W
+            use_labels = self._labels_usable(snap)
             cap_e = 4 * B
+            if use_labels and self._riders_expected(snap):
+                # hub rows: the label route cuts a chunk by pairs, at most
+                # the pair cap a query, and sends check_step the riders
+                # alone, entries packed narrow (``device_part``). A chunk may
+                # bring that many entries a query before it is split, or a
+                # take would be a dozen hybrid slices of two launches each
+                cap_e = self._LABEL_PAIR_CAP * B
             if not self._multiprocess:
-                # service-time-aware split bound (never below one B —
-                # the geometric floor keeps slice counts bounded)
+                # service-time-aware split bound (never below a quarter of
+                # the geometric bound, one B where that is 4·B — the floor
+                # keeps slice counts bounded: what a slice costs whatever
+                # it carries, its pulls, is paid again by every piece)
                 budget = self.stream_ctrl.entry_budget()
                 if budget is not None:
-                    cap_e = min(cap_e, max(B, budget))
+                    cap_e = min(cap_e, max(cap_e // 4, budget))
             cnt = self._entry_counts(snap, sd, tg, multi)
             if int(cnt.sum()) > cap_e:
                 reach = self._device_reach(snap)
@@ -1360,7 +1425,6 @@ class CheckDispatch:
                     i1 = max(i0 + 1, min(i1, nq))
                     bounds.append((i0, i1))
                     i0 = i1
-            use_labels = self._labels_usable(snap)
             for a, b in bounds:
                 # sub-chunks keep the slice width: queries pad, geometry stays
                 if use_labels:
@@ -1415,14 +1479,38 @@ class CheckDispatch:
             return _bits(f[:W], nq) | host_ans[:nq], int(f[W]), bool(f[W + 1])
         return self._decode_packed(jax.device_get(dev), host_ans, nq)
 
-    def _note_bfs_steps(self, iters: int) -> None:
+    def _note_bfs_steps(self, iters: int, words: int = 0) -> None:
         """One landed slice that ran ``check_step``: its pulls, for bench's
         percentiles and for ``keto_check_bfs_steps_total`` /
-        ``keto_check_bfs_slices_total``."""
+        ``keto_check_bfs_slices_total``, and its pulls times the ``words``
+        of a bitmap row in the program that ran them
+        (``keto_check_pull_words_total``: times the ELL's slots and 4, the
+        bytes its pulls gathered)."""
         self.bfs_steps_stats.observe(float(iters))
         self.maintenance.incr("bfs_slices")
         if iters:
             self.maintenance.incr("bfs_steps", by=int(iters))
+            if words:
+                self.maintenance.incr("bfs_pull_words", by=int(iters) * words)
+
+    def _note_packed(self, snap: GraphSnapshot, packed, nq: int) -> None:
+        """One chunk went through ``pack_chunk``: its checks, and the rows
+        either side of them (``keto_check_packed_total``,
+        ``keto_check_pack_rows_total{side}``). Seed rows are the e1 and e2
+        entries; target-side rows are the interior targets and the rows the
+        sinks' answers are gathered from."""
+        self.maintenance.incr("packed_checks", by=nq)
+        if packed is None:
+            return
+        ni = snap.num_int
+        e1r, _, e2r, _, ar, _, targets = packed
+        seeds = np.count_nonzero(e1r != ni + 1) + np.count_nonzero(e2r != ni + 1)
+        rows = np.count_nonzero(ar < ni) + np.count_nonzero(targets[:nq] < ni)
+        if snap.hub_rows is not None:
+            # a relay row of a hub sink stands for the rows it holds
+            rows += int(snap.hub_rows[ar[ar > ni] - (ni + 1)].sum())
+        self.maintenance.incr("pack_rows_seed", by=int(seeds))
+        self.maintenance.incr("pack_rows_target", by=int(rows))
 
     def _note_route(self, route: str, nq: int, ms: float) -> None:
         """Record one landed slice's route (label | hybrid | bfs | host |
@@ -1520,6 +1608,7 @@ class CheckDispatch:
         packed, host_ans = pack_chunk(snap, sd, tg, multi, i0, i1, W)
         clk.poll()
         nq = i1 - i0
+        self._note_packed(snap, packed, nq)
         leases: list = []
         if packed is None:
             return None, host_ans, leases  # nothing reaches any device path
@@ -1558,17 +1647,17 @@ class CheckDispatch:
             fall_back("self_hit", e1_q_v[self_hit])
 
         # target-side rows per query: the interior target, or the sink
-        # answer-gather rows
-        b_rows = np.concatenate(
-            [tq[t_int], ar[ma].astype(np.int64)]
-        )
-        b_q = np.concatenate([np.nonzero(t_int)[0], aq[ma].astype(np.int64)])
-
-        # count each side per query first: a query over the pair cap takes
-        # neither side into the sort and the cross-join below
+        # answer-gather rows. Each side is counted per query first: a query
+        # over the pair cap takes neither side into the sort and the
+        # cross-join below, and where targets are hub rows its answer rows
+        # are most of the chunk's entries
+        a_rows_v, a_q_v = ar[ma], aq[ma]
         ns = np.bincount(s_q, minlength=nq)
-        nr = np.bincount(b_q, minlength=nq)
+        nr = np.bincount(a_q_v, minlength=nq) + t_int
         over = ns * nr > self._LABEL_PAIR_CAP
+        if snap.hub_ptr is not None:
+            # a relay row stands for more rows than the cap: its query rides
+            over[a_q_v[a_rows_v > ni]] = True
         if over.any():
             fall_back("pair_cap", over)
 
@@ -1583,9 +1672,10 @@ class CheckDispatch:
         whole = rides_whole()
         if not whole:
             keep_s = ~fallback[s_q]
-            keep_b = ~fallback[b_q]
             s_rows, s_q = s_rows[keep_s], s_q[keep_s]
-            b_rows, b_q = b_rows[keep_b], b_q[keep_b]
+            t_keep, keep_a = t_int & ~fallback, ~fallback[a_q_v]
+            b_rows = np.concatenate([tq[t_keep], a_rows_v[keep_a].astype(np.int64)])
+            b_q = np.concatenate([np.nonzero(t_keep)[0], a_q_v[keep_a].astype(np.int64)])
             # group both sides by query, then cross-join per query
             so = np.argsort(s_q, kind="stable")
             s_rows, s_q = s_rows[so], s_q[so]
@@ -1673,9 +1763,10 @@ class CheckDispatch:
                 if lmet == INLINE:
                     self.geoms.add("label", lshape, lfixed, own)
 
-        bfs_dev = None
-        bfs_pos = None
-        if n_fb:
+        sub = None  # the riders' slice
+        if n_fb and self._sharded and snap.device_shards is not None:
+            # the sharded kernel takes a chunk whole: the riders are packed
+            # again as a chunk of their own
             pos = np.nonzero(fallback)[0]
             gidx = pos + i0
             multi2 = {
@@ -1691,11 +1782,19 @@ class CheckDispatch:
                 )
                 leases.extend(bfs_leases)
                 host_ans[pos] |= host2  # what the host granted without the device
-                if sub is not None:
-                    bfs_dev, bfs_pos = sub.bfs_dev, sub.bfs_pos
-        if ldev is None and bfs_dev is None:
-            return None, host_ans, leases
-        return _HybridSlice(ldev, bfs_dev, bfs_pos), host_ans, leases
+        elif n_fb:
+            # ``device_part`` cuts the chunk's own entries down to the
+            # riders that need the device: nothing is walked or gathered twice
+            clk.enter(PACK)
+            faults.check("device-exec")
+            sub, bfs_leases = self._launch_check(
+                snap, packed, host_ans, it_cap, "hybrid", only=fallback
+            )
+            leases.extend(bfs_leases)
+        if sub is None:
+            return (None if ldev is None else _HybridSlice(ldev)), host_ans, leases
+        sub.label_dev = ldev
+        return sub, host_ans, leases
 
     def _device_batch(
         self,
@@ -1718,6 +1817,7 @@ class CheckDispatch:
         faults.check("device-exec")
         dispatch_clock().enter(PACK)
         packed, host_ans = pack_chunk(snap, sd, tg, multi, i0, i1, force_W)
+        self._note_packed(snap, packed, i1 - i0)
         if packed is None:
             # no query in the chunk reaches the device: host_ans is the
             # whole answer
@@ -1728,24 +1828,25 @@ class CheckDispatch:
     def _launch_check(
         self, snap: GraphSnapshot, packed, host_ans: np.ndarray,
         it_cap: Optional[int], route: str, sub_of: Optional[np.ndarray] = None,
+        only: Optional[np.ndarray] = None,
     ):
         """Ship what of one packed chunk the device has to see
         (``device_part``: the rest is granted into ``host_ans`` here) to
         ``check_step``. Returns ``(slice, leases)``: a ``_HybridSlice`` of
         no label part whose BFS part answers the chunk's positions that
         needed the device, or None where none does. ``sub_of`` says where
-        the chunk's own queries sit in a wider slice."""
+        the chunk's own queries sit in a wider slice; ``only`` (single
+        device) keeps the chunk's other queries off the device whatever
+        they need: the label kernel has them."""
         clk = dispatch_clock()
         leases: list = []
         it_cap = it_cap or self._it_cap
         if self._sharded and snap.device_shards is not None:
             dev = self._dispatch_sharded(snap, packed, it_cap, leases=leases)
             return (dev if sub_of is None else _HybridSlice(None, dev, sub_of)), leases
-        packed, pos = device_part(snap, packed, host_ans)
+        packed, pos = device_part(snap, packed, host_ans, only)
         if packed is None:
             return None, leases
-        if sub_of is not None:
-            pos = sub_of[pos]
         stg = met = None
         if self._mesh is None:
             own = tuple(packed[i].shape[0] for i in (0, 2, 4, 6))
@@ -1769,6 +1870,7 @@ class CheckDispatch:
                 self._put(buf),
                 ov_nbrs=None if ov is None else ov[0],
                 ov_dst=None if ov is None else ov[1],
+                hub_nbrs=snap.device_hub,
                 sizes=sizes,
                 n_active=snap.num_active,
                 n_int=snap.num_int,
@@ -1780,7 +1882,7 @@ class CheckDispatch:
         )
         if met == INLINE:
             self.geoms.add("check", shape, fixed, own)
-        return _HybridSlice(None, dev, pos), leases
+        return _HybridSlice(None, dev, pos, sizes[3] // 32), leases
 
     def _dispatch_sharded(
         self, snap: GraphSnapshot, packed, it_cap: int, leases=None

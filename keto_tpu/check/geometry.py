@@ -14,10 +14,15 @@ compiled and asks it before every launch:
   is (``warm_compile`` leaves one minimum rung per ladder width, so a slice
   whose entries outgrew its width's rung rides a wider width's). The slice is
   padded to it with the sentinels the warm-up already uses and answers
-  bit-identically. Behind it a worker thread compiles the slice's own
-  program, which the next slice of those sizes launches as packed: what runs
-  in the steady state is what an inline compile would have left, so a
-  deployment that never leaves its warmed rung runs nothing else than before.
+  bit-identically. Where the program it rides is wider than its own, a
+  worker thread compiles the slice's own behind it, which the next slice of
+  those sizes launches as packed: what runs in the steady state is what an
+  inline compile would have left, so a deployment that never leaves its
+  warmed rung runs nothing else than before. A slice that rides a program of
+  its own width pads entries only and asks for nothing: ``warm_compile``
+  leaves such rungs where the snapshot shows hub rows (a sink gathered from
+  rows by the hundred), whose riders bring thousands of entries for a few
+  dozen queries.
 - ``inline_compile``: nothing compiled fits (an engine nobody warmed, a
   snapshot shape met for the first time, a slice with more entries than the
   widest warmed rung holds): the launch compiles, as it always did, and is
@@ -106,7 +111,11 @@ class KernelGeometries:
                     # the narrowest width first (the bitmaps are what a
                     # wider program costs), then the fewest entries
                     met, use = PADDED_UP, min(fits, key=lambda s: (s[-1], sum(s)))
-                    self._ask(kernel, shape, fixed, sizes)
+                    if use[-1] > sizes[-1]:
+                        # padded entries are dropped seeds and all-zero
+                        # answer rows: only a wider bitmap is worth a
+                        # program of the slice's own
+                        self._ask(kernel, shape, fixed, sizes)
             self._counts[(kernel, met)] += 1
             return use, met
 

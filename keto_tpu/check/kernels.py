@@ -74,6 +74,7 @@ def check_step(
     entries: jnp.ndarray,  # int32[2·S1+2·S2+2·SA+B] packed entry arrays
     ov_nbrs: Optional[jnp.ndarray] = None,  # int32[K, C] overlay-ELL gather
     ov_dst: Optional[jnp.ndarray] = None,  # int32[K] unique active rows (pad → n_active)
+    hub_nbrs: Optional[jnp.ndarray] = None,  # int32[Kh, C] relay rows of hub sinks (pad → n_int)
     *,
     sizes: tuple[int, int, int, int],  # (S1, S2, SA, B)
     n_active: int,
@@ -93,7 +94,8 @@ def check_step(
     #   e1_rows  int32[S1] interior start rows (padding → n_int+1)
     #   e1_q     int32[S1] owning query index (padding → 0)
     #   e2_*               same pair for host-propagated seeds
-    #   a_rows   int32[SA] interior in-neighbors of sink targets
+    #   a_rows   int32[SA] interior in-neighbors of sink targets; with
+    #                      ``hub_nbrs``, n_int+1+k names relay row k
     #   a_q      int32[SA] owning query index (padding → 0 w/ row n_int)
     #   targets  int32[B]  interior target rows, n_int = none
     S1, S2, SA, B = sizes
@@ -151,6 +153,13 @@ def check_step(
         # neighbors contribute to a pull is their seed bits, the same in
         # every step, and is gathered from R0 once (row n_int is all-zero:
         # active neighbor ids are sent there).
+        if bitmap_sharding is None:
+            # one device: the rows a bucket is padded with (to a power of
+            # two) are never read, and gathering them is a third of a pull
+            # on a graph whose buckets are half full. On a mesh the
+            # buckets are sharded by rows and are pulled whole, as before
+            bucket_nbrs = tuple(b[:n] for b, n in zip(bucket_nbrs, valid_rows))
+
         def in_A(ids):
             return jnp.minimum(ids, n_active)
 
@@ -217,9 +226,23 @@ def check_step(
     # exact.
     aw = a_q // 32
     ab = (a_q % 32).astype(jnp.uint32)
-    fix = R0[a_rows, aw]
-    if A_fix is not None:
-        fix = jnp.where(a_rows < n_active, A_fix[jnp.minimum(a_rows, n_active), aw], fix)
+    if hub_nbrs is None:
+        fix = R0[a_rows, aw]
+        if A_fix is not None:
+            fix = jnp.where(a_rows < n_active, A_fix[jnp.minimum(a_rows, n_active), aw], fix)
+    else:
+        # hub sinks (a user in groups by the hundred, a target that every
+        # query on it would gather row by row): the snapshot keeps their
+        # in-neighbor lists as relay rows of C, OR-reduced here once a
+        # slice for all its queries by the row gather a pull is made of,
+        # and an entry names a relay row where it would have named C rows
+        F = R0 if A_fix is None else R0.at[:n_active].set(A_fix[:n_active])
+        block = max(1, _DEGREE_CHUNK * 64 // hub_nbrs.shape[1])  # a bucket's worth of slots
+        relay = [
+            lax.reduce(F[hub_nbrs[r0 : r0 + block]], np.uint32(0), lax.bitwise_or, (1,))
+            for r0 in range(0, hub_nbrs.shape[0], block)
+        ]
+        fix = jnp.concatenate([F] + relay, axis=0)[a_rows, aw]
     vals = (fix >> ab) & jnp.uint32(1)
     hit = hit.at[a_q].max(vals)
 

@@ -15,7 +15,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from keto_tpu.check import native_pack
-from keto_tpu.graph.snapshot import GraphSnapshot
+from keto_tpu.graph.snapshot import GraphSnapshot, _csr_gather_host
 
 # batch widths (in 32-query words) the engine compiles for; a request is
 # padded up to the smallest fitting width so jit caches stay small
@@ -31,12 +31,15 @@ class _HybridSlice:
     answer without the device). Quacks like a device array where the
     streaming pipeline needs it (``copy_to_host_async`` / ``is_ready``)."""
 
-    __slots__ = ("label_dev", "bfs_dev", "bfs_pos")
+    __slots__ = ("label_dev", "bfs_dev", "bfs_pos", "bfs_words")
 
-    def __init__(self, label_dev, bfs_dev=None, bfs_pos=None):
+    def __init__(self, label_dev, bfs_dev=None, bfs_pos=None, bfs_words=0):
         self.label_dev = label_dev
         self.bfs_dev = bfs_dev
         self.bfs_pos = bfs_pos
+        #: words of a bitmap row in the ``check_step`` program that ran the
+        #: BFS part (0 where the sharded kernel did)
+        self.bfs_words = bfs_words
 
     def parts(self) -> list:
         # label_dev is None on the BFS route, and where no certifiable pair
@@ -272,7 +275,9 @@ def _pad_packed(packed, sizes: tuple, ni: int):
     )
 
 
-def device_part(snap: GraphSnapshot, packed, host_ans: np.ndarray):
+def device_part(
+    snap: GraphSnapshot, packed, host_ans: np.ndarray, only: Optional[np.ndarray] = None
+):
     """What of a packed chunk ``check_step`` has to see, and what the host
     can say without it.
 
@@ -282,11 +287,14 @@ def device_part(snap: GraphSnapshot, packed, host_ans: np.ndarray):
     set intersection the kernel would otherwise do as a scatter into, and a
     gather from, a bitmap over all interior rows), and a query gets
     nothing more from the device unless its target side has an active
-    row: an answer row below ``num_active``, or an active interior target
+    row: an answer row below ``num_active`` (or a relay row of a hub sink,
+    which the host does not look into), or an active interior target
     (a passive one is answered by the host walk's own hit alone). The
     others leave the chunk, so on a graph whose device part is small the
     kernel runs a narrow sub-batch of few entries; where most rows are
-    active nearly every query stays.
+    active nearly every query stays. ``only`` (bool by query) names the
+    queries that may stay: a hybrid slice's riders, the rest being the
+    label kernel's.
 
     ORs the direct grants into ``host_ans`` and returns ``(packed, pos)``:
     the seven arrays of the queries that need the device, renumbered 0..
@@ -304,8 +312,10 @@ def device_part(snap: GraphSnapshot, packed, host_ans: np.ndarray):
         )
         host_ans[aq[direct]] = True
     need = targets[:nq] < na
-    need[aq[ar < na]] = True
+    need[aq[(ar < na) | (ar > ni)]] = True  # an active answer row, or a relay row
     need &= ~host_ans
+    if only is not None:
+        need &= only
     k1, k2 = need[e1q], need[e2q]
     if not k1.any() and not k2.any():
         return None, None  # no query is left, or nothing seeds those that are
@@ -315,10 +325,13 @@ def device_part(snap: GraphSnapshot, packed, host_ans: np.ndarray):
     ka = need[aq]
     # one pad for the three entry arrays, B·4^k: how many of its queries a
     # chunk sends here varies from chunk to chunk, and every combination
-    # of pads is a program of its own to compile
-    E = B
+    # of pads is a program of its own to compile. The riders of a hybrid
+    # slice (``only``) pad B·2^k: ``warm_compile`` leaves their rungs where
+    # a snapshot has them at all, and at ten thousand entries and more a
+    # pad of four times is most of what the kernel scatters
+    E, step = B, 4 if only is None else 2
     while E < max(int(k1.sum()), int(k2.sum()), int(ka.sum())):
-        E *= 4
+        E *= step
 
     def side(rows, q, keep, pad_row):
         out_r, out_q = np.full(E, pad_row, np.int32), np.zeros(E, np.int32)
@@ -332,6 +345,17 @@ def device_part(snap: GraphSnapshot, packed, host_ans: np.ndarray):
         side(e1r, e1q, k1, ni + 1) + side(e2r, e2q, k2, ni + 1)
         + side(ar, aq, ka, ni) + (sub_targets,),
         pos,
+    )
+
+
+def hub_usable(snap: GraphSnapshot) -> bool:
+    """May answer entries name the snapshot's relay rows? Where the engine
+    has put them on the device and no overlay edge or tombstone reaches a
+    sink: the relay rows are the base's."""
+    return (
+        snap.device_hub is not None
+        and not snap.ov_sink_in
+        and (snap.ov_removed is None or snap.ov_removed.size == 0)
     )
 
 
@@ -489,6 +513,18 @@ def pack_chunk(
             tgc, np.fromiter(snap.ov_sink_in.keys(), np.int64)
         )
     m_ans = has_start & m_sink_t
+    if m_ans.any() and hub_usable(snap):
+        # a hub sink's answer comes from its relay rows (``hub_relays``): an
+        # entry a relay row, not one a row, and nothing gathered here
+        sink = np.where(m_ans, tgc - sb, 0)
+        m_hub = m_ans & (snap.hub_ptr[sink + 1] > snap.hub_ptr[sink])
+        if m_hub.any():
+            relays, n_relay = _csr_gather_host(
+                snap.hub_ptr, np.arange(snap.hub_rows.shape[0]), sink[m_hub]
+            )
+            ans[0].append((ni + 1 + relays).astype(np.int32))
+            ans[1].append(np.repeat(qi[m_hub], n_relay).astype(np.int32))
+            m_ans = m_ans & ~m_hub
     if m_ans.any():
         if use_native:
             # overlay-free by eligibility: the native gather mirrors

@@ -46,7 +46,14 @@ class StreamSliceController:
 
     ``floor`` bounds narrowing so a latency spike cannot collapse
     throughput (2048 queries/slice keeps > 50k checks/s even at 25
-    slices/s).
+    slices/s). And narrowing has to buy time: a slice that pulls
+    (``bfs_steps`` > 0) costs its pulls whatever it carries, which the
+    proportional model cannot see. Where such slices of a rung were seen,
+    within ``ROUTE_RECENCY`` slices, to take nearly as long as those of the
+    rung above it, and that rung's time is within the target, the wider
+    rung is served - a quarter of the width for the same wait is four
+    times the work (``FUTILE_FRAC``). Slices that do not pull (the label
+    route) leave no reading and are scheduled as before.
     """
 
     #: widen when observed ms < WIDEN_FRAC · target, ``patience`` times in a row
@@ -58,6 +65,13 @@ class StreamSliceController:
     ROUTE_RECENCY = 64
     #: recompute the tail guard every this many observations
     TAIL_EVERY = 32
+    #: a rung whose pulling slices take at least this share of the next
+    #: wider rung's time is not worth narrowing to: a take that half fills
+    #: the wider rung is two slices of the narrower, so at a half the two
+    #: ways cost the same and above it the narrower costs more (a
+    #: proportional cost would read a quarter to a half, a cost of pulls
+    #: alone 1)
+    FUTILE_FRAC = 0.5
 
     def __init__(
         self,
@@ -84,6 +98,9 @@ class StreamSliceController:
         #: per-route cost model: route → {per_q, per_entry, bfs_steps,
         #: last_seen} (EWMAs; last_seen is a slice counter)
         self._routes: dict[str, dict] = {}
+        #: ladder index -> [EWMA service ms, last_seen] of the pulling
+        #: slices that filled that rung (the narrowest rung that holds them)
+        self._rung_ms: dict[int, list] = {}
         self._slices = 0
         self._ring: collections.deque = collections.deque(maxlen=256)
         self._guard = 1.0
@@ -113,12 +130,24 @@ class StreamSliceController:
 
     def cap(self) -> int:
         """Per-slice query cap for the NEXT slice: the reactive ladder
-        rung bounded by the model's predicted-service-time width (always
-        a compiled ladder width)."""
+        rung bounded by the model's predicted-service-time width, then
+        the wider rung for as long as pulling slices showed that this one
+        buys no time (``FUTILE_FRAC``). Always a compiled ladder width."""
         with self._lock:
             cap = self._ladder[self._i]
             m = self._model_cap_locked()
-            return cap if m is None else max(self._ladder[self._lo], min(cap, m))
+            if m is not None:
+                cap = max(self._ladder[self._lo], min(cap, m))
+            k = self._ladder.index(cap)
+            horizon = self._slices - self.ROUTE_RECENCY
+            while k + 1 < len(self._ladder):
+                mine, wider = self._rung_ms.get(k), self._rung_ms.get(k + 1)
+                if mine is None or wider is None or min(mine[1], wider[1]) < horizon:
+                    break
+                if wider[0] > self.target_ms or mine[0] < self.FUTILE_FRAC * wider[0]:
+                    break
+                k += 1  # this rung's slices were no faster: serve the wider
+            return self._ladder[k]
 
     def entry_budget(self) -> Optional[int]:
         """Device entries one sub-chunk may carry before its predicted
@@ -186,6 +215,15 @@ class StreamSliceController:
                         else 0.3 * old + 0.7 * pe
                     )
             st["bfs_steps"] = 0.7 * st["bfs_steps"] + 0.3 * float(bfs_steps)
+            if bfs_steps > 0:
+                rung = next(
+                    (k for k, c in enumerate(self._ladder) if c >= nq),
+                    len(self._ladder) - 1,
+                )
+                seen = self._rung_ms.get(rung)
+                self._rung_ms[rung] = [
+                    ms if seen is None else 0.5 * seen[0] + 0.5 * ms, self._slices
+                ]
             st["last_seen"] = self._slices
             st["n"] += 1
             self._ring.append(ms)

@@ -1238,6 +1238,8 @@ class TpuCheckEngine:
                     )
                 new.device_buckets = tuple(bufs)
                 self.hbm.register("snapshot", new.bucket_device_bytes())
+        if new.hub_ptr is None:
+            self._upload_hub(new)  # the fold's sinks have lists of their own
         # label index maintenance: compaction patched incrementally,
         # kept the index, or left it for a rebuild here (folded ELL
         # deletions / patch budget) — either way the compacted snapshot
@@ -1508,7 +1510,22 @@ class TpuCheckEngine:
             nbrs = np.concatenate([nbrs, pad], axis=0)
         return jax.device_put(np.ascontiguousarray(nbrs), self._bucket_sharding)
 
+    def _note_ell(self, snap: GraphSnapshot) -> None:
+        """What one pull gathers from the snapshot's ELL, once an upload:
+        real edges, the valid rows' padding up to their bucket's degree, the
+        widest row (``keto_snapshot_ell_slots{kind}``,
+        ``keto_snapshot_max_in_degree``)."""
+        degrees = [(b.nbrs[: b.n] != snap.num_int).sum(axis=1) for b in snap.buckets]
+        edges = sum(int(d.sum()) for d in degrees)
+        slots = sum(int(b.n) * int(b.nbrs.shape[1]) for b in snap.buckets)
+        self.maintenance.set_gauge("ell_slots_edge", edges)
+        self.maintenance.set_gauge("ell_slots_pad", slots - edges)
+        self.maintenance.set_gauge(
+            "max_in_degree", max((int(d.max()) for d in degrees if d.size), default=0)
+        )
+
     def _upload_buckets(self, snap: GraphSnapshot) -> None:
+        self._note_ell(snap)
         if self._sharded:
             return self._upload_buckets_sharded(snap)
         # plan BEFORE uploading: during a swap the old snapshot's buckets
@@ -1524,6 +1541,21 @@ class TpuCheckEngine:
             ),
         )
         self.hbm.register("snapshot", need)
+        self._upload_hub(snap)
+
+    def _upload_hub(self, snap: GraphSnapshot) -> None:
+        """The relay rows of the snapshot's hub sinks beside its buckets
+        (``GraphSnapshot.hub_relays``; one device, no mesh: the sharded
+        kernels gather a sink's rows one by one). A few hundred KB where a
+        graph has hubs at all, so it rides the buckets' plan."""
+        relays = None if self._mesh is not None else snap.hub_relays()
+        if relays is None:
+            snap.hub_ptr = snap.hub_rows = snap.device_hub = None
+            return
+        snap.hub_ptr, snap.hub_rows, nbrs = relays
+        snap.device_hub = self._guard_alloc(
+            "snapshot-upload", lambda: jax.device_put(nbrs)
+        )
 
     def _upload_buckets_sharded(self, snap: GraphSnapshot) -> None:
         """Sharded mode: partition the buckets into row-range shards

@@ -1756,6 +1756,66 @@ class Registry:
             bfs_steps("bfs_slices"),
         )
 
+        m.register_callback(
+            "keto_check_pull_words_total", "counter",
+            "Pulls of check_step times the 32-bit words of a bitmap row in "
+            "the program that ran them, summed over the slices landed on one "
+            "device. Times the slots a pull gathers (keto_snapshot_ell_slots, "
+            "both kinds) and 4: the bytes the pulls gathered.",
+            bfs_steps("bfs_pull_words"),
+        )
+
+        def pack_rows():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((side,), float(counters.get(f"pack_rows_{side}", 0)))
+                for side in ("seed", "target")
+            ]
+
+        m.register_callback(
+            "keto_check_pack_rows_total", "counter",
+            "Device rows the host packed for check_step or the label kernel, "
+            "by side of the check: seed (start rows, after the host walk) and "
+            "target (the interior target, or the rows a sink's answer is "
+            "gathered from; a hub sink's relay row counts the rows it holds). "
+            "Over keto_check_packed_total: rows a check.",
+            pack_rows, ("side",),
+        )
+        m.register_callback(
+            "keto_check_packed_total", "counter",
+            "Checks that went through pack_chunk (counted once a chunk, riders "
+            "of a hybrid slice included: they come out of the chunk's own pack).",
+            bfs_steps("packed_checks"),
+        )
+
+        def ell_slots():
+            _, gauges, _ = maintenance_raw()
+            return [
+                ((kind,), float(gauges.get(f"ell_slots_{kind}", 0)))
+                for kind in ("edge", "pad")
+            ]
+
+        m.register_callback(
+            "keto_snapshot_ell_slots", "gauge",
+            "Slots of the serving snapshot's bucketed ELL (the in-neighbour "
+            "lists check_step pulls over), at its upload: edge (a real "
+            "in-neighbour) and pad (a valid row's padding up to its bucket's "
+            "power-of-two degree). Their sum is what one pull gathers on one "
+            "device; the rows a bucket is padded with are not counted.",
+            ell_slots, ("kind",),
+        )
+
+        def max_in_degree():
+            _, gauges, _ = maintenance_raw()
+            yield (), float(gauges.get("max_in_degree", 0))
+
+        m.register_callback(
+            "keto_snapshot_max_in_degree", "gauge",
+            "Most in-neighbours any row of the serving snapshot's ELL has; "
+            "above 1,024 the pull gathers that bucket in chunks.",
+            max_in_degree,
+        )
+
         def kernel_geometries():
             engine = self.peek("permission_engine")
             counts = getattr(engine, "kernel_geometry_counts", dict)()

@@ -190,6 +190,19 @@ def _covered_fn():
     return covered
 
 
+@lru_cache(maxsize=1)
+def _store_fn():
+    """Scatter a batch's new entries onto a device label array: one
+    program per (padded) entry count."""
+    import jax
+
+    @jax.jit
+    def store(lab, rows, cols, vals):
+        return lab.at[rows, cols].set(vals, mode="drop")
+
+    return store
+
+
 def _compute_covered(lab_d, own_rows_host: np.ndarray, lanes: int, wt: int, pad):
     """Covered bitmap ``uint32[n+1, wt]`` for one orientation: union the
     batch's own pre-batch label entries (host mirror rows), build the
@@ -204,9 +217,14 @@ def _compute_covered(lab_d, own_rows_host: np.ndarray, lanes: int, wt: int, pad)
     n1 = int(lab_d.shape[0])
     if not vals:
         return jnp.zeros((n1, wt), jnp.uint32)
-    U = np.array(sorted(vals), np.int32)
-    masks = np.zeros((U.size, wt), np.uint32)
-    for i, v in enumerate(U.tolist()):
+    # padded to a power of two with a value no label holds (zero mask): the
+    # kernel is one program per size, and a batch's union has a size of its
+    # own - unpadded, every batch compiled it anew, twice
+    size = _ceil_pow2(len(vals))
+    U = np.full(size, np.iinfo(np.int32).max, np.int32)
+    U[: len(vals)] = sorted(vals)
+    masks = np.zeros((size, wt), np.uint32)
+    for i, v in enumerate(U[: len(vals)].tolist()):
         m = vals[v]
         for w in range(wt):
             masks[i, w] = (m >> (32 * w)) & 0xFFFFFFFF
@@ -430,19 +448,21 @@ class _Mirror:
             pend = self._pending[side]
             if not pend:
                 continue
-            rows = np.concatenate([p[0] for p in pend])
-            cols = np.concatenate([p[1] for p in pend])
-            vals = np.concatenate([p[2] for p in pend])
             import jax.numpy as jnp
 
+            # one scatter program per power of two, not per batch: the pad
+            # entries name a row past the array and are dropped
+            n_pend = sum(p[0].size for p in pend)
+            pad = _ceil_pow2(n_pend) - n_pend
+            rows = np.concatenate([p[0] for p in pend] + [np.full(pad, self.n + 1, np.int64)])
+            cols = np.concatenate([p[1] for p in pend] + [np.zeros(pad, np.int64)])
+            vals = np.concatenate([p[2] for p in pend] + [np.zeros(pad, np.int32)])
+            lab = self.out_d if side == "out" else self.in_d
+            lab = _store_fn()(lab, jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals))
             if side == "out":
-                self.out_d = self.out_d.at[jnp.asarray(rows), jnp.asarray(cols)].set(
-                    jnp.asarray(vals)
-                )
+                self.out_d = lab
             else:
-                self.in_d = self.in_d.at[jnp.asarray(rows), jnp.asarray(cols)].set(
-                    jnp.asarray(vals)
-                )
+                self.in_d = lab
             self._pending[side] = []
 
     def row(self, side: str, u: int) -> np.ndarray:

@@ -58,6 +58,11 @@ from keto_tpu.graph.interner import InternedGraph, intern_rows
 
 #: namespace sentinel meaning "wildcard" in a resolved query pattern
 WILDCARD = -1
+#: a sink whose answer is gathered from more rows than this gets relay rows
+#: of this many (``GraphSnapshot.hub_relays``): twice the label route's pair
+#: cap, so a deployment whose widest sink sits at the cap is not moved in
+#: and out of it by its seed
+HUB_RELAY_ROWS = 128
 
 
 def _ceil_pow2(x: int) -> int:
@@ -256,6 +261,14 @@ class GraphSnapshot:
     sink_indptr: Optional[np.ndarray] = None  # int64 [num_live-num_int+1]
     sink_indices: Optional[np.ndarray] = None  # int32
     device_buckets: Any = None  # jnp arrays, populated lazily by the engine
+    #: relay rows of hub sinks (``hub_relays``), engine-set beside the
+    #: buckets on the single-device path: the relay rows of sink ``s`` are
+    #: ``hub_ptr[s]..hub_ptr[s+1]`` (int64 [n_sinks+1]; none for a sink of
+    #: at most HUB_RELAY_ROWS), ``hub_rows[k]`` is how many of the sink's
+    #: rows relay row ``k`` holds, ``device_hub`` the int32 [Kh, C] lists
+    hub_ptr: Optional[np.ndarray] = None
+    hub_rows: Optional[np.ndarray] = None
+    device_hub: Any = None
 
     # -- delta overlay (keto_tpu/graph/overlay.py) ---------------------------
     # Insert-only writes since the base build live in a small overlay
@@ -374,6 +387,40 @@ class GraphSnapshot:
         snapshot: an index exists and no pending overlay mutation touched
         the interior (ELL) subgraph it indexes."""
         return self.labels is not None and not self.lab_dirty
+
+    def hub_relays(self):
+        """``(hub_ptr, hub_rows, nbrs)`` for the sinks whose answer is
+        gathered from more than ``HUB_RELAY_ROWS`` rows (a user in groups
+        by the hundred), or None where there is none: each such sink's
+        in-neighbor list cut into relay rows of that many (``nbrs`` int32
+        [Kh, HUB_RELAY_ROWS], the rows padded to a multiple of 256 and the
+        holes filled with ``num_int``, the all-zero bitmap row).
+        ``check_step`` OR-reduces every relay row once a slice, and an
+        answer entry of a query on a hub sink names relay row ``k`` as
+        ``num_int + 1 + k`` where it would have named its rows one by one
+        (``check/pack.py`` ``pack_chunk``). The base CSR only: a snapshot
+        whose overlay reaches sinks does not use them."""
+        sp = self.sink_indptr
+        if sp is None or sp.shape[0] < 2:
+            return None
+        C = HUB_RELAY_ROWS
+        deg = np.diff(sp)
+        hubs = np.flatnonzero(deg > C)
+        if not hubs.size:
+            return None
+        n_of = np.zeros(deg.shape[0], np.int64)
+        n_of[hubs] = -(-deg[hubs] // C)
+        hub_ptr = np.zeros(deg.shape[0] + 1, np.int64)
+        np.cumsum(n_of, out=hub_ptr[1:])
+        kh = int(hub_ptr[-1])
+        nbrs = np.full((-(-kh // 256) * 256) * C, self.num_int, np.int32)
+        # a hub's rows fill its relay rows end to end: slot j of the sink's
+        # list is slot j from the start of its first relay row
+        rows, cnts = _csr_gather_host(sp, self.sink_indices, hubs)
+        slots, _ = _csr_gather_counts(hub_ptr * C, np.arange(nbrs.size), hubs, cnts)
+        nbrs[slots] = rows
+        nbrs = nbrs.reshape(-1, C)
+        return hub_ptr, (nbrs != self.num_int).sum(axis=1).astype(np.int64), nbrs
 
     def bucket_device_bytes(self) -> int:
         """Device footprint of the bucket matrices as uploaded — what the

@@ -398,9 +398,14 @@ class ReplicaController:
             if span is not None:
                 span.tags["applied"] = applied
             if applied:
-                self.durable.store(token)
+                # first, before the durable write: the store's watermark is
+                # already raised, and until the open windows are closed a
+                # tokenless read admitted at it could be served a decision
+                # this delta invalidated (a file write, on a loaded host,
+                # is long enough for a reader to come by)
                 if self.checkcache is not None:
                     self.checkcache.note_commit(token)
+                self.durable.store(token)
                 with self._applied:
                     self._applied.notify_all()
                 # ride the engine's existing delta-overlay/compaction path

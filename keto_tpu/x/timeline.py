@@ -106,7 +106,8 @@ class DispatchClock:
     once per loop pass in ``idle``) each state is also a
     ``TraceAnnotation`` named ``keto.dispatch.<state>`` carrying the
     round's ``tuples``, ``slices`` launched so far and ``lane_depth`` (a
-    ``launch`` also its slice's ``route`` and ``geometry``):
+    ``launch`` also its slice's ``route``, ``kernel`` and ``geometry``: the
+    two launches of a ``hybrid`` slice differ in ``kernel``):
     contiguous spans on the device trace's clock, so an idle gap of the
     device is named by what its one feeder was doing."""
 
@@ -190,6 +191,7 @@ class DispatchClock:
                 route, kernel, sizes, met = note
                 attrs = {
                     "route": route,
+                    "kernel": kernel,
                     "geometry": f"{kernel} {'x'.join(map(str, sizes))} {met or 'untracked'}",
                 }
             self._ann = self._session.annotation(

@@ -271,6 +271,27 @@ def test_live_scrape_is_strictly_valid_and_spans_the_stack(daemon):
     assert _get(daemon.write_port, "/metrics")[0] == 200
 
 
+def test_pack_row_and_ell_families_are_declared_with_their_label_values(daemon):
+    """What ``target_rows_mean.bulk`` and the ELL's padding share read: both
+    sides of ``keto_check_pack_rows_total``, ``keto_check_packed_total``,
+    both kinds of ``keto_snapshot_ell_slots`` and the widest in-degree are
+    on a scrape from boot, at 0 until a chunk is packed or a graph with
+    interior rows is uploaded."""
+    assert _get(daemon.read_port, "/check?namespace=files&object=o&relation=r&subject_id=u")[0] == 200
+    families = parse_exposition(_get(daemon.read_port, "/metrics")[1])
+    assert families["keto_check_pack_rows_total"]["type"] == "counter"
+    sides = {l["side"] for _, l, _ in families["keto_check_pack_rows_total"]["samples"]}
+    assert sides == {"seed", "target"}
+    assert families["keto_check_packed_total"]["type"] == "counter"
+    assert value_of(families, "keto_check_packed_total") >= 0
+    assert families["keto_snapshot_ell_slots"]["type"] == "gauge"
+    kinds = {l["kind"] for _, l, _ in families["keto_snapshot_ell_slots"]["samples"]}
+    assert kinds == {"edge", "pad"}
+    assert value_of(families, "keto_snapshot_max_in_degree") == 0  # one direct tuple: no interior row
+    assert families["keto_check_pull_words_total"]["type"] == "counter"
+    assert value_of(families, "keto_check_pull_words_total") == 0  # and nothing pulled
+
+
 def test_route_label_cardinality_is_bounded(daemon):
     """A path-scanning client cannot grow the route label set: 40 junk
     paths all fold into 'other' in the metrics AND the telemetry sink."""

@@ -287,6 +287,44 @@ def test_sub_chunks_of_a_full_take_speak_for_the_caps_width():
     assert ctrl.cap() == 2048
 
 
+def _narrowed(wide_ms: float, narrow_ms: float, steps: int = 10) -> StreamSliceController:
+    """A controller that saw one slice of the 8,192 rung and then, narrowed by
+    the model or not, one of the 2,048 rung."""
+    ctrl = StreamSliceController(target_ms=40.0)
+    ctrl.observe(4096, wide_ms, route="hybrid", bfs_steps=steps, entries=70_000)
+    ctrl.observe(2048, narrow_ms, route="bfs", bfs_steps=steps, entries=35_000, full_take=True)
+    return ctrl
+
+
+@pytest.mark.parametrize(
+    "wide_ms, narrow_ms, steps, cap",
+    [
+        (30.0, 30.0, 10, 8192),  # the narrower rung bought nothing: the wider is served
+        (30.0, 16.0, 10, 8192),  # over half the time for half a take: still nothing
+        (30.0, 12.0, 10, 2048),  # narrowing bought time: the model's rung is kept
+        (48.0, 40.0, 10, 2048),  # the wider rung is over the target: never served
+        (30.0, 30.0, 0, 2048),  # slices that do not pull leave no reading
+    ],
+)
+def test_a_rung_whose_pulling_slices_are_no_faster_than_the_wider_rungs_is_not_served(
+    wide_ms, narrow_ms, steps, cap
+):
+    """A ``check_step`` of ten pulls costs its pulls whatever it carries: the
+    model, which reads time as proportional to width, narrows on the bfs
+    route's cost per query, sees the narrow slices take as long as the wide
+    ones, and goes back. Where narrowing does buy time, or the wider rung is
+    over the target, the model's rung is kept."""
+    assert _narrowed(wide_ms, narrow_ms, steps).cap() == cap
+
+
+def test_a_rungs_reading_lapses_with_the_routes():
+    ctrl = _narrowed(30.0, 30.0)
+    assert ctrl.cap() == 8192
+    for _ in range(StreamSliceController.ROUTE_RECENCY + 1):
+        ctrl.observe(2048, 30.0, route="bfs", bfs_steps=10, entries=35_000, full_take=True)
+    assert ctrl.cap() == 2048  # nothing recent says what the wider rung takes
+
+
 def test_the_stream_tells_the_controller_which_slices_came_from_a_full_take(make_persister, monkeypatch):
     """``_stream`` cuts up to the controller's cap off its source; whatever the
     entry budget then makes of a take, each launched slice carries whether the
