@@ -598,11 +598,12 @@ class CheckDispatch:
         if isinstance(tuples, QueryBatch):
             why = self._frame_blocker(snap, tuples)
             if why is None:
-                got = self._resolve_records(snap, tuples, *self._records_of(snap, tuples))
+                got = self._records_of(snap, tuples)
                 if got is not None:
-                    return got
+                    return self._resolve_records(snap, tuples, *got)
                 why = "rejected"
             tuples = tuples.tuples(why)
+        self.maintenance.incr("resolve_tuples_thread", by=len(tuples))
         if hasattr(snap.interned, "resolve_queries"):
             got = self._resolve_bulk_native(snap, tuples)
             if got is not None:
@@ -627,28 +628,84 @@ class CheckDispatch:
         return None
 
     def _records_of(self, snap: GraphSnapshot, batch: QueryBatch):
-        """``batch`` as one buffer of query records plus the indices the
-        records cannot speak for: ``(buf, special, dead, no_target)``. A
-        framed part contributes a slice of its buffer and its flags; a
-        part that is a list goes through the framing loop."""
-        bufs: list[bytes] = []
+        """``batch`` as raw node ids plus the indices the records cannot
+        speak for: ``(start_raw, sub_raw, special, dead, no_target)``, or
+        None when a buffer's framing is unsafe.
+
+        A framed part whose body was resolved at the door (``QueryFrame.
+        resolve_at_door``) by the very object that ``snap.interned`` is
+        contributes slices of those arrays. Any other part (a list, which
+        goes through the framing loop first; a frame without door ids; a
+        frame whose ids came from tables a rebuild, a compaction fold or a
+        snapshot-cache reload has since replaced) contributes its records,
+        and a run of such parts is resolved here in one C++ pass. Counts
+        ``keto_check_resolve_tuples_total{where}`` once a batch."""
+        interned = snap.interned
+        starts: list[np.ndarray] = []
+        subs: list[np.ndarray] = []
+        bufs: list[bytes] = []  # the run of records waiting for this thread's pass
+        held = 0
         marked: tuple[list, list, list] = ([], [], [])  # special, dead, no_target
+        at_door = 0
+
+        def resolve_held() -> bool:
+            nonlocal held
+            if held:
+                got = self._resolve_buffer(interned, b"".join(bufs), held)
+                if got is None:
+                    return False
+                starts.append(got[0])
+                subs.append(got[1])
+                bufs.clear()
+                held = 0
+            return True
+
         base = 0
         for src, a, b in batch.parts:
-            if isinstance(src, QueryFrame):
-                off = src.off
-                bufs.append(src.buf[int(off[a]) : int(off[b])])
+            framed = isinstance(src, QueryFrame)
+            door = src.door if framed else None
+            if door is not None and door[0] is interned:
+                if not resolve_held():
+                    return None
+                starts.append(door[1][a:b])
+                subs.append(door[2][a:b])
+                at_door += b - a
+            elif framed:
+                bufs.append(src.buf[int(src.off[a]) : int(src.off[b])])
+                held += b - a
+            else:
+                buf, *lists = self._frame_tuples(snap, src[a:b])
+                bufs.append(buf)
+                held += b - a
+                for k, idxs in enumerate(lists):
+                    marked[k].extend(i + base for i in idxs)
+            if framed:
                 fl = src.flags[a:b]
                 if fl.any():
                     for k, flag in enumerate((SPECIAL, DEAD, NO_TARGET)):
                         marked[k].extend((np.flatnonzero(fl == flag) + base).tolist())
-            else:
-                buf, *lists = self._frame_tuples(snap, src[a:b])
-                bufs.append(buf)
-                for k, idxs in enumerate(lists):
-                    marked[k].extend(i + base for i in idxs)
             base += b - a
-        return (b"".join(bufs), *marked)
+        if not resolve_held():
+            return None
+        for where, n in (("door", at_door), ("thread", base - at_door)):
+            if n:
+                self.maintenance.incr(f"resolve_tuples_{where}", by=n)
+        one = len(starts) == 1
+        return (
+            starts[0] if one else np.concatenate(starts),
+            subs[0] if one else np.concatenate(subs),
+            *marked,
+        )
+
+    @staticmethod
+    def _resolve_buffer(interned, buf: bytes, n: int):
+        """``n`` query records as ``(start_raw, sub_raw)`` in one C++ pass
+        over ``interned``'s tables, None when the buffer's framing is unsafe."""
+        # separator bytes inside strings corrupt framing — detectable as a
+        # field-count mismatch, same check as the ingest path
+        if buf.count(b"\x1f") != 6 * n or buf.count(b"\x1e") != n:
+            return None
+        return interned.resolve_queries(buf, n)
 
     def _resolve_bulk_native(
         self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]
@@ -657,7 +714,11 @@ class CheckDispatch:
         in one C++ pass; route the rest through the per-query Python path.
         Returns None when the buffer framing is unsafe (separator bytes in
         strings) — callers fall back to the pure host loop."""
-        return self._resolve_records(snap, tuples, *self._frame_tuples(snap, tuples))
+        buf, *marked = self._frame_tuples(snap, tuples)
+        raw = self._resolve_buffer(snap.interned, buf, len(tuples))
+        if raw is None:
+            return None
+        return self._resolve_records(snap, tuples, *raw, *marked)
 
     def _frame_tuples(self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]):
         """The framing loop: ``tuples`` as query records, and the indices
@@ -740,25 +801,17 @@ class CheckDispatch:
         return b"".join(parts), special, dead, no_target
 
     def _resolve_records(
-        self, snap: GraphSnapshot, queries, buf: bytes,
+        self, snap: GraphSnapshot, queries, start_raw: np.ndarray, sub_raw: np.ndarray,
         special: list[int], dead: list[int], no_target: list[int],
     ):
-        """Resolve ``len(queries)`` query records in one C++ pass and patch
-        in what the records could not say. ``queries`` (a list or a
-        ``QueryBatch``) is only asked for the tuples at ``special`` and,
-        on a snapshot with nodes the C++ tables do not know, at the
-        misses. None when the buffer's framing is unsafe."""
-        n = len(queries)
+        """The raw node ids of ``len(queries)`` query records (as the C++
+        tables of ``snap.interned`` gave them, here or at the door) as
+        device rows of ``snap``, with what the records could not say
+        patched in. ``queries`` (a list or a ``QueryBatch``) is only asked
+        for the tuples at ``special`` and, on a snapshot with nodes the
+        C++ tables do not know, at the misses."""
         nl = snap.num_live
-        # separator bytes inside strings corrupt framing — detectable as a
-        # field-count mismatch, same check as the ingest path
-        if buf.count(b"\x1f") != 6 * n or buf.count(b"\x1e") != n:
-            return None
-        got = snap.interned.resolve_queries(buf, n)
-        if got is None:
-            return None
         dispatch_clock().poll()
-        start_raw, sub_raw = got
         r2d = snap.raw2dev
         sd = np.where(start_raw >= 0, r2d[np.clip(start_raw, 0, None)], -1)
         t = r2d[np.clip(sub_raw, 0, None)]

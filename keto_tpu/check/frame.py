@@ -76,9 +76,17 @@ class QueryFrame:
     """One framed request body. ``buf[off[i]:off[i + 1]]`` is query ``i``'s
     record and ``flags[i]`` its flag; ``manager`` is the namespace manager
     whose ids the records carry (the frame is only valid against that very
-    object); ``body`` is kept for ``tuples`` / ``pick``."""
+    object); ``body`` is kept for ``tuples`` / ``pick``.
 
-    __slots__ = ("buf", "off", "flags", "body", "manager", "n", "_raw", "_tuples")
+    ``door`` is None, or ``(interned, start_raw, sub_raw)``: the records'
+    raw node ids as ``interned.resolve_queries`` gave them on the thread
+    that framed the body (``resolve_at_door``). Raw ids are a function of
+    the immutable intern tables alone, so a round whose snapshot has that
+    very ``interned`` takes slices of them and resolves nothing; any other
+    round resolves the records itself. Holding ``interned`` here is also
+    what keeps its native tables alive while a frame points at them."""
+
+    __slots__ = ("buf", "off", "flags", "body", "manager", "n", "door", "_raw", "_tuples")
 
     def __init__(self, buf: bytes, off: np.ndarray, flags: np.ndarray, body: bytes, manager):
         self.buf = buf
@@ -87,11 +95,26 @@ class QueryFrame:
         self.body = body
         self.manager = manager
         self.n = int(flags.shape[0])
+        self.door: Optional[tuple] = None
         self._raw: Optional[list] = None
         self._tuples: Optional[list] = None
 
     def __len__(self) -> int:
         return self.n
+
+    def resolve_at_door(self, snap) -> None:
+        """Resolve the records against ``snap``'s intern tables, on the
+        caller's thread (the native call releases the GIL). ``snap`` is
+        whatever the engine is serving right now, or None; a snapshot
+        whose interner has no bulk entry point, or one that rejects the
+        buffer, leaves the frame as it was."""
+        interned = getattr(snap, "interned", None)
+        resolve = getattr(interned, "resolve_queries", None)
+        if resolve is None:
+            return
+        got = resolve(self.buf, self.n)
+        if got is not None:
+            self.door = (interned, *got)
 
     def _decoded(self) -> list:
         if self._raw is None:

@@ -465,6 +465,12 @@ class TpuCheckEngine:
         self._kick_background_refresh()
         return self._snapshot
 
+    def peek_snapshot(self) -> Optional[GraphSnapshot]:
+        """The snapshot being served right now, None before the first
+        build: a plain read that refreshes nothing and waits for nothing.
+        The next round may well choose another one."""
+        return self._snapshot
+
     def _snapshot_for(self, at_least, mode: str) -> GraphSnapshot:
         if at_least is not None:
             return self.snapshot(at_least=at_least)

@@ -320,6 +320,14 @@ class CheckBatcher:
         valid snapshot until released) and the swap needs no quiesce."""
         self._engine = engine
 
+    def peek_snapshot(self):
+        """The snapshot the engine behind this batcher is serving right
+        now, or None (no device engine, nothing built yet, a cold tenant):
+        a plain read for the thread that frames a ``/check/batch`` body
+        (``QueryFrame.resolve_at_door``). The round still chooses its own."""
+        peek = getattr(self._engine, "peek_snapshot", None)
+        return None if peek is None else peek()
+
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> None:

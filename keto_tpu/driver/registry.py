@@ -1955,6 +1955,25 @@ class Registry:
             ("why",),
         )
 
+        def resolve_tuples():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((where,), float(counters.get(f"resolve_tuples_{where}", 0)))
+                for where in ("door", "thread")
+            ]
+
+        m.register_callback(
+            "keto_check_resolve_tuples_total", "counter",
+            "Check queries by where their records were resolved to raw node "
+            "ids: door (on the REST pool thread that framed the /check/batch "
+            "body, against the intern tables the round's snapshot still has) "
+            "or thread (on the dispatch thread: lists of tuples, frames "
+            "without door ids, frames whose tables a rebuild, a compaction "
+            "fold or a reload has since replaced). Counted once a batch a "
+            "round resolves, never per tuple.",
+            resolve_tuples, ("where",),
+        )
+
         # streaming snapshot build (keto_tpu/graph/stream_build.py): the
         # live pipeline phase plus cumulative ingest counters, read from
         # the engine's BuildProgress at scrape time — a multi-minute

@@ -105,6 +105,12 @@ class _TenantEngineProxy:
     def __init__(self, ctx: "TenantContext"):
         self._ctx = ctx
 
+    def peek_snapshot(self):
+        """The resident engine's serving snapshot, None while the tenant
+        is cold: looking never faults an engine in."""
+        peek = getattr(self._ctx._engine, "peek_snapshot", None)
+        return None if peek is None else peek()
+
     def batch_check_with_token(self, tuples, **kw):
         ctx = self._ctx
         with ctx.dispatch() as engine:

@@ -864,7 +864,10 @@ class RestApp:
         native framer takes (native/ingest.cpp ``check_frame_body``), else
         None — and the body is then decoded as it always was, so every
         error a request can get comes from there. Counts the decline by
-        reason."""
+        reason. A frame is resolved to raw node ids here, on this pool
+        thread, against the tables of whatever ``scope``'s engine is
+        serving: the dispatch thread uses them where its round's snapshot
+        still has those tables and resolves the records itself where not."""
         manager = scope.namespace_manager()
         cached = self._frame_table
         if cached[0] is not manager:
@@ -877,7 +880,9 @@ class RestApp:
         else:
             got = table.frame(body, MAX_BATCH_CHECK)
             if not isinstance(got, str):
-                return QueryFrame(*got, body, manager)
+                frame = QueryFrame(*got, body, manager)
+                frame.resolve_at_door(scope.check_batcher().peek_snapshot())
+                return frame
             reason = got
         self._frame_declines.inc((reason,))
         return None

@@ -264,11 +264,18 @@ def assert_frame_equals_decode(engine, body: bytes, got) -> None:
     for i in multi:
         assert np.array_equal(multi[i][0], multi_o[i][0])
         assert np.array_equal(multi[i][1], multi_o[i][1])
-    # a cut in the middle resolves to the same rows
-    if n > 2:
-        a, b = n // 3, n - 1
-        sd_c, tg_c, _ = eng.dispatch._resolve_bulk(snap, QueryBatch([(framed, a, b)]))
-        assert np.array_equal(sd_c, sd_o[a:b]) and np.array_equal(tg_c, tg_o[a:b])
+    # a cut in the middle resolves to the same rows, with the raw ids of
+    # the door (QueryFrame.resolve_at_door) as without them
+    for door in (False, True):
+        if door:
+            framed.resolve_at_door(snap)
+            assert framed.door[0] is snap.interned
+            sd_d, tg_d, _ = eng.dispatch._resolve_bulk(snap, QueryBatch([(framed, 0, n)]))
+            assert np.array_equal(sd_d, sd_o) and np.array_equal(tg_d, tg_o)
+        if n > 2:
+            a, b = n // 3, n - 1
+            sd_c, tg_c, _ = eng.dispatch._resolve_bulk(snap, QueryBatch([(framed, a, b)]))
+            assert np.array_equal(sd_c, sd_o[a:b]) and np.array_equal(tg_c, tg_o[a:b])
 
 
 @pytest.mark.parametrize("seed", range(40))
