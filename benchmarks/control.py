@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
-"""The control of ``correct``: the plain reference put in the program's
-place with one guarantee of the configuration broken, and it has to come out
-as NOT correct.
+"""The control of ``correct``: the configuration's own plain reference cut
+short of its deepest grant chain, put in the program's place; it has to come
+out as NOT correct.
 
-The configurations state that every answer equals the reference's. The
-control answers with a search cut one edge short of the configuration's
-deepest grant chain (``control_max_depth`` in the configuration's file): the
-step a later PR would be tempted by, fewer hops per slice. It needs no daemon:
+The configurations state that every answer equals their reference's (the one
+the configuration's file names, ``reference.py`` where it names none: the
+same one a run is judged by). The control answers with that reference's
+search cut one step short of the configuration's deepest grant chain
+(``control_max_depth`` in the configuration's file): the step a later PR
+would be tempted by, fewer hops per slice. It needs no daemon:
 for each seed it builds the cell's graph and requests at the cell's own size,
 answers them as the control, and runs them through the comparison a run makes
 (every answer against the analytic expectation, a seeded sample against the
@@ -24,7 +26,6 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from benchmarks.reference import Reference  # noqa: E402
 from benchmarks.run import REFERENCE_SAMPLE, Cell, check_sample  # noqa: E402
 
 ANSWERED = 20_000  # answers the control gives per seed
@@ -34,12 +35,13 @@ def control_run(cell: Cell, seed: int, seconds: float, n: int = ANSWERED) -> dic
     ctx = cell.inputs(seed, seconds)
     queries, expected = requests_of(cell, ctx)
     queries, expected = queries[:n], expected[:n]
-    reference = Reference(ctx.graph.rows)
+    reference = cell.reference.build(ctx.graph.rows)
     depth = int(cell.config["control_max_depth"])
     served = [reference.allowed(*q, max_depth=depth) for q in queries]
     sound = [reference.allowed(*q) for q in queries]
     return {
-        "seed": seed, "answers": len(queries), "control_max_depth": depth,
+        "seed": seed, "answers": len(queries), "reference": cell.reference.file,
+        "control_max_depth": depth,
         "control_vs_analytic": sum(1 for g, w in zip(served, expected) if g != w),
         "control_vs_reference_sample": check_sample(
             reference, [(queries, served)], seed, REFERENCE_SAMPLE)[1],

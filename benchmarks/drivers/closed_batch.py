@@ -3,7 +3,9 @@ connection, each sending ``POST /check/batch`` of ``batch`` tuples and the next
 call when the last returns; a caller that is refused with 429 waits the
 ``Retry-After`` the server advises, as the SDK does. The bodies are a pool of
 ``pool_calls`` calls built from the seed at set-up and sent round-robin, so
-every seed offers the same amount of work per call. Reports ``checks_per_s``: correct decisions
+every seed offers the same amount of work per call; where the configuration
+names a ``work_seed`` the calls are drawn from that one and the run's seed
+puts them, and the tuples of each, in another order. Reports ``checks_per_s``: correct decisions
 delivered, over the time from the window's start to its last reply.
 """
 
@@ -25,12 +27,21 @@ WARM_PASSES_MOST = 10
 def prepare(ctx) -> dict:
     mix = ctx.mix
     n_calls, batch = int(mix["pool_calls"]), int(mix["batch"])
-    objects = traffic.skewed_objects(ctx.seed, ctx.graph.n_objects, n_calls * batch, mix["skew"])
-    queries, expected = ctx.generator.queries(ctx.graph, random.Random(ctx.seed + 1), objects)
+    objects = traffic.skewed_objects(ctx.work_seed, ctx.graph.n_objects, n_calls * batch, mix["skew"])
+    queries, expected = ctx.generator.queries(ctx.graph, random.Random(ctx.work_seed + 1), objects)
+    calls = [list(zip(queries[i:i + batch], expected[i:i + batch]))
+             for i in range(0, len(queries), batch)]
+    if ctx.work_seed != ctx.seed:
+        # the configuration fixed the work: the same calls for every seed,
+        # sent in another order, each with its tuples in another order
+        order = random.Random(ctx.seed)
+        order.shuffle(calls)
+        for call in calls:
+            order.shuffle(call)
     pool = []
-    for i in range(0, len(queries), batch):
-        pool.append((traffic.batch_body(queries[i:i + batch]), queries[i:i + batch],
-                     expected[i:i + batch]))
+    for call in calls:
+        qs, es = [q for q, _ in call], [e for _, e in call]
+        pool.append((traffic.batch_body(qs), qs, es))
     return {"pool": pool}
 
 

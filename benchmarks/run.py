@@ -12,9 +12,11 @@ print one JSON line. ``--trace 0`` reports the end-to-end metrics; ``--trace
 1`` runs the same traffic with a few seconds of it under the profiler and
 reports the per-layer metrics, the device's busy time and a breakdown.
 
-Cells, configurations, traffic mixes, drivers and per-layer readers are found
-by name: ``configs/<config>.json`` (which names ``generators/<name>.py``),
-``traffic/<mix>.json`` (which names ``drivers/<name>.py``) and
+Cells, configurations, traffic mixes, drivers, per-layer readers and plain
+references are found by name: ``configs/<config>.json`` (which names
+``generators/<name>.py`` and may name ``references/<name>.py``, the judge of
+its own semantics; without the key it is ``reference.py``, Check as Keto v0.7
+defines it), ``traffic/<mix>.json`` (which names ``drivers/<name>.py``) and
 ``layers/<metric>.py``. Adding one adds files and a manifest entry.
 
 A run that did not serve from the device prints no result. ``--platform cpu
@@ -75,6 +77,24 @@ def load_config(entry: dict) -> dict:
         return json.load(f)
 
 
+def load_reference(config: dict) -> SimpleNamespace:
+    """The configuration's plain reference, found by name: ``file`` says
+    where it lives and ``build(rows)`` makes it. A configuration that names
+    none is judged by ``reference.py``; a named one is
+    ``references/<name>.py``, whose ``Reference(rows, config)`` reads its
+    schema from the configuration's file."""
+    name = config.get("reference")
+    if name is None:
+        return SimpleNamespace(file="benchmarks/reference.py", build=Reference)
+    module = load_module("references", name)
+    cls = getattr(module, "Reference", None)
+    if not callable(getattr(cls, "allowed", None)):
+        raise BenchFailure(f"reference {name!r}: {module.__file__} has no class Reference with "
+                           f"allowed(ns, obj, rel, user, max_depth=None)")
+    return SimpleNamespace(file=f"benchmarks/references/{name}.py",
+                           build=lambda rows: cls(rows, config))
+
+
 def guard(run, platform: str, chips: int) -> list[str]:
     """Why this run is not a measurement of the device path, if it is not."""
     problems = []
@@ -97,7 +117,7 @@ def guard(run, platform: str, chips: int) -> list[str]:
     return problems
 
 
-def check_sample(reference: Reference, answered, seed: int, n: int, max_depth=None):
+def check_sample(reference, answered, seed: int, n: int, max_depth=None):
     """A seeded sample of what the window served against the plain reference:
     ``(compared, mismatches)``. ``answered`` is ``[(queries, results)]``."""
     starts = list(itertools.accumulate((len(q) for q, _ in answered), initial=0))
@@ -123,6 +143,7 @@ class Cell:
         self.mix = traffic.load_mix(self.workload["traffic"])
         self.generator = load_module("generators", self.config["generator"])
         self.driver = load_module("drivers", self.mix["driver"])
+        self.reference = load_reference(self.config)
         reported = lambda m: manifest_mod.reported_by(m, self.name, self.manifest)
         self.e2e = [m for m in self.manifest["end_to_end"] if reported(m)]
         self.layers = [m for m in self.manifest["per_layer"] if reported(m)]
@@ -132,10 +153,15 @@ class Cell:
 
     def inputs(self, seed: int, seconds: float) -> SimpleNamespace:
         """The graph from the seed, and what the driver needs to build and
-        send the cell's requests."""
-        graph = self.generator.build(random.Random(seed), self.n_tuples)
+        send the cell's requests. A configuration that names a ``work_seed``
+        draws its graph and its requests from that one whatever the run's
+        seed is, so that every seed does the same work: the run's seed then
+        puts the requests in another order and draws the reference's sample."""
+        work_seed = int(self.config.get("work_seed", seed))
+        graph = self.generator.build(random.Random(work_seed), self.n_tuples)
         return SimpleNamespace(
-            mix=self.mix, seed=seed, graph=graph, generator=self.generator, seconds=seconds,
+            mix=self.mix, seed=seed, work_seed=work_seed, graph=graph,
+            generator=self.generator, seconds=seconds,
             config_name=self.config_entry["name"], read_port=None, write_port=None,
         )
 
@@ -218,14 +244,16 @@ def execute(args, say, entry: Path | None = None) -> dict:
 def result_line(cell: Cell, ctx, run, args, setup_s: float, say) -> dict:
     """Correctness, once the window has closed and the daemon has gone: every
     answer against the generator's analytic expectation (the drivers count
-    ``wrong``) and a seeded sample against the plain reference. Then the line."""
+    ``wrong``) and a seeded sample against the configuration's plain
+    reference. Then the line."""
     result = run.result
-    reference = Reference(ctx.graph.rows)
+    reference = cell.reference.build(ctx.graph.rows)
     compared, ref_bad = check_sample(reference, result["answered"], args.seed, REFERENCE_SAMPLE)
     delivered = result["attempted"] - result["failed"]
     say(f"correct: answers differing from the analytic expectation {result['wrong']} of "
-        f"{delivered} (limit 0); sample differing from the plain reference {ref_bad} of "
-        f"{compared} (limit 0); failed or shed {result['failed']} of {result['attempted']}")
+        f"{delivered} (limit 0); sample differing from the plain reference "
+        f"({cell.reference.file}) {ref_bad} of {compared} (limit 0); failed or shed "
+        f"{result['failed']} of {result['attempted']}")
     correct = result["wrong"] == 0 and ref_bad == 0 and delivered > 0 and compared > 0
 
     line = {"correct": correct, "attempted": result["attempted"], "failed": result["failed"]}
@@ -260,6 +288,10 @@ def result_line(cell: Cell, ctx, run, args, setup_s: float, say) -> dict:
         f"label builds on the device {int(run.after.event('label_device_builds'))}")
     say(f"window: {json.dumps(extras)}; compile cache {run.cache_before} -> {run.cache_after} "
         f"entries inside the window")
+    # each number compared, beside its limit: last in the line, and main()
+    # repeats them as the last lines of standard error
+    line["compared"] = {"differ_from_analytic": {"value": result["wrong"], "limit": 0},
+                        "differ_from_reference": {"value": ref_bad, "limit": 0}}
     return line
 
 
@@ -306,6 +338,9 @@ def main(argv=None) -> int:
     if "jax" in sys.modules:
         print(f"{tag} FAILED: the parent imported jax", file=sys.stderr)
         return 1
+    for name, c in line["compared"].items():
+        print(f"{tag} compared {name} = {c['value']} (limit {c['limit']})", file=sys.stderr,
+              flush=True)
     print(json.dumps(line), flush=True)
     return 0
 
