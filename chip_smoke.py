@@ -347,10 +347,13 @@ class Smoke:
             # BASELINE config 3 is shallow: its interior ELL holds ~8 slots
             # per 1,000 tuples, under the default 65,536-slot gate of the
             # device label build at 1M tuples. The smoke exists to run that
-            # build on the chip, so it lowers the gate (by the same ratio
-            # ~80k slots at --tuples 10000000 would pass the default — an
-            # estimate: the one 10M run also had the gate at 1).
-            "labels_device_min_edges": 1,
+            # build on the chip, so it forces it: 0 takes both halves of the
+            # gate away, the slots and (since PR 38) the host builder going
+            # first, which indexes this shallow graph in well under a second
+            # and would leave the device build unrun. (By the slots alone
+            # ~80k at --tuples 10000000 would pass the default — an
+            # estimate: the one 10M run had the gate at 1.)
+            "labels_device_min_edges": 0,
             # the daemon's own shadow auditor re-verifies a sample of the
             # served decisions against the CPU oracle
             "audit_sample_rate": 0.02,

@@ -52,6 +52,7 @@ from keto_tpu.check.pack import (
 )
 from keto_tpu.check.slice_ctrl import StreamSliceController
 from keto_tpu.graph.snapshot import WILDCARD, GraphSnapshot, _ceil_pow2
+from keto_tpu.namespace.rewrites import GATED, REWRITTEN
 from keto_tpu.relationtuple.model import RelationTuple, SubjectID, SubjectSet
 from keto_tpu.x import faults
 from keto_tpu.x.errors import ErrNamespaceUnknown
@@ -98,6 +99,7 @@ class CheckDispatch:
         guard_alloc: Callable,
         audit: Callable,
         current_snapshot: Callable[[], Optional[GraphSnapshot]],
+        oracle: Callable,
         it_cap: int,
         max_batch: int,
         mem_budget_bytes: int,
@@ -118,6 +120,9 @@ class CheckDispatch:
         self._guard_alloc = guard_alloc
         self._audit_sample = audit
         self._current_snapshot = current_snapshot
+        #: () -> the CPU oracle (``check/engine.py``): answers the checks
+        #: whose closure reaches an intersection or an exclusion
+        self._oracle = oracle
         self._it_cap = it_cap
         self._max_batch = max_batch
         # bound on the BFS workspace (~3 W-wide uint32 bitmaps over interior
@@ -1437,6 +1442,9 @@ class CheckDispatch:
             s1 = min(s0 + cap_q, n)
             clk.enter(RESOLVE)
             sd, tg, multi = self._resolve_bulk(snap, tuples[s0:s1])
+            oracle_ans = None
+            if snap.rewrites is not None:
+                oracle_ans = self._rewrite_split(snap, tuples[s0:s1], sd, tg, multi)
             clk.poll()
             nq = s1 - s0
             W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
@@ -1488,10 +1496,87 @@ class CheckDispatch:
                     dev, host_ans, leases = self._device_batch(
                         snap, sd, tg, multi, a, b, W, it_cap=it_cap
                     )
+                if oracle_ans is not None:
+                    # the oracle's answers land as the host's own do
+                    host_ans[: b - a] |= oracle_ans[a:b]
                 yield [
                     dev, host_ans, b - a, tuples[s0 + a : s0 + b],
                     leases, int(cnt[a:b].sum()),
                 ]
+
+    def _rewrite_split(self, snap: GraphSnapshot, queries, sd, tg, multi):
+        """Under a rewrite schema: count a resolved batch by closure
+        (``keto_check_rewrite_checks_total``) from the snapshot's one byte a
+        device row, and take the checks whose ``(namespace, relation)`` can
+        reach an intersection or an exclusion out of the batch: the CPU
+        oracle answers them here, their rows are unset so that pack sends
+        the device nothing for them, and their answers come back as
+        ``bool[n]`` for the slice's host answers (None when none was
+        taken). The rest of the batch rides the device. A wildcard pattern
+        (its starts in ``multi``) carries the bits of every start and of
+        the relation it names."""
+        plan = snap.rewrites
+        n = sd.shape[0]
+        flags = plan.flags_of(snap)
+
+        def bits_of(dev: int) -> int:
+            if dev < flags.shape[0]:
+                return int(flags[dev])
+            kind, key = snap.key_of_dev(dev)  # a start the overlay brought
+            return plan.relation_flags(key[0], key[2]) if kind == "set" else 0
+
+        f = np.zeros(n, np.uint8)
+        in_base = (sd >= 0) & (sd < flags.shape[0])
+        f[in_base] = flags[sd[in_base]]
+        for i in np.flatnonzero(sd >= flags.shape[0]).tolist():
+            f[i] = bits_of(int(sd[i]))
+        if multi:
+            ns_of = self._ns_resolver()
+            at = sorted(multi)
+            for i, rt in zip(at, pick_tuples(queries, at, "rewrite")):
+                ns_id = ns_of(rt.namespace)
+                bits = plan.relation_flags(ns_id, rt.relation) if isinstance(ns_id, int) else 0
+                for starts in multi[i]:
+                    for dev in np.asarray(starts).tolist():
+                        bits |= bits_of(int(dev))
+                f[i] = bits
+        rewritten = int(np.count_nonzero(f & REWRITTEN))
+        incr = self.maintenance.incr
+        incr("rewrite_checks_rewritten", by=rewritten)
+        incr("rewrite_checks_plain", by=n - rewritten)
+        if not plan.has_gated:
+            incr("rewrite_route_device", by=rewritten)
+            return None
+        gated = np.flatnonzero(f & GATED)
+        # a gated relation's node exists only where rows name it: a check
+        # that found no start may still be one the oracle grants
+        missing = np.flatnonzero(sd == -1).tolist()
+        unresolved: list[int] = []
+        if missing:
+            ns_of = self._ns_resolver()
+            for i, rt in zip(missing, pick_tuples(queries, missing, "rewrite")):
+                ns_id = ns_of(rt.namespace)
+                if isinstance(ns_id, int) and plan.relation_flags(ns_id, rt.relation) & GATED:
+                    unresolved.append(i)
+        incr("rewrite_route_device", by=rewritten - int(gated.size))
+        if not gated.size and not unresolved:
+            return None
+        patterns = int(np.count_nonzero(sd[gated] == -2))
+        incr("rewrite_route_oracle", by=int(gated.size) + len(unresolved))
+        incr("rewrite_oracle_gated_closure", by=int(gated.size) - patterns)
+        incr("rewrite_oracle_gated_pattern", by=patterns)
+        incr("rewrite_oracle_gated_unresolved", by=len(unresolved))
+        taken = sorted(gated.tolist() + unresolved)
+        oracle = self._oracle()
+        out = np.zeros(n, bool)
+        for i, rt in zip(taken, pick_tuples(queries, taken, "rewrite")):
+            out[i] = oracle.subject_is_allowed(rt)
+        at = np.asarray(taken)
+        sd[at] = -1
+        tg[at] = -1
+        for i in taken:
+            multi.pop(i, None)
+        return out
 
     @staticmethod
     def _decode_packed(f: np.ndarray, host_ans: np.ndarray, nq: int):

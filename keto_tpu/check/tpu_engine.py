@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from keto_tpu import namespace as namespace_pkg
+from keto_tpu.namespace.rewrites import RewritePlan, schema_of
 from keto_tpu.check.dispatch import CheckDispatch
 from keto_tpu.check.frame import pick_tuples
 from keto_tpu.check.kernels import _label_witness_kernel
@@ -287,6 +288,7 @@ class TpuCheckEngine:
             guard_alloc=self._guard_alloc,
             audit=self._audit_sample,
             current_snapshot=lambda: self._snapshot,
+            oracle=self._fallback,
             it_cap=it_cap,
             max_batch=max_batch,
             mem_budget_bytes=mem_budget_bytes,
@@ -378,6 +380,8 @@ class TpuCheckEngine:
         """
         snap = self._snapshot
         wm = self._store.watermark()
+        if snap is not None and self._schema_moved(snap):
+            snap = None  # built under another rewrite schema: never served
         if snap is not None and snap.snapshot_id == wm:
             self._maybe_kick_compaction(snap)
             return snap
@@ -411,6 +415,10 @@ class TpuCheckEngine:
         ``snapshot(at_least=token)``.
         """
         snap = self._snapshot
+        if snap is not None and self._schema_moved(snap):
+            # the one rebuild the read plane waits for: answers under the
+            # old schema are not stale, they are another deployment's
+            return self.snapshot()
         if snap is None or self._last_full_build_s <= self._sync_rebuild_budget_s:
             try:
                 return self.snapshot()
@@ -464,6 +472,13 @@ class TpuCheckEngine:
         # serve stale, catch up off the serving path
         self._kick_background_refresh()
         return self._snapshot
+
+    def _schema_moved(self, snap: GraphSnapshot) -> bool:
+        """Was ``snap`` built under another rewrite schema than the current
+        namespaces carry? The schema's fingerprint is a build input, as
+        ``wild_ns_ids`` is: derived edges stand for it in the adjacency."""
+        built = snap.rewrites.fingerprint if snap.rewrites is not None else ""
+        return built != schema_of(self._nm()).fingerprint
 
     def peek_snapshot(self) -> Optional[GraphSnapshot]:
         """The snapshot being served right now, None before the first
@@ -856,7 +871,7 @@ class TpuCheckEngine:
             if self._fallback_engine_obj is None:
                 from keto_tpu.check.engine import CheckEngine
 
-                self._fallback_engine_obj = CheckEngine(self._store)
+                self._fallback_engine_obj = CheckEngine(self._store, namespaces=self._nm)
             return self._fallback_engine_obj
 
     def set_store(self, store) -> None:
@@ -1001,6 +1016,14 @@ class TpuCheckEngine:
         snap = self._snapshot
         wm = self._store.watermark()
         fold_failed = False
+        rewrites = schema_of(self._nm())
+        if snap is not None and self._schema_moved(snap):
+            if delta_only:
+                return None
+            # nothing of a graph built under another schema carries over:
+            # not its overlay, not its fold history
+            snap = None
+            self._fold_base, self._seg_log, self._pending_seg = None, [], None
         if snap is None and self._cache_dir is not None and not delta_only:
             snap = self._load_cache_locked(wm)
         # an over-budget overlay owes a fold even when the snapshot is
@@ -1096,6 +1119,7 @@ class TpuCheckEngine:
                 progress=self.build_progress,
                 read_retry=self._read_store,
                 chunk_rows=self._build_chunk_rows,
+                rewrites=rewrites,
             )
             self._upload_buckets(new)
             # labels phase overlaps the rest of the pipeline: the device
@@ -1376,6 +1400,12 @@ class TpuCheckEngine:
         )
         if snap.wild_ns_ids != wild_now:
             return None  # namespace config changed — expansion differs
+        # the rewrite schema is a build input too: a cache saved under
+        # another fingerprint holds other derived edges
+        saved, schema = snap.rewrites, schema_of(self._nm())
+        if (saved or {}).get("fingerprint", "") != schema.fingerprint:
+            return None
+        snap.rewrites = RewritePlan.from_meta(saved, schema) if saved else None
         self._upload_buckets(snap)
         if snap.labels is not None and not self._labels_enabled:
             snap.labels = None  # cached labels ignored when disabled
@@ -1528,6 +1558,16 @@ class TpuCheckEngine:
         self.maintenance.set_gauge("ell_slots_pad", slots - edges)
         self.maintenance.set_gauge(
             "max_in_degree", max((int(d.max()) for d in degrees if d.size), default=0)
+        )
+        # all the snapshot's edges, and those of them the rewrite expansion
+        # derived (``keto_snapshot_edges``, ``keto_snapshot_rewrite_edges{kind}``)
+        plan = snap.rewrites
+        self.maintenance.set_gauge("snapshot_edges", snap.n_edges)
+        self.maintenance.set_gauge(
+            "rewrite_edges_computed_userset", plan.n_computed if plan is not None else 0
+        )
+        self.maintenance.set_gauge(
+            "rewrite_edges_tuple_to_userset", plan.n_ttu if plan is not None else 0
         )
 
     def _upload_buckets(self, snap: GraphSnapshot) -> None:
@@ -1796,6 +1836,13 @@ class TpuCheckEngine:
     #: ones (coverage misses just fall back to BFS, bit-identically)
     LABELS_AUTO_CAP = 131072
 
+    #: the least one batch of the device label build costs: two sweep
+    #: dispatches and the transfer back (0.25-0.75 s a batch measured on a
+    #: v5e, PERF.md section 6, PR 34 and PR 38). The device build takes
+    #: ``landmarks / serve.labels_batch`` of them whatever pruning leaves
+    #: of the work; the host builder's time IS what pruning leaves
+    LABEL_BATCH_FLOOR_S = 0.1
+
     def _ensure_labels(self, snap: GraphSnapshot) -> None:
         """Build (or rebuild) the label index for ``snap`` when enabled
         and missing, and place it on device. Called wherever a fresh
@@ -1878,6 +1925,9 @@ class TpuCheckEngine:
                 and self._interior_ell_slots(snap) >= self._labels_device_min_edges
             )
             if eligible:
+                idx = self._host_labels_first(snap, landmarks)
+                if idx is not None:
+                    return idx
                 need = label_build.estimate_build_bytes(
                     n, self._labels_max_width, self._labels_batch
                 )
@@ -1925,6 +1975,43 @@ class TpuCheckEngine:
         )
         if landmarks < n:
             self._note_label_truncation("cap", idx)
+        return idx
+
+    def _host_labels_first(self, snap: GraphSnapshot, landmarks: int):
+        """The other half of the device-build gate. ELL slots say that a
+        graph is big enough for the device build to pay its dispatches,
+        not that the build is hard: the device sweeps every batch of
+        landmarks over the whole interior, the host builder walks what
+        pruning leaves, and on a shallow forest (a code host's teams and
+        role chains, however many rows) that is seconds against minutes.
+        So above the gate the host builder goes first, for as long as the
+        device's batches would take at the least (``LABEL_BATCH_FLOOR_S``
+        each): a graph it indexes within that never waits for the device,
+        and one it does not has cost less than the device build it then
+        gets. Entry-identical either way, by the builders' contract. None
+        (the device path as it was) when the host ran out of time, where
+        the two would not process the same landmarks (the host's auto-cap,
+        ``labels_min_gain``), on a multi-controller mesh (every host takes
+        the same path, and a clock is not the same on two of them), and
+        with ``labels_device_min_edges`` 0, which forces the device."""
+        from keto_tpu.graph.labels import build_labels
+
+        n = snap.num_int
+        if (
+            self._multiprocess
+            or self._labels_device_min_edges <= 0
+            or self._labels_min_gain > 0.0
+            or (landmarks == 0 and n > self.LABELS_AUTO_CAP)
+        ):
+            return None
+        batches = -(-(min(landmarks, n) if landmarks > 0 else n) // max(32, self._labels_batch))
+        idx = build_labels(
+            snap, max_width=self._labels_max_width, landmarks=landmarks,
+            deadline_s=batches * self.LABEL_BATCH_FLOOR_S,
+        )
+        self.maintenance.incr(
+            "label_host_first_builds" if idx is not None else "label_host_first_timeouts"
+        )
         return idx
 
     def _label_build_progress(self, done: int, total: int, entries: int) -> None:

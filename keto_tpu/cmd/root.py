@@ -348,13 +348,17 @@ def namespace():
 @click.argument("files", nargs=-1, required=True)
 def validate(files):
     """Validate namespace definition files against the JSON schema
-    (reference cmd/namespace/validate.go:20-58)."""
+    (reference cmd/namespace/validate.go:20-58), and the userset rewrites
+    they carry (``config.relations``) as the server does when it loads them."""
+    from keto_tpu import namespace as namespace_pkg
     from keto_tpu.config.provider import parse_namespace_file
 
     failed = False
     for fn in files:
         try:
-            for ns in parse_namespace_file(Path(fn)):
+            nss = parse_namespace_file(Path(fn))
+            namespace_pkg.MemoryManager(nss)  # raises SchemaError, naming the relation
+            for ns in nss:
                 click.echo(f"{fn}: namespace {ns.name!r} (id {ns.id}) is valid")
         except Exception as e:
             click.echo(f"{fn}: INVALID — {e}", err=True)

@@ -254,25 +254,27 @@ class NamespaceWatcher:
             return False
         self._stamp = stamp
         try:
-            nss = load_namespaces_from_uri(self.uri)
-        except Exception:
+            # a rewrite schema that does not validate is a parse error too
+            manager = namespace_pkg.MemoryManager(load_namespaces_from_uri(self.uri))
+        except Exception as e:
+            _log.warning("namespace reload from %s rejected: %s", self.uri, e)
             return False  # keep last-good (reference namespace_watcher.go:110-121)
         with self._lock:
-            self._manager = namespace_pkg.MemoryManager(nss)
+            self._manager = manager
         if self.on_change:
             self.on_change()
         return True
 
     def _apply_ws_snapshot(self, text: str) -> None:
         try:
-            nss = parse_namespaces_data(yaml.safe_load(text))
+            manager = namespace_pkg.MemoryManager(parse_namespaces_data(yaml.safe_load(text)))
         except Exception as e:
             # keep last-good, exactly like the file source — but tell the
             # operator (an invalid push is otherwise invisible)
             _log.warning("namespace snapshot from %s rejected: %s", self.uri, e)
             return
         with self._lock:
-            self._manager = namespace_pkg.MemoryManager(nss)
+            self._manager = manager
         self._first_snapshot.set()
         if self.on_change:
             self.on_change()

@@ -13,7 +13,10 @@ NAMESPACE_SCHEMA = {
         "$schema": {"type": "string"},
         "name": {"type": "string"},
         "id": {"type": "integer", "minimum": 0},
-        "config": {"type": "object"},
+        "config": {
+            "type": "object",
+            "description": "Per-namespace settings. config.relations is the namespace's userset rewrites: an object of relation -> expression (this, computed_userset, tuple_to_userset, union, intersection, exclusion); a relation without an entry is 'this'. Validated when the namespaces load or reload; see docs/concepts/userset-rewrites.md.",
+        },
     },
     "additionalProperties": False,
     "required": ["name", "id"],
@@ -183,7 +186,7 @@ CONFIG_SCHEMA = {
                 "labels_device_min_edges": {
                     "type": "integer",
                     "default": 65536,
-                    "description": "Interior adjacency slots (ELL rows x width) below which the label build skips the device path and uses the host walk directly — tiny graphs finish on host faster than one XLA dispatch. Set 0 to force the device path everywhere (parity tests do).",
+                    "description": "Interior adjacency slots (ELL rows x width) below which the label build skips the device path and uses the host walk directly — tiny graphs finish on host faster than one XLA dispatch. Above it the host walk still goes first, for as long as the device build's batches would take at the least (0.1 s each): a graph that pruning leaves little of (shallow forests, however many rows) is indexed in seconds and never waits for the device; a graph the host does not finish in that time gets the device build. Set 0 to force the device path everywhere (parity tests do).",
                 },
                 "hbm_budget_bytes": {
                     "type": "integer",

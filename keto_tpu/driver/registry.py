@@ -771,15 +771,17 @@ class Registry:
                     self.metrics().histogram(
                         "keto_build_phase_duration_seconds",
                         "Wall time per streaming-build pipeline phase "
-                        "(scan / intern / device_build / labels / "
-                        "cache_save), one histogram series per phase.",
+                        "(scan / intern / rewrites / device_build / labels / "
+                        "cache_save), one histogram series per phase; "
+                        "rewrites (the expansion of userset rewrites into "
+                        "edges) only under a schema that has some.",
                         ("phase",),
                         buckets=(0.01, 0.05, 0.25, 1.0, 5.0, 15.0, 60.0,
                                  300.0, 1200.0),
                     )
                 )
                 return engine
-            return CheckEngine(store)
+            return CheckEngine(store, namespaces=self.namespaces_source())
 
         return build()
 
@@ -828,7 +830,9 @@ class Registry:
                 from keto_tpu.expand.tpu_engine import SnapshotExpandEngine
 
                 return SnapshotExpandEngine(check, self.namespaces_source())
-            return ExpandEngine(self.relation_tuple_manager())
+            return ExpandEngine(
+                self.relation_tuple_manager(), namespaces=self.namespaces_source()
+            )
 
         return self._memo("expand_engine", build)
 
@@ -902,7 +906,9 @@ class Registry:
                 )
             from keto_tpu.list.engine import ListEngine
 
-            return ListEngine(self.relation_tuple_manager())
+            return ListEngine(
+                self.relation_tuple_manager(), namespaces=self.namespaces_source()
+            )
 
         return self._memo("list_engine", build)
 
@@ -1972,6 +1978,71 @@ class Registry:
             "fold or a reload has since replaced). Counted once a batch a "
             "round resolves, never per tuple.",
             resolve_tuples, ("where",),
+        )
+
+        # userset rewrites (keto_tpu/namespace/rewrites.py): all zero where
+        # no namespace carries ``config.relations``
+        def maintenance_counts(prefix: str, labels: tuple):
+            def read():
+                counters, _, _ = maintenance_raw()
+                return [((lab,), float(counters.get(prefix + lab, 0))) for lab in labels]
+            return read
+
+        m.register_callback(
+            "keto_check_rewrite_checks_total", "counter",
+            "Checks under a rewrite schema by the closure of their (namespace, "
+            "relation): rewritten (a userset rewrite is reachable from it, so "
+            "derived edges may carry the answer) or plain. Counted once a "
+            "resolved batch from one byte a device row, never per tuple.",
+            maintenance_counts("rewrite_checks_", ("rewritten", "plain")), ("closure",),
+        )
+        m.register_callback(
+            "keto_check_rewrite_route_total", "counter",
+            "Checks with a rewritten closure by who answered: device (the "
+            "union class, compiled into the snapshot's edges) or oracle (the "
+            "closure reaches an intersection or an exclusion: the CPU oracle "
+            "answers, the rest of the batch rides the device). Not a fault "
+            "path: keto_maintenance_events_total{event=\"fallback_checks\"} does not move.",
+            maintenance_counts("rewrite_route_", ("device", "oracle")), ("route",),
+        )
+        m.register_callback(
+            "keto_check_rewrite_oracle_total", "counter",
+            "Checks the rewrite route handed to the CPU oracle, by reason: "
+            "gated_closure (the start node's closure reaches an intersection or "
+            "an exclusion), gated_pattern (a wildcard pattern one of whose "
+            "starts does) or gated_unresolved (such a relation on an object "
+            "no row names, so the snapshot has no node for it).",
+            maintenance_counts(
+                "rewrite_oracle_", ("gated_closure", "gated_pattern", "gated_unresolved")
+            ),
+            ("reason",),
+        )
+
+        def snapshot_gauge(key: str):
+            def read():
+                _, gauges, _ = maintenance_raw()
+                yield (), float(gauges.get(key, 0))
+            return read
+
+        m.register_callback(
+            "keto_snapshot_edges", "gauge",
+            "Edges of the serving snapshot at its upload, derived ones included.",
+            snapshot_gauge("snapshot_edges"),
+        )
+
+        def rewrite_edges():
+            _, gauges, _ = maintenance_raw()
+            return [
+                ((kind,), float(gauges.get(f"rewrite_edges_{kind}", 0)))
+                for kind in ("computed_userset", "tuple_to_userset")
+            ]
+
+        m.register_callback(
+            "keto_snapshot_rewrite_edges", "gauge",
+            "Edges of the serving snapshot that no stored row states: derived "
+            "from a computed_userset (one an object a rewrite) or from a "
+            "tuple_to_userset (one a tupleset row a rewrite), at its upload.",
+            rewrite_edges, ("kind",),
         )
 
         # streaming snapshot build (keto_tpu/graph/stream_build.py): the

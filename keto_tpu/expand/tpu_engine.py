@@ -41,6 +41,11 @@ suite compares trees order-insensitively like the reference's):
   can interleave differently than global row order when wildcard-bearing
   keys also match.
 
+Under userset rewrites (keto_tpu/namespace/rewrites.py) a root whose
+closure has a rewrite is expanded by the host engine, by the schema: the
+snapshot's adjacency holds the derived edges of the union class there, and
+the bulk capture would show them as stored children.
+
 While a delta overlay is pending, the fast path still serves: the
 snapshot's unified overlay adjacency (``ov_fwd``,
 keto_tpu/graph/overlay.py) is merged into each node's base child list
@@ -64,6 +69,7 @@ import numpy as np
 from keto_tpu import namespace as namespace_pkg
 from keto_tpu.expand.tree import LEAF, UNION, Tree
 from keto_tpu.graph.snapshot import WILDCARD, GraphSnapshot
+from keto_tpu.namespace.rewrites import REWRITTEN
 from keto_tpu.relationtuple.model import Subject, SubjectID, SubjectSet
 from keto_tpu.x.errors import ErrNamespaceUnknown
 from keto_tpu.x.graph import check_and_add_visited
@@ -90,7 +96,7 @@ class SnapshotExpandEngine:
         from keto_tpu.expand.engine import ExpandEngine
 
         #: exact-order engine for overlay-pending snapshots (see module doc)
-        self._manager_engine = ExpandEngine(check_engine._store)
+        self._manager_engine = ExpandEngine(check_engine._store, namespaces=self._nm)
 
     # -- public API (host engine signature) ----------------------------------
 
@@ -114,6 +120,16 @@ class SnapshotExpandEngine:
             # unknown namespace raises, exactly like the host engine's
             # first Manager query (reference engine.go:51-61 propagates)
             ns_id = nm.get_namespace_by_name(ns).id
+
+        plan = snap.rewrites
+        if plan is not None and (
+            ns_id == WILDCARD or subject.relation == ""
+            or plan.relation_flags(ns_id, subject.relation) & REWRITTEN
+        ):
+            # the closure of this root has a userset rewrite: the adjacency
+            # holds derived edges there, which the bulk capture would show as
+            # stored children; the host engine expands by the schema
+            return self._manager_engine.build_tree(subject, rest_depth)
 
         root_dev = None
         if ns_id != WILDCARD:

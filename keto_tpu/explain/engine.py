@@ -39,10 +39,12 @@ import numpy as np
 from keto_tpu.explain.decision_log import DecisionLog
 from keto_tpu.explain.witness import (
     DEFAULT_MAX_HEADS,
+    GatedClosure,
     build_witness,
     oracle_witness,
     verify_witness,
 )
+from keto_tpu.namespace.rewrites import schema_for
 from keto_tpu.relationtuple.manager import Manager
 from keto_tpu.relationtuple.model import RelationTuple
 
@@ -134,6 +136,30 @@ class ExplainEngine:
         allowed, route, token = self._decide(requested, at_least)
         with self._lock:
             self.requests_by_route[route] = self.requests_by_route.get(route, 0) + 1
+        rewrites = self._rewrites()
+        if rewrites is not None:
+            try:
+                return self._explain(requested, allowed, route, token, at_least,
+                                     trace_id, tenant, rewrites)
+            except GatedClosure as e:
+                # an intersection or an exclusion decided: no path is a
+                # witness of that, and none is made up
+                return {
+                    "allowed": allowed, "route": route,
+                    "snaptoken": str(token) if token is not None else "",
+                    "tuple": requested.to_json(), "witness": None, "certificate": None,
+                    "verified": False, "witness_source": "",
+                    "rewrite": f"no witness: {e} holds an intersection or an exclusion",
+                }
+        return self._explain(requested, allowed, route, token, at_least, trace_id, tenant, None)
+
+    def _rewrites(self):
+        """``(namespace manager, schema)`` where the store's namespaces
+        carry userset rewrites, else None."""
+        nm, schema = schema_for(None, self._manager)
+        return (nm, schema) if schema else None
+
+    def _explain(self, requested, allowed, route, token, at_least, trace_id, tenant, rewrites):
 
         path = None
         certificate = None
@@ -142,7 +168,8 @@ class ExplainEngine:
 
         if route == "cpu":
             # the oracle decided; its own traversal IS the witness
-            path = oracle_witness(self._manager, requested, page_size=self._page_size)
+            path = oracle_witness(
+                self._manager, requested, page_size=self._page_size, rewrites=rewrites)
             witness_source = "oracle"
             if allowed != (path is not None):
                 divergence = True
@@ -152,6 +179,7 @@ class ExplainEngine:
                     requested,
                     page_size=self._page_size,
                     max_heads=self._max_heads,
+                    rewrites=rewrites,
                 )
         else:
             found, path, certificate = build_witness(
@@ -159,6 +187,7 @@ class ExplainEngine:
                 requested,
                 page_size=self._page_size,
                 max_heads=self._max_heads,
+                rewrites=rewrites,
             )
             witness_source = "backtrace"
             if found != allowed:
@@ -169,18 +198,18 @@ class ExplainEngine:
         verified = False
         if allowed:
             ok, reason = (
-                verify_witness(self._manager, requested, path)
+                verify_witness(self._manager, requested, path, rewrites)
                 if path
                 else (False, "no witness path found for an allowed decision")
             )
             if not ok:
                 self._note_failure(requested, route, tenant, path, reason)
                 path = oracle_witness(
-                    self._manager, requested, page_size=self._page_size
+                    self._manager, requested, page_size=self._page_size, rewrites=rewrites
                 )
                 witness_source = "oracle-fallback"
                 if path:
-                    ok, _ = verify_witness(self._manager, requested, path)
+                    ok, _ = verify_witness(self._manager, requested, path, rewrites)
             verified = bool(ok and path)
         elif divergence:
             # denied by the engine but the closure holds a path: count it

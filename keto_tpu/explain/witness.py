@@ -18,10 +18,21 @@ Three entry points:
 
 All three speak only the Manager contract, so they work identically against
 the in-memory store, a tenant-scoped store view, or a snapshot-pinned read.
+
+Under userset rewrites (keto_tpu/namespace/rewrites.py; ``rewrites=(namespace
+manager, schema)``) a witness may hold steps that no stored row states: a
+``RewriteStep`` says "this head holds that subject set by the relation's
+``computed_userset`` / ``tuple_to_userset``" (the latter with the tupleset
+row behind it as ``via``). ``build_witness`` follows the union class by the
+schema, ``verify_witness`` checks a rewrite step against the schema and its
+``via`` row against the store, and a stored edge only where the relation's
+rewrite has a ``this``. A head whose rewrite holds an intersection or an
+exclusion has no path-shaped witness: ``GatedClosure`` is raised.
 """
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Any, Optional
 
 from keto_tpu.relationtuple.manager import Manager
@@ -30,7 +41,7 @@ from keto_tpu.relationtuple.model import (
     RelationTuple,
     SubjectSet,
 )
-from keto_tpu.x.errors import ErrNotFound
+from keto_tpu.x.errors import ErrNamespaceUnknown, ErrNotFound
 from keto_tpu.x.graph import check_and_add_visited
 from keto_tpu.x.pagination import with_size, with_token
 
@@ -40,6 +51,77 @@ from keto_tpu.x.pagination import with_size, with_token
 DEFAULT_MAX_HEADS = 100_000
 
 WitnessPath = list[RelationTuple]
+
+
+@dataclass(frozen=True)
+class RewriteStep(RelationTuple):
+    """A witness step stated by the schema, not by a stored row:
+    ``namespace:object#relation`` holds the subject set ``subject`` by the
+    relation's ``rewrite`` (``computed_userset`` or ``tuple_to_userset``;
+    for the latter ``via`` is the tupleset's stored row)."""
+
+    rewrite: str = "computed_userset"
+    via: Optional[RelationTuple] = None
+
+    def to_json(self) -> dict[str, Any]:
+        body = super().to_json()
+        body["rewrite"] = self.rewrite
+        if self.via is not None:
+            body["via"] = self.via.to_json()
+        return body
+
+
+class GatedClosure(Exception):
+    """The search met a relation whose rewrite holds an intersection or an
+    exclusion: no path is a witness there."""
+
+
+def _leaves(rewrites, head: SubjectSet):
+    """The ``(operator, argument)`` leaves of ``head``'s rewrite under
+    ``rewrites = (namespace manager, schema)``; plain ``this`` without one."""
+    if not rewrites or not rewrites[1]:
+        return (("this", {}),)
+    nm, schema = rewrites
+    try:
+        ns_id = nm.get_namespace_by_name(head.namespace).id
+    except ErrNamespaceUnknown:
+        return (("this", {}),)
+    leaves = schema.union_leaves(ns_id, head.relation)
+    if leaves is None:
+        raise GatedClosure(f"{head.namespace}#{head.relation}")
+    return leaves
+
+
+def _steps(manager: Manager, rewrites, head: SubjectSet, page_size: int):
+    """Every step out of ``head``: its stored rows where its rewrite has a
+    ``this``, then the steps its rewrite derives."""
+    leaves = _leaves(rewrites, head)
+    for op, arg in leaves:
+        if op == "this":
+            query = RelationQuery(
+                namespace=head.namespace, object=head.object, relation=head.relation
+            )
+            for rels in _iter_pages(manager, query, page_size):
+                yield from rels
+    for op, arg in leaves:
+        if op == "computed_userset":
+            yield RewriteStep(
+                head.namespace, head.object, head.relation,
+                SubjectSet(head.namespace, head.object, arg), rewrite=op,
+            )
+        elif op == "tuple_to_userset":
+            query = RelationQuery(
+                namespace=head.namespace, object=head.object, relation=arg["tupleset"]
+            )
+            for rels in _iter_pages(manager, query, page_size):
+                for row in rels:
+                    s = row.subject
+                    if isinstance(s, SubjectSet):
+                        yield RewriteStep(
+                            head.namespace, head.object, head.relation,
+                            SubjectSet(s.namespace, s.object, arg["computed_userset"]),
+                            rewrite=op, via=row,
+                        )
 
 
 def _iter_pages(manager: Manager, query: RelationQuery, page_size: int):
@@ -66,6 +148,7 @@ def build_witness(
     *,
     page_size: int = 0,
     max_heads: int = DEFAULT_MAX_HEADS,
+    rewrites=None,
 ) -> tuple[bool, Optional[WitnessPath], Optional[dict[str, Any]]]:
     """BFS back-trace: returns ``(allowed, path, certificate)``.
 
@@ -94,10 +177,8 @@ def build_witness(
         next_frontier: list[SubjectSet] = []
         for head in frontier:
             head_key = str(head)
-            query = RelationQuery(
-                namespace=head.namespace, object=head.object, relation=head.relation
-            )
-            for rels in _iter_pages(manager, query, page_size):
+            # one "page" of every step where a schema derives some
+            for rels in (_steps(manager, rewrites, head, page_size),):
                 for sr in rels:
                     edges_scanned += 1
                     if check_and_add_visited(visited, sr.subject):
@@ -144,11 +225,15 @@ def _backtrace(
 
 
 def oracle_witness(
-    manager: Manager, requested: RelationTuple, *, page_size: int = 0
+    manager: Manager, requested: RelationTuple, *, page_size: int = 0, rewrites=None
 ) -> Optional[WitnessPath]:
     """The CPU oracle's own witness: DFS threading the reference engine's
     traversal (keto_tpu/check/engine.py) with an explicit edge stack. Returns
-    the path the oracle walked to its first match, or None on deny."""
+    the path the oracle walked to its first match, or None on deny. Under a
+    rewrite schema the oracle's descent has no one path of its own; the
+    shortest one stands for it."""
+    if rewrites and rewrites[1]:
+        return build_witness(manager, requested, page_size=page_size, rewrites=rewrites)[1]
     visited: set[str] = set()
     path: WitnessPath = []
 
@@ -191,8 +276,40 @@ def _head_matches(head: SubjectSet, edge: RelationTuple) -> bool:
     )
 
 
+def _verify_rewrite_step(manager: Manager, rewrites, edge: RewriteStep) -> str:
+    """"" when the schema (and, behind a ``tuple_to_userset``, the store)
+    states ``edge``; else why not."""
+    head = SubjectSet(edge.namespace, edge.object, edge.relation)
+    try:
+        leaves = _leaves(rewrites, head)
+    except GatedClosure:
+        return f"{head} holds an intersection or an exclusion"
+    s = edge.subject
+    for op, arg in leaves:
+        if op != edge.rewrite or not isinstance(s, SubjectSet):
+            continue
+        if op == "computed_userset":
+            if s == SubjectSet(edge.namespace, edge.object, arg):
+                return ""
+        elif op == "tuple_to_userset" and isinstance(edge.via, RelationTuple):
+            via = edge.via
+            vs = via.subject
+            if (
+                (via.namespace, via.object, via.relation)
+                == (edge.namespace, edge.object, arg["tupleset"])
+                and isinstance(vs, SubjectSet)
+                and s == SubjectSet(vs.namespace, vs.object, arg["computed_userset"])
+            ):
+                try:
+                    rels, _ = manager.get_relation_tuples(via.to_query(), with_size(2))
+                except ErrNotFound:
+                    return "tupleset row's namespace unknown to the store"
+                return "" if via in rels else f"tupleset row ({via}) not present in the store"
+    return f"the schema has no {edge.rewrite} of {head} that gives {s}"
+
+
 def verify_witness(
-    manager: Manager, requested: RelationTuple, path: WitnessPath
+    manager: Manager, requested: RelationTuple, path: WitnessPath, rewrites=None
 ) -> tuple[bool, str]:
     """Validate a witness edge-by-edge. Returns ``(ok, reason)``; reason is
     "" when the witness holds, else a human-readable description of the first
@@ -235,6 +352,23 @@ def verify_witness(
             head = edge.subject
 
     for i, edge in enumerate(path):
+        if isinstance(edge, RewriteStep):
+            # a rewrite step is checked against the schema, not the store
+            why = _verify_rewrite_step(manager, rewrites, edge)
+            if why:
+                return False, f"edge {i} ({edge}): {why}"
+            continue
+        if rewrites and rewrites[1]:
+            try:
+                ops = [op for op, _ in _leaves(
+                    rewrites, SubjectSet(edge.namespace, edge.object, edge.relation))]
+            except GatedClosure:
+                ops = []
+            if "this" not in ops:
+                return False, (
+                    f"edge {i} ({edge}) is a stored row on a relation whose rewrite "
+                    "has no 'this'"
+                )
         try:
             rels, _ = manager.get_relation_tuples(edge.to_query(), with_size(2))
         except ErrNotFound:

@@ -387,6 +387,7 @@ def compact_snapshot(
         interned=new_interned,
         raw2dev=raw2dev,
         wild_ns_ids=snap.wild_ns_ids,
+        rewrites=snap.rewrites,
         fwd_indptr=new_indptr,
         fwd_indices=new_indices,
         sink_indptr=new_sink_indptr,

@@ -314,13 +314,18 @@ class _BudgetExceeded(Exception):
     pass
 
 
-def build_labels(snap, max_width: int = 64, landmarks: int = 0) -> LabelIndex:
+def build_labels(
+    snap, max_width: int = 64, landmarks: int = 0, deadline_s: Optional[float] = None
+) -> Optional[LabelIndex]:
     """Construct the index for ``snap`` (see module docstring).
     ``landmarks == 0`` processes every interior node (exact oracle);
     a positive cap processes only the top-ranked ones (coverage shrinks,
     soundness holds). Deterministic: rank ties break on device id, BFS
     label content is visit-order independent — the multi-controller
-    lockstep contract holds for label-path decisions too."""
+    lockstep contract holds for label-path decisions too. With
+    ``deadline_s`` the build gives up (None) once it has run that long:
+    the caller has a builder whose time does not depend on what pruning
+    leaves (``TpuCheckEngine._build_label_index``)."""
     import time
 
     t0 = time.monotonic()
@@ -336,7 +341,10 @@ def build_labels(snap, max_width: int = 64, landmarks: int = 0) -> LabelIndex:
     out_ok = np.ones(n, bool)
     in_ok = np.ones(n, bool)
 
+    give_up_at = None if deadline_s is None else t0 + deadline_s
     for v in order[:K].tolist():
+        if give_up_at is not None and time.monotonic() > give_up_at:
+            return None
         # self entries first: reach0(v, v) must hit, and the prune tests
         # below rely on v ∈ own label
         if len(out_sets[v]) < max_width:

@@ -95,6 +95,27 @@ def apply_delta(
         # the overlay pends — the first real build is trivially cheap
         return None
 
+    plan = base.rewrites
+    if plan is not None:
+        # userset rewrites (keto_tpu/namespace/rewrites.py): the stored
+        # ops become the ops of the graph's edges, derived edges added and
+        # retired with the rows that state them; a subject-set pair the
+        # relation graph has not seen yet may move a closure into another
+        # class, and a cycle through a subtract is the full build's to refuse
+        from keto_tpu.namespace.rewrites import SchemaError, expand_delta
+
+        try:
+            plan = plan.with_pairs(
+                (r.namespace_id, r.relation, r.sset_namespace_id, r.sset_relation)
+                for kind, r in ops if kind == "ins" and r.subject_id is None
+            )
+        except SchemaError:
+            return None
+        got = expand_delta(plan, base, ops)
+        if got is None:
+            return None
+        ops, plan = got
+
     # net effect per tuple key: the last op wins (deletes remove ALL rows
     # of a key, so edge presence after the delta is decided by whether the
     # final op re-inserted it). First-seen key order keeps processing
@@ -488,9 +509,10 @@ def apply_delta(
     if removed:
         removed_arr = np.sort(np.fromiter(removed, np.int64, len(removed)))
 
-    return dataclasses.replace(
+    new = dataclasses.replace(
         base,
         snapshot_id=new_watermark,
+        rewrites=plan,
         ov_set_ids=ov_set,
         ov_leaf_ids=ov_leaf,
         ov_class=ov_class,
@@ -512,6 +534,10 @@ def apply_delta(
         _pattern_cache={},
         _cache_lock=__import__("threading").Lock(),
     )
+    flags = base.__dict__.get("_rewrite_flags")
+    if flags is not None and plan is not None and flags[0] is plan.flags:
+        new.__dict__["_rewrite_flags"] = flags  # same closure bits, same base rows
+    return new
 
 
 def _ceil_pow2(x: int) -> int:

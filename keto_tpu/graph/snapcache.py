@@ -305,6 +305,16 @@ class CachedInterned:
         return self._str_of("leaf", idx)
 
 
+def _rewrite_fingerprint(path: Path) -> str:
+    """The rewrite-schema fingerprint a cache was saved under ("" without
+    rewrites, or where its meta cannot be read)."""
+    try:
+        meta = json.loads((path / "meta.json").read_text())
+    except (OSError, ValueError):
+        return ""
+    return (meta.get("rewrites") or {}).get("fingerprint", "")
+
+
 def save_snapshot(
     snap: GraphSnapshot, cache_dir: str, shards: int = 1, labels_wait=None
 ) -> Optional[str]:
@@ -343,8 +353,13 @@ def save_snapshot(
     base = Path(cache_dir)
     tag = f"v{FORMAT_VERSION}-w{snap.snapshot_id}"
     final = base / tag
+    plan_meta = snap.rewrites.to_meta() if snap.rewrites is not None else None
     if final.exists():
-        return str(final)
+        if _rewrite_fingerprint(final) == (plan_meta or {}).get("fingerprint", ""):
+            return str(final)
+        # the same watermark built under another rewrite schema: a reload
+        # would refuse it (the fingerprint is a build input), so it goes
+        shutil.rmtree(final, ignore_errors=True)
     base.mkdir(parents=True, exist_ok=True)
     tmp = base / f".tmp-{tag}-{os.getpid()}-{threading.get_ident()}"
     if tmp.exists():
@@ -461,6 +476,10 @@ def save_snapshot(
             "segments": segments,
             "groups": groups,
         }
+        if plan_meta is not None:
+            # the key exists only under a rewrite schema: without one the
+            # cache is byte for byte what it was before rewrites existed
+            meta["rewrites"] = plan_meta
         (tmp / "meta.json").write_text(json.dumps(meta))
         _fsync_file(tmp / "meta.json")
         _fsync_dir(tmp)
@@ -720,6 +739,9 @@ def load_snapshot(path: str, verify: bool = True, sorter=None) -> GraphSnapshot:
         interned=interned,
         raw2dev=mm("raw2dev.npy"),
         wild_ns_ids=frozenset(meta["wild_ns_ids"]),
+        # as saved (``RewritePlan.to_meta``): the engine compares its
+        # fingerprint with the current schema's and makes a plan of it
+        rewrites=meta.get("rewrites"),
         fwd_indptr=mm("fwd_indptr.npy"),
         fwd_indices=mm("fwd_indices.npy"),
         sink_indptr=mm("sink_indptr.npy"),

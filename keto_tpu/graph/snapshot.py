@@ -253,6 +253,10 @@ class GraphSnapshot:
     interned: Any
     raw2dev: np.ndarray  # int64 [n_nodes]: raw node id → device id
     wild_ns_ids: FrozenSet[int] = frozenset()
+    #: the plan of the rewrite schema this snapshot was built under
+    #: (keto_tpu/namespace/rewrites.py ``RewritePlan``): its fingerprint is a
+    #: build input like ``wild_ns_ids``; None where no namespace has rewrites
+    rewrites: Any = None
     # forward CSR over device ids, host-side (expand assist, debugging)
     fwd_indptr: Optional[np.ndarray] = None  # int64 [n_nodes+1]
     fwd_indices: Optional[np.ndarray] = None  # int32 [E]

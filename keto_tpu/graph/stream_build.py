@@ -48,8 +48,11 @@ from keto_tpu.graph.snapshot import GraphSnapshot, build_snapshot, layout_snapsh
 #: pool stays busy while the cursor fetches the next chunk
 DEFAULT_CHUNK_ROWS = 262144
 
-#: build phases in pipeline order; "idle" means no build in flight
-PHASES = ("scan", "intern", "device_build", "labels", "cache_save")
+#: build phases in pipeline order; "idle" means no build in flight.
+#: "rewrites" (the expansion of userset rewrites into edges, inside the
+#: scan's chunk loop and just after the intern) is observed only by a build
+#: under a schema that has rewrites
+PHASES = ("scan", "intern", "rewrites", "device_build", "labels", "cache_save")
 
 #: per-phase weight of the pct estimate (scan/intern dominate at scale;
 #: labels/cache_save land after the snapshot already serves)
@@ -187,12 +190,20 @@ class BuildProgress:
             }
 
 
-def _scan_and_intern(store, wild_ns_ids, progress, chunk_rows):
-    """One streaming scan+intern attempt: returns ``(interned, wm)``.
-    Raises on store failure with the in-flight native builder aborted —
-    the caller's retry policy re-runs with fresh state."""
+def _scan_and_intern(store, wild_ns_ids, progress, chunk_rows, rewrites=None):
+    """One streaming scan+intern attempt: returns ``(interned, wm,
+    expander)``. Raises on store failure with the in-flight native builder
+    aborted — the caller's retry policy re-runs with fresh state. Under a
+    rewrite schema every chunk goes through a ``RowExpander`` first: both
+    interners see the derived edges as rows and deduplicate them as they
+    deduplicate any other."""
     from keto_tpu.graph.native import NativeStreamBuilder
 
+    expander = None
+    if rewrites:
+        from keto_tpu.namespace.rewrites import RowExpander
+
+        expander = RowExpander(rewrites)
     state = {
         "native": NativeStreamBuilder.create(wild_ns_ids),
         "py": None,
@@ -203,6 +214,9 @@ def _scan_and_intern(store, wild_ns_ids, progress, chunk_rows):
         state["py"] = IncrementalInterner(wild_ns_ids)
 
     def on_chunk(chunk):
+        n_stored = len(chunk)
+        if expander is not None:
+            chunk = expander.expand(chunk)
         t0 = time.monotonic()
         nb = state["native"]
         if nb is not None:
@@ -220,7 +234,7 @@ def _scan_and_intern(store, wild_ns_ids, progress, chunk_rows):
         else:
             state["py"].add_rows(chunk)
         state["intern_s"] += time.monotonic() - t0
-        progress.add_rows(len(chunk))
+        progress.add_rows(n_stored)
 
     progress.set_phase("scan")
     t_scan = time.monotonic()
@@ -250,10 +264,24 @@ def _scan_and_intern(store, wild_ns_ids, progress, chunk_rows):
     # phase is that packing/feeding plus the merge tail. With the native
     # pool the worker time overlaps the fetches entirely — which is the
     # point — so scan_s + intern_s may exceed the pipeline wall.
-    in_scan_intern = min(state["intern_s"], scan_wall)
-    progress.observe("scan", scan_wall - in_scan_intern)
+    in_chunks = state["intern_s"] + (expander.seconds if expander is not None else 0.0)
+    progress.observe("scan", scan_wall - min(in_chunks, scan_wall))
     progress.observe("intern", state["intern_s"])
-    return g, wm
+    return g, wm, expander
+
+
+def _planned(snap: GraphSnapshot, rewrites, g, expander, prog) -> GraphSnapshot:
+    """``snap`` with the plan of the schema it was built under
+    (``GraphSnapshot.rewrites``), and the ``rewrites`` phase observed: the
+    chunk loop's expansion plus the closure over the relation graph."""
+    if rewrites:
+        from keto_tpu.namespace.rewrites import plan_for
+
+        t0 = time.monotonic()
+        snap.rewrites = plan_for(rewrites, g, expander)
+        spent = expander.seconds if expander is not None else 0.0
+        prog.observe("rewrites", spent + time.monotonic() - t0)
+    return snap
 
 
 def full_build(
@@ -265,6 +293,7 @@ def full_build(
     progress: Optional[BuildProgress] = None,
     read_retry: Optional[Callable] = None,
     chunk_rows: int = DEFAULT_CHUNK_ROWS,
+    rewrites=None,
 ) -> GraphSnapshot:
     """Build a full snapshot from ``store`` at its current watermark via
     the fastest available path, in preference order:
@@ -279,6 +308,11 @@ def full_build(
     All three produce bit-identical snapshots; ``read_retry`` (the
     engine's ``_read_store`` — x/retry with backoff) wraps each store
     read so a transient failure mid-scan retries with fresh state.
+
+    ``rewrites`` (a truthy ``RewriteSchema``, keto_tpu/namespace/rewrites.py)
+    compiles the union-class rewrites into edges on the way into the
+    interner; the column bundle holds stored rows only and is passed over.
+    Without one nothing here differs from a build before rewrites existed.
     """
     prog = progress if progress is not None else BuildProgress()
     read_retry = read_retry or (lambda fn, *a: fn(*a))
@@ -286,7 +320,7 @@ def full_build(
     try:
         # -- 1) column-bundle fast path (native interner required) -----------
         cols_fn = getattr(store, "snapshot_columns", None)
-        if cols_fn is not None:
+        if cols_fn is not None and not rewrites:
             wm = store.watermark()
             columns = cols_fn(wm)
             if columns is not None:
@@ -308,17 +342,27 @@ def full_build(
         # -- 2) streaming scan+intern ----------------------------------------
         scan_fn = getattr(store, "snapshot_scan", None)
         if scan_fn is not None and getattr(store, "scan_chunks_preferred", True):
-            g, wm = read_retry(
-                lambda: _scan_and_intern(store, wild_ns_ids, prog, chunk_rows)
+            g, wm, expander = read_retry(
+                lambda: _scan_and_intern(store, wild_ns_ids, prog, chunk_rows, rewrites)
             )
-            return layout_snapshot(
+            snap = layout_snapshot(
                 g, wm, wild_ns_ids, peel_seed_cap=peel_seed_cap,
                 sorter=sorter, progress=prog,
             )
+            return _planned(snap, rewrites, g, expander, prog)
 
         # -- 3) legacy one-shot ----------------------------------------------
         with prog.phase("scan"):
             rows, wm = read_retry(store.snapshot_rows)
+        if rewrites:
+            from keto_tpu.namespace.rewrites import RowExpander
+
+            expander = RowExpander(rewrites)
+            snap = build_snapshot(
+                expander.expand(rows), wm, wild_ns_ids, peel_seed_cap=peel_seed_cap,
+                sorter=sorter, progress=prog,
+            )
+            return _planned(snap, rewrites, snap.interned, expander, prog)
         cols = cols_fn(wm) if cols_fn is not None else None
         return build_snapshot(
             rows, wm, wild_ns_ids, peel_seed_cap=peel_seed_cap,

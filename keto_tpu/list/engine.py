@@ -33,7 +33,10 @@ from keto_tpu.relationtuple.model import (
     SubjectID,
     SubjectSet,
 )
-from keto_tpu.x.errors import ErrMalformedPageToken, ErrNotFound
+from keto_tpu.namespace.rewrites import schema_for
+from keto_tpu.x.errors import (
+    ErrBadRequest, ErrMalformedPageToken, ErrNamespaceUnknown, ErrNotFound,
+)
 from keto_tpu.x.pagination import with_size, with_token
 
 #: default page size for list-objects / list-subjects responses
@@ -75,9 +78,28 @@ def slice_page(items: list, cursor: str, size: int) -> tuple[list, str]:
 class ListEngine:
     """Manager-backed reverse-query engine (CPU reference)."""
 
-    def __init__(self, manager: Manager, page_size: int = 0):
+    def __init__(self, manager: Manager, page_size: int = 0, namespaces=None):
+        """``namespaces``: where the rewrite schema is read, as for
+        ``CheckEngine``; without it the store's own."""
         self._manager = manager
         self._page_size = page_size
+        self._namespaces = namespaces
+
+    def _union_leaves(self, nm, schema, ss: SubjectSet):
+        """The leaves of ``ss``'s rewrite: a listing walks the union class
+        by the schema and refuses, by name, a relation that holds an
+        intersection or an exclusion."""
+        try:
+            ns_id = nm.get_namespace_by_name(ss.namespace).id
+        except ErrNamespaceUnknown:
+            return [("this", {})]
+        leaves = schema.union_leaves(ns_id, ss.relation)
+        if leaves is None:
+            raise ErrBadRequest(
+                f"cannot list over {ss.namespace}#{ss.relation}: its userset rewrite "
+                f"holds an intersection or an exclusion, which only Check evaluates"
+            )
+        return leaves
 
     # -- traversal -----------------------------------------------------------
 
@@ -105,12 +127,31 @@ class ListEngine:
         out: set[str] = set()
         visited: set[str] = set()
         stack = [SubjectSet(namespace=namespace, object=object, relation=relation)]
+        nm, schema = schema_for(self._namespaces, self._manager)
         while stack:
             ss = stack.pop()
             key = str(ss)
             if key in visited:
                 continue
             visited.add(key)
+            if schema:
+                stored = False
+                for op, arg in self._union_leaves(nm, schema, ss):
+                    if op == "this":
+                        stored = True
+                    elif op == "computed_userset":
+                        stack.append(SubjectSet(ss.namespace, ss.object, arg))
+                    else:
+                        for rt in self._pages(RelationQuery(
+                            namespace=ss.namespace, object=ss.object, relation=arg["tupleset"]
+                        )):
+                            if isinstance(rt.subject, SubjectSet):
+                                stack.append(SubjectSet(
+                                    rt.subject.namespace, rt.subject.object,
+                                    arg["computed_userset"],
+                                ))
+                if not stored:
+                    continue
             for rt in self._pages(
                 RelationQuery(
                     namespace=ss.namespace, object=ss.object, relation=ss.relation
@@ -136,6 +177,13 @@ class ListEngine:
         matched row enqueues its wildcard key variants too. Objects named
         ``""`` are patterns, not objects — never returned (both engines
         share this contract)."""
+        if schema_for(self._namespaces, self._manager)[1]:
+            # backward through derived edges is the snapshot engine's alone
+            # (keto_tpu/list/tpu_engine.py): refused, never answered wrongly
+            raise ErrBadRequest(
+                "ListObjects over the store's rows cannot follow userset rewrites; "
+                "it is served from the device snapshot (engine.backend: tpu or auto)"
+            )
         out: set[str] = set()
         visited: set[str] = set()
         frontier: list[Subject] = [subject]

@@ -61,7 +61,8 @@ from keto_tpu.list.engine import (
     slice_page,
 )
 from keto_tpu.relationtuple.model import Subject, SubjectID, SubjectSet
-from keto_tpu.x.errors import ErrNamespaceUnknown
+from keto_tpu.namespace.rewrites import GATED
+from keto_tpu.x.errors import ErrBadRequest, ErrNamespaceUnknown
 
 _log = logging.getLogger("keto_tpu.list")
 
@@ -167,7 +168,7 @@ class SnapshotListEngine:
             self._nm = namespaces
         #: Manager-backed oracle: wildcard-namespace queries and the
         #: degraded-store fallback route here
-        self.oracle = ListEngine(check_engine._store)
+        self.oracle = ListEngine(check_engine._store, namespaces=self._nm)
         self._lock = threading.Lock()  # guards: _cache, device_list uploads
         self._cache: OrderedDict = OrderedDict()
         self._cache_entries = int(cache_entries)
@@ -213,6 +214,21 @@ class SnapshotListEngine:
             return self._nm().get_namespace_by_name(name).id
         except ErrNamespaceUnknown:
             return None
+
+    @staticmethod
+    def _refuse_gated(snap: GraphSnapshot, ns_id, namespace: str, relation: str) -> None:
+        """Under userset rewrites a listing is reachability over the
+        snapshot's graph, derived edges included, which is right for the
+        union class by construction. A relation whose closure reaches an
+        intersection or an exclusion has no such graph: refused, by name."""
+        plan = snap.rewrites
+        if plan is not None and ns_id is not None and (
+            plan.relation_flags(ns_id, relation) & GATED
+        ):
+            raise ErrBadRequest(
+                f"cannot list over {namespace}#{relation}: its userset rewrites reach "
+                f"an intersection or an exclusion, which only Check evaluates"
+            )
 
     # -- fixpoints -----------------------------------------------------------
 
@@ -382,6 +398,7 @@ class SnapshotListEngine:
         wild = namespace == "" or object == "" or relation == "" or (
             ns_id is not None and ns_id in snap.wild_ns_ids
         )
+        self._refuse_gated(snap, ns_id, namespace, relation)
         if wild:
             # pattern/wildcard listings ride the Manager oracle (the
             # fallback-matrix entry for wildcard semantics)
@@ -479,7 +496,17 @@ class SnapshotListEngine:
         wild = namespace == "" or relation == "" or (
             ns_id is not None and ns_id in snap.wild_ns_ids
         )
+        self._refuse_gated(snap, ns_id, namespace, relation)
         if wild:
+            if snap.rewrites is not None:
+                # the Manager oracle walks stored rows backwards and cannot
+                # follow derived edges; a pattern has no meaning in the
+                # schema either: refused for what it is, never answered wrongly
+                raise ErrBadRequest(
+                    f"cannot list objects over the wildcard pattern "
+                    f"{namespace!r}#{relation!r} under userset rewrites: "
+                    f"name the namespace and the relation"
+                )
             self._count("objects", "oracle")
             return self.oracle.list_objects(namespace, relation, subject), token
         if ns_id is None:

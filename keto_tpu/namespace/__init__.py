@@ -8,6 +8,9 @@ graph interner, and a unique name used by the APIs.
 
 Mirrors reference internal/namespace/definitons.go:8-22 and
 internal/driver/config/namespace_memory.go:18-58.
+
+A namespace's ``config`` may carry ``relations``: its userset rewrites
+(keto_tpu/namespace/rewrites.py; docs/concepts/userset-rewrites.md).
 """
 
 from __future__ import annotations
@@ -55,6 +58,13 @@ class MemoryManager(Manager):
         for n in namespaces:
             self._by_name[n.name] = n
             self._by_id[n.id] = n
+        # userset rewrites (``config.relations``), parsed and validated here:
+        # a malformed schema is an error of the configuration that names the
+        # relation (``SchemaError``), raised when the namespaces load or
+        # reload; falsy where no namespace has one
+        from keto_tpu.namespace.rewrites import RewriteSchema
+
+        self.rewrites = RewriteSchema(self._by_name.values())
 
     def get_namespace_by_name(self, name: str) -> Namespace:
         try:

@@ -362,6 +362,30 @@ void mux_stop(Mux* m) {
     ssize_t ignored = write(m->wake, &one, sizeof(one));
     (void)ignored;
     if (m->loop.joinable()) m->loop.join();
+    // The drain's last step. The loop has gone, perhaps with events in
+    // hand: a response the backend has written (the REST drain waits for
+    // exactly that) but the loop has not relayed yet must still reach its
+    // client before the connection is closed: the zero-dropped-requests
+    // half of a rolling restart. Backend -> client only, bounded.
+    uint64_t until = now_ms() + 500;
+    for (;;) {
+        bool pending = false;
+        for (auto& [fd, c] : m->by_fd) {
+            if (fd != c->client || c->phase != Conn::SPLICE || c->doomed) continue;
+            for (bool dead = false;;) {
+                m->pump(c->backend, c->b2c, c->client, c->b2c_shut, c, dead);
+                if (dead) break;
+                if (c->b2c.len) {
+                    pending = true;  // the client's socket is full: come back
+                    break;
+                }
+                char more;
+                if (c->b2c.eof || recv(c->backend, &more, 1, MSG_PEEK | MSG_DONTWAIT) <= 0) break;
+            }
+        }
+        if (!pending || now_ms() > until) break;
+        usleep(1000);
+    }
     std::vector<Conn*> conns;
     for (auto& [fd, c] : m->by_fd)
         if (fd == c->client) conns.push_back(c);

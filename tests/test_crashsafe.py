@@ -399,7 +399,17 @@ def test_rolling_restart_drains_in_flight_checks():
         ]
         for t in threads:
             t.start()
-        time.sleep(0.05)  # let them hit the batcher's coalescing window
+        # the contract is about requests ACCEPTED before the signal: wait
+        # until the batcher holds (or has answered) every one of them, not
+        # for a span of wall clock a loaded host may spend starting threads
+        batcher = d.registry.peek("check_batcher")
+        give_up = time.monotonic() + 10.0
+        while time.monotonic() < give_up:
+            with lock:
+                answered = len(results)
+            if answered + batcher.inflight >= n:
+                break
+            time.sleep(0.002)
         d.drain_and_shutdown()
         for t in threads:
             t.join(timeout=20)

@@ -739,3 +739,65 @@ def test_httpclient_explain_roundtrip(rest_registry):
     finally:
         read.stop()
         write.stop()
+
+
+# -- userset rewrites: a witness step the schema states, not the store ----------
+
+
+def test_a_rewrite_step_is_typed_and_verified_against_the_schema():
+    from keto_tpu.explain.witness import GatedClosure, RewriteStep
+
+    nss = [
+        namespace_pkg.Namespace(id=1, name="g"),
+        namespace_pkg.Namespace(id=2, name="d", config={"relations": {
+            "edit": {"union": [{"this": {}}, {"computed_userset": "own"}]},
+            "view": {"union": [{"computed_userset": "edit"}, {"tuple_to_userset": {
+                "tupleset": "parent", "computed_userset": "view"}}]},
+            "share": {"intersection": [{"this": {}}, {"computed_userset": "own"}]},
+        }}),
+    ]
+    p = MemoryPersister(namespace_pkg.MemoryManager(nss))
+    p.write_relation_tuples(
+        T("d", "doc", "parent", SubjectSet("d", "dir", "...")),
+        T("d", "dir", "own", SubjectID("ann")),
+        T("d", "doc", "view", SubjectID("mallory")),  # view has no 'this': not a grant
+    )
+    rewrites = (p.namespaces(), p.namespaces().rewrites)
+    requested = T("d", "doc", "view", SubjectID("ann"))
+    found, path, _ = build_witness(p, requested, rewrites=rewrites)
+    assert found
+    assert [getattr(e, "rewrite", None) for e in path] == [
+        "tuple_to_userset", "computed_userset", "computed_userset", None]
+    assert isinstance(path[0], RewriteStep) and path[0].via == T(
+        "d", "doc", "parent", SubjectSet("d", "dir", "..."))
+    assert path[0].to_json()["rewrite"] == "tuple_to_userset"
+    assert verify_witness(p, requested, path, rewrites) == (True, "")
+    # a rewrite step is held to the schema: another relation, another tupleset row
+    forged = [RewriteStep("d", "doc", "view", SubjectSet("d", "dir", "own"),
+                          rewrite="tuple_to_userset", via=path[0].via)] + path[3:]
+    ok, why = verify_witness(p, requested, forged, rewrites)
+    assert not ok and "schema has no tuple_to_userset" in why
+    gone = [RewriteStep("d", "doc", "view", SubjectSet("d", "dir", "view"), rewrite="tuple_to_userset",
+                        via=T("d", "doc", "parent", SubjectSet("d", "other", "...")))] + path[1:]
+    assert not verify_witness(p, requested, gone, rewrites)[0]
+    # a stored row on a relation whose rewrite has no 'this' is no step
+    ok, why = verify_witness(
+        p, T("d", "doc", "view", SubjectID("mallory")),
+        [T("d", "doc", "view", SubjectID("mallory"))], rewrites)
+    assert not ok and "no 'this'" in why
+    assert not build_witness(p, T("d", "doc", "view", SubjectID("mallory")), rewrites=rewrites)[0]
+    # without the schema the same rows give the v0.7 answer, as before
+    assert build_witness(p, T("d", "doc", "view", SubjectID("mallory")))[0]
+    with pytest.raises(GatedClosure):
+        build_witness(p, T("d", "doc", "share", SubjectID("ann")), rewrites=rewrites)
+
+    engine = TpuCheckEngine(p, p.namespaces)
+    try:
+        resp = ExplainEngine(engine, p).explain(requested)
+        assert resp["allowed"] and resp["verified"]
+        assert [e.get("rewrite") for e in resp["witness"]][:1] == ["tuple_to_userset"]
+        gated = ExplainEngine(engine, p).explain(T("d", "doc", "share", SubjectID("ann")))
+        assert gated["witness"] is None and not gated["verified"]
+        assert "intersection" in gated["rewrite"]
+    finally:
+        engine.close()

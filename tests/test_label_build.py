@@ -330,6 +330,33 @@ def test_engine_tiny_graph_stays_on_host_path():
     eng.close()
 
 
+@pytest.mark.parametrize("floor_s, backend, event", [
+    (60.0, "host", "label_host_first_builds"),
+    (0.0, "device", "label_host_first_timeouts"),
+])
+def test_above_the_gate_the_host_walk_goes_first(monkeypatch, floor_s, backend, event):
+    """Above ``labels_device_min_edges`` the host walk gets as long as the
+    device's batches would take at the least: a graph it indexes in that
+    time never waits for the device; one it does not gets the device build.
+    The same entries either way."""
+    monkeypatch.setattr(TpuCheckEngine, "LABEL_BATCH_FLOOR_S", floor_s)
+    p = deep_store(depth=12)
+    eng = quiet_engine(p, labels_device_min_edges=1)
+    want = quiet_engine(p)  # under the default gate: the host walk, no deadline
+    try:
+        assert eng.labels_settled() and want.labels_settled()
+        m = eng.maintenance.snapshot()
+        assert m.get(event, 0) == 1
+        assert m.get("label_device_builds", 0) == (backend == "device")
+        got, ref = eng._snapshot.labels, want._snapshot.labels
+        assert got.backend == backend and ref.backend == "host"
+        assert np.array_equal(got.out_lab, ref.out_lab) and np.array_equal(got.in_lab, ref.in_lab)
+        assert not any(k.startswith("label_host_first") for k in want.maintenance.snapshot())
+    finally:
+        eng.close()
+        want.close()
+
+
 def test_snapcache_roundtrip_carries_device_built_labels(tmp_path):
     """save → cold reload of a device-built index: the arrays and the
     backend tag ride the cache, construction is skipped, decisions
