@@ -46,9 +46,11 @@ from keto_tpu.check import kernels
 from keto_tpu.check.kernels import (
     _check_kernel, _check_kernel_donated, _label_kernel, _label_kernel_donated,
 )
+from keto_tpu.check import native_pack
 from keto_tpu.check.pack import (
-    _WORD_WIDTHS, _HybridSlice, _ShardedSlice, _StagingPool, _entry_pad,
-    _pad_packed, _padding_packed, device_part, hub_usable, pack_chunk, pack_entries,
+    LABEL_REASONS, _WORD_WIDTHS, _HybridSlice, _ShardedSlice, _StagingPool, _entry_pad,
+    _pad_packed, _padding_packed, device_part, hub_usable, label_pairs, pack_chunk,
+    pack_entries, put_pairs, whole_min,
 )
 from keto_tpu.check.slice_ctrl import StreamSliceController
 from keto_tpu.graph.snapshot import WILDCARD, GraphSnapshot, _ceil_pow2
@@ -133,6 +135,10 @@ class CheckDispatch:
         # snapshot id last counted as a label invalidation (overlay
         # mutated the interior subgraph) — one count per transition
         self._label_blocked_snap: Optional[int] = None
+        #: the snapshot's arrays as the label route's native pass reads them
+        #: (``native_pack.PackView``), remade when the snapshot, its label
+        #: index or its relay rows change
+        self._pack_view: Optional[native_pack.PackView] = None
         self._mesh = mesh
         self._shard_count = shard_count  # 0: not explicitly sharded
         self._sharded = shard_count > 0
@@ -1704,6 +1710,20 @@ class CheckDispatch:
     #: costs more as intersections than as one more BFS rider
     _LABEL_PAIR_CAP = 64
 
+    def _fused_decline(self, snap: GraphSnapshot, multi: dict, i0: int, i1: int):
+        """Why the label route's one native pass (``native_pack.pack_labeled``)
+        cannot pack chunk ``[i0, i1)``, or None where it can: from what the
+        code observes, never a setting. ``keto_check_pack_declines_total``."""
+        if not native_pack.available():
+            return "no_library"
+        if self._mesh is not None:
+            return "mesh"  # the sharded kernels route a chunk's entries themselves
+        if not native_pack.walk_eligible(snap):
+            return "overlay"
+        if multi and any(i0 <= i < i1 for i in multi):
+            return "multi"  # wildcard and multi-start queries: several starts a query
+        return None
+
     def _device_batch_labeled(
         self,
         snap: GraphSnapshot,
@@ -1715,26 +1735,21 @@ class CheckDispatch:
         W: int,
         it_cap: Optional[int] = None,
     ):
-        """The label fast path for one sub-chunk: resolve the chunk with
-        the SAME host machinery as the BFS path (``pack_chunk`` — host
-        walk, sink gathers, host-decided grants), then answer every
-        label-certifiable query with ONE intersection kernel step and
-        ride the rest on a compacted BFS sub-batch, bit-identically.
+        """The label fast path for one sub-chunk: resolve the chunk on the
+        host as the BFS path does (host walk, sink gathers, host-decided
+        grants), then answer every label-certifiable query with ONE
+        intersection kernel step and ride the rest on a compacted BFS
+        sub-batch, bit-identically.
 
-        The reach0 mapping (see keto_tpu/graph/labels.py):
-
-        - a query's **pairs** are (seed row u) × (target-side row r):
-          the interior target itself, or a sink target's interior
-          in-neighbor gathers (``a_rows`` — exactly what the BFS kernel
-          gathers from the fixpoint);
-        - an e1 seed equal to an interior target would conflate reach0
-          with the "via ≥ 1 edge" rule — that query falls back (the
-          kernel's R0-vs-pull distinction, which labels don't carry);
-          an e2 seed equal to the target was reached via a real edge on
-          the host walk, so ``host_ans`` already granted it and the pair
-          drops;
-        - wildcard/multi-start queries, uncertifiable pairs (coverage
-          gaps), and over-fanout queries fall back.
+        The host part is one GIL-released native pass wherever nothing
+        declines it (``_fused_decline``): ``native_pack.pack_labeled`` walks,
+        routes and pairs query by query and writes the pairs straight into
+        the staging lease. A declined chunk takes ``pack_chunk`` and
+        ``label_pairs`` (keto_tpu/check/pack.py): the same answers by the
+        same routes for the same reasons, and what the pass is fuzzed
+        against. The reach0 mapping and the reasons a query leaves the label
+        route are ``label_pairs``'s docstring (keto_tpu/graph/labels.py for
+        reach0 itself).
         """
         idx = snap.labels
         if idx is None or self._labels_dev(snap) is None:
@@ -1743,111 +1758,46 @@ class CheckDispatch:
             return self._device_batch(snap, sd, tg, multi, i0, i1, W, it_cap=it_cap)
         clk = dispatch_clock()
         clk.enter(PACK)
-        packed, host_ans = pack_chunk(snap, sd, tg, multi, i0, i1, W)
-        clk.poll()
         nq = i1 - i0
-        self._note_packed(snap, packed, nq)
-        leases: list = []
-        if packed is None:
-            return None, host_ans, leases  # nothing reaches any device path
-        (e1r, e1q, e2r, e2q, ar, aq, targets) = packed
         ni = snap.num_int
         B = 32 * W
-        tq = np.asarray(targets[:nq], np.int64)
-        t_int = tq < ni
+        leases: list = []
+        declined = self._fused_decline(snap, multi, i0, i1)
+        if declined is None:
+            view, hub = self._pack_view, hub_usable(snap)
+            if view is None or not view.of(snap, idx, hub):
+                view = self._pack_view = native_pack.PackView(
+                    snap, idx, self._LABEL_PAIR_CAP, hub
+                )
+            host_ans, fallback, counts = native_pack.pack_labeled(
+                view, sd, tg, i0, i1, whole_min(W)
+            )
+            clk.poll()
+            self.maintenance.incr("packed_checks", by=nq)
+            if not counts.packed:
+                return None, host_ans, leases  # nothing reaches any device path
+            self.maintenance.incr("pack_rows_seed", by=counts.seed_rows)
+            self.maintenance.incr("pack_rows_target", by=counts.target_rows)
+            n_pairs, n_fb, whole = counts.pairs, counts.fallbacks, bool(counts.whole)
+            reasons = {r: getattr(counts, r) for r in LABEL_REASONS}
+            # the riders' entries alone: the label kernel has the others
+            packed = native_pack.labeled_riders(counts, B) if n_fb else None
+            write_pairs = native_pack.labeled_pairs
+        else:
+            self.maintenance.incr(f"pack_declines_{declined}")
+            packed, host_ans = pack_chunk(snap, sd, tg, multi, i0, i1, W)
+            clk.poll()
+            self._note_packed(snap, packed, nq)
+            if packed is None:
+                return None, host_ans, leases  # nothing reaches any device path
+            fallback, reasons, whole, pairs = label_pairs(
+                snap, idx, packed, multi, i0, i1, W, self._LABEL_PAIR_CAP
+            )
+            n_pairs, n_fb = int(pairs[0].size), int(np.count_nonzero(fallback))
 
-        fallback = np.zeros(nq, bool)
-        for i in multi:
-            if i0 <= i < i1:
-                fallback[i - i0] = True
-        # why each query left the label path, first cause wins
-        # (keto_label_fallbacks_total{reason})
-        reasons = {"multi": int(np.count_nonzero(fallback))}
+            def write_pairs(entries, P):
+                put_pairs(entries, P, pairs, ni)
 
-        def fall_back(reason: str, where) -> None:
-            fresh = np.zeros(nq, bool)
-            fresh[where] = True
-            fresh &= ~fallback
-            reasons[reason] = reasons.get(reason, 0) + int(np.count_nonzero(fresh))
-            fallback[where] = True
-
-        # valid (non-padding) entries; e1/e2 pad with row ni+1, a with ni
-        m1 = (e1r != ni + 1) & (e1q < nq)
-        m2 = (e2r != ni + 1) & (e2q < nq)
-        ma = (ar != ni) & (aq < nq)
-        s_rows = np.concatenate([e1r[m1], e2r[m2]]).astype(np.int64)
-        s_q = np.concatenate([e1q[m1], e2q[m2]]).astype(np.int64)
-        # e1 seed == interior target: reach0 would count the 0-edge path
-        e1_rows_v = e1r[m1].astype(np.int64)
-        e1_q_v = e1q[m1].astype(np.int64)
-        self_hit = t_int[e1_q_v] & (e1_rows_v == tq[e1_q_v])
-        if self_hit.any():
-            fall_back("self_hit", e1_q_v[self_hit])
-
-        # target-side rows per query: the interior target, or the sink
-        # answer-gather rows. Each side is counted per query first: a query
-        # over the pair cap takes neither side into the sort and the
-        # cross-join below, and where targets are hub rows its answer rows
-        # are most of the chunk's entries
-        a_rows_v, a_q_v = ar[ma], aq[ma]
-        ns = np.bincount(s_q, minlength=nq)
-        nr = np.bincount(a_q_v, minlength=nq) + t_int
-        over = ns * nr > self._LABEL_PAIR_CAP
-        if snap.hub_ptr is not None:
-            # a relay row stands for more rows than the cap: its query rides
-            over[a_q_v[a_rows_v > ni]] = True
-        if over.any():
-            fall_back("pair_cap", over)
-
-        def rides_whole() -> bool:
-            # a sub-batch as wide as the slice holds the slice: the queries
-            # the label kernel could take ride it too, and the label kernel
-            # is not launched
-            n = int(np.count_nonzero(fallback))
-            return n > 0 and next(w for w in _WORD_WIDTHS if 32 * w >= n) >= W
-
-        pa = pb = pq = np.zeros(0, np.int64)
-        whole = rides_whole()
-        if not whole:
-            keep_s = ~fallback[s_q]
-            s_rows, s_q = s_rows[keep_s], s_q[keep_s]
-            t_keep, keep_a = t_int & ~fallback, ~fallback[a_q_v]
-            b_rows = np.concatenate([tq[t_keep], a_rows_v[keep_a].astype(np.int64)])
-            b_q = np.concatenate([np.nonzero(t_keep)[0], a_q_v[keep_a].astype(np.int64)])
-            # group both sides by query, then cross-join per query
-            so = np.argsort(s_q, kind="stable")
-            s_rows, s_q = s_rows[so], s_q[so]
-            bo = np.argsort(b_q, kind="stable")
-            b_rows, b_q = b_rows[bo], b_q[bo]
-            ns = np.bincount(s_q, minlength=nq) if s_q.size else np.zeros(nq, np.int64)
-            nr = np.bincount(b_q, minlength=nq) if b_q.size else np.zeros(nq, np.int64)
-            rep_nr = np.repeat(nr, ns)  # aligned to s_rows
-            total = int(rep_nr.sum())
-            if total:
-                b_starts = np.cumsum(nr) - nr
-                seed_q = s_q
-                base = np.repeat(b_starts[seed_q], rep_nr)
-                csum = np.cumsum(rep_nr) - rep_nr
-                within = np.arange(total) - np.repeat(csum, rep_nr)
-                pa = np.repeat(s_rows, rep_nr)
-                pb = b_rows[base + within]
-                pq = np.repeat(seed_q, rep_nr)
-                # e2-seed == target pairs: already host-granted, reach0 would
-                # double-count the 0-edge path — drop (e1 cases fell back)
-                drop = t_int[pq] & (pa == pb)
-                if drop.any():
-                    pa, pb, pq = pa[~drop], pb[~drop], pq[~drop]
-                # coverage: a miss on an uncertifiable pair is not a deny
-                cert = idx.certifiable(pa, pb)
-                if not cert.all():
-                    fall_back("uncertifiable", np.unique(pq[~cert]))
-                    keep = ~fallback[pq]
-                    pa, pb, pq = pa[keep], pb[keep], pq[keep]
-                    whole = rides_whole()
-        if whole:
-            fall_back("whole_slice", ~fallback)
-
-        n_fb = int(np.count_nonzero(fallback))
         self.maintenance.incr("label_checks", by=nq - n_fb)
         if n_fb:
             self.maintenance.incr("label_fallbacks", by=n_fb)
@@ -1860,23 +1810,20 @@ class CheckDispatch:
             return dev, host_ans, leases
 
         ldev = None
-        if pa.size:
+        if n_pairs:
             faults.check("device-exec")
-            P = _entry_pad(B, pa.size)
+            P = _entry_pad(B, n_pairs)
             dl = self._labels_dev(snap)
             lmet = None
             if self._mesh is None:
                 own = (P, B)
                 lshape, lfixed = self._label_shape(dl), self._label_fixed()
                 (P, B), lmet = self.geoms.meet("label", lshape, lfixed, own)
-            pad = P - pa.size
             stg = self._stage_acquire(3 * P) if self._mesh is None else None
             if stg is not None:
                 leases.append(stg)
             entries = np.empty(3 * P, np.int32) if stg is None else stg
-            entries[:P] = np.concatenate([pa, np.full(pad, ni, np.int64)])
-            entries[P : 2 * P] = np.concatenate([pb, np.full(pad, ni, np.int64)])
-            entries[2 * P :] = np.concatenate([pq, np.zeros(pad, np.int64)])
+            write_pairs(entries, P)
             clk.enter(
                 LAUNCH, ("hybrid" if n_fb else "label", "label_step", (P, B), lmet)
             )

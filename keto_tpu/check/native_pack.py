@@ -1,41 +1,57 @@
-"""ctypes binding for the native pack walk (native/pack.cpp).
+"""ctypes binding for the native pack (native/pack.cpp).
 
-``pack_chunk``'s host walk — frontier expansion of host-propagated
-starts through the forward CSR, (query, row) seen/seed dedup, target-hit
-grants, and the sink answer gather — runs here as one GIL-released C++
-call on the eligible path, so resolve/pack of slice k+2 genuinely
-overlaps device execution of k+1 instead of fighting the GIL. The numpy
-implementation in keto_tpu/check/pack.py remains the contract
-(bit-identical output, fuzz-compared in tests/test_native_pack.py) and
-the fallback.
+Three paths pack a chunk of a check slice, counted a chunk in ``COUNTERS``
+(``keto_native_pack_chunks_total{path}``):
 
-**Eligibility** (``walk_eligible``): the walk reads ONLY the base
+- ``fused`` — the label route's whole ``pack`` as ONE GIL-released call
+  (``pack_labeled``: ``keto_pack_labeled``): query by query the host walk
+  from a host-propagated start, the seed rows, a sink target's answer or
+  relay rows, the route's decisions (``self_hit``, ``pair_cap``,
+  ``uncertifiable``, ``whole_slice``) and the pairs, which a second call
+  copies straight into the staging lease ``label_step`` ships; a third
+  hands out ``pack_chunk``'s seven arrays for the queries that fell back.
+  ``check/dispatch.py`` ``_device_batch_labeled`` takes it wherever nothing
+  declines it, and counts a decline once a chunk by reason
+  (``keto_check_pack_declines_total{reason}``): ``no_library``, ``mesh``
+  (the sharded kernels route entries themselves), ``overlay`` (below),
+  ``multi`` (a wildcard or multi-start query in the chunk). A snapshot
+  with hub sinks is handled: relay rows are one CSR slice a query.
+- ``native`` — ``pack_chunk``'s host walk (frontier expansion through the
+  forward CSR, (query, row) seen/seed dedup, target-hit grants) and its
+  sink answer gather as two GIL-released calls with numpy around them:
+  the BFS route, the sharded riders and every chunk the fused pass
+  declines for ``multi`` or ``mesh``.
+- ``numpy`` — keto_tpu/check/pack.py alone. It remains the contract
+  (``native`` is bit-identical to it, ``fused`` equal set for set: both
+  fuzz-compared in tests/test_native_pack.py and tests/test_pack_fused.py)
+  and the fallback.
+
+**Eligibility** (``walk_eligible``): the native walks read ONLY the base
 forward/sink CSRs, so any overlay state that would change what
 ``out_neighbors_bulk``/``sink_in_rows_bulk`` return routes the chunk to
 numpy: host out-adjacency (``ov_out``), tombstones (``ov_removed``), or
 overlay sink in-edges (``ov_sink_in``). Interior overlay-ELL edges are
 device-side and do not affect the host walk, so the common
-insert-only-delta serving state keeps the native path.
+insert-only-delta serving state keeps the native paths.
 
 Loading is opportunistic: ``load_library()`` returns None (and callers
 fall back to numpy) when the shared object is absent, stale
-(``keto_pack_version`` mismatch) or ``KETO_TPU_NATIVE=0`` (every native
+(``keto_pack_version`` is not ABI 3) or ``KETO_TPU_NATIVE=0`` (every native
 off: a box without a compiler). Build with ``make native``.
-
-``COUNTERS`` tracks which path packed each chunk; the registry scrapes
-it as ``keto_native_pack_chunks_total{path}``.
 """
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import os
+import weakref
 from pathlib import Path
 from typing import Optional
 
 import numpy as np
 
-_ABI_VERSION = 2
+_ABI_VERSION = 3
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_checked = False
@@ -43,11 +59,26 @@ _lib_checked = False
 #: chunks packed per path since process start (scraped as
 #: ``keto_native_pack_chunks_total{path}``; "numpy" counts fallbacks for
 #: ANY reason — library absent, disabled, or overlay-ineligible)
-COUNTERS = {"native": 0, "numpy": 0}
+COUNTERS = {"fused": 0, "native": 0, "numpy": 0}
 
 _I64 = ctypes.POINTER(ctypes.c_int64)
 _I32 = ctypes.POINTER(ctypes.c_int32)
 _U8 = ctypes.POINTER(ctypes.c_uint8)
+
+
+class _KetoPackView(ctypes.Structure):
+    """``KetoPackView`` of native/pack.cpp, field for field."""
+
+    _fields_ = [
+        (name, ctypes.c_void_p)
+        for name in (
+            "fwd_indptr", "fwd_indices", "sink_indptr", "sink_indices",
+            "hub_ptr", "hub_rows", "out_ok", "in_ok", "processed",
+        )
+    ] + [
+        (name, ctypes.c_int64)
+        for name in ("n_base", "ni", "sb", "nl", "n_lab", "pair_cap")
+    ]
 
 
 def _candidate_paths():
@@ -94,6 +125,12 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.keto_gather_fetch.argtypes = [p, _I32, _I64]
         lib.keto_gather_free.argtypes = [p]
         lib.keto_pairs_member.argtypes = [_I32, _I32, c, _I32, _I32, c, _U8]
+        lib.keto_pack_labeled.restype = None
+        lib.keto_pack_labeled.argtypes = [p, p, p, c, c, c, p, p, p]
+        lib.keto_pack_labeled_pairs.restype = c
+        lib.keto_pack_labeled_pairs.argtypes = [p, c]
+        lib.keto_pack_labeled_riders.restype = None
+        lib.keto_pack_labeled_riders.argtypes = [p, p, p, p, p, p, p, c]
         _lib = lib
         return _lib
     return None
@@ -215,3 +252,101 @@ def pairs_member(
         _ptr(out, ctypes.c_uint8),
     )
     return out.view(bool)
+
+
+#: what ``keto_pack_labeled`` counts over a chunk, in the order of its
+#: int64[13] (native/pack.cpp): any seed at all (``pack_chunk``'s packed is
+#: not None), the pairs, the queries fallen back, whether the whole slice
+#: rides, the fallbacks by reason, the chunk's seed and target-side rows (a
+#: relay row counts the rows it holds), the riders' e1, e2 and answer entries
+LabeledCounts = collections.namedtuple("LabeledCounts", [
+    "packed", "pairs", "fallbacks", "whole",
+    "self_hit", "pair_cap", "uncertifiable", "whole_slice",
+    "seed_rows", "target_rows", "rider_e1", "rider_e2", "rider_answers",
+])
+
+
+class PackView:
+    """One snapshot's arrays as ``keto_pack_labeled`` reads them
+    (``KetoPackView``), made once a snapshot and label index and kept by
+    the caller: a chunk then passes one pointer. Holds the arrays it points
+    into; ``of`` says whether it still describes what a chunk is about to
+    be packed against. ``hub``: answer entries may name the snapshot's relay
+    rows (``pack.hub_usable``). Only for a ``walk_eligible`` snapshot."""
+
+    __slots__ = ("_snap", "_labels", "_hub", "_arrays", "_struct", "ref")
+
+    def __init__(self, snap, labels, pair_cap: int, hub: bool):
+        self._snap = weakref.ref(snap)
+        self._labels = labels
+        self._hub = hub
+        flags = [
+            np.ascontiguousarray(a).view(np.uint8)
+            for a in (labels.out_ok, labels.in_ok, labels.processed)
+        ]
+        self._arrays = [
+            np.ascontiguousarray(snap.fwd_indptr, np.int64),
+            np.ascontiguousarray(snap.fwd_indices, np.int32),
+            np.ascontiguousarray(snap.sink_indptr, np.int64),
+            np.ascontiguousarray(snap.sink_indices, np.int32),
+            np.ascontiguousarray(snap.hub_ptr, np.int64) if hub else None,
+            np.ascontiguousarray(snap.hub_rows, np.int64) if hub else None,
+            *flags,
+        ]
+        self._struct = _KetoPackView(
+            *(None if a is None else a.ctypes.data for a in self._arrays),
+            snap.n_base_nodes, snap.num_int, snap.sink_base, snap.num_live,
+            min(f.shape[0] for f in flags), pair_cap,
+        )
+        self.ref = ctypes.addressof(self._struct)
+
+    def of(self, snap, labels, hub: bool) -> bool:
+        return self._snap() is snap and self._labels is labels and self._hub == hub
+
+
+def pack_labeled(view: PackView, sd: np.ndarray, tg: np.ndarray, i0: int, i1: int,
+                 whole_min: int):
+    """The fused pass over queries ``[i0, i1)`` of a resolved batch with no
+    ``multi`` entry among them. Returns ``(host_ans, fallback, counts)``:
+    bool[nq] each, and a ``LabeledCounts``. The pairs and the riders'
+    entries wait in the calling thread's native scratch for
+    ``labeled_pairs`` / ``labeled_riders``, until its next call here."""
+    lib = load_library()
+    assert lib is not None, "pack_labeled called without the native library"
+    sd = np.ascontiguousarray(sd, np.int64)
+    tg = np.ascontiguousarray(tg, np.int64)
+    if not 0 <= i0 <= i1 <= min(sd.shape[0], tg.shape[0]):
+        raise ValueError(f"chunk [{i0}, {i1}) outside a batch of {sd.shape[0]}")
+    nq = i1 - i0
+    host_ans = np.empty(nq, np.uint8)
+    fallback = np.empty(nq, np.uint8)
+    counts = np.empty(len(LabeledCounts._fields), np.int64)
+    lib.keto_pack_labeled(
+        view.ref, sd.ctypes.data, tg.ctypes.data, i0, i1, whole_min,
+        host_ans.ctypes.data, fallback.ctypes.data, counts.ctypes.data,
+    )
+    COUNTERS["fused"] += 1
+    return host_ans.view(bool), fallback.view(bool), LabeledCounts._make(counts.tolist())
+
+
+def labeled_pairs(out: np.ndarray, P: int) -> None:
+    """The pairs of this thread's last ``pack_labeled`` into ``out``
+    (contiguous int32[3 * P], ``P`` at least their count) at the three
+    offsets ``label_step`` reads, padded (ni, ni, 0)."""
+    if out.dtype != np.int32 or out.shape != (3 * P,) or not out.flags.c_contiguous:
+        raise ValueError("the pairs need a contiguous int32[3 * P]")
+    if load_library().keto_pack_labeled_pairs(out.ctypes.data, P) < 0:
+        raise ValueError(f"more pairs than the {P} the buffer was sized for")
+
+
+def labeled_riders(counts: LabeledCounts, B: int):
+    """``pack_chunk``'s seven arrays for the queries this thread's last
+    ``pack_labeled`` took off the label route, unpadded (``device_part``
+    strips padding first thing): their entries under their positions in
+    the chunk, and the chunk's targets at width ``B``."""
+    n1, n2, na = counts.rider_e1, counts.rider_e2, counts.rider_answers
+    arrays = tuple(
+        np.empty(n, np.int32) for n in (n1, n1, n2, n2, na, na, B)
+    )
+    load_library().keto_pack_labeled_riders(*(a.ctypes.data for a in arrays), B)
+    return arrays

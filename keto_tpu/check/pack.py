@@ -1,10 +1,14 @@
 """Host side of a check slice: resolved queries in, kernel entry arrays out.
 
 ``pack_chunk`` walks what the host can walk and packs the rest for
-``kernels.check_step``; ``device_part`` cuts a packed chunk down to what the
-device has to see; ``_StagingPool`` keeps the host buffers the entries ship
-from; the slice records carry a launched slice's device outputs to where it
-lands. numpy and ``native_pack`` only — nothing here touches jax.
+``kernels.check_step``; ``label_pairs`` routes a packed chunk's queries
+between the label kernel and the BFS riders and pairs the former's rows
+(the two together are what ``native_pack.pack_labeled`` does in one native
+pass, and what it is fuzzed against); ``device_part`` cuts a packed chunk
+down to what the device has to see; ``_StagingPool`` keeps the host buffers
+the entries ship from; the slice records carry a launched slice's device
+outputs to where it lands. numpy and ``native_pack`` only — nothing here
+touches jax.
 """
 
 from __future__ import annotations
@@ -545,3 +549,134 @@ def pack_chunk(
         + _pad_entries(*ans, B, ni) + (targets,),
         host_ans,
     )
+
+
+#: why a query left the label route, in the order the causes are tried
+#: (``keto_label_fallbacks_total{reason}``; ``multi`` before them all)
+LABEL_REASONS = ("self_hit", "pair_cap", "uncertifiable", "whole_slice")
+
+
+def whole_min(W: int) -> int:
+    """The fewest fallen-back queries whose sub-batch is as wide as a slice
+    of ``W`` words: from there on the slice's other queries ride it too and
+    the label kernel is not launched."""
+    return 1 + 32 * max((w for w in _WORD_WIDTHS if w < W), default=0)
+
+
+def label_pairs(snap: GraphSnapshot, idx, packed, multi: dict, i0: int, i1: int,
+                W: int, pair_cap: int):
+    """Route the ``nq`` queries of a packed chunk between the label kernel
+    and the BFS riders, and pair the rows of those it keeps.
+
+    A query's **pairs** are (seed row u) x (target-side row r): the interior
+    target itself, or a sink target's answer rows. It falls back, first cause
+    wins: ``multi`` (wildcard and multi-start queries, as a class);
+    ``self_hit`` (an e1 seed equal to an interior target: reach0 would count
+    the 0-edge path, a distinction labels do not carry); ``pair_cap`` (more
+    pairs than ``pair_cap``, or a relay row, which stands for more rows than
+    the cap); ``uncertifiable`` (a pair whose miss is no sound deny);
+    ``whole_slice`` (every other query, once the riders fill a sub-batch as
+    wide as the slice, ``whole_min``). An e2 seed equal to the target was
+    reached over a real edge on the host walk, so ``host_ans`` granted it
+    and the pair drops.
+
+    Returns ``(fallback, reasons, whole, (pa, pb, pq))``: bool[nq], counts
+    by reason, whether the whole slice rides, and the pairs as int64 arrays
+    (none where ``whole``)."""
+    nq = i1 - i0
+    (e1r, e1q, e2r, e2q, ar, aq, targets) = packed
+    ni = snap.num_int
+    tq = np.asarray(targets[:nq], np.int64)
+    t_int = tq < ni
+
+    fallback = np.zeros(nq, bool)
+    for i in multi:
+        if i0 <= i < i1:
+            fallback[i - i0] = True
+    reasons = {"multi": int(np.count_nonzero(fallback))}
+
+    def fall_back(reason: str, where) -> None:
+        fresh = np.zeros(nq, bool)
+        fresh[where] = True
+        fresh &= ~fallback
+        reasons[reason] = reasons.get(reason, 0) + int(np.count_nonzero(fresh))
+        fallback[where] = True
+
+    # valid (non-padding) entries; e1/e2 pad with row ni+1, a with ni
+    m1 = (e1r != ni + 1) & (e1q < nq)
+    m2 = (e2r != ni + 1) & (e2q < nq)
+    ma = (ar != ni) & (aq < nq)
+    s_rows = np.concatenate([e1r[m1], e2r[m2]]).astype(np.int64)
+    s_q = np.concatenate([e1q[m1], e2q[m2]]).astype(np.int64)
+    e1_rows_v = e1r[m1].astype(np.int64)
+    e1_q_v = e1q[m1].astype(np.int64)
+    self_hit = t_int[e1_q_v] & (e1_rows_v == tq[e1_q_v])
+    if self_hit.any():
+        fall_back("self_hit", e1_q_v[self_hit])
+
+    # target-side rows per query: the interior target, or the sink
+    # answer-gather rows. Each side is counted per query first: a query
+    # over the pair cap takes neither side into the sort and the
+    # cross-join below, and where targets are hub rows its answer rows
+    # are most of the chunk's entries
+    a_rows_v, a_q_v = ar[ma], aq[ma]
+    ns = np.bincount(s_q, minlength=nq)
+    nr = np.bincount(a_q_v, minlength=nq) + t_int
+    over = ns * nr > pair_cap
+    if snap.hub_ptr is not None:
+        over[a_q_v[a_rows_v > ni]] = True
+    if over.any():
+        fall_back("pair_cap", over)
+
+    def rides_whole() -> bool:
+        return int(np.count_nonzero(fallback)) >= whole_min(W)
+
+    pa = pb = pq = np.zeros(0, np.int64)
+    whole = rides_whole()
+    if not whole:
+        keep_s = ~fallback[s_q]
+        s_rows, s_q = s_rows[keep_s], s_q[keep_s]
+        t_keep, keep_a = t_int & ~fallback, ~fallback[a_q_v]
+        b_rows = np.concatenate([tq[t_keep], a_rows_v[keep_a].astype(np.int64)])
+        b_q = np.concatenate([np.nonzero(t_keep)[0], a_q_v[keep_a].astype(np.int64)])
+        # group both sides by query, then cross-join per query
+        so = np.argsort(s_q, kind="stable")
+        s_rows, s_q = s_rows[so], s_q[so]
+        bo = np.argsort(b_q, kind="stable")
+        b_rows, b_q = b_rows[bo], b_q[bo]
+        ns = np.bincount(s_q, minlength=nq) if s_q.size else np.zeros(nq, np.int64)
+        nr = np.bincount(b_q, minlength=nq) if b_q.size else np.zeros(nq, np.int64)
+        rep_nr = np.repeat(nr, ns)  # aligned to s_rows
+        total = int(rep_nr.sum())
+        if total:
+            b_starts = np.cumsum(nr) - nr
+            base = np.repeat(b_starts[s_q], rep_nr)
+            csum = np.cumsum(rep_nr) - rep_nr
+            within = np.arange(total) - np.repeat(csum, rep_nr)
+            pa = np.repeat(s_rows, rep_nr)
+            pb = b_rows[base + within]
+            pq = np.repeat(s_q, rep_nr)
+            drop = t_int[pq] & (pa == pb)
+            if drop.any():
+                pa, pb, pq = pa[~drop], pb[~drop], pq[~drop]
+            # coverage: a miss on an uncertifiable pair is not a deny
+            cert = idx.certifiable(pa, pb)
+            if not cert.all():
+                fall_back("uncertifiable", np.unique(pq[~cert]))
+                keep = ~fallback[pq]
+                pa, pb, pq = pa[keep], pb[keep], pq[keep]
+                whole = rides_whole()
+    if whole:
+        fall_back("whole_slice", ~fallback)
+        pa = pb = pq = np.zeros(0, np.int64)
+    return fallback, reasons, whole, (pa, pb, pq)
+
+
+def put_pairs(entries: np.ndarray, P: int, pairs, ni: int) -> None:
+    """``label_pairs``'s pairs into ``label_step``'s int32[3 * P] buffer:
+    pa, pb, pq at 0, P, 2 * P, padded (ni, ni, 0)."""
+    pa, pb, pq = pairs
+    pad = P - pa.size
+    entries[:P] = np.concatenate([pa, np.full(pad, ni, np.int64)])
+    entries[P : 2 * P] = np.concatenate([pb, np.full(pad, ni, np.int64)])
+    entries[2 * P :] = np.concatenate([pq, np.zeros(pad, np.int64)])

@@ -1932,14 +1932,36 @@ class Registry:
         def native_pack_paths():
             from keto_tpu.check.native_pack import COUNTERS
 
-            return [((p,), float(COUNTERS.get(p, 0))) for p in ("native", "numpy")]
+            return [((p,), float(COUNTERS.get(p, 0))) for p in ("fused", "native", "numpy")]
 
         m.register_callback(
             "keto_native_pack_chunks_total", "counter",
-            "Check chunks packed per host-walk path: native (GIL-released "
-            "C++ walk, native/pack.cpp) vs numpy (library absent/disabled, "
-            "or the snapshot carries host-visible overlay state).",
+            "Check chunks packed per path (native/pack.cpp): fused (the label "
+            "route's walk, routing and pairing as one GIL-released pass), "
+            "native (pack_chunk with its host walk and sink gather native: "
+            "the BFS route, and label-route chunks the fused pass declined "
+            "for multi or mesh) vs numpy (library absent/disabled, or the "
+            "snapshot carries host-visible overlay state).",
             native_pack_paths, ("path",),
+        )
+
+        def pack_declines():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((reason,), float(counters.get(f"pack_declines_{reason}", 0)))
+                for reason in ("no_library", "mesh", "overlay", "multi")
+            ]
+
+        m.register_callback(
+            "keto_check_pack_declines_total", "counter",
+            "Label-route chunks the fused native pass did not pack, once a "
+            "chunk by the first cause found: no_library (absent, stale or "
+            "disabled), mesh (the sharded kernels route entries themselves), "
+            "overlay (host-visible overlay state: tombstones, overlay "
+            "adjacency or sink in-edges), multi (a wildcard or multi-start "
+            "query in the chunk). Such a chunk takes pack_chunk and the numpy "
+            "pairing: the same answers.",
+            pack_declines, ("reason",),
         )
 
         # /check/batch query frames (keto_tpu/check/frame.py): how often

@@ -1,4 +1,27 @@
-// Native batch-setup pack walk: the host side of the check hot path.
+// Native pack: the host side of the check hot path, behind a C ABI
+// (keto_tpu/check/native_pack.py binds it with ctypes, which releases the
+// GIL for every call). ABI version 3. Two generations live here:
+//
+//  1. keto_pack_walk / keto_sink_gather / keto_pairs_member: the pieces of
+//     keto_tpu/check/pack.py's pack_chunk and device_part that were worth a
+//     native call by themselves, each over a whole chunk's frontier with
+//     numpy passes between them (the BFS route, the sharded riders, every
+//     chunk the pass below declines);
+//  2. keto_pack_labeled (+ _pairs, _riders; at the end of this file): the
+//     label route's whole `pack` as ONE call, query by query: the host walk
+//     with a visited stamp a row instead of a chunk-wide hash set, the seed
+//     rows, the sink target's answer or relay rows, the route's decisions
+//     (self_hit, pair_cap, uncertifiable, whole_slice), the certified pairs,
+//     copied by the second call straight into the staging lease label_step
+//     ships, and by the third pack_chunk's seven arrays for the queries that
+//     fell back. What it must equal, set for set, is pack_chunk followed by
+//     pack.py's label_pairs (tests/test_pack_fused.py fuzzes it on the graph
+//     shapes the benchmark serves). The caller declines it, by what it
+//     observes and never by a setting, for: no (or a stale) library, a mesh,
+//     host-visible overlay state, a wildcard or multi-start query in the
+//     chunk (check/dispatch.py _fused_decline).
+//
+// The rest of this header is the first generation's contract.
 //
 // keto_tpu/check/pack.py:pack_chunk expands host-propagated starts
 // (static, peeled-interior, overlay nodes) through the forward CSR until
@@ -44,6 +67,7 @@
 // Ownership of result handles stays with the caller (keto_pack_free /
 // keto_gather_free).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
@@ -156,12 +180,49 @@ int pack_threads() {
 // frontier work below this many gathered neighbors stays serial
 constexpr int64_t kParallelThreshold = 1 << 16;
 
+// What one keto_pack_labeled call leaves for the two fetch calls that
+// follow it on the same thread: every query's seed rows and answer rows
+// (a CSR by query), its target, whether it left the label route, and the
+// pairs of those that did not. One a thread, reused from chunk to chunk:
+// nothing is allocated once the vectors have grown to a chunk's size.
+struct LabeledScratch {
+    std::vector<int32_t> seed_rows, ans_rows;  // by query, seed_off / ans_off
+    std::vector<int64_t> seed_off, ans_off;    // [nq + 1]
+    std::vector<int32_t> targets;              // [nq]: interior target or ni
+    std::vector<uint8_t> e1;                   // [nq]: the seed is the start itself
+    std::vector<uint8_t> fallback;             // [nq]
+    std::vector<int32_t> pa, pb, pq;
+    std::vector<int64_t> stack;
+    // stamp[row] == epoch: this query's walk has been at row (< sink_base)
+    std::vector<uint32_t> stamp;
+    uint32_t epoch = 0;
+    int64_t nq = 0, ni = 0;
+};
+
+thread_local LabeledScratch labeled_scratch;
+
 }  // namespace
+
+// A snapshot's arrays as keto_pack_labeled reads them: filled once a
+// snapshot by the binding (native_pack.PackView), which keeps the arrays
+// alive. hub_ptr is null where answer entries may not name relay rows.
+struct KetoPackView {
+    const int64_t* fwd_indptr;
+    const int32_t* fwd_indices;
+    const int64_t* sink_indptr;
+    const int32_t* sink_indices;
+    const int64_t* hub_ptr;
+    const int64_t* hub_rows;
+    const uint8_t* out_ok;
+    const uint8_t* in_ok;
+    const uint8_t* processed;
+    int64_t n_base, ni, sb, nl, n_lab, pair_cap;
+};
 
 extern "C" {
 
 // ABI version probe: the Python binding refuses a stale .so.
-int64_t keto_pack_version() { return 2; }
+int64_t keto_pack_version() { return 3; }
 
 void* keto_pack_walk(
     const int64_t* fwd_indptr, const int32_t* fwd_indices, int64_t n_base,
@@ -326,6 +387,277 @@ void keto_pairs_member(const int32_t* set_rows, const int32_t* set_q,
             j = (j + 1) & set.mask;
         }
         out[i] = hit;
+    }
+}
+
+// The label route's whole pack of one chunk [i0, i1) of a resolved batch,
+// query by query (check/dispatch.py _device_batch_labeled; pack_chunk and
+// the numpy pairing are the contract, set for set):
+//
+//  - the host walk from a host-propagated start, a visited stamp a row in
+//    place of a hash set; a traversed edge landing on the target grants;
+//    rows < ni seed the device (e2), the start itself where it is one (e1);
+//  - a sink target's answer rows from the sink reverse CSR, or its relay
+//    rows (ni + 1 + k) where the view has them;
+//  - the route, first cause wins: self_hit (an e1 seed that is the interior
+//    target), pair_cap (seeds x target-side rows over the cap, or a relay
+//    row), then, unless the fallbacks so far fill a sub-batch as wide as the
+//    slice (n_fb >= whole_min, the caller's rule), the cross-join with the
+//    e2-seed == target pair dropped and every pair certified
+//    (out_ok[a] & in_ok[b] & (processed[a] | processed[b])): one miss and
+//    the query falls back (uncertifiable); whole_slice for the rest where
+//    the fallbacks then fill the slice.
+//
+// host_ans and fallback are uint8[nq]; counts is int64[13]:
+//   0 any seed at all (pack_chunk's packed is not None)   1 pairs
+//   2 queries fallen back   3 the whole slice rides
+//   4-7 self_hit, pair_cap, uncertifiable, whole_slice
+//   8-9 seed rows and target-side rows of the chunk (a relay row counts
+//       the rows it holds)   10-12 e1, e2 and answer entries of the riders
+// The pairs and the riders' entries stay in this thread's scratch for
+// keto_pack_labeled_pairs / keto_pack_labeled_riders.
+void keto_pack_labeled(const KetoPackView* v, const int64_t* sd,
+                       const int64_t* tg, int64_t i0, int64_t i1,
+                       int64_t whole_min, uint8_t* host_ans,
+                       uint8_t* fallback_out, int64_t* counts) {
+    LabeledScratch& S = labeled_scratch;
+    const int64_t nq = i1 - i0, ni = v->ni, sb = v->sb, nl = v->nl;
+    const int64_t n_base = v->n_base, n_lab = v->n_lab;
+    const int64_t* const fwd_indptr = v->fwd_indptr;
+    const int32_t* const fwd_indices = v->fwd_indices;
+    const int64_t* const sink_indptr = v->sink_indptr;
+    const int32_t* const sink_indices = v->sink_indices;
+    const int64_t* const hub_ptr = v->hub_ptr;
+    sd += i0;
+    tg += i0;
+    S.nq = nq;
+    S.ni = ni;
+    S.seed_rows.clear();
+    S.ans_rows.clear();
+    S.seed_off.resize((size_t)nq + 1);
+    S.ans_off.resize((size_t)nq + 1);
+    S.targets.resize((size_t)nq);
+    S.e1.assign((size_t)nq, 0);
+    S.fallback.assign((size_t)nq, 0);
+    if ((int64_t)S.stamp.size() != sb) {
+        S.stamp.assign((size_t)sb, 0);
+        S.epoch = 0;
+    }
+    uint32_t* const stamp = S.stamp.data();
+    std::vector<int32_t>& seeds = S.seed_rows;
+    std::vector<int32_t>& answers = S.ans_rows;
+    std::vector<int64_t>& stack = S.stack;
+    for (int k = 0; k < 13; ++k) counts[k] = 0;
+    int64_t n_fb = 0, target_rows = 0, pairs_most = 0;
+
+    // the walks are 4,096 short chains of dependent cache misses: the first
+    // two links of a later query's chain are asked for while this one walks
+    constexpr int64_t kAhead = 8;
+    auto prefetch_rows = [&](int64_t li) {
+        const int64_t s = sd[li], t = tg[li];
+        if (s >= ni && s < n_base) __builtin_prefetch(fwd_indptr + s);
+        if (t >= sb && t < nl) __builtin_prefetch(sink_indptr + (t - sb));
+    };
+    auto prefetch_lists = [&](int64_t li) {
+        const int64_t s = sd[li], t = tg[li];
+        if (s >= ni && s < n_base) __builtin_prefetch(fwd_indices + fwd_indptr[s]);
+        if (t >= sb && t < nl) __builtin_prefetch(sink_indices + sink_indptr[t - sb]);
+    };
+    for (int64_t li = 0; li < nq && li < kAhead; ++li) prefetch_rows(li);
+    for (int64_t li = 0; li < nq && li < kAhead / 2; ++li) prefetch_lists(li);
+
+    for (int64_t li = 0; li < nq; ++li) {
+        if (li + kAhead < nq) prefetch_rows(li + kAhead);
+        if (li + kAhead / 2 < nq) prefetch_lists(li + kAhead / 2);
+        const int64_t s = sd[li], t = tg[li];
+        const bool t_int = t >= 0 && t < ni;
+        S.targets[(size_t)li] = (int32_t)(t_int ? t : ni);
+        const int64_t seed0 = (int64_t)seeds.size(), ans0 = (int64_t)answers.size();
+        S.seed_off[(size_t)li] = seed0;
+        S.ans_off[(size_t)li] = ans0;
+        bool has_start = false, hit = false, e1 = false;
+        if (s >= 0 && s < ni) {
+            has_start = e1 = true;
+            S.e1[(size_t)li] = 1;
+            seeds.push_back((int32_t)s);
+        } else if ((s >= ni && s < sb) || s >= nl) {
+            has_start = true;
+            if (++S.epoch == 0) {  // wrapped: old stamps could read as new
+                std::fill(S.stamp.begin(), S.stamp.end(), 0u);
+                S.epoch = 1;
+            }
+            const uint32_t ep = S.epoch;
+            if (s < sb) stamp[s] = ep;
+            stack.clear();
+            stack.push_back(s);
+            while (!stack.empty()) {
+                const int64_t row = stack.back();
+                stack.pop_back();
+                if (row >= n_base) continue;  // overlay id: no base out-edges
+                for (int64_t e = fwd_indptr[row], end = fwd_indptr[row + 1]; e < end; ++e) {
+                    const int64_t nbr = fwd_indices[e];
+                    if (nbr == t) hit = true;
+                    if (nbr >= sb || stamp[nbr] == ep) continue;
+                    stamp[nbr] = ep;
+                    if (nbr < ni) {
+                        seeds.push_back((int32_t)nbr);
+                    } else {
+                        __builtin_prefetch(fwd_indptr + nbr);
+                        stack.push_back(nbr);
+                    }
+                }
+            }
+        }
+        host_ans[li] = hit;
+        bool relay = false;
+        if (has_start && t >= sb && t < nl) {
+            const int64_t sink = t - sb;
+            if (hub_ptr && hub_ptr[sink + 1] > hub_ptr[sink]) {
+                relay = true;
+                for (int64_t k = hub_ptr[sink]; k < hub_ptr[sink + 1]; ++k) {
+                    answers.push_back((int32_t)(ni + 1 + k));
+                    target_rows += v->hub_rows[k];
+                }
+            } else {
+                const int64_t lo = sink_indptr[sink], hi = sink_indptr[sink + 1];
+                answers.insert(answers.end(), sink_indices + lo, sink_indices + hi);
+                target_rows += hi - lo;
+            }
+        }
+        const int64_t n_s = (int64_t)seeds.size() - seed0;
+        const int64_t n_r = (int64_t)answers.size() - ans0 + (t_int ? 1 : 0);
+        target_rows += t_int ? 1 : 0;
+        bool fb = false;
+        if (e1 && t_int && s == t) {
+            fb = true;
+            ++counts[4];
+        }
+        if (n_s * n_r > v->pair_cap || relay) {
+            if (!fb) ++counts[5];
+            fb = true;
+        }
+        if (fb) {
+            S.fallback[(size_t)li] = 1;
+            ++n_fb;
+        } else {
+            pairs_most += n_s * n_r;
+        }
+    }
+    S.seed_off[(size_t)nq] = (int64_t)seeds.size();
+    S.ans_off[(size_t)nq] = (int64_t)answers.size();
+    counts[8] = (int64_t)seeds.size();
+    counts[9] = target_rows;
+    counts[0] = counts[8] > 0;
+
+    bool whole = n_fb >= whole_min;
+    int64_t n_pairs = 0;
+    if (!whole && counts[0]) {
+        S.pa.resize((size_t)pairs_most);
+        S.pb.resize((size_t)pairs_most);
+        S.pq.resize((size_t)pairs_most);
+        int32_t* const pa = S.pa.data();
+        int32_t* const pb = S.pb.data();
+        int32_t* const pq = S.pq.data();
+        const uint8_t* const out_ok = v->out_ok;
+        const uint8_t* const in_ok = v->in_ok;
+        const uint8_t* const processed = v->processed;
+        const int32_t* const seed_rows = seeds.data();
+        for (int64_t li = 0; li < nq; ++li) {
+            if (S.fallback[(size_t)li]) continue;
+            const int32_t t = S.targets[(size_t)li];
+            const bool t_int = t < ni;
+            const int32_t* rows = t_int ? &t : answers.data() + S.ans_off[(size_t)li];
+            const int64_t n_r =
+                t_int ? 1 : S.ans_off[(size_t)li + 1] - S.ans_off[(size_t)li];
+            const int64_t mark = n_pairs;
+            bool cert = true;
+            for (int64_t k = S.seed_off[(size_t)li]; cert && k < S.seed_off[(size_t)li + 1]; ++k) {
+                const int32_t a = seed_rows[k];
+                const bool a_lab = a < n_lab;
+                const bool a_ok = a_lab && out_ok[a], a_done = a_lab && processed[a];
+                for (int64_t j = 0; j < n_r; ++j) {
+                    const int32_t b = rows[j];
+                    // an e2 seed that is the target was reached over a real
+                    // edge: the host granted it, reach0 would count 0 edges
+                    if (t_int && a == b) continue;
+                    if (a_lab && b < n_lab
+                        && !(a_ok && in_ok[b] && (a_done || processed[b]))) {
+                        cert = false;
+                        break;
+                    }
+                    pa[n_pairs] = a;
+                    pb[n_pairs] = b;
+                    pq[n_pairs] = (int32_t)li;
+                    ++n_pairs;
+                }
+            }
+            if (!cert) {
+                n_pairs = mark;
+                S.fallback[(size_t)li] = 1;
+                ++n_fb;
+                ++counts[6];
+            }
+        }
+        whole = n_fb >= whole_min;
+    }
+    if (whole) {
+        counts[7] = nq - n_fb;
+        n_fb = nq;
+        std::fill(S.fallback.begin(), S.fallback.end(), (uint8_t)1);
+        n_pairs = 0;
+    }
+    S.pa.resize((size_t)n_pairs);
+    S.pb.resize((size_t)n_pairs);
+    S.pq.resize((size_t)n_pairs);
+    counts[1] = n_pairs;
+    counts[2] = n_fb;
+    counts[3] = whole;
+    for (int64_t li = 0; li < nq; ++li) {
+        fallback_out[li] = S.fallback[(size_t)li];
+        if (!S.fallback[(size_t)li]) continue;
+        const int64_t n_s = S.seed_off[(size_t)li + 1] - S.seed_off[(size_t)li];
+        counts[S.e1[(size_t)li] ? 10 : 11] += n_s;
+        counts[12] += S.ans_off[(size_t)li + 1] - S.ans_off[(size_t)li];
+    }
+}
+
+// The last keto_pack_labeled's pairs as label_step reads them: out is
+// int32[3 * P], pa at 0, pb at P, pq at 2 * P, padded (ni, ni, 0).
+// Returns their count, or -1 with nothing written where P is under it.
+int64_t keto_pack_labeled_pairs(int32_t* out, int64_t P) {
+    const LabeledScratch& S = labeled_scratch;
+    const size_t n = S.pa.size();
+    if ((int64_t)n > P) return -1;
+    const std::vector<int32_t>* parts[3] = {&S.pa, &S.pb, &S.pq};
+    for (int k = 0; k < 3; ++k) {
+        int32_t* dst = out + (int64_t)k * P;
+        if (n) std::memcpy(dst, parts[k]->data(), n * sizeof(int32_t));
+        std::fill(dst + n, dst + P, k < 2 ? (int32_t)S.ni : 0);
+    }
+    return (int64_t)n;
+}
+
+// The last keto_pack_labeled's riders as pack_chunk's seven arrays hold
+// them, unpadded: the entries of the queries that fell back, under their
+// positions in the chunk; targets is int32[B], ni past the chunk.
+void keto_pack_labeled_riders(int32_t* e1r, int32_t* e1q, int32_t* e2r,
+                              int32_t* e2q, int32_t* ar, int32_t* aq,
+                              int32_t* targets, int64_t B) {
+    const LabeledScratch& S = labeled_scratch;
+    std::memcpy(targets, S.targets.data(), (size_t)S.nq * sizeof(int32_t));
+    std::fill(targets + S.nq, targets + B, (int32_t)S.ni);
+    for (int64_t li = 0; li < S.nq; ++li) {
+        if (!S.fallback[(size_t)li]) continue;
+        int32_t*& rows = S.e1[(size_t)li] ? e1r : e2r;
+        int32_t*& qs = S.e1[(size_t)li] ? e1q : e2q;
+        for (int64_t k = S.seed_off[(size_t)li]; k < S.seed_off[(size_t)li + 1]; ++k) {
+            *rows++ = S.seed_rows[(size_t)k];
+            *qs++ = (int32_t)li;
+        }
+        for (int64_t k = S.ans_off[(size_t)li]; k < S.ans_off[(size_t)li + 1]; ++k) {
+            *ar++ = S.ans_rows[(size_t)k];
+            *aq++ = (int32_t)li;
+        }
     }
 }
 

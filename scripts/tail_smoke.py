@@ -16,7 +16,7 @@ machinery end to end:
    (sample rate 1.0) re-verifies served decisions with zero mismatches;
 3. native pack == numpy pack BYTE parity on the serving snapshot
    (every packed kernel array and host-decided grant), and the native
-   path actually ran (keto_native_pack_chunks_total{path="native"} > 0);
+   path actually ran (keto_native_pack_chunks_total, ``native`` or ``fused``);
 4. the staging ledger reconciles: the governor's ``staging`` tag equals
    the engine pool's own accounting, with zero outstanding leases after
    the workload drains;
@@ -192,7 +192,7 @@ def main() -> int:
         if not native_pack.available():
             problems.append("native pack library not available in the smoke")
         else:
-            if native_pack.COUNTERS["native"] == 0:
+            if native_pack.COUNTERS["native"] + native_pack.COUNTERS["fused"] == 0:
                 problems.append("native pack path never ran")
             snap = engine.snapshot()
             qs = [RelationTuple(namespace=t["namespace"], object=t["object"],
