@@ -72,12 +72,40 @@ _WILD = object()
 #: native-format record whose result is overwritten on the Python side
 _PLACEHOLDER = b"0\x1f\x1f\x1f1\x1f\x1f\x1f\x1e"
 _LANES = np.arange(32, dtype=np.uint32)
+#: what cut a resolved chunk into sub-chunks (``_dispatch_slices``)
+CHUNK_CUTS = ("none", "geometry", "budget")
 
 
 def _bits(words: np.ndarray, nq: int) -> np.ndarray:
     """A kernel's packed decisions, bit ``q % 32`` of word ``q // 32``, as
     ``bool[nq]``."""
     return ((words[:, None] >> _LANES) & 1).astype(bool).ravel()[:nq]
+
+
+def stream_chunk_metrics(m, counters_of) -> None:
+    """Declare ``_dispatch_slices``' families on ``m`` (driver/registry.py
+    calls this once); ``counters_of()`` is the serving engine's maintenance
+    counters, ``{}`` while there is no engine."""
+
+    def chunks():
+        counters = counters_of()
+        return [((cut,), float(counters.get(f"stream_chunks_{cut}", 0))) for cut in CHUNK_CUTS]
+
+    m.register_callback(
+        "keto_stream_chunks_total", "counter",
+        "Resolved chunks of check slices by what cut them into sub-chunks "
+        "before dispatch: none, geometry (their device entries pass the "
+        "geometric bound: 4 x B, or the pair cap x B on a snapshot of hub "
+        "rows), budget (whole under that bound, cut only because the slice "
+        "controller's entry budget lowered it).",
+        chunks, ("cut",),
+    )
+    m.register_callback(
+        "keto_stream_chunk_pieces_total", "counter",
+        "Sub-chunks those chunks were dispatched as, a whole chunk counting "
+        "one: over keto_stream_chunks_total, the slices a chunk costs.",
+        lambda: [((), float(counters_of().get("stream_chunk_pieces", 0)))],
+    )
 
 
 class CheckDispatch:
@@ -1086,11 +1114,14 @@ class CheckDispatch:
         clk = dispatch_clock()
         off = 0
         while True:
-            cap = min(bound, ctrl.cap()) if ctrl is not None else bound
+            rung = ctrl.cap() if ctrl is not None else bound
+            cap = min(bound, rung)
             clk.enter(RESOLVE)  # pulling the caller's tuples is part of it
             batch = take(cap)
             if not batch:
                 return
+            if ctrl is not None:
+                ctrl.count_take(rung, bound)
             if lockstep:
                 # per stream slice, BEFORE any dispatch (same contract
                 # as batch_check_with_token): divergence fails loudly
@@ -1464,6 +1495,7 @@ class CheckDispatch:
                 # bring that many entries a query before it is split, or a
                 # take would be a dozen hybrid slices of two launches each
                 cap_e = self._LABEL_PAIR_CAP * B
+            cap_geo = cap_e
             if not self._multiprocess:
                 # service-time-aware split bound (never below a quarter of
                 # the geometric bound, one B where that is 4·B — the floor
@@ -1473,7 +1505,8 @@ class CheckDispatch:
                 if budget is not None:
                     cap_e = min(cap_e, max(cap_e // 4, budget))
             cnt = self._entry_counts(snap, sd, tg, multi)
-            if int(cnt.sum()) > cap_e:
+            total = int(cnt.sum())
+            if total > cap_e:
                 reach = self._device_reach(snap)
                 if reach is not None:
                     # a query whose target side has no row that a pull
@@ -1481,7 +1514,8 @@ class CheckDispatch:
                     # its entries do not count towards a split
                     known = (tg >= 0) & (tg < snap.num_live)
                     cnt[known & ~reach[np.where(known, tg, 0)]] = 0
-            if int(cnt.sum()) <= cap_e:
+                    total = int(cnt.sum())
+            if total <= cap_e:
                 bounds = [(0, nq)]
             else:
                 csum = np.concatenate([np.zeros(1, np.int64), np.cumsum(cnt)])
@@ -1492,6 +1526,13 @@ class CheckDispatch:
                     i1 = max(i0 + 1, min(i1, nq))
                     bounds.append((i0, i1))
                     i0 = i1
+            # what cut the chunk: its entries pass the geometric bound, or
+            # only the cap the controller's entry budget lowered
+            cut = "none" if len(bounds) == 1 else (
+                "geometry" if total > cap_geo else "budget"
+            )
+            self.maintenance.incr(f"stream_chunks_{cut}")
+            self.maintenance.incr("stream_chunk_pieces", by=len(bounds))
             for a, b in bounds:
                 # sub-chunks keep the slice width: queries pad, geometry stays
                 if use_labels:

@@ -15,7 +15,8 @@ compiled and asks it before every launch:
   whose entries outgrew its width's rung rides a wider width's). The slice is
   padded to it with the sentinels the warm-up already uses and answers
   bit-identically. Where the program it rides is wider than its own, a
-  worker thread compiles the slice's own behind it, which the next slice of
+  worker thread compiles the slice's own behind it (under a profiler
+  session: a ``keto.geometry.compile`` span), which the next slice of
   those sizes launches as packed: what runs in the steady state is what an
   inline compile would have left, so a deployment that never leaves its
   warmed rung runs nothing else than before. A slice that rides a program of
@@ -42,6 +43,8 @@ import queue
 import threading
 from collections import Counter
 from typing import Callable, Optional
+
+from keto_tpu.x.profiling import SESSION
 
 _log = logging.getLogger("keto_tpu.check.geometry")
 
@@ -139,13 +142,27 @@ class KernelGeometries:
             if job is None:
                 return
             try:
-                if not self._closed and self._compile_fn(*job):
+                if not self._closed and self._compile(job):
                     self.add(*job)
             except Exception:
                 # slices of these sizes keep riding the wider program
                 _log.warning("background compile of %s %s failed", job[0], job[3], exc_info=True)
             with self._lock:
                 self._inflight -= 1
+
+    def _compile(self, job: tuple) -> bool:
+        """The worker's compile; while a profiler session is open, under a
+        ``keto.geometry.compile`` annotation on this thread that says which
+        program: a compile behind the window is then named in the capture,
+        not only seen as compiler internals."""
+        if not SESSION.open:
+            return self._compile_fn(*job)
+        kernel, shape, _fixed, sizes = job
+        with SESSION.annotation(
+            "keto.geometry.compile", kernel=kernel, shape=str(shape),
+            sizes="x".join(map(str, sizes)),
+        ):
+            return self._compile_fn(*job)
 
     def reset(self) -> None:
         """The compiled programs were dropped (warm-ladder eviction)."""

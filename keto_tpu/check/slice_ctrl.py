@@ -7,6 +7,14 @@ import threading
 from typing import Optional
 
 from keto_tpu.check.pack import _WORD_WIDTHS
+from keto_tpu.x.timeline import dispatch_clock
+
+#: what set the cap a take was offered (``keto_stream_take_cap_total{by}``)
+TAKE_CAP_BY = ("reactive", "model", "futile", "bound")
+#: what moved the controller (``keto_stream_ctrl_events_total{event}``)
+CTRL_EVENTS = ("narrow", "widen", "guard_down")
+#: the routes a slice is observed under (``_stream``'s ``land``)
+ROUTES = ("label", "hybrid", "bfs", "host", "cpu")
 
 
 class StreamSliceController:
@@ -54,6 +62,12 @@ class StreamSliceController:
     rung is served - a quarter of the width for the same wait is four
     times the work (``FUTILE_FRAC``). Slices that do not pull (the label
     route) leave no reading and are scheduled as before.
+
+    Every decision is counted where it is taken, under the lock it is
+    taken under: the rung a take was offered and which of the mechanisms
+    above set it (``count_take``), the readings that moved the controller
+    (``observe``). ``snapshot()`` carries the counts and the model's state;
+    ``stream_ctrl_metrics`` puts them on ``/metrics``.
     """
 
     #: widen when observed ms < WIDEN_FRAC · target, ``patience`` times in a row
@@ -94,7 +108,6 @@ class StreamSliceController:
         # first observations on a slow link land near the target
         self._i = max(self._lo, len(self._ladder) - 3)
         self._good = 0
-        self._ewma_ms_per_q: Optional[float] = None
         #: per-route cost model: route → {per_q, per_entry, bfs_steps,
         #: last_seen} (EWMAs; last_seen is a slice counter)
         self._routes: dict[str, dict] = {}
@@ -106,6 +119,13 @@ class StreamSliceController:
         self._guard = 1.0
         self._tail_p50 = 0.0
         self._tail_p99 = 0.0
+        #: which mechanism set ``cap()``'s last answer (a ``TAKE_CAP_BY``)
+        self._cap_by = "reactive"
+        #: takes by the rung they were offered, and by what set it
+        self._takes = {c: 0 for c in self._ladder[self._lo:]}
+        self._take_cap = dict.fromkeys(TAKE_CAP_BY, 0)
+        #: event -> route of the observed slice -> readings
+        self._events: dict[str, dict] = {e: {} for e in CTRL_EVENTS}
 
     def _recent_locked(self):
         horizon = self._slices - self.ROUTE_RECENCY
@@ -135,8 +155,11 @@ class StreamSliceController:
         buys no time (``FUTILE_FRAC``). Always a compiled ladder width."""
         with self._lock:
             cap = self._ladder[self._i]
+            by = "reactive"
             m = self._model_cap_locked()
             if m is not None:
+                if m < cap:
+                    by = "model"
                 cap = max(self._ladder[self._lo], min(cap, m))
             k = self._ladder.index(cap)
             horizon = self._slices - self.ROUTE_RECENCY
@@ -147,7 +170,21 @@ class StreamSliceController:
                 if wider[0] > self.target_ms or mine[0] < self.FUTILE_FRAC * wider[0]:
                     break
                 k += 1  # this rung's slices were no faster: serve the wider
+                by = "futile"
+            self._cap_by = by
             return self._ladder[k]
+
+    def count_take(self, rung: int, bound: int) -> None:
+        """The stream cut a take of ``min(bound, rung)`` off its source,
+        ``rung`` being what ``cap()`` just answered: count the rung and what
+        set it, ``bound`` where the caller's (the memory-derived slice cap, a
+        ``slice_cap``) was lower still. The batcher's own call of ``cap()``
+        sizes a round and a take that finds the source dry took nothing:
+        neither is counted. The reason is the one ``cap()`` left; a ``cap()``
+        of another thread between the two can mislabel a take, never lose it."""
+        with self._lock:
+            self._takes[rung] = self._takes.get(rung, 0) + 1
+            self._take_cap["bound" if bound < rung else self._cap_by] += 1
 
     def entry_budget(self) -> Optional[int]:
         """Device entries one sub-chunk may carry before its predicted
@@ -155,14 +192,16 @@ class StreamSliceController:
         bound ``_dispatch_slices`` applies. None before the model has an
         entry-cost estimate."""
         with self._lock:
-            recent = self._recent_locked()
-            per_e = max(
-                (st["per_entry"] for st in recent if st["per_entry"] > 0),
-                default=None,
-            )
-            if per_e is None:
-                return None
-            return max(256, int(self.target_ms * self._guard / per_e))
+            return self._entry_budget_locked()
+
+    def _entry_budget_locked(self) -> Optional[int]:
+        per_e = max(
+            (st["per_entry"] for st in self._recent_locked() if st["per_entry"] > 0),
+            default=None,
+        )
+        if per_e is None:
+            return None
+        return max(256, int(self.target_ms * self._guard / per_e))
 
     def observe(
         self,
@@ -185,7 +224,9 @@ class StreamSliceController:
         if nq <= 0:
             return
         per_q = ms / nq
+        events = []
         with self._lock:
+            before = self._ladder[self._i]
             self._slices += 1
             st = self._routes.get(route)
             if st is None:
@@ -228,11 +269,12 @@ class StreamSliceController:
             st["n"] += 1
             self._ring.append(ms)
             if self._slices % self.TAIL_EVERY == 0:
+                guard = self._guard
                 self._retune_tail_locked()
-            e = self._ewma_ms_per_q
-            self._ewma_ms_per_q = per_q if e is None else 0.7 * e + 0.3 * per_q
-            cap = self._ladder[self._i]
+                if self._guard < guard:
+                    events.append("guard_down")
             if ms > self.NARROW_FRAC * self.target_ms:
+                events.append("narrow")  # the reading a narrow regime starts from
                 want = self._lo
                 for k in range(self._i, self._lo - 1, -1):
                     if self._ladder[k] * per_q <= self.target_ms:
@@ -240,13 +282,25 @@ class StreamSliceController:
                         break
                 self._i = min(self._i, max(self._lo, want))
                 self._good = 0
-            elif ms < self.WIDEN_FRAC * self.target_ms and (nq >= cap or full_take):
+            elif ms < self.WIDEN_FRAC * self.target_ms and (nq >= before or full_take):
                 self._good += 1
                 if self._good >= self._patience and self._i + 1 < len(self._ladder):
                     self._i += 1
                     self._good = 0
+                    events.append("widen")
             else:
                 self._good = 0
+            for event in events:
+                by_route = self._events[event]
+                by_route[route] = by_route.get(route, 0) + 1
+            after = self._ladder[self._i]
+        for event in events:
+            # on the device trace's clock while a profiler session is open
+            # (the dispatch thread's clock; any other thread's is a no-op)
+            dispatch_clock().mark(
+                f"keto.ctrl.{event}", route=route, nq=nq, ms=round(ms, 3),
+                rung_before=before, rung_after=after,
+            )
 
     def _retune_tail_locked(self) -> None:
         vals = sorted(self._ring)
@@ -265,13 +319,18 @@ class StreamSliceController:
             self._guard = min(1.0, self._guard * 1.1)
 
     def snapshot(self) -> dict:
-        """Controller state for introspection (bench, /debug)."""
+        """The controller's state and counts: the one source of
+        ``stream_ctrl_metrics``' families, of bench.py and of
+        scripts/tail_smoke.py. ``cap`` is the reactive rung."""
         with self._lock:
             return {
                 "cap": self._ladder[self._i],
                 "target_ms": self.target_ms,
-                "ewma_ms_per_query": self._ewma_ms_per_q,
                 "model_cap": self._model_cap_locked(),
+                "entry_budget": self._entry_budget_locked(),
+                "takes": dict(self._takes),
+                "take_cap": dict(self._take_cap),
+                "events": {e: dict(n) for e, n in self._events.items()},
                 "tail_ratio": self.tail_ratio,
                 "tail_guard": self._guard,
                 "tail_p50_ms": round(self._tail_p50, 3),
@@ -286,3 +345,81 @@ class StreamSliceController:
                     for r, st in self._routes.items()
                 },
             }
+
+
+def stream_ctrl_metrics(m, snapshot_of) -> None:
+    """Declare the controller's families on ``m`` (driver/registry.py calls
+    this once). ``snapshot_of()`` is ``StreamSliceController.snapshot()`` of
+    the serving engine's controller, or None while there is none: every
+    family then reads 0 over the label set of a controller at its
+    defaults, so a window's delta is defined from the first scrape."""
+    idle = StreamSliceController().snapshot()
+
+    def rows(read):
+        return lambda: read(snapshot_of() or idle)
+
+    m.register_callback(
+        "keto_stream_takes_total", "counter",
+        "Takes of the check stream (check/dispatch.py _slices) by the ladder "
+        "rung the slice controller offered: 32 x the kernel word widths at "
+        "or above the controller's floor. With engine.batch_size 4096 a "
+        "round is bound by the controller at rung 2048 alone.",
+        rows(lambda s: [((str(r),), float(n)) for r, n in s["takes"].items()]),
+        ("rung",),
+    )
+    m.register_callback(
+        "keto_stream_take_cap_total", "counter",
+        "Takes of the check stream by what set their cap: reactive (the "
+        "controller's ladder index), model (the predicted-service-time "
+        "width was below it), futile (a wider rung served because pulling "
+        "slices of the narrower took half its time or more), bound (the "
+        "memory-derived slice cap or the caller's was lower still).",
+        rows(lambda s: [((by,), float(n)) for by, n in s["take_cap"].items()]),
+        ("by",),
+    )
+    m.register_callback(
+        "keto_stream_ctrl_events_total", "counter",
+        "Slice readings that moved the controller, by the observed slice's "
+        "route: narrow (over NARROW_FRAC x the target: the reading a narrow "
+        "regime starts from), widen (the reactive index went up), guard_down "
+        "(the tail guard was halved).",
+        rows(lambda s: [
+            ((e, r), float(s["events"][e].get(r, 0))) for e in CTRL_EVENTS for r in ROUTES
+        ]),
+        ("event", "route"),
+    )
+    def gauge(key):
+        return rows(lambda s: [((), float(s[key] or 0))])
+
+    m.register_callback(
+        "keto_stream_ctrl_rung", "gauge",
+        "The slice controller's reactive ladder rung (queries a slice).",
+        gauge("cap"),
+    )
+    m.register_callback(
+        "keto_stream_ctrl_model_cap", "gauge",
+        "The widest rung whose predicted service time fits the target, over "
+        "the routes seen lately; 0 before any reading.",
+        gauge("model_cap"),
+    )
+    m.register_callback(
+        "keto_stream_ctrl_tail_guard", "gauge",
+        "The tail guard: 1 at rest, halved (to 0.25 at the least) while the "
+        "slices' p99/p50 is over serve.stream_tail_ratio.",
+        gauge("tail_guard"),
+    )
+    m.register_callback(
+        "keto_stream_ctrl_entry_budget", "gauge",
+        "Device entries a sub-chunk may carry before the model predicts it "
+        "over the target; 0 before an entry-cost reading.",
+        gauge("entry_budget"),
+    )
+    m.register_callback(
+        "keto_stream_ctrl_route_ms_per_query", "gauge",
+        "The model's cost of a query by route (asymmetric EWMA of a slice's "
+        "ms over its queries); 0 for a route not seen.",
+        rows(lambda s: [
+            ((r,), float(s["routes"].get(r, {}).get("per_q_ms", 0.0))) for r in ROUTES
+        ]),
+        ("route",),
+    )

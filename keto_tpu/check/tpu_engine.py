@@ -531,10 +531,13 @@ class TpuCheckEngine:
         if wm is not None and snap.snapshot_id >= wm:
             self._behind_since = None
             return 0.0
-        if self._behind_since is None:
-            self._behind_since = now
+        # read once: another poller that finds the snapshot current clears
+        # the attribute between a test of it and a use of it
+        since = self._behind_since
+        if since is None:
+            since = self._behind_since = now
         self._kick_background_refresh()
-        return now - self._behind_since
+        return now - since
 
     def health(self) -> dict:
         """Live inputs for the health state machine

@@ -286,6 +286,9 @@ class CheckBatcher:
         #: whether the round taken last carried interactive work (it may
         #: still be on the device); the collector thread's alone
         self._inter_rode = False
+        #: the room the round taken last had for batch-lane work and what
+        #: set it (``_take_locked``); the collector thread's alone
+        self._round_room = (batch_size, "batch_size")
         self.admission = admission
         self._cond = threading.Condition()  # guards: _lanes, _lane_tuples, _current_round, shed_count, shed_by_lane, admission_shed_count
         self._lanes: dict[str, deque] = {lane: deque() for lane in LANES}
@@ -791,9 +794,9 @@ class CheckBatcher:
         # this one's launch. A quiet lane lets the round fill: the thread
         # pays most of a round per round, not per tuple
         busy = bool(segments or inter)
-        batch_cap = cap - n
-        if busy or self._inter_rode:
-            batch_cap = min(batch_cap, self._sub_slice)
+        batch_cap, cap_by = cap - n, "batch_size"
+        if (busy or self._inter_rode) and self._sub_slice < batch_cap:
+            batch_cap, cap_by = self._sub_slice, "sub_slice"
         self._inter_rode = busy
         # service-time-aware sub-slicing: the engine's slice controller
         # predicts how many queries fit one target-latency slice for the
@@ -806,7 +809,12 @@ class CheckBatcher:
             getattr(self._engine, "stream_ctrl", None), "cap", None
         )
         if cap_fn is not None:
-            batch_cap = min(batch_cap, max(1, int(cap_fn())))
+            ctrl_cap = max(1, int(cap_fn()))
+            if ctrl_cap < batch_cap:
+                batch_cap, cap_by = ctrl_cap, "controller"
+        # the round's room and what set it: counted and carried on the
+        # round's spans once it is launched (``DispatchClock.round``)
+        self._round_room = (batch_cap, cap_by)
         while batchq and batch_cap > 0:
             head = batchq[0]
             if head.fut.done():
@@ -889,7 +897,7 @@ class CheckBatcher:
         if self.admission is not None:
             self.admission.tick(backlog=backlog)
         round_ = _Round(segments, self._expire)
-        clock.round(round_.n_tuples, backlog, overlapped)
+        clock.round(round_.n_tuples, backlog, overlapped, *self._round_room)
         clock.enter(RESOLVE)
         t0 = time.monotonic()
         is_open = False

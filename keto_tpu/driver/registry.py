@@ -979,6 +979,9 @@ class Registry:
             pool = self.peek("tenants")
             if pool is not None:
                 b.on_shed = pool.note_shed
+            # declared with the registry (x/timeline.py
+            # dispatch_clock_metrics); None when metrics are off
+            b.clock.long_stays = self.metrics().family("keto_dispatch_long_stay_seconds")
             b.start()
             return b
 
@@ -1895,7 +1898,7 @@ class Registry:
         # streaming slice scheduler: per-route landing counts, the
         # observed tail ratio the service-time controller guards, and
         # which pack path (native C++ vs numpy) built each chunk
-        STREAM_ROUTES = ("label", "hybrid", "bfs", "host", "cpu")
+        from keto_tpu.check.slice_ctrl import ROUTES as STREAM_ROUTES
 
         def route_slices():
             engine = self.peek("permission_engine")
@@ -1963,6 +1966,20 @@ class Registry:
             "pairing: the same answers.",
             pack_declines, ("reason",),
         )
+
+        # what the slice controller, the stream and the dispatch clock
+        # decided, declared by the modules that count it
+        from keto_tpu.check.dispatch import stream_chunk_metrics
+        from keto_tpu.check.slice_ctrl import stream_ctrl_metrics
+        from keto_tpu.x.timeline import dispatch_clock_metrics
+
+        def stream_ctrl_snapshot():
+            ctrl = getattr(self.peek("permission_engine"), "stream_ctrl", None)
+            return ctrl.snapshot() if ctrl is not None else None
+
+        stream_ctrl_metrics(m, stream_ctrl_snapshot)
+        stream_chunk_metrics(m, lambda: maintenance_raw()[0])
+        dispatch_clock_metrics(m, batcher_clock)
 
         # /check/batch query frames (keto_tpu/check/frame.py): how often
         # the framed path engages, and how often a frame's tuples had to

@@ -211,6 +211,11 @@ class _Histogram:
         self._lock = lock
         self._children: dict[tuple, _HistChild] = {}
 
+    def seed(self, labels: tuple = ()) -> None:
+        """Export ``labels`` from now on, at 0 until something is observed."""
+        with self._lock:
+            self._children.setdefault(labels, _HistChild(len(self.buckets) + 1))
+
     def observe(self, labels: tuple = (), value: float = 0.0, trace_id: str = "") -> None:
         i = bisect.bisect_left(self.buckets, value)
         with self._lock:
@@ -399,6 +404,9 @@ class _NullInstrument:
         pass
 
     def observe(self, labels=(), value=0.0, trace_id=""):
+        pass
+
+    def seed(self, labels=()):
         pass
 
 

@@ -76,6 +76,14 @@ DISPATCH_STATES = (
 WAIT_WORK, TAKE, RESOLVE, PACK, LAUNCH, DEVICE_WAIT, FILL = range(7)
 _SPAN_NAMES = tuple(f"keto.dispatch.{s}" for s in DISPATCH_STATES)
 
+#: what set the room a round had for batch-lane work (driver/batch.py
+#: ``_take_locked``): the round's own size, the sub-slice while
+#: interactive work is about, or the slice controller's cap
+ROUND_CAP_BY = ("batch_size", "sub_slice", "controller")
+#: a stay in one state at least this long is a long stay: several times the
+#: longest state of a wide round (1-7 ms)
+LONG_STAY_S = 0.016
+
 #: cap on stamps one timeline may hold — a 64k-tuple batch riding many
 #: sub-slices must not grow an unbounded stamp list (the flag records
 #: that the tail was dropped, the ring stays bounded either way)
@@ -105,15 +113,21 @@ class DispatchClock:
     While a ``jax.profiler`` session is open (``session.open``, read
     once per loop pass in ``idle``) each state is also a
     ``TraceAnnotation`` named ``keto.dispatch.<state>`` carrying the
-    round's ``tuples``, ``slices`` launched so far and ``lane_depth`` (a
-    ``launch`` also its slice's ``route``, ``kernel`` and ``geometry``: the
-    two launches of a ``hybrid`` slice differ in ``kernel``):
+    round's ``tuples``, ``slices`` launched so far, ``lane_depth``, and the
+    room it had for batch-lane work with what set it, ``cap`` and ``cap_by``
+    (a ``launch`` also its slice's ``route``, ``kernel`` and ``geometry``:
+    the two launches of a ``hybrid`` slice differ in ``kernel``):
     contiguous spans on the device trace's clock, so an idle gap of the
-    device is named by what its one feeder was doing."""
+    device is named by what its one feeder was doing, and a gap under a
+    narrowed round says so (``wait_work`` and ``take`` come before the
+    round is known: they say the one before's). ``mark`` writes a
+    zero-length annotation there (the slice controller's events,
+    ``keto.ctrl.<event>``)."""
 
     __slots__ = (
-        "seconds", "rounds", "overlapped", "round_tuples", "_state", "_t", "_session",
-        "_tracing", "_ann", "_tuples", "_slices", "_lane_depth", "_probes",
+        "seconds", "rounds", "overlapped", "round_tuples", "round_cap", "long_stays",
+        "_state", "_t", "_session", "_tracing", "_ann", "_tuples", "_slices",
+        "_lane_depth", "_cap", "_cap_by", "_probes",
     )
 
     def __init__(self, session=None):
@@ -125,13 +139,19 @@ class DispatchClock:
         self.overlapped = 0
         #: the tuples those rounds took off the lanes, all together
         self.round_tuples = 0
+        #: those rounds by what set their room, a ``ROUND_CAP_BY``
+        self.round_cap = dict.fromkeys(ROUND_CAP_BY, 0)
+        #: ``keto_dispatch_long_stay_seconds`` once the registry attached
+        #: it (``dispatch_clock_metrics``); None: long stays are not kept
+        self.long_stays = None
         self._probes: list = []
         self._state = WAIT_WORK
         self._t = time.perf_counter()
         self._session = session
         self._tracing = False
         self._ann = None
-        self._tuples = self._slices = self._lane_depth = 0
+        self._tuples = self._slices = self._lane_depth = self._cap = 0
+        self._cap_by = ROUND_CAP_BY[0]
 
     def enter(self, state: int, note=None) -> None:
         """``note``, on a ``launch``: ``(route, kernel, sizes, how the slice
@@ -139,7 +159,10 @@ class DispatchClock:
         now = time.perf_counter()
         # _t moves first: snapshot() retries when it sees _t change
         t, self._t = self._t, now
-        self.seconds[self._state] += now - t
+        stay = now - t
+        self.seconds[self._state] += stay
+        if stay >= LONG_STAY_S and self.long_stays is not None:
+            self.long_stays.observe((DISPATCH_STATES[self._state],), stay)
         self._state = state
         if self._tracing or self._ann is not None:
             self._annotate(state, note)
@@ -168,16 +191,29 @@ class DispatchClock:
         self._tracing = self._session.open
         self.enter(WAIT_WORK)
 
-    def round(self, tuples: int, lane_depth: int, overlapped: bool = False) -> None:
+    def mark(self, name: str, **attrs) -> None:
+        """A zero-length annotation on this thread, while a session is open."""
+        if self._tracing:
+            with self._session.annotation(name, **attrs):
+                pass
+
+    def round(
+        self, tuples: int, lane_depth: int, overlapped: bool = False,
+        cap: int = 0, cap_by: str = ROUND_CAP_BY[0],
+    ) -> None:
         """A round was taken: what its spans will say of it.
-        ``overlapped``: another round is open (launched, not landed)."""
+        ``overlapped``: another round is open (launched, not landed);
+        ``cap``, ``cap_by``: the room it had for batch-lane work and which
+        ``ROUND_CAP_BY`` set it."""
         self.rounds += 1
         self.overlapped += overlapped
         self.round_tuples += tuples
+        self.round_cap[cap_by] += 1
         # ``idle`` is not passed while rounds follow each other without a
         # gap: a profiler session opened under load is seen here
         self._tracing = self._session.open
         self._tuples, self._slices, self._lane_depth = tuples, 0, lane_depth
+        self._cap, self._cap_by = cap, cap_by
 
     def _annotate(self, state: int, note=None) -> None:
         if self._ann is not None:
@@ -196,7 +232,7 @@ class DispatchClock:
                 }
             self._ann = self._session.annotation(
                 _SPAN_NAMES[state], tuples=self._tuples, slices=self._slices,
-                lane_depth=self._lane_depth, **attrs,
+                lane_depth=self._lane_depth, cap=self._cap, cap_by=self._cap_by, **attrs,
             )
             self._ann.__enter__()
 
@@ -227,6 +263,9 @@ class _NoClock:
     def poll(self) -> None:
         pass
 
+    def mark(self, name: str, **attrs) -> None:
+        pass
+
 
 _NO_CLOCK = _NoClock()
 _dispatch = threading.local()
@@ -242,6 +281,39 @@ def dispatch_clock():
     """The calling thread's state clock — a no-op one off the dispatch
     thread (library callers, warm-up, explain)."""
     return getattr(_dispatch, "clock", None) or _NO_CLOCK
+
+
+def dispatch_clock_metrics(m, clock_of):
+    """Declare the families a ``DispatchClock`` keeps beside its states
+    (driver/registry.py calls this once; ``clock_of()`` is the serving
+    batcher's clock, or None while there is none). Returns the long-stay
+    histogram, for that clock's ``long_stays``."""
+
+    def round_cap():
+        clock = clock_of()
+        counts = clock.round_cap if clock is not None else {}
+        return [((by,), float(counts.get(by, 0))) for by in ROUND_CAP_BY]
+
+    m.register_callback(
+        "keto_dispatch_round_cap_total", "counter",
+        "Dispatch rounds by what set the room they had for batch-lane work: "
+        "batch_size (the round's own size), sub_slice (serve.batch_sub_slice, "
+        "while interactive work is about), controller (the slice "
+        "controller's cap was the least: a narrowed round). The values sum "
+        "to keto_dispatch_rounds_total.",
+        round_cap, ("by",),
+    )
+    hist = m.histogram(
+        "keto_dispatch_long_stay_seconds",
+        "Stays of the dispatch thread in one state of 16 ms or more (several "
+        "times the longest state of a wide round), by state: _sum is the time "
+        "they took. Outside wait_work a stall of the host, a compile on the "
+        "thread or a slow device; in wait_work, callers that sent nothing.",
+        ("state",), buckets=(0.064, 0.256, 1.024),
+    )
+    for state in DISPATCH_STATES:
+        hist.seed((state,))
+    return hist
 
 
 class Timeline:
@@ -523,6 +595,7 @@ __all__ = [
     "LISTENER_STAGES",
     "DISPATCH_STATES",
     "DispatchClock",
+    "dispatch_clock_metrics",
     "bind_dispatch_clock",
     "dispatch_clock",
     "MAX_STAMPS",

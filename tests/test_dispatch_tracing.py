@@ -166,7 +166,9 @@ def test_annotations_follow_the_session_flag(monkeypatch):
         for state in DISPATCH_STATES:
             assert f"keto.dispatch.{state}" in names
         launch = next(args for name, args in session.made if name.endswith(".launch"))
-        assert launch == {"tuples": 32, "slices": 1, "lane_depth": 0}
+        assert launch == {
+            "tuples": 32, "slices": 1, "lane_depth": 0, "cap": 8192, "cap_by": "batch_size",
+        }
         session.open = False
         time.sleep(0.3)
         made = len(session.made)
@@ -221,6 +223,102 @@ def test_the_two_launches_of_a_hybrid_slice_differ_in_their_spans_kernel():
     assert [a["slices"] for a in launches] == [1, 2]
     assert launches[1]["geometry"] == "check_step 2048x8192x8192x2048 padded_up"
     assert all("kernel" not in args for name, args in session.made if not name.endswith(".launch"))
+
+
+def test_a_rounds_spans_carry_its_room_and_what_set_it():
+    """``round()`` takes the round's ``cap`` and ``cap_by``: every span of the
+    round says them, so an idle gap of the device under a narrowed round is
+    seen to be one."""
+    session = FakeSession()
+    session.open = True
+    clock = DispatchClock(session)
+    clock.round(2048, 6000, cap=2048, cap_by="controller")
+    clock.enter(RESOLVE)
+    clock.enter(PACK)
+    clock.enter(LAUNCH, ("hybrid", "check_step", (2048, 8192, 8192, 2048), "compiled"))
+    clock.enter(DEVICE_WAIT)
+    clock.round(4096, 0, cap=4096, cap_by="batch_size")
+    clock.enter(RESOLVE)
+    rooms = [(name, args["cap"], args["cap_by"]) for name, args in session.made]
+    assert rooms == [
+        ("keto.dispatch.resolve", 2048, "controller"),
+        ("keto.dispatch.pack", 2048, "controller"),
+        ("keto.dispatch.launch", 2048, "controller"),
+        ("keto.dispatch.device_wait", 2048, "controller"),
+        ("keto.dispatch.resolve", 4096, "batch_size"),
+    ]
+    assert clock.round_cap == {"batch_size": 1, "sub_slice": 0, "controller": 1}
+
+
+def test_a_controller_event_is_a_zero_length_annotation_on_the_dispatch_thread():
+    """``StreamSliceController.observe`` marks what moved it through the
+    calling thread's clock: written while a session is open, on the dispatch
+    thread only, closed before the next span opens."""
+    from keto_tpu.check.slice_ctrl import StreamSliceController
+    from keto_tpu.x.timeline import bind_dispatch_clock
+
+    session = FakeSession()
+    clock = DispatchClock(session)
+    ctrl = StreamSliceController(target_ms=40.0)
+    ctrl.observe(4096, 14.0, route="hybrid", bfs_steps=12, entries=50_000)
+    bind_dispatch_clock(clock)
+    try:
+        ctrl.observe(4096, 60.0, route="hybrid", bfs_steps=12, entries=50_000)
+        assert session.made == []  # no session: counted, not written
+        assert ctrl.snapshot()["events"]["narrow"] == {"hybrid": 1}
+        session.open = True
+        clock.round(4096, 0)
+        clock.enter(FILL)
+        ctrl.observe(2048, 75.0, route="bfs", bfs_steps=12, entries=25_000)
+        clock.enter(WAIT_WORK)
+    finally:
+        bind_dispatch_clock(None)
+    name, args = session.made[1]
+    assert name == "keto.ctrl.narrow"
+    assert args == {"route": "bfs", "nq": 2048, "ms": 75.0, "rung_before": 2048, "rung_after": 2048}
+    assert session.events == [
+        ("enter", "keto.dispatch.fill"),
+        ("enter", "keto.ctrl.narrow"), ("exit", "keto.ctrl.narrow"),
+        ("exit", "keto.dispatch.fill"), ("enter", "keto.dispatch.wait_work"),
+    ]
+    made = len(session.made)
+    ctrl.observe(2048, 75.0, route="bfs")  # off the dispatch thread: the no-op clock
+    assert len(session.made) == made
+    assert ctrl.snapshot()["events"]["narrow"] == {"hybrid": 1, "bfs": 2}
+
+
+def test_the_geometry_workers_compile_is_named_while_a_session_is_open(monkeypatch):
+    """A padded-up slice asks the worker for a program of its own width: under
+    an open session the compile is a ``keto.geometry.compile`` span on the
+    worker's thread that says which program; none is built otherwise."""
+    from keto_tpu.check import geometry
+
+    session = FakeSession()
+    monkeypatch.setattr(geometry, "SESSION", session)
+    threads = []
+
+    def compile_fn(kernel, shape, fixed, sizes):
+        threads.append(threading.current_thread().name)
+        return True
+
+    def ask(sizes):
+        geoms = geometry.KernelGeometries(compile_fn)
+        geoms.add("check", (7, 3), ("fixed",), (1024, 8192))
+        geoms.mark_warmed("check", (7, 3))
+        assert geoms.meet("check", (7, 3), ("fixed",), sizes) == ((1024, 8192), geometry.PADDED_UP)
+        geoms.close(timeout=10)
+        assert geoms.pending() == 0
+        assert geoms.meet("check", (7, 3), ("fixed",), sizes)[1] == geometry.COMPILED
+
+    ask((512, 2048))
+    assert session.made == [] and threads == ["keto-tpu-geometry-compile"]
+    session.open = True
+    ask((256, 2048))
+    assert session.made == [
+        ("keto.geometry.compile", {"kernel": "check", "shape": "(7, 3)", "sizes": "256x2048"})
+    ]
+    assert session.events == [("enter", "keto.geometry.compile"), ("exit", "keto.geometry.compile")]
+    assert threads == ["keto-tpu-geometry-compile"] * 2
 
 
 def test_states_sum_to_the_threads_wall_time_and_wait_work_grows_when_idle():
@@ -567,6 +665,16 @@ def test_profiler_capture_holds_contiguous_dispatch_spans_on_one_thread(daemon, 
     assert {name for _, _, name, _ in spans} == {f"keto.dispatch.{s}" for s in DISPATCH_STATES}
     launch = next(stats for _, _, name, stats in spans if name.endswith(".launch"))
     assert launch["tuples"] == 100 and launch["slices"] >= 1 and "lane_depth" in launch
+    # the round's room on every span: the first round after another test's
+    # singles is held to the sub-slice, the others have the round's own size,
+    # or the controller's floor if this module's bodies of 10,000 narrowed it
+    # (``wait_work`` and ``take`` come before ``round()``: they say what the
+    # round before them said, as they do for ``tuples``)
+    rooms = {
+        (stats["cap"], stats["cap_by"])
+        for _, _, name, stats in spans if not name.endswith((".wait_work", ".take"))
+    }
+    assert rooms and rooms <= {(4096, "batch_size"), (1024, "sub_slice"), (2048, "controller")}
 
 
 def test_trace_hook_is_installed_once_and_wraps_both_functions():
