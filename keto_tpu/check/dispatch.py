@@ -82,6 +82,43 @@ def _bits(words: np.ndarray, nq: int) -> np.ndarray:
     return ((words[:, None] >> _LANES) & 1).astype(bool).ravel()[:nq]
 
 
+def check_sweep_metrics(m, stats_of) -> None:
+    """Declare the families that say in which order ``check_step`` sweeps
+    its buckets (``kernels.SWEEPS``) on ``m`` (driver/registry.py calls this
+    once); ``stats_of()`` is the serving engine's maintenance counters and
+    gauges (``MaintenanceStats.raw()``), empty while there is no engine."""
+
+    def slices():
+        counters = stats_of()[0]
+        return [((o,), float(counters.get(f"sweep_slices_{o}", 0))) for o in kernels.SWEEPS]
+
+    def probe():
+        gauges = stats_of()[1]
+        return [
+            ((o,), float(gauges.get(f"sweep_probe_pulls_{o}", 0)))
+            for o in kernels.IN_PLACE_SWEEPS
+        ]
+
+    m.register_callback(
+        "keto_check_sweep_slices_total", "counter",
+        "Landed slices that ran check_step (keto_check_bfs_slices_total), by "
+        "the order a pull updated the buckets in: up or down (in place, a "
+        "bucket's gather reads what the buckets before it in the same sweep "
+        "wrote: by ascending or descending device id, whichever the "
+        "warm-up's probe of the snapshot converged under in fewer pulls), "
+        "whole (every bucket from the same carry: a mesh).",
+        slices, ("order",),
+    )
+    m.register_callback(
+        "keto_check_sweep_probe_pulls", "gauge",
+        "Pulls the warm-up's probe of the serving snapshot took to its "
+        "fixpoint under each in-place order (CheckDispatch."
+        "_settle_block_iters): the order of the smaller reading serves, up "
+        "on a tie; 0 where no probe ran (no warm-up, no active rows, a mesh).",
+        probe, ("order",),
+    )
+
+
 def stream_chunk_metrics(m, counters_of) -> None:
     """Declare ``_dispatch_slices``' families on ``m`` (driver/registry.py
     calls this once); ``counters_of()`` is the serving engine's maintenance
@@ -190,6 +227,12 @@ class CheckDispatch:
         # pulls per convergence observation, adapted to the workload's
         # traversal depth from the iteration counts kernels report back
         self._block_iters = 8
+        #: the order one pull of ``check_step`` updates the buckets in
+        #: (``kernels.SWEEPS``): in place on one device, by ascending device
+        #: id until ``_settle_block_iters`` has probed the snapshot for the
+        #: order that converges in fewer pulls; whole on a mesh, whose
+        #: row-sharded buckets cost one all-gather a pull, not one a bucket
+        self._sweep = "up" if mesh is None else "whole"
         # which kernel programs are compiled, so that a served slice pads up
         # to one that is before it compiles its own on the dispatch thread
         # (keto_tpu/check/geometry.py); warm_compile settles block_iters, a
@@ -389,7 +432,7 @@ class CheckDispatch:
         return shape
 
     def _check_fixed(self, it_cap: int) -> tuple:
-        return (it_cap, self._block_iters, self._donate_entries)
+        return (it_cap, self._block_iters, self._donate_entries, self._sweep)
 
     @staticmethod
     def _label_shape(labs) -> tuple:
@@ -406,12 +449,13 @@ class CheckDispatch:
         return self._bitmap_sharding
 
     def _run_check_padding(
-        self, snap: GraphSnapshot, sizes: tuple, it_cap: int, seeds=None
+        self, snap: GraphSnapshot, sizes: tuple, it_cap: int, seeds=None, sweep=None
     ) -> np.ndarray:
         """One ``check_step`` at ``sizes`` = (S1, S2, SA, B) on entries that
         are all padding - dropped seed rows, the all-zero answer row - so
         that the program of these sizes is compiled; with ``seeds``, those
-        interior rows start one query each. Returns the device output."""
+        interior rows start one query each, under ``sweep`` where the probe
+        names one. Returns the device output."""
         ni = snap.num_int
         packed = _padding_packed(sizes, ni)
         if seeds is not None:
@@ -435,6 +479,7 @@ class CheckDispatch:
                 it_cap=it_cap,
                 block_iters=self._block_iters,
                 bitmap_sharding=self._bitmap_sharding_for(sizes[3]),
+                sweep=sweep or self._sweep,
             ).block_until_ready(),
         )
 
@@ -455,12 +500,16 @@ class CheckDispatch:
         )
 
     def _settle_block_iters(self, snap: GraphSnapshot, B: int) -> None:
-        """``block_iters`` is a static of every ``check_step`` program, so a
-        change recompiles them all: settle it before the ladder is warmed,
-        from how deep the snapshot's own device part runs - a BFS from a
-        spread of the interior rows nothing on the device points at (the
-        sources of what the pulls walk) - and leave it there for as long as
-        snapshots keep this shape (``_after_batch``)."""
+        """``block_iters`` and the sweep order are statics of every
+        ``check_step`` program, so a change recompiles them all: settle both
+        before the ladder is warmed, from how deep the snapshot's own device
+        part runs - a BFS from a spread of the interior rows nothing on the
+        device points at (the sources of what the pulls walk), once in each
+        order: the one that took fewer pulls is kept (device-id order on a
+        tie; which one wins is the graph's, no edge count predicts it) - and
+        leave them there for as long as snapshots keep this shape
+        (``_after_batch``). The order not kept is compiled for this probe
+        alone. A mesh keeps the whole-step pull and is not probed."""
         self._block_iters_shape = self._check_shape(snap)
         na, ni = snap.num_active, snap.num_int
         if na == 0 or ni <= na or not snap.buckets:
@@ -468,11 +517,18 @@ class CheckDispatch:
         seeds = np.unique(
             np.linspace(na, ni - 1, num=min(B, ni - na)).astype(np.int32)
         )
-        out = np.asarray(
-            self._run_check_padding(snap, (B, B, B, B), self._it_cap, seeds=seeds)
-        )
+        pulls = {}
+        for order in kernels.IN_PLACE_SWEEPS:
+            out = np.asarray(
+                self._run_check_padding(
+                    snap, (B, B, B, B), self._it_cap, seeds=seeds, sweep=order
+                )
+            )
+            pulls[order] = int(out[B // 32])
+            self.maintenance.set_gauge(f"sweep_probe_pulls_{order}", pulls[order])
+        self._sweep = min(pulls, key=pulls.get)  # "up" comes first: it wins a tie
         self._block_iters = max(
-            self._block_iters, min(32, _ceil_pow2(int(out[B // 32]) + 1))
+            self._block_iters, min(32, _ceil_pow2(pulls[self._sweep] + 1))
         )
 
     def _compile_geometry(self, kernel: str, shape: tuple, fixed: tuple, sizes: tuple) -> bool:
@@ -1673,6 +1729,7 @@ class CheckDispatch:
         bytes its pulls gathered)."""
         self.bfs_steps_stats.observe(float(iters))
         self.maintenance.incr("bfs_slices")
+        self.maintenance.incr(f"sweep_slices_{self._sweep}")
         if iters:
             self.maintenance.incr("bfs_steps", by=int(iters))
             if words:
@@ -2004,6 +2061,7 @@ class CheckDispatch:
                 it_cap=it_cap,
                 block_iters=self._block_iters,
                 bitmap_sharding=self._bitmap_sharding_for(sizes[3]),
+                sweep=self._sweep,
             ),
         )
         if met == INLINE:

@@ -14,9 +14,27 @@ the 2-hop label intersection.
   rationale). Rows that can change ("active") form a prefix of the bitmap;
   the loop updates them in place via an aliased carry — nothing the size of
   the full graph is ever copied per step;
+- on one device a pull is a **sweep over the buckets in place** (``sweep``
+  = ``"up"`` / ``"down"``, by ascending or descending device id): a bucket's
+  rows are written into the carry before the next bucket's gather is built,
+  so a bit crosses as many edges in one pull as the sweep's order lays end
+  to end, and a slice converges in fewer pulls than its longest shortest
+  path. The update only ORs bits in, over a finite lattice, so every order
+  of applying the bucket updates reaches the same least fixpoint above the
+  seeds (chaotic iteration); and the sweep in which no bucket changed
+  computed every bucket's pull from a carry that already was the fixpoint,
+  so the pull it carries out of the loop is ``pull(fixpoint)``, bit for bit
+  what the whole-step form carries. Which order needs fewer pulls is the
+  graph's (an edge count does not predict it): ``check/dispatch.py``
+  ``_settle_block_iters`` probes the snapshot under both at warm-up and
+  keeps the better. On a mesh the buckets are sharded by rows and pulled
+  **whole** (``"whole"``: every bucket from the same carry, one all-gather
+  a pull where a sweep in place would make it one a bucket);
 - ``lax.while_loop`` iterates to the reachability fixpoint (the analog of
   the reference's visited-set cycle guard — monotone bitmaps make cycles
-  terminate for free);
+  terminate for free); ``it_cap`` is no depth limit (a slice it cuts is
+  re-run to the exact fixpoint), and each sweep that changes something sets
+  a new bit, so ``num_active + 1`` sweeps never truncate under any order;
 - the answer for query q is the target-row bit of ``pull(fixpoint) ∪
   one-hop-term``, i.e. "reached via ≥ 1 edge", reproducing the reference's
   rule that a subject only matches via an actual tuple, never by being the
@@ -30,6 +48,7 @@ Nothing here knows of an engine: ``check/dispatch.py`` launches these,
 
 from __future__ import annotations
 
+import itertools
 from functools import partial
 from typing import Optional, Sequence
 
@@ -40,6 +59,11 @@ from jax import lax
 
 # cap on the [rows, chunk, W] gather intermediate per bucket
 _DEGREE_CHUNK = 1024
+
+#: the order one pull of ``check_step`` updates the buckets in: in place by
+#: ascending device id, in place by descending, or all from the same carry
+SWEEPS = ("up", "down", "whole")
+IN_PLACE_SWEEPS = SWEEPS[:2]
 
 
 def pull(
@@ -83,6 +107,7 @@ def check_step(
     it_cap: int,
     block_iters: int = 8,
     bitmap_sharding=None,  # NamedSharding for the [rows, words] bitmaps
+    sweep: str = "whole",  # SWEEPS: the order a pull updates the buckets in
 ) -> jnp.ndarray:
     # ``entries`` ships every per-batch host-built array in ONE H2D
     # transfer, and seeds travel as 8-byte (row, query) pairs whose word
@@ -98,6 +123,8 @@ def check_step(
     #                      ``hub_nbrs``, n_int+1+k names relay row k
     #   a_q      int32[SA] owning query index (padding → 0 w/ row n_int)
     #   targets  int32[B]  interior target rows, n_int = none
+    if sweep not in SWEEPS:
+        raise ValueError(f"sweep {sweep!r} is none of {SWEEPS}")
     S1, S2, SA, B = sizes
     o = 0
     e1_rows = entries[o : o + S1]; o += S1
@@ -176,15 +203,55 @@ def check_step(
             ovo = lax.reduce(R0[in_R0(ov_nbrs)], np.uint32(0), lax.bitwise_or, (1,))
             p_passive = p_passive.at[ov_dst].set(p_passive[ov_dst] | ovo, mode="drop")
 
-        def step(st):
+        if bitmap_sharding is not None:
+            # row-sharded buckets are pulled whole, one all-gather a pull:
+            # a sweep in place would make it one a bucket
+            sweep = "whole"
+
+        def ov_pull(A):
+            return lax.reduce(A[in_A(ov_nbrs)], np.uint32(0), lax.bitwise_or, (1,))
+
+        def step_whole(st):
             A, _, _, it = st
             p = pull(bucket_nbrs, valid_rows, A, in_A) | p_passive
             if ov_nbrs is not None:
-                ovo = lax.reduce(A[in_A(ov_nbrs)], np.uint32(0), lax.bitwise_or, (1,))
-                p = p.at[ov_dst].set(p[ov_dst] | ovo, mode="drop")
+                p = p.at[ov_dst].set(p[ov_dst] | ov_pull(A), mode="drop")
             act = A[:n_active]
             nxt = lax.bitwise_or(p, act)
             return A.at[:n_active].set(nxt), p, jnp.any(nxt != act), it + 1
+
+        offsets = [0, *itertools.accumulate(valid_rows)]  # a bucket's first row
+        order = range(len(bucket_nbrs))[:: -1 if sweep == "down" else 1]
+
+        def step_in_place(st):
+            # Gauss-Seidel over the monotone OR: bucket b's gather reads the
+            # rows that the buckets before it in this sweep have just
+            # written, so a bit can cross several edges in one pull
+            A, _, _, it = st
+            changed = jnp.bool_(False)
+            parts = [None] * len(bucket_nbrs)
+            for b in order:
+                off, n_b = offsets[b], valid_rows[b]
+                p_b = pull(bucket_nbrs[b : b + 1], (n_b,), A, in_A) | p_passive[off : off + n_b]
+                old = A[off : off + n_b]
+                new = lax.bitwise_or(p_b, old)
+                changed |= jnp.any(new != old)
+                A = lax.dynamic_update_slice(A, new, (off, 0))
+                parts[b] = p_b
+            p = jnp.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+            if ov_nbrs is not None:
+                # the overlay's rows last, into the carry and the pull both
+                # (ov_dst pads with n_active, which p drops and A holds as
+                # its all-zero row: nothing may be written there)
+                real = (ov_dst < n_active)[:, None]
+                ovo = jnp.where(real, ov_pull(A), 0)
+                old = A[ov_dst]
+                changed |= jnp.any((ovo & ~old) != 0)
+                A = A.at[ov_dst].set(old | ovo)
+                p = p.at[ov_dst].set(p[ov_dst] | ovo, mode="drop")
+            return A, p, changed, it + 1
+
+        step = step_whole if sweep == "whole" else step_in_place
 
         # Each while iteration runs a *block* of pulls, each skipped via
         # lax.cond once the fixpoint is reached (monotone bitmaps:
@@ -276,7 +343,7 @@ def _jit_check(**kw):
         check_step,
         static_argnames=(
             "sizes", "n_active", "n_int", "valid_rows", "it_cap", "block_iters",
-            "bitmap_sharding",
+            "bitmap_sharding", "sweep",
         ),
         **kw,
     )

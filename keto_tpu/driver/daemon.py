@@ -335,7 +335,8 @@ class Daemon:
                     n = engine.warm_compile()
                     self.registry.logger().info(
                         "width-ladder warmup compiled/loaded %d kernels "
-                        "(block_iters %s)", n, engine.dispatch._block_iters,
+                        "(block_iters %s, sweep %s)", n,
+                        engine.dispatch._block_iters, engine.dispatch._sweep,
                     )
             except Exception:
                 stats = getattr(engine, "maintenance", None)

@@ -1755,7 +1755,8 @@ class Registry:
         m.register_callback(
             "keto_check_bfs_steps_total", "counter",
             "Frontier pulls of check_step, summed over the landed BFS and "
-            "hybrid slices (each slice's convergence check included).",
+            "hybrid slices (each slice's convergence check included); a pull "
+            "is one sweep over the buckets (keto_check_sweep_slices_total).",
             bfs_steps("bfs_steps"),
         )
         m.register_callback(
@@ -1969,7 +1970,7 @@ class Registry:
 
         # what the slice controller, the stream and the dispatch clock
         # decided, declared by the modules that count it
-        from keto_tpu.check.dispatch import stream_chunk_metrics
+        from keto_tpu.check.dispatch import check_sweep_metrics, stream_chunk_metrics
         from keto_tpu.check.slice_ctrl import stream_ctrl_metrics
         from keto_tpu.x.timeline import dispatch_clock_metrics
 
@@ -1979,6 +1980,7 @@ class Registry:
 
         stream_ctrl_metrics(m, stream_ctrl_snapshot)
         stream_chunk_metrics(m, lambda: maintenance_raw()[0])
+        check_sweep_metrics(m, maintenance_raw)
         dispatch_clock_metrics(m, batcher_clock)
 
         # /check/batch query frames (keto_tpu/check/frame.py): how often

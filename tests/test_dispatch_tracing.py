@@ -775,3 +775,60 @@ def test_device_memory_rows_read_every_local_device(monkeypatch):
         (("0", "in_use"), 5.0), (("0", "peak"), 9.0), (("0", "limit"), 100.0),
         (("1", "in_use"), 2.0),
     ]
+
+
+# -- the order check_step sweeps its buckets in (PR 42) ---------------------------
+
+
+def test_sweep_families_count_landed_slices_under_the_settled_order_and_hold_the_probe(monkeypatch):
+    """``keto_check_sweep_slices_total{order}`` moves once a landed
+    ``check_step`` slice, under the order the warm-up's probe kept, and
+    ``keto_check_sweep_probe_pulls{order}`` holds both of its readings; a pull
+    of ``keto_check_bfs_steps_total`` is one sweep, and the three older
+    families keep their names and what they are worth to each other."""
+    from keto_tpu.check.dispatch import check_sweep_metrics
+    from keto_tpu.check.kernels import SWEEPS
+    from keto_tpu.config.provider import Config
+    from keto_tpu.driver.registry import Registry
+    from keto_tpu.x.metrics import MetricsRegistry
+    from tests.test_check_step_sweep import NSS, nested_rows
+
+    def rows(fams, family):
+        return {have["order"]: v for _, have, v in fams[family]["samples"]}
+
+    # without an engine: the whole label sets at 0
+    m = MetricsRegistry()
+    check_sweep_metrics(m, lambda: ({}, {}, {}))
+    fams = parse_exposition(m.render())
+    assert rows(fams, "keto_check_sweep_slices_total") == dict.fromkeys(SWEEPS, 0.0)
+    assert rows(fams, "keto_check_sweep_probe_pulls") == {"up": 0.0, "down": 0.0}
+    assert fams["keto_check_sweep_slices_total"]["type"] == "counter"
+    assert fams["keto_check_sweep_probe_pulls"]["type"] == "gauge"
+
+    reg = Registry(Config(overrides={
+        "namespaces": [{"id": n.id, "name": n.name} for n in NSS], "serve.labels_enabled": False,
+    }))
+    try:
+        stored, queries = nested_rows(42)
+        reg.relation_tuple_manager().write_relation_tuples(*stored)
+        engine = reg.permission_engine()
+        monkeypatch.setattr(engine.dispatch, "stream_widths", lambda snap: [32])
+        assert engine.warm_compile() == 1
+        order = engine.dispatch._sweep
+        fams = parse_exposition(reg.metrics().render())
+        probe = rows(fams, "keto_check_sweep_probe_pulls")
+        assert set(probe) == {"up", "down"} and min(probe.values()) >= 2
+        assert order == min(probe, key=probe.get)
+        assert rows(fams, "keto_check_sweep_slices_total") == dict.fromkeys(SWEEPS, 0.0)
+
+        engine.batch_check(queries[:64])
+        fams = parse_exposition(reg.metrics().render())
+        slices = _value(fams, "keto_check_bfs_slices_total")
+        steps = _value(fams, "keto_check_bfs_steps_total")
+        assert slices >= 1 and steps > 2 * slices  # deeper than the floor of either scheme
+        assert rows(fams, "keto_check_sweep_slices_total") == {**dict.fromkeys(SWEEPS, 0.0), order: slices}
+        # 64 queries ride a bitmap of 8 words (the ladder's 256 rung)
+        assert _value(fams, "keto_check_pull_words_total") == steps * 8
+        assert rows(fams, "keto_check_sweep_probe_pulls") == probe
+    finally:
+        reg.close()

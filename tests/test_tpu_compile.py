@@ -41,7 +41,8 @@ def widest(kernel):
     return max((sizes for k, sizes in CheckDispatch._hub_rungs(WIDTHS) if k == kernel), key=sum)
 
 
-def test_check_step_compiles_at_the_widest_hub_rung_with_a_bucket_past_the_degree_chunk(one_chip):
+@pytest.mark.parametrize("sweep", kernels.SWEEPS)
+def test_check_step_compiles_at_the_widest_hub_rung_with_a_bucket_past_the_degree_chunk(one_chip, sweep):
     assert BUCKETS[-1][1] > kernels._DEGREE_CHUNK, "the chunk loop of pull would not iterate"
     sizes = widest("check")
     assert sizes == (131072, 131072, 131072, 2048)
@@ -52,6 +53,7 @@ def test_check_step_compiles_at_the_widest_hub_rung_with_a_bucket_past_the_degre
     compiled = kernels._check_kernel.lower(
         buckets, entries, ov_nbrs=None, ov_dst=None, hub_nbrs=hub, sizes=sizes, n_active=N_ACTIVE,
         n_int=N_INT, valid_rows=VALID_ROWS, it_cap=64, block_iters=16, bitmap_sharding=None,
+        sweep=sweep,
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES // 16
