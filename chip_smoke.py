@@ -73,7 +73,41 @@ N_SINGLE = 200
 N_GRPC = 200
 N_ORACLE_SAMPLE = 2_000
 N_SPECIAL = 64  # per special shape
-NAMESPACES = [{"id": 0, "name": "docs"}, {"id": 1, "name": "groups"}]
+
+
+def _from_owner(rel: str) -> dict:
+    return {"tuple_to_userset": {"tupleset": "owner", "computed_userset": rel}}
+
+
+#: ``orgs`` and ``repos`` carry userset rewrites with the two gates of
+#: OpenFGA's modelling guide (docs/concepts/userset-rewrites.md): a blocklist
+#: (``can_read = reader but not blocked from owner``) and a two-condition
+#: permission (``can_delete = admin and member from owner``)
+NAMESPACES = [
+    {"id": 0, "name": "docs"}, {"id": 1, "name": "groups"},
+    {"id": 2, "name": "orgs"},
+    {"id": 3, "name": "repos", "config": {"relations": {
+        "reader": {"union": [{"this": {}}, _from_owner("member")]},
+        "can_read": {"exclusion": {"base": {"computed_userset": "reader"},
+                                   "subtract": _from_owner("blocked")}},
+        "can_delete": {"intersection": [{"computed_userset": "admin"}, _from_owner("member")]},
+    }}},
+]
+#: the gated case: rows, and ``(object, relation, user) -> allowed``
+GATE_ROWS = (
+    ("orgs", "acme", "member", "gate-member"), ("orgs", "acme", "member", "gate-blocked"),
+    ("orgs", "acme", "blocked", "gate-blocked"),
+    ("repos", "site", "admin", "gate-member"), ("repos", "site", "admin", "gate-outsider"),
+)
+GATE_CHECKS = (
+    ("site", "reader", "gate-blocked", True),  # a member, so a reader ...
+    ("site", "can_read", "gate-blocked", False),  # ... and blocked: the grant does not count
+    ("site", "can_read", "gate-member", True),
+    ("site", "can_delete", "gate-member", True),  # an admin and a member
+    ("site", "can_delete", "gate-outsider", False),  # an admin, no member
+    ("site", "can_delete", "gate-blocked", False),
+    ("none", "can_read", "gate-member", False),  # no row mentions the repo
+)
 
 #: maintenance events that must not have moved / must have moved
 #: (keto_maintenance_events_total{event=...})
@@ -271,6 +305,12 @@ class Smoke:
                 T("docs", f"anyrel-{i}", "view", SubjectID(f"user-{rng.randrange(n_users)}"))
             )
         special_q.append(T("nope", "x", "y", SubjectID("z")))  # unknown namespace
+        # the gated case rides the first call too, and is held to its
+        # hand-written answers besides the oracle's (``drive``)
+        tuples.append(T("repos", "site", "owner", SubjectSet("orgs", "acme", "...")))
+        tuples += [T(ns, obj, rel, SubjectID(u)) for ns, obj, rel, u in GATE_ROWS]
+        self.gate_q = [T("repos", obj, rel, SubjectID(u)) for obj, rel, u, _ in GATE_CHECKS]
+        special_q += self.gate_q
         self.tuples = tuples
         self.special_q = special_q
         n_rbac = N_BATCHED + BATCH + N_SINGLE + N_GRPC
@@ -302,7 +342,7 @@ class Smoke:
         from keto_tpu.persistence.sqlite import SQLitePersister
 
         nm = namespace_pkg.MemoryManager(
-            [namespace_pkg.Namespace(id=n["id"], name=n["name"]) for n in NAMESPACES]
+            [namespace_pkg.namespace_from_json(n) for n in NAMESPACES]
         )
         return SQLitePersister(f"sqlite://{self.workdir / 'store.sqlite'}", lambda: nm)
 
@@ -499,6 +539,13 @@ class Smoke:
                     [oracle.subject_is_allowed(served_q[i]) for i in sample],
                 )
                 counts["oracle_sample"] = len(sample)
+                by_query = dict(zip(served_q, served))
+                self.compare(
+                    "gated checks (a blocklist, a two-condition permission) vs their "
+                    "hand-written answers",
+                    self.gate_q, [by_query[q] for q in self.gate_q],
+                    [want for *_, want in GATE_CHECKS],
+                )
             singles = self.rbac_q[pos : pos + N_SINGLE]
             pos += N_SINGLE
             with self.phase("single REST checks"):
@@ -642,6 +689,15 @@ class Smoke:
             problems.append("no slice was answered by the BFS kernel")
         if routes["cpu"]:
             problems.append(f"{routes['cpu']} slices answered by the CPU fallback")
+        gated = {
+            served: int(self.metric(fam, "keto_check_gate_checks_total", served=served))
+            for served in ("device", "oracle")
+        }
+        self.say(f"gated checks by who served them: {json.dumps(gated)}")
+        if gated["device"] < len(GATE_CHECKS) - 1:  # ``reader`` reaches no gate
+            problems.append("the gated checks were not expanded on the device")
+        if gated["oracle"]:
+            problems.append(f"{gated['oracle']} gated checks went to the CPU oracle")
         native = int(self.metric(fam, "keto_native_pack_chunks_total", path="native"))
         self.say(f"native pack chunks: {native}")
         if native <= 0:

@@ -42,7 +42,7 @@ from keto_tpu.check.frame import (
     DEAD, NO_TARGET, SPECIAL, QueryBatch, QueryFrame, as_tuples, pick_tuples,
 )
 from keto_tpu.check.geometry import INLINE, KernelGeometries
-from keto_tpu.check import kernels
+from keto_tpu.check import gates, kernels
 from keto_tpu.check.kernels import (
     _check_kernel, _check_kernel_donated, _label_kernel, _label_kernel_donated,
 )
@@ -54,7 +54,7 @@ from keto_tpu.check.pack import (
 )
 from keto_tpu.check.slice_ctrl import StreamSliceController
 from keto_tpu.graph.snapshot import WILDCARD, GraphSnapshot, _ceil_pow2
-from keto_tpu.namespace.rewrites import GATED, REWRITTEN
+from keto_tpu.namespace.rewrites import REWRITTEN
 from keto_tpu.relationtuple.model import RelationTuple, SubjectID, SubjectSet
 from keto_tpu.x import faults
 from keto_tpu.x.errors import ErrNamespaceUnknown
@@ -1161,9 +1161,12 @@ class CheckDispatch:
     def _slices(self, snap, take, bound, ctrl, it_cap):
         """The launching side of a stream: cut up to the controller's cap
         off the source, resolve, pack and launch it, and yield one
-        ``(offset, dev, host_ans, nq, chunk, leases, n_entries, full_take)``
-        a launched slice; ``full_take``: the take it was cut from filled
-        the cap (``StreamSliceController.observe``)."""
+        ``(offset, dev, host_ans, nq, chunk, leases, n_entries, full_take,
+        gate)`` a launched slice; ``full_take``: the take it was cut from
+        filled the cap (``StreamSliceController.observe``). ``nq`` counts the
+        slice's device positions; under a schema with gates ``gate`` (a
+        ``gates.GateSlice``) turns their answers into those of the slice's
+        checks, which are what ``offset`` and ``chunk`` count."""
         lockstep = self._lockstep_verify
         if lockstep:
             from keto_tpu.parallel.lockstep import verify_lockstep
@@ -1188,16 +1191,16 @@ class CheckDispatch:
             if snap.n_nodes == 0 or snap.n_edges == 0:
                 yield (
                     off, None, np.zeros(len(batch), dtype=bool),
-                    len(batch), batch, [], 0, False,
+                    len(batch), batch, [], 0, False, None,
                 )
                 off += len(batch)
                 continue
             full = len(batch) >= cap
-            for dev, host_ans, nq, chunk, leases, n_ent in (
+            for dev, host_ans, nq, chunk, leases, n_ent, gate in (
                 self._dispatch_slices(snap, batch, it_cap=it_cap)
             ):
-                yield off, dev, host_ans, nq, chunk, leases, n_ent, full
-                off += nq
+                yield off, dev, host_ans, nq, chunk, leases, n_ent, full, gate
+                off += nq if gate is None else gate.n_checks
 
     def _stream(
         self, snap, tuples_iter, *, depth, slice_cap, ordered,
@@ -1239,7 +1242,7 @@ class CheckDispatch:
             # unpack one slice (blocks iff its transfer hasn't finished);
             # a truncated frontier re-runs exactly, mid-stream
             nonlocal max_iters, t_prev_ready
-            _seq, off, dev, host_ans, nq, chunk, leases, n_ent, full, t_disp = rec
+            _seq, off, dev, host_ans, nq, chunk, leases, n_ent, full, gate, t_disp = rec
             clk.enter(DEVICE_WAIT)
             try:
                 out, iters, truncated = self._unpack_slice(dev, host_ans, nq)
@@ -1248,7 +1251,14 @@ class CheckDispatch:
                 # will be re-answered elsewhere): the H2D staging copy is
                 # over, the buffers may be re-leased
                 self._stage_release(leases)
+            if gate is not None:
+                clk.gates(gate.n_gated, nq)
             clk.enter(FILL)
+            if gate is not None and not truncated:
+                # positions to checks; a truncated slice is re-run by its checks
+                t_gate = time.perf_counter()
+                out = gate.combine(out)
+                self.maintenance.incr("gate_seconds_combine", by=time.perf_counter() - t_gate)
             if dev is not None and not (
                 isinstance(dev, _HybridSlice) and dev.bfs_dev is None
             ):
@@ -1360,12 +1370,12 @@ class CheckDispatch:
                     if nxt is None:
                         exhausted = True
                         break
-                    off, dev, host_ans, nq, chunk, leases, n_ent, full = nxt
+                    off, dev, host_ans, nq, chunk, leases, n_ent, full, gate = nxt
                     if dev is not None:
                         dev.copy_to_host_async()
                     inflight.append((
                         seq, off, dev, host_ans, nq, chunk, leases, n_ent,
-                        full, time.perf_counter(),
+                        full, gate, time.perf_counter(),
                     ))
                     seq += 1
                 if not inflight and exhausted:
@@ -1525,9 +1535,16 @@ class CheckDispatch:
         the pre-dispatch half of the slice-tail control loop.
 
         Yields ``[dev | None, host_ans, nq, chunk_tuples, leases,
-        n_entries]``: ``chunk_tuples`` lets a truncated slice re-run,
+        n_entries, gate]``: ``chunk_tuples`` lets a truncated slice re-run,
         ``leases`` are staging buffers released only once the slice has
-        landed, ``n_entries`` feeds the controller's entry-cost model."""
+        landed, ``n_entries`` feeds the controller's entry-cost model.
+
+        Under a schema with gates (keto_tpu/check/gates.py) a resolved
+        chunk's checks become positions first: a gated check is its own row
+        and one an operand of every gate it reaches, side by side. From
+        there on ``nq``, the width and the entry counts are of positions; a
+        cut falls between checks, and ``gate`` (None where the chunk has no
+        gated check) gives the slice's checks their answers when it lands."""
         cap_q = self._slice_cap(snap)
         n = len(tuples)
         clk = dispatch_clock()
@@ -1535,89 +1552,131 @@ class CheckDispatch:
             s1 = min(s0 + cap_q, n)
             clk.enter(RESOLVE)
             sd, tg, multi = self._resolve_bulk(snap, tuples[s0:s1])
-            oracle_ans = None
+            oracle_ans = exp = None
             if snap.rewrites is not None:
-                oracle_ans = self._rewrite_split(snap, tuples[s0:s1], sd, tg, multi)
+                oracle_ans, exp = self._rewrite_split(snap, tuples[s0:s1], sd, tg, multi, cap_q)
             clk.poll()
-            nq = s1 - s0
-            W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
-            B = 32 * W
-            use_labels = self._labels_usable(snap)
-            cap_e = 4 * B
-            if use_labels and self._riders_expected(snap):
-                # hub rows: the label route cuts a chunk by pairs, at most
-                # the pair cap a query, and sends check_step the riders
-                # alone, entries packed narrow (``device_part``). A chunk may
-                # bring that many entries a query before it is split, or a
-                # take would be a dozen hybrid slices of two launches each
-                cap_e = self._LABEL_PAIR_CAP * B
-            cap_geo = cap_e
-            if not self._multiprocess:
-                # service-time-aware split bound (never below a quarter of
-                # the geometric bound, one B where that is 4·B — the floor
-                # keeps slice counts bounded: what a slice costs whatever
-                # it carries, its pulls, is paid again by every piece)
-                budget = self.stream_ctrl.entry_budget()
-                if budget is not None:
-                    cap_e = min(cap_e, max(cap_e // 4, budget))
-            cnt = self._entry_counts(snap, sd, tg, multi)
-            total = int(cnt.sum())
-            if total > cap_e:
-                reach = self._device_reach(snap)
-                if reach is not None:
-                    # a query whose target side has no row that a pull
-                    # changes sends the device nothing (``device_part``):
-                    # its entries do not count towards a split
-                    known = (tg >= 0) & (tg < snap.num_live)
-                    cnt[known & ~reach[np.where(known, tg, 0)]] = 0
-                    total = int(cnt.sum())
-            if total <= cap_e:
-                bounds = [(0, nq)]
+            if exp is None:
+                pieces = [(0, s1 - s0)]
             else:
-                csum = np.concatenate([np.zeros(1, np.int64), np.cumsum(cnt)])
-                bounds = []
-                i0 = 0
-                while i0 < nq:
-                    i1 = int(np.searchsorted(csum, csum[i0] + cap_e, side="right")) - 1
-                    i1 = max(i0 + 1, min(i1, nq))
-                    bounds.append((i0, i1))
-                    i0 = i1
-            # what cut the chunk: its entries pass the geometric bound, or
-            # only the cap the controller's entry budget lowered
-            cut = "none" if len(bounds) == 1 else (
-                "geometry" if total > cap_geo else "budget"
-            )
-            self.maintenance.incr(f"stream_chunks_{cut}")
-            self.maintenance.incr("stream_chunk_pieces", by=len(bounds))
-            for a, b in bounds:
-                # sub-chunks keep the slice width: queries pad, geometry stays
-                if use_labels:
-                    dev, host_ans, leases = self._device_batch_labeled(
-                        snap, sd, tg, multi, a, b, W, it_cap=it_cap
-                    )
-                else:
-                    dev, host_ans, leases = self._device_batch(
-                        snap, sd, tg, multi, a, b, W, it_cap=it_cap
-                    )
+                # the chunk as positions: the oracle's answers and the
+                # patterns' starts move to their checks' own positions
+                sd, tg = exp.sd, exp.tg
+                multi = {int(exp.self_pos[i]): starts for i, starts in multi.items()}
                 if oracle_ans is not None:
-                    # the oracle's answers land as the host's own do
-                    host_ans[: b - a] |= oracle_ans[a:b]
-                yield [
-                    dev, host_ans, b - a, tuples[s0 + a : s0 + b],
-                    leases, int(cnt[a:b].sum()),
-                ]
+                    at = np.flatnonzero(oracle_ans)
+                    oracle_ans = np.zeros(sd.shape[0], bool)
+                    oracle_ans[exp.self_pos[at]] = True
+                ptr, pieces, c0 = exp.pos_ptr, [], 0
+                while c0 < s1 - s0:
+                    c1 = exp.check_at(int(ptr[c0]) + cap_q, c0)
+                    pieces.append((int(ptr[c0]), int(ptr[c1])))
+                    c0 = c1
+            use_labels = self._labels_usable(snap)
+            cnt = self._entry_counts(snap, sd, tg, multi)
+            for q0, q1 in pieces:
+                yield from self._dispatch_piece(
+                    snap, tuples, s0, sd, tg, multi, cnt, q0, q1, exp, oracle_ans,
+                    use_labels, it_cap,
+                )
 
-    def _rewrite_split(self, snap: GraphSnapshot, queries, sd, tg, multi):
+    def _dispatch_piece(
+        self, snap, tuples, s0, sd, tg, multi, cnt, q0, q1, exp, oracle_ans,
+        use_labels, it_cap,
+    ):
+        """Positions ``[q0, q1)`` of a resolved chunk (at most a slice's
+        width of them) as one slice, or as several where their entries pass
+        the budget (``_dispatch_slices``)."""
+        nq = q1 - q0
+        W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
+        B = 32 * W
+        cap_e = 4 * B
+        if use_labels and self._riders_expected(snap):
+            # hub rows: the label route cuts a chunk by pairs, at most
+            # the pair cap a query, and sends check_step the riders
+            # alone, entries packed narrow (``device_part``). A chunk may
+            # bring that many entries a query before it is split, or a
+            # take would be a dozen hybrid slices of two launches each
+            cap_e = self._LABEL_PAIR_CAP * B
+        cap_geo = cap_e
+        if not self._multiprocess:
+            # service-time-aware split bound (never below a quarter of
+            # the geometric bound, one B where that is 4·B — the floor
+            # keeps slice counts bounded: what a slice costs whatever
+            # it carries, its pulls, is paid again by every piece)
+            budget = self.stream_ctrl.entry_budget()
+            if budget is not None:
+                cap_e = min(cap_e, max(cap_e // 4, budget))
+        cnt = cnt[q0:q1]
+        total = int(cnt.sum())
+        if total > cap_e:
+            reach = self._device_reach(snap)
+            if reach is not None:
+                # a query whose target side has no row that a pull
+                # changes sends the device nothing (``device_part``):
+                # its entries do not count towards a split
+                t = tg[q0:q1]
+                known = (t >= 0) & (t < snap.num_live)
+                cnt = cnt.copy()
+                cnt[known & ~reach[np.where(known, t, 0)]] = 0
+                total = int(cnt.sum())
+        if total <= cap_e:
+            bounds = [(q0, q1)]
+        else:
+            csum = np.concatenate([np.zeros(1, np.int64), np.cumsum(cnt)])
+            bounds = []
+            i0 = 0
+            while i0 < nq:
+                i1 = int(np.searchsorted(csum, csum[i0] + cap_e, side="right")) - 1
+                i1 = max(i0 + 1, min(i1, nq))
+                if exp is not None and i1 < nq:
+                    # between two checks: a check's positions land together
+                    c0 = int(np.searchsorted(exp.pos_ptr, q0 + i0, side="right")) - 1
+                    i1 = int(exp.pos_ptr[exp.check_at(q0 + i1, c0)]) - q0
+                bounds.append((q0 + i0, q0 + i1))
+                i0 = i1
+        # what cut the chunk: its entries pass the geometric bound, or
+        # only the cap the controller's entry budget lowered
+        cut = "none" if len(bounds) == 1 else (
+            "geometry" if total > cap_geo else "budget"
+        )
+        self.maintenance.incr(f"stream_chunks_{cut}")
+        self.maintenance.incr("stream_chunk_pieces", by=len(bounds))
+        for a, b in bounds:
+            # sub-chunks keep the slice width: queries pad, geometry stays
+            if use_labels:
+                dev, host_ans, leases = self._device_batch_labeled(
+                    snap, sd, tg, multi, a, b, W, it_cap=it_cap
+                )
+            else:
+                dev, host_ans, leases = self._device_batch(
+                    snap, sd, tg, multi, a, b, W, it_cap=it_cap
+                )
+            if oracle_ans is not None:
+                # the oracle's answers land as the host's own do
+                host_ans[: b - a] |= oracle_ans[a:b]
+            gate, ca, cb = None, a, b
+            if exp is not None:
+                ca, cb = (int(np.searchsorted(exp.pos_ptr, x)) for x in (a, b))
+                gate = exp.cut(ca, cb)
+            yield [
+                dev, host_ans, b - a, tuples[s0 + ca : s0 + cb],
+                leases, int(cnt[a - q0 : b - q0].sum()), gate,
+            ]
+
+    def _rewrite_split(
+        self, snap: GraphSnapshot, queries, sd, tg, multi, cap_q: Optional[int] = None
+    ):
         """Under a rewrite schema: count a resolved batch by closure
         (``keto_check_rewrite_checks_total``) from the snapshot's one byte a
-        device row, and take the checks whose ``(namespace, relation)`` can
-        reach an intersection or an exclusion out of the batch: the CPU
-        oracle answers them here, their rows are unset so that pack sends
-        the device nothing for them, and their answers come back as
-        ``bool[n]`` for the slice's host answers (None when none was
-        taken). The rest of the batch rides the device. A wildcard pattern
-        (its starts in ``multi``) carries the bits of every start and of
-        the relation it names."""
+        device row and, where the plan has gates, hand it to
+        ``gates.split``: the checks whose closure reaches an intersection or
+        an exclusion become several device positions each, or are answered
+        by the CPU oracle where the expansion does not reach (their rows are
+        then unset so that pack sends the device nothing for them). Returns
+        ``(the oracle's answers as bool[n] or None, a gates.Expansion or
+        None)``. A wildcard pattern (its starts in ``multi``) carries the
+        bits of every start and of the relation it names."""
         plan = snap.rewrites
         n = sd.shape[0]
         flags = plan.flags_of(snap)
@@ -1649,37 +1708,8 @@ class CheckDispatch:
         incr("rewrite_checks_plain", by=n - rewritten)
         if not plan.has_gated:
             incr("rewrite_route_device", by=rewritten)
-            return None
-        gated = np.flatnonzero(f & GATED)
-        # a gated relation's node exists only where rows name it: a check
-        # that found no start may still be one the oracle grants
-        missing = np.flatnonzero(sd == -1).tolist()
-        unresolved: list[int] = []
-        if missing:
-            ns_of = self._ns_resolver()
-            for i, rt in zip(missing, pick_tuples(queries, missing, "rewrite")):
-                ns_id = ns_of(rt.namespace)
-                if isinstance(ns_id, int) and plan.relation_flags(ns_id, rt.relation) & GATED:
-                    unresolved.append(i)
-        incr("rewrite_route_device", by=rewritten - int(gated.size))
-        if not gated.size and not unresolved:
-            return None
-        patterns = int(np.count_nonzero(sd[gated] == -2))
-        incr("rewrite_route_oracle", by=int(gated.size) + len(unresolved))
-        incr("rewrite_oracle_gated_closure", by=int(gated.size) - patterns)
-        incr("rewrite_oracle_gated_pattern", by=patterns)
-        incr("rewrite_oracle_gated_unresolved", by=len(unresolved))
-        taken = sorted(gated.tolist() + unresolved)
-        oracle = self._oracle()
-        out = np.zeros(n, bool)
-        for i, rt in zip(taken, pick_tuples(queries, taken, "rewrite")):
-            out[i] = oracle.subject_is_allowed(rt)
-        at = np.asarray(taken)
-        sd[at] = -1
-        tg[at] = -1
-        for i in taken:
-            multi.pop(i, None)
-        return out
+            return None, None
+        return gates.split(self, snap, queries, sd, tg, multi, f, cap_q or self._slice_cap(snap))
 
     @staticmethod
     def _decode_packed(f: np.ndarray, host_ans: np.ndarray, nq: int):

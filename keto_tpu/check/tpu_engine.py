@@ -35,6 +35,7 @@ from keto_tpu.check.frame import pick_tuples
 from keto_tpu.check.kernels import _label_witness_kernel
 from keto_tpu.check.pack import _WORD_WIDTHS
 from keto_tpu.driver.hbm import HbmGovernor, MemoryPressure, is_resource_exhausted
+from keto_tpu.graph.gate_tables import tables_of as gate_tables_of
 from keto_tpu.graph.snapshot import GraphSnapshot, _ceil_pow2
 from keto_tpu.relationtuple.model import RelationTuple
 from keto_tpu.x import faults
@@ -1572,6 +1573,14 @@ class TpuCheckEngine:
         self.maintenance.set_gauge(
             "rewrite_edges_tuple_to_userset", plan.n_ttu if plan is not None else 0
         )
+        # the gates among its nodes (``keto_snapshot_gate_nodes{kind}``,
+        # ``keto_snapshot_gate_reach_rows``): the build, the cache or the
+        # fold left the tables on it
+        tables = gate_tables_of(snap, build=False)
+        self.maintenance.set_gauge("snapshot_set_nodes", snap.num_sets)
+        self.maintenance.set_gauge("gate_nodes_gate", tables.n_gate_nodes if tables else 0)
+        self.maintenance.set_gauge("gate_nodes_operand", tables.n_operand_nodes if tables else 0)
+        self.maintenance.set_gauge("gate_reach_rows", tables.n_reach_rows if tables else 0)
 
     def _upload_buckets(self, snap: GraphSnapshot) -> None:
         self._note_ell(snap)

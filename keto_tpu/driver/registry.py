@@ -1970,6 +1970,7 @@ class Registry:
 
         # what the slice controller, the stream and the dispatch clock
         # decided, declared by the modules that count it
+        from keto_tpu.check import gates as check_gates
         from keto_tpu.check.dispatch import check_sweep_metrics, stream_chunk_metrics
         from keto_tpu.check.slice_ctrl import stream_ctrl_metrics
         from keto_tpu.x.timeline import dispatch_clock_metrics
@@ -2040,24 +2041,31 @@ class Registry:
         m.register_callback(
             "keto_check_rewrite_route_total", "counter",
             "Checks with a rewritten closure by who answered: device (the "
-            "union class, compiled into the snapshot's edges) or oracle (the "
-            "closure reaches an intersection or an exclusion: the CPU oracle "
+            "union class, compiled into the snapshot's edges, and the gated "
+            "checks the expansion served: keto_check_gate_checks_total) or "
+            "oracle (a gated check the expansion does not reach, by "
+            "keto_check_rewrite_oracle_total's reasons: the CPU oracle "
             "answers, the rest of the batch rides the device). Not a fault "
             "path: keto_maintenance_events_total{event=\"fallback_checks\"} does not move.",
             maintenance_counts("rewrite_route_", ("device", "oracle")), ("route",),
         )
         m.register_callback(
             "keto_check_rewrite_oracle_total", "counter",
-            "Checks the rewrite route handed to the CPU oracle, by reason: "
-            "gated_closure (the start node's closure reaches an intersection or "
-            "an exclusion), gated_pattern (a wildcard pattern one of whose "
-            "starts does) or gated_unresolved (such a relation on an object "
-            "no row names, so the snapshot has no node for it).",
-            maintenance_counts(
-                "rewrite_oracle_", ("gated_closure", "gated_pattern", "gated_unresolved")
-            ),
+            "Checks the rewrite route handed to the CPU oracle, by reason. A "
+            "check whose closure reaches an intersection or an exclusion is "
+            "expanded into device positions (keto_tpu/check/gates.py) unless: "
+            "gated_pattern (a wildcard pattern one of whose starts reaches a "
+            "gate), fanout (the start reaches more gates, or would become more "
+            "positions, than the caps), cycle (an operand reaches its own "
+            "gate), overlay (writes since the last build moved what the gate "
+            "tables were made from, or brought the start). gated_closure and "
+            "gated_unresolved stay at 0 since the expansion: the first was the "
+            "whole gated class, the second a gated relation on an object no "
+            "row names, which the snapshot now has a node for.",
+            maintenance_counts("rewrite_oracle_", check_gates.ORACLE_REASONS),
             ("reason",),
         )
+        check_gates.gate_metrics(m, maintenance_raw)
 
         def snapshot_gauge(key: str):
             def read():

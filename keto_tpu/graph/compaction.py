@@ -51,6 +51,7 @@ from typing import Optional
 import numpy as np
 
 from keto_tpu.graph.interner import ExtendedInterned
+from keto_tpu.graph.gate_tables import tables_of
 from keto_tpu.graph.snapshot import Bucket, GraphSnapshot
 
 
@@ -387,7 +388,9 @@ def compact_snapshot(
         interned=new_interned,
         raw2dev=raw2dev,
         wild_ns_ids=snap.wild_ns_ids,
-        rewrites=snap.rewrites,
+        # the fold renumbers the rows: the gate tables are made anew from
+        # them below, on this thread, before the snapshot is published
+        rewrites=snap.rewrites.settled() if snap.rewrites is not None else None,
         fwd_indptr=new_indptr,
         fwd_indices=new_indices,
         sink_indptr=new_sink_indptr,
@@ -408,6 +411,7 @@ def compact_snapshot(
     new_snap.lay_fwd, new_snap.lay_rev = build_list_layouts(
         new_indptr, new_indices, n_nodes_new, new_snap.sink_base, sorter=sorter
     )
+    tables_of(new_snap)  # off the dispatch thread: no gated check waits for them
     # reuse untouched device buckets; the engine re-uploads the touched set
     if snap.device_buckets is not None:
         bufs = list(snap.device_buckets)

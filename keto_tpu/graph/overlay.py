@@ -537,6 +537,9 @@ def apply_delta(
     flags = base.__dict__.get("_rewrite_flags")
     if flags is not None and plan is not None and flags[0] is plan.flags:
         new.__dict__["_rewrite_flags"] = flags  # same closure bits, same base rows
+    tables = base.__dict__.get("_gate_tables")
+    if tables is not None and plan is not None:
+        new.__dict__["_gate_tables"] = tables  # of the base rows: ``plan.gates_stale`` says if they hold
     return new
 
 

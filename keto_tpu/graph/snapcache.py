@@ -48,6 +48,7 @@ from typing import Optional
 
 import numpy as np
 
+from keto_tpu.graph.gate_tables import GateTables
 from keto_tpu.graph.snapshot import Bucket, GraphSnapshot
 
 #: bump when the on-disk layout or the snapshot's array semantics change —
@@ -378,6 +379,12 @@ def save_snapshot(
         if snap.rev_indptr is not None:
             sv("rev_indptr", snap.rev_indptr)
             sv("rev_indices", snap.rev_indices)
+        gate_tables = snap.__dict__.get("_gate_tables")
+        if gate_tables is not None and plan_meta is not None:
+            # the gates each row reaches (keto_tpu/graph/gate_tables.py), behind
+            # the plan's fingerprint like the derived edges themselves
+            gate_tables.save(sv)
+            plan_meta["gate_tables"] = gate_tables.to_meta()
         if shards > 1:
             # per-shard bucket stripes: rows split by the SERVE-TIME
             # shard ownership (graph/device_build.shard_row_ranges over
@@ -753,6 +760,8 @@ def load_snapshot(path: str, verify: bool = True, sorter=None) -> GraphSnapshot:
     # builder — identical to a from-scratch build)
     from keto_tpu.graph.snapshot import build_list_layouts
 
+    if (meta.get("rewrites") or {}).get("gate_tables") is not None:
+        snap.__dict__["_gate_tables"] = GateTables.load(mm, meta["rewrites"]["gate_tables"])
     snap.rev_indptr = mm("rev_indptr.npy")
     snap.rev_indices = mm("rev_indices.npy")
     fi = np.asarray(snap.fwd_indptr)

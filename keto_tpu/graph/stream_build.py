@@ -40,6 +40,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from keto_tpu.graph.gate_tables import tables_of
 from keto_tpu.graph.interner import IncrementalInterner
 from keto_tpu.graph.snapshot import GraphSnapshot, build_snapshot, layout_snapshot
 
@@ -273,12 +274,15 @@ def _scan_and_intern(store, wild_ns_ids, progress, chunk_rows, rewrites=None):
 def _planned(snap: GraphSnapshot, rewrites, g, expander, prog) -> GraphSnapshot:
     """``snap`` with the plan of the schema it was built under
     (``GraphSnapshot.rewrites``), and the ``rewrites`` phase observed: the
-    chunk loop's expansion plus the closure over the relation graph."""
+    chunk loop's expansion, the closure over the relation graph and, where
+    the schema has gates, the gates each row reaches
+    (keto_tpu/graph/gate_tables.py)."""
     if rewrites:
         from keto_tpu.namespace.rewrites import plan_for
 
         t0 = time.monotonic()
         snap.rewrites = plan_for(rewrites, g, expander)
+        tables_of(snap)
         spent = expander.seconds if expander is not None else 0.0
         prog.observe("rewrites", spent + time.monotonic() - t0)
     return snap

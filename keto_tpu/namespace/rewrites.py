@@ -19,9 +19,14 @@ Two classes of relation follow from an expression:
   the ingest seam: everything downstream of the interner sees a union-only
   graph and stays as it is.
 - **gated class**: the expression holds an ``intersection`` or an
-  ``exclusion``. No edges stand for it; a query whose ``(namespace,
-  relation)`` can reach such a relation (``RewritePlan``) is answered by the
-  CPU oracle (``keto_tpu/check/engine.py``).
+  ``exclusion``. The node of such a relation (a *gate*) has no out-edges:
+  the expression is cut into its maximal union-only subtrees, each an
+  *operand*, a hidden relation on the same object (``operand_name``) that
+  compiles into edges as any union-class rewrite does, and a postfix boolean
+  program over the operands (``GateDef``). A check whose closure reaches a
+  gate is asked of the device once an operand and combined on the host
+  (``keto_tpu/check/gates.py``); what that does not cover goes to the CPU
+  oracle (``keto_tpu/check/engine.py``), counted by reason.
 """
 
 from __future__ import annotations
@@ -36,7 +41,78 @@ THIS = {"this": {}}
 
 #: bits of ``RewritePlan.flags_of``
 REWRITTEN = 1  # the closure of the node's (namespace, relation) holds a rewrite
-GATED = 2  # ... holds an intersection or an exclusion: the oracle answers
+GATED = 2  # ... holds an intersection or an exclusion: is a gate, or reaches one through edges
+IS_GATE = 4  # the relation's own expression holds one: its node is a gate
+HIDDEN = 8  # an operand or the anchor: a name of ours, which no query may ask
+
+#: first character of the relation names the gate plan makes up (ASCII group
+#: separator). In a namespace that has a gate such names are reserved: a
+#: stored row that carries one is no edge (``RowExpander.reserved``), a query
+#: that names one is denied
+HIDDEN_MARK = "\x1d"
+#: the relation of the node that keeps an object's gate nodes in the graph
+#: whether or not a row names them: ``o#ANCHOR -> o#<gate>`` for every gate
+#: of the namespace, stated with the object's ``computed_userset`` edges.
+#: Nothing points at an anchor, so no check walks through one
+ANCHOR = HIDDEN_MARK + "gates"
+
+#: a gate's postfix program: operand indexes (>= 0) and these
+AND, OR, AND_NOT = -1, -2, -3
+#: part of a gated schema's fingerprint: a snapshot cache built under another
+#: way of cutting gates is not loaded
+GATE_PLAN_VERSION = 1
+
+
+def operand_name(relation: str, k: int) -> str:
+    """The hidden relation of operand ``k`` of the gate ``relation``."""
+    return f"{HIDDEN_MARK}{relation}{HIDDEN_MARK}{k}"
+
+
+def _union_only(expr) -> bool:
+    return not any(next(iter(e)) in ("intersection", "exclusion") for e in _walk(expr))
+
+
+class GateDef:
+    """A gated relation cut at its operators: ``operands[k]`` is ``(hidden
+    relation, union-only expression)``, ``program`` the postfix program over
+    operand indexes that gives the relation's answer from theirs."""
+
+    __slots__ = ("relation", "operands", "program")
+
+    def __init__(self, relation: str, expr: dict):
+        self.relation = relation
+        self.operands: list[tuple[str, dict]] = []
+        program: list[int] = []
+
+        def operand(e):
+            program.append(len(self.operands))
+            self.operands.append((operand_name(relation, len(self.operands)), e))
+
+        def cut(e):
+            if _union_only(e):
+                return operand(e)
+            (op, arg), = e.items()
+            if op == "exclusion":
+                cut(arg["base"])
+                cut(arg["subtract"])
+                program.append(AND_NOT)
+                return
+            if op == "union":
+                # the union-only children are one operand between them
+                plain = [c for c in arg if _union_only(c)]
+                parts = [c for c in arg if not _union_only(c)]
+                if plain:
+                    operand({"union": plain} if len(plain) > 1 else plain[0])
+                for c in parts:
+                    cut(c)
+                program.extend([OR] * (bool(plain) + len(parts) - 1))
+                return
+            for c in arg:  # intersection
+                cut(c)
+            program.extend([AND] * (len(arg) - 1))
+
+        cut(expr)
+        self.program = tuple(program)
 
 
 class SchemaError(ValueError):
@@ -91,7 +167,8 @@ def _walk(expr):
 class _NsPlan:
     """What one namespace's rewrites make of a stored row on it."""
 
-    __slots__ = ("computed", "ttu", "no_this", "plain_tuplesets", "derivers")
+    __slots__ = ("computed", "ttu", "no_this", "plain_tuplesets", "derivers",
+                 "this_to", "gates")
 
     def __init__(self):
         #: ``[(r, r')]``: an edge ``o#r -> o#r'`` on every object of the namespace
@@ -108,6 +185,13 @@ class _NsPlan:
         #: _#r'`` (None: a ``computed_userset``, on the same object only). A
         #: stored row of that shape may coincide with a derived edge
         self.derivers: dict[tuple[str, str], list] = {}
+        #: gated relation -> the operands whose expression has a ``this``: a
+        #: stored row on the relation is an edge of each of them, never of
+        #: the gate's own node
+        self.this_to: dict[str, list[str]] = {}
+        #: the namespace's gated relations: ``o#ANCHOR -> o#<gate>`` on every
+        #: object (``RowExpander._first_mention``)
+        self.gates: list[str] = []
 
 
 class RewriteSchema:
@@ -137,23 +221,39 @@ class RewriteSchema:
                     self.exprs[(n.id, rel)] = kept[rel] = expr
             if kept:
                 doc[str(n.id)] = kept
+        #: ``(namespace id, gated relation) -> GateDef``, in a fixed order
+        self.gates: dict[tuple[int, str], GateDef] = {
+            key: GateDef(key[1], self.exprs[key])
+            for key in sorted(self.exprs) if not _union_only(self.exprs[key])
+        }
+        if self.gates:
+            # how a gate is cut is part of what a snapshot was built under
+            doc["gates"] = GATE_PLAN_VERSION
         #: stable over key order and over entries that say ``this``; "" without rewrites
         self.fingerprint = (
             hashlib.sha256(json.dumps(doc, sort_keys=True).encode()).hexdigest()[:32]
             if doc else ""
         )
-        self._gated = {
-            key for key, expr in self.exprs.items()
-            if any(next(iter(e)) in ("intersection", "exclusion") for e in _walk(expr))
-        }
+        #: the hidden relations: every gate's operands, and the anchor of a
+        #: namespace that has a gate
+        self.hidden: set[tuple[int, str]] = set()
         self.plans: dict[int, _NsPlan] = {}
-        for (ns_id, rel), expr in self.exprs.items():
+        union_class = [(key, expr) for key, expr in self.exprs.items() if key not in self.gates]
+        for (ns_id, rel), gate in self.gates.items():
+            plan = self.plans.setdefault(ns_id, _NsPlan())
+            plan.no_this.add(rel)  # the gate's own node has no out-edges
+            plan.gates.append(rel)
+            self.hidden.add((ns_id, ANCHOR))
+            for name, expr in gate.operands:
+                self.hidden.add((ns_id, name))
+                union_class.append(((ns_id, name), expr))
+                if any("this" in e for e in _walk(expr)):
+                    plan.this_to.setdefault(rel, []).append(name)
+        for (ns_id, rel), expr in union_class:
             plan = self.plans.setdefault(ns_id, _NsPlan())
             leaves = [next(iter(e.items())) for e in _walk(expr)]
             if not any(op == "this" for op, _ in leaves):
                 plan.no_this.add(rel)
-            if (ns_id, rel) in self._gated:
-                continue  # no edges stand for a gated relation
             for op, arg in leaves:
                 if op == "computed_userset":
                     plan.computed.append((rel, arg))
@@ -179,7 +279,7 @@ class RewriteSchema:
         flattened; None for a relation that holds an intersection or an
         exclusion. What walks the schema edge by edge (List, witnesses)
         reads a relation through this."""
-        if (ns_id, rel) in self._gated:
+        if (ns_id, rel) in self.gates:
             return None
         return [
             next(iter(e.items())) for e in _walk(self.exprs.get((ns_id, rel), THIS))
@@ -226,7 +326,7 @@ class RewriteSchema:
                 got = memo[relation] = self.edges(relation, targets)
             return got
 
-        for start in self._gated:
+        for start in self.gates:
             for first, negated in edges_of(start):
                 if not negated:
                     continue
@@ -251,9 +351,10 @@ class RewriteSchema:
                     )
 
     def closure_flags(self, targets: dict) -> dict[tuple[int, str], int]:
-        """``relation -> REWRITTEN | GATED`` bits for every relation that
-        has a rewrite, holds rows, or is reached from one that does."""
-        nodes = set(self.exprs) | set(targets)
+        """``relation -> REWRITTEN | GATED | IS_GATE | HIDDEN`` bits for
+        every relation that has a rewrite, holds rows, or is reached from
+        one that does."""
+        nodes = set(self.exprs) | set(targets) | self.hidden
         fwd: dict = {}
         todo = list(nodes)
         while todo:
@@ -268,7 +369,7 @@ class RewriteSchema:
             for r in reached:
                 rev.setdefault(r, []).append(relation)
         flags = dict.fromkeys(nodes, 0)
-        for bit, seeds in ((REWRITTEN, list(self.exprs)), (GATED, list(self._gated))):
+        for bit, seeds in ((REWRITTEN, list(self.exprs)), (GATED, list(self.gates))):
             stack = seeds
             for s in stack:
                 flags[s] |= bit
@@ -277,6 +378,10 @@ class RewriteSchema:
                     if not flags[r] & bit:
                         flags[r] |= bit
                         stack.append(r)
+        for relation in self.gates:
+            flags[relation] |= IS_GATE
+        for relation in self.hidden:
+            flags[relation] |= HIDDEN
         return flags
 
 
@@ -311,10 +416,17 @@ def schema_for(namespaces, store=None):
     return nm, schema_of(nm)
 
 
-def _virtual_row(ns_id, obj, rel, sns, sobj, srel):
+def _virtual_row(ns_id, obj, rel, sns, sobj, srel, subject_id=None):
     from keto_tpu.persistence.memory import InternalRow
 
-    return InternalRow(ns_id, obj, rel, None, sns, sobj, srel, 0)
+    return InternalRow(ns_id, obj, rel, subject_id, sns, sobj, srel, 0)
+
+
+def _moved_row(r, rel: str):
+    """The stored row ``r`` as a row on ``rel`` of the same object (a gate's
+    ``this`` rows are edges of its operands)."""
+    return _virtual_row(r.namespace_id, r.object, rel, r.sset_namespace_id,
+                        r.sset_object, r.sset_relation, r.subject_id)
 
 
 class RowExpander:
@@ -324,7 +436,10 @@ class RowExpander:
     object's ``computed_userset`` edges; a tupleset row brings its
     ``tuple_to_userset`` edges. Derived edges are stated once (the interner
     would deduplicate them anyway; stating them once is what lets them be
-    counted)."""
+    counted). A stored row on a gated relation is an edge of the operands
+    whose expression has a ``this``; the first row on an object also brings
+    the anchor's edges to the object's gate nodes, so that every object the
+    store mentions has them."""
 
     def __init__(self, schema: RewriteSchema):
         self.schema = schema
@@ -338,6 +453,8 @@ class RowExpander:
         self.stored_too: set = set()
         self.n_computed = 0
         self.n_ttu = 0
+        #: stored rows under a reserved name (``HIDDEN_MARK``), passed over
+        self.reserved = 0
         self.seconds = 0.0
 
     def expand(self, rows) -> list:
@@ -354,20 +471,28 @@ class RowExpander:
             if r.subject_id is None:
                 # an object named only as a subject is mentioned too
                 named = plans.get(r.sset_namespace_id)
-                if named is not None and named.computed:
-                    self._computed(named, r.sset_namespace_id, r.sset_object, keep)
+                if named is not None and (named.computed or named.gates):
+                    self._first_mention(named, r.sset_namespace_id, r.sset_object, keep)
             if plan is None:
                 keep(r)
                 continue
             obj, rel = r.object, r.relation
+            if plan.gates and rel.startswith(HIDDEN_MARK):
+                self.reserved += 1
+                continue
             if rel not in plan.no_this:
                 keep(r)
                 if r.subject_id is None and (rel, r.sset_relation) in plan.derivers:
                     self.stored_too.add(r.key7())
-            elif r.subject_id is None:
-                self.dropped.add((ns_id, rel, r.sset_namespace_id, r.sset_relation))
-            if plan.computed:
-                self._computed(plan, ns_id, obj, keep)
+            else:
+                for name in plan.this_to.get(rel, ()):
+                    keep(_moved_row(r, name))
+                if r.subject_id is None:
+                    # no edge of the relation's own node: the relation graph
+                    # still reads it off the row
+                    self.dropped.add((ns_id, rel, r.sset_namespace_id, r.sset_relation))
+            if plan.computed or plan.gates:
+                self._first_mention(plan, ns_id, obj, keep)
             through = plan.ttu.get(rel)
             if through and r.subject_id is None:
                 key = (ns_id, obj, rel, r.sset_namespace_id, r.sset_object)
@@ -379,14 +504,16 @@ class RowExpander:
         self.seconds += time.monotonic() - t0
         return out
 
-    def _computed(self, plan: _NsPlan, ns_id: int, obj: str, keep) -> None:
-        """The ``computed_userset`` edges of ``ns_id:obj``, the first time
-        the store mentions that object."""
+    def _first_mention(self, plan: _NsPlan, ns_id: int, obj: str, keep) -> None:
+        """The ``computed_userset`` edges of ``ns_id:obj`` and its anchor's,
+        the first time the store mentions that object."""
         if (ns_id, obj) in self._seen_objects:
             return
         self._seen_objects.add((ns_id, obj))
         for a, b in plan.computed:
             keep(_virtual_row(ns_id, obj, a, ns_id, obj, b))
+        for gate in plan.gates:
+            keep(_virtual_row(ns_id, obj, ANCHOR, ns_id, obj, gate))
         self.n_computed += len(plan.computed)
 
 
@@ -451,6 +578,12 @@ def expand_delta(plan: "RewritePlan", base, ops: list) -> Optional[tuple]:
         if p is None:
             out.append((kind, payload))
             continue
+        if p.gates and rel.startswith(HIDDEN_MARK):
+            continue  # a reserved name: no edge (``RowExpander.reserved``)
+        for name in p.this_to.get(rel, ()):
+            # a gate's stored row is an edge of its operands
+            out.append(("ins", _moved_row(payload, name)) if kind == "ins"
+                       else ("del", (ns_id, obj, name) + key[3:]))
         if rel not in p.no_this:
             stays = False
             if kind == "del" and sid is None and (rel, srel) in p.derivers:
@@ -476,7 +609,43 @@ def expand_delta(plan: "RewritePlan", base, ops: list) -> Optional[tuple]:
                 return None
             if not stays:
                 out.append(("del", edge))
+    if plan.has_gated and not plan.gates_stale and _stales_gates(plan, base, out):
+        plan = plan.replace(gates_stale=True)
     return out, plan
+
+
+def _stales_gates(plan: "RewritePlan", base, ops: list) -> bool:
+    """Does this delta move what the base rows' gate tables
+    (``keto_tpu/check/gates.py``) were worked out from: an edge into a
+    closure that holds a gate comes or goes (the gates a row reaches), or an
+    operand gains its first edge (the tables have no row for it). Until the
+    next build or fold the oracle then answers the gated checks."""
+    flags = plan.flags
+    for kind, payload in ops:
+        key = payload if kind == "del" else payload.key7()
+        ns_id, obj, rel, sid, sns, _sobj, srel = key
+        if sid is None and flags.get((sns, srel), 0) & GATED:
+            # an insert that states an edge the base rows hold moves nothing
+            # (every insert brings its object's computed edges again)
+            if kind == "del" or not _base_edge(base, key):
+                return True
+        if kind == "ins" and rel.startswith(HIDDEN_MARK) and flags.get((ns_id, rel), 0) & HIDDEN:
+            dev = base.resolve_set(ns_id, obj, rel)
+            if dev is None or dev >= base.n_base_nodes:
+                return True
+    return False
+
+
+def _base_edge(base, key: tuple) -> bool:
+    """Do ``base``'s own rows (its overlay aside) hold the set -> set edge
+    that ``key`` states."""
+    ns_id, obj, rel, _sid, sns, sobj, srel = key
+    src, dst = base.resolve_set(ns_id, obj, rel), base.resolve_set(sns, sobj, srel)
+    nb = base.n_base_nodes
+    if src is None or dst is None or src >= nb or dst >= nb:
+        return False
+    nbrs, _ = base.out_neighbors_bulk(np.asarray([src], np.int64), overlay=False)
+    return bool((np.asarray(nbrs) == dst).any())
 
 
 def _tupleset_row_stays(base, deleted: set, ns_id, obj, t, sns, sobj) -> bool:
@@ -503,10 +672,11 @@ class RewritePlan:
     a device row, once a snapshot."""
 
     def __init__(self, schema: RewriteSchema, flags: dict, n_computed: int, n_ttu: int,
-                 targets: Optional[dict] = None, stored_too: Iterable[tuple] = ()):
+                 targets: Optional[dict] = None, stored_too: Iterable[tuple] = (),
+                 gates_stale: bool = False):
         self.schema = schema
         self.fingerprint = schema.fingerprint
-        #: ``(ns id, relation) -> REWRITTEN | GATED``
+        #: ``(ns id, relation) -> REWRITTEN | GATED | IS_GATE | HIDDEN``
         self.flags = flags
         self.n_computed = int(n_computed)
         self.n_ttu = int(n_ttu)
@@ -517,6 +687,25 @@ class RewritePlan:
         #: derives too (``_NsPlan.derivers``): while such a row stands, the
         #: edge outlives the tupleset row that derived it (``expand_delta``)
         self.stored_too = frozenset(stored_too)
+        #: an overlay delta moved what the snapshot's gate tables were
+        #: worked out from (``_stales_gates``): the oracle answers the gated
+        #: checks until a build or a fold makes tables anew
+        self.gates_stale = bool(gates_stale)
+
+    def replace(self, **changes) -> "RewritePlan":
+        """This plan with some of its parts exchanged. The closure bits stay
+        the same object unless given, so a snapshot's ``flags_of`` may be
+        carried over."""
+        kw = dict(flags=self.flags, n_computed=self.n_computed, n_ttu=self.n_ttu,
+                  targets=self.targets, stored_too=self.stored_too,
+                  gates_stale=self.gates_stale)
+        kw.update(changes)
+        return RewritePlan(self.schema, **kw)
+
+    def settled(self) -> "RewritePlan":
+        """The plan of a snapshot whose overlay has been folded into its
+        base rows: itself, or itself without ``gates_stale``."""
+        return self.replace(gates_stale=False) if self.gates_stale else self
 
     def to_meta(self) -> dict:
         return {
@@ -558,15 +747,11 @@ class RewritePlan:
         for a, b, c, d in fresh:
             targets.setdefault((a, b), set()).add((c, d))
         self.schema.refuse_cycles_through_subtract(targets)
-        return RewritePlan(self.schema, self.schema.closure_flags(targets),
-                           self.n_computed, self.n_ttu, targets, self.stored_too)
+        return self.replace(flags=self.schema.closure_flags(targets), targets=targets)
 
     def with_stored_too(self, stored_too: Iterable[tuple]) -> "RewritePlan":
-        """This plan with another set of coinciding stored rows; the
-        closure bits are the same object, so a snapshot's ``flags_of`` may
-        be carried over."""
-        return RewritePlan(self.schema, self.flags, self.n_computed, self.n_ttu,
-                           self.targets, stored_too)
+        """This plan with another set of coinciding stored rows."""
+        return self.replace(stored_too=stored_too)
 
     def flags_of(self, snap) -> np.ndarray:
         """``uint8[n_base_nodes]`` by device row: the closure bits of the

@@ -127,7 +127,7 @@ class DispatchClock:
     __slots__ = (
         "seconds", "rounds", "overlapped", "round_tuples", "round_cap", "long_stays",
         "_state", "_t", "_session", "_tracing", "_ann", "_tuples", "_slices",
-        "_lane_depth", "_cap", "_cap_by", "_probes",
+        "_lane_depth", "_cap", "_cap_by", "_probes", "_gates",
     )
 
     def __init__(self, session=None):
@@ -152,6 +152,7 @@ class DispatchClock:
         self._ann = None
         self._tuples = self._slices = self._lane_depth = self._cap = 0
         self._cap_by = ROUND_CAP_BY[0]
+        self._gates = None
 
     def enter(self, state: int, note=None) -> None:
         """``note``, on a ``launch``: ``(route, kernel, sizes, how the slice
@@ -197,6 +198,15 @@ class DispatchClock:
             with self._session.annotation(name, **attrs):
                 pass
 
+    def gates(self, gated: int, positions: int) -> None:
+        """Under a schema with gates (keto_tpu/check/gates.py): the spans
+        from here on carry ``gated`` (checks whose closure reaches a gate)
+        and ``positions`` (what they and the rest became on the device), of
+        the chunk a ``resolve`` works on or of the slice a ``fill`` lands,
+        until the next round."""
+        if self._tracing:
+            self._gates = {"gated": gated, "positions": positions}
+
     def round(
         self, tuples: int, lane_depth: int, overlapped: bool = False,
         cap: int = 0, cap_by: str = ROUND_CAP_BY[0],
@@ -214,6 +224,7 @@ class DispatchClock:
         self._tracing = self._session.open
         self._tuples, self._slices, self._lane_depth = tuples, 0, lane_depth
         self._cap, self._cap_by = cap, cap_by
+        self._gates = None
 
     def _annotate(self, state: int, note=None) -> None:
         if self._ann is not None:
@@ -222,14 +233,13 @@ class DispatchClock:
         if self._tracing:
             if state == LAUNCH:
                 self._slices += 1
-            attrs = {}
+            attrs = dict(self._gates) if self._gates is not None else {}
             if note is not None:
                 route, kernel, sizes, met = note
-                attrs = {
-                    "route": route,
-                    "kernel": kernel,
-                    "geometry": f"{kernel} {'x'.join(map(str, sizes))} {met or 'untracked'}",
-                }
+                attrs.update(
+                    route=route, kernel=kernel,
+                    geometry=f"{kernel} {'x'.join(map(str, sizes))} {met or 'untracked'}",
+                )
             self._ann = self._session.annotation(
                 _SPAN_NAMES[state], tuples=self._tuples, slices=self._slices,
                 lane_depth=self._lane_depth, cap=self._cap, cap_by=self._cap_by, **attrs,
@@ -264,6 +274,9 @@ class _NoClock:
         pass
 
     def mark(self, name: str, **attrs) -> None:
+        pass
+
+    def gates(self, gated: int, positions: int) -> None:
         pass
 
 

@@ -5,7 +5,8 @@ against the CPU oracle and the plain reference on random union-class
 schemas (``batch_check`` and a framed ``/check/batch`` body); writes and
 deletes through the overlay against a rebuild; a reload of the namespaces
 with another schema, from memory and from the snapshot cache; the gated
-class through the oracle route, counted; List, Expand and explain under a
+class on the device and its patterns through the oracle route, counted
+(tests/test_gates_device.py has the rest); List, Expand and explain under a
 schema; a store without ``config.relations`` builds a byte-identical
 snapshot."""
 
@@ -151,7 +152,7 @@ def test_a_framed_body_resolves_derived_nodes_at_the_door(seed):
         engine.close()
 
 
-# -- the gated class: the oracle answers, counted, the rest rides the device --------
+# -- the gated class: expanded on the device; a pattern over it is the oracle's --------
 
 GATED_SCHEMA = schema(
     doc={"viewer": {"intersection": [THIS, computed("member")]},
@@ -166,9 +167,11 @@ GATED_ROWS = [row(s) for s in (
 )]
 
 
-def test_a_gated_closure_goes_to_the_oracle_and_is_counted():
+@pytest.fixture(scope="module")
+def gated_run():
+    """One engine and one batch for the cases below: every (node, user) of
+    ``GATED_SCHEMA``'s store asked once, and the counters after it."""
     p = store_of(GATED_SCHEMA, GATED_ROWS)
-    judge = reference.Reference(GATED_ROWS, GATED_SCHEMA)
     engine = quiet_engine(p)
     try:
         users = ["ann", "bob", "cat", "dan", "eve", "zed"]
@@ -176,28 +179,50 @@ def test_a_gated_closure_goes_to_the_oracle_and_is_counted():
                  for r in ("viewer", "reader", "editor", "owner", "member", "page")]
         qs = [(*n, u) for n in nodes for u in users]
         got = list(engine.batch_check([ask(*q) for q in qs]))
-        assert got == [judge.allowed(*q) for q in qs]
-        assert judge.allowed("doc", "a", "reader", "ann") and not judge.allowed("doc", "a", "reader", "cat")
-        c = counters(engine)
         plan = engine.snapshot().rewrites
         gated_nodes = [n for n in nodes if plan.relation_flags(0, n[2]) & GATED]
-        assert {n[2] for n in gated_nodes} == {"viewer", "reader", "page"}
-        assert c["rewrite_route_oracle"] == len(gated_nodes) * len(users)
-        assert (c["rewrite_oracle_gated_closure"] + c["rewrite_oracle_gated_unresolved"]
-                == c["rewrite_route_oracle"])
-        assert c["rewrite_oracle_gated_unresolved"] > 0  # doc:b#reader has no node
-        # editor (rewritten, union class) on the three objects that rows name
-        assert c["rewrite_route_device"] == 3 * len(users)
-        assert c.get("fallback_checks", 0) == 0
-        assert engine.route_slice_counts().get("cpu", 0) == 0
+        yield users, qs, got, counters(engine), gated_nodes, engine.route_slice_counts()
     finally:
         engine.close()
 
 
+@pytest.mark.parametrize("what", [
+    "answers_are_the_references", "gated_checks_are_served_by_the_device",
+    "the_oracle_is_not_asked", "the_union_class_is_not_gated",
+])
+def test_a_gated_closure_is_expanded_on_the_device_and_is_counted(gated_run, what):
+    """Until PR 43 every check of a gated closure was one descent of the CPU
+    oracle; now it is a few device positions and a combine
+    (tests/test_gates_device.py holds the route to more)."""
+    users, qs, got, c, gated_nodes, routes = gated_run
+    if what == "answers_are_the_references":
+        judge = reference.Reference(GATED_ROWS, GATED_SCHEMA)
+        assert got == [judge.allowed(*q) for q in qs]
+        assert judge.allowed("doc", "a", "reader", "ann") and not judge.allowed("doc", "a", "reader", "cat")
+    elif what == "gated_checks_are_served_by_the_device":
+        assert {n[2] for n in gated_nodes} == {"viewer", "reader", "page"}
+        # every one of them, the ones that found no start included (a gated
+        # relation on an object no row mentions is denied at resolve)
+        assert c["gate_checks_device"] == len(gated_nodes) * len(users)
+        assert c["gate_positions_operand"] > c["gate_checks_device"]
+    elif what == "the_oracle_is_not_asked":
+        assert c.get("rewrite_route_oracle", 0) == c.get("gate_checks_oracle", 0) == 0
+        assert not any(k.startswith("rewrite_oracle_") for k in c)
+        assert c.get("fallback_checks", 0) == 0
+        assert routes.get("cpu", 0) == 0
+    else:
+        # editor (rewritten, union class) on the three objects that rows
+        # name, and the gated checks that found a start
+        editor = 3 * len(users)
+        assert c["rewrite_route_device"] == c["rewrite_checks_rewritten"]
+        assert c["rewrite_checks_rewritten"] >= editor
+        assert c["rewrite_checks_rewritten"] + c["rewrite_checks_plain"] == len(qs)
+
+
 def test_a_wildcard_pattern_over_a_gated_closure_goes_to_the_oracle_too():
     """A pattern's starts ride in ``multi``, not in the start row: one that
-    names a gated relation, or matches a node of one, must not ride the
-    device over the gated relation's ``this`` edges."""
+    names a gated relation, or matches a node of one, is not expanded (its
+    starts are many) and must not ride the device over the gates' operands."""
     p = store_of(GATED_SCHEMA, GATED_ROWS)
     oracle = CheckEngine(p)
     engine = quiet_engine(p)
