@@ -702,6 +702,13 @@ class Smoke:
         self.say(f"native pack chunks: {native}")
         if native <= 0:
             problems.append("the native pack path never ran")
+        resolved = {
+            path: int(self.metric(fam, "keto_check_resolve_chunks_total", path=path))
+            for path in ("native", "numpy")
+        }
+        self.say(f"resolved chunks by path: {json.dumps(resolved)}")
+        if resolved["native"] <= 0:
+            problems.append("the native resolve pass never ran")
         events = {e: int(self.event(fam, e)) for e in MUST_BE_ZERO + MUST_BE_POSITIVE}
         events["audit_checks"] = int(self.event(fam, "audit_checks"))
         events["label_invalidations"] = int(self.event(fam, "label_invalidations"))

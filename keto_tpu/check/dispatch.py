@@ -145,6 +145,13 @@ def stream_chunk_metrics(m, counters_of) -> None:
     )
 
 
+#: how a stream's chunk was resolved, and why ``resolve``'s one native pass
+#: did not take it (``keto_check_resolve_chunks_total{path}``,
+#: ``keto_check_resolve_declines_total{reason}``)
+RESOLVE_PATHS = ("native", "numpy")
+RESOLVE_DECLINES = ("no_library", "special", "overlay", "overlay_start")
+
+
 class CheckDispatch:
     """The answering half of ``TpuCheckEngine`` (module docstring). Built
     once by the engine with what never changes after — the mesh and what
@@ -204,6 +211,10 @@ class CheckDispatch:
         #: (``native_pack.PackView``), remade when the snapshot, its label
         #: index or its relay rows change
         self._pack_view: Optional[native_pack.PackView] = None
+        #: the same for ``resolve``'s one pass (``native_pack.ResolveView``:
+        #: the snapshot alone), remade when the snapshot, its relay rows'
+        #: use or its plan's closure bytes change
+        self._resolve_view: Optional[native_pack.ResolveView] = None
         self._mesh = mesh
         self._shard_count = shard_count  # 0: not explicitly sharded
         self._sharded = shard_count > 0
@@ -680,30 +691,147 @@ class CheckDispatch:
     ) -> tuple[np.ndarray, np.ndarray, dict]:
         """Resolve every query to device rows (see ``_resolve_bulk_py`` for
         the result contract). Literal queries go through the C++ intern
-        tables in one bulk call when the native library provides it;
-        wildcard/pattern/unknown-namespace queries and the pure-Python
-        interner use the host loop.
+        tables in one bulk call when the native library provides it
+        (``_raw_ids``); wildcard/pattern/unknown-namespace queries and the
+        pure-Python interner use the host loop. The numpy form of the
+        ``resolve`` state: what a stream's chunk takes where
+        ``_resolve_chunk``'s one native pass declines it, and what that pass
+        is held to."""
+        tuples, raw = self._raw_ids(snap, tuples)
+        if raw is None:
+            return self._resolve_bulk_py(snap, tuples)
+        return self._resolve_records(snap, tuples, *raw)
 
-        ``tuples`` is a list of ``RelationTuple`` or a ``QueryBatch``
-        (keto_tpu/check/frame.py): framed ranges bring their records with
-        them and are resolved without a loop over tuples. Where the
-        records cannot be trusted against this snapshot (see
-        ``_frame_blocker``) the batch is turned into objects and takes
-        the list's path."""
+    def _raw_ids(self, snap: GraphSnapshot, tuples):
+        """``tuples`` (a list of ``RelationTuple`` or a ``QueryBatch``,
+        keto_tpu/check/frame.py) as the raw node ids of ``snap.interned``'s
+        C++ tables: ``(tuples, (start_raw, sub_raw, special, dead,
+        no_target))``, or ``(tuples, None)`` where there are no such tables
+        or the records' framing is unsafe.
+
+        Framed ranges bring their records with them and are resolved
+        without a loop over tuples. Where the records cannot be trusted
+        against this snapshot (see ``_frame_blocker``) the batch is turned
+        into objects, handed back as ``tuples``, and takes the list's path."""
         if isinstance(tuples, QueryBatch):
             why = self._frame_blocker(snap, tuples)
             if why is None:
                 got = self._records_of(snap, tuples)
                 if got is not None:
-                    return self._resolve_records(snap, tuples, *got)
+                    return tuples, got
                 why = "rejected"
             tuples = tuples.tuples(why)
         self.maintenance.incr("resolve_tuples_thread", by=len(tuples))
         if hasattr(snap.interned, "resolve_queries"):
-            got = self._resolve_bulk_native(snap, tuples)
-            if got is not None:
-                return got
-        return self._resolve_bulk_py(snap, tuples)
+            return tuples, self._raw_of_tuples(snap, tuples)
+        return tuples, None
+
+    def _resolve_decline(self, snap: GraphSnapshot, raw) -> Optional[str]:
+        """Why ``resolve``'s one native pass (``native_pack.resolve_chunk``)
+        cannot take a chunk whose raw ids are ``raw``, or None where it can
+        try: from what the code observes, never a setting. Two more causes
+        show only in what the pass counts (``_resolve_chunk``).
+        ``keto_check_resolve_declines_total``."""
+        if (
+            not native_pack.available()
+            or not hasattr(snap.interned, "resolve_queries")
+            or snap.fwd_indptr is None
+            or snap.sink_indptr is None
+        ):
+            return "no_library"  # or no native tables to have raw ids from
+        if raw is None or raw[2]:
+            # records that cannot speak for their queries: wildcards and
+            # patterns (they make ``multi``), strings with separator bytes
+            return "special"
+        return None
+
+    def _resolve_view_of(self, snap: GraphSnapshot) -> native_pack.ResolveView:
+        """``snap`` as the native pass reads it, the reach mask
+        (``_device_reach``, made once a snapshot) in it: the pass always
+        hands over both running sums."""
+        hub, reach = hub_usable(snap), self._device_reach(snap)
+        plan = snap.rewrites
+        flags = None if plan is None else plan.flags_of(snap)
+        view = self._resolve_view
+        if view is None or not view.of(snap, hub, reach, flags):
+            view = self._resolve_view = native_pack.ResolveView(
+                snap, hub, reach, flags, REWRITTEN
+            )
+        return view
+
+    def _resolve_chunk(self, snap: GraphSnapshot, tuples):
+        """The ``resolve`` of one chunk of a stream: ``(sd, tg, multi,
+        closure, sums, view)``. ``sd`` / ``tg`` / ``multi`` are
+        ``_resolve_bulk``'s; ``view`` is the ``ResolveView`` the native pass
+        read, None where the numpy path resolved the chunk; ``closure``
+        (under a rewrite plan: the closure byte a query and how many hold
+        ``REWRITTEN``, ``_rewrite_split``'s) is None then, and the caller
+        makes it; ``sums`` (``_entry_sums``) is None then too, and under a
+        plan with gates, where what is counted is the positions the expansion
+        makes of the chunk.
+
+        One GIL-released call (native/pack.cpp ``keto_resolve_chunk``)
+        wherever nothing declines it: no library; ``special`` records; a
+        snapshot with overlay or extension nodes and a query that missed a
+        start or a target (the tables do not know such nodes: the host path
+        re-resolves the misses); a start row past the plan's closure bytes.
+        A declined chunk takes ``_resolve_records`` (or the host loop), which
+        is also what the pass is fuzzed against. Counted once a chunk; under
+        an open profiler session the ``resolve`` span that holds either
+        carries its ``path``."""
+        tuples, raw = self._raw_ids(snap, tuples)
+        incr = self.maintenance.incr
+        clk = dispatch_clock()
+        declined = self._resolve_decline(snap, raw)
+        if declined is None:
+            view = self._resolve_view_of(snap)
+            plan = snap.rewrites
+            clk.resolving("native")
+            got = native_pack.resolve_chunk(
+                view, raw[0], raw[1], raw[3], raw[4],
+                count=plan is None or not plan.has_gated,
+            )
+            clk.poll()
+            if got.bad_inputs:
+                # what numpy says of a raw id past ``raw2dev``
+                raise IndexError(f"{got.bad_inputs} raw node ids or marks out of range")
+            if got.misses and (
+                snap.ov_set_ids or snap.ov_leaf_ids or getattr(snap.interned, "has_ext", False)
+            ):
+                declined = "overlay"
+            elif got.overlay_starts:
+                declined = "overlay_start"
+            else:
+                incr("resolve_chunks_native")
+                closure = None if got.flags is None else (got.flags, got.rewritten)
+                return got.sd, got.tg, {}, closure, got.sums, view
+        incr("resolve_chunks_numpy")
+        incr(f"resolve_declines_{declined}")
+        clk.resolving("numpy")
+        if raw is None:
+            sd, tg, multi = self._resolve_bulk_py(snap, tuples)
+        else:
+            sd, tg, multi = self._resolve_records(snap, tuples, *raw)
+        return sd, tg, multi, None, None, None
+
+    def _entry_sums(self, snap: GraphSnapshot, sd, tg, multi: dict) -> np.ndarray:
+        """``int64[2, n + 1]``: the running sums from 0 of resolved
+        positions' entry counts, which is all a cut of the chunk asks:
+        ``[0]`` of ``_entry_counts``, ``[1]`` of the same with the positions
+        zeroed whose target no pull can change (``_device_reach``; ``[0]``
+        again where every target counts). The numpy form of what the native
+        pass hands over (``ResolvedChunk.sums``, ``native_pack.entry_sums``)."""
+        cnt = self._entry_counts(snap, sd, tg, multi)
+        sums = np.zeros((2, cnt.shape[0] + 1), np.int64)
+        np.cumsum(cnt, out=sums[0, 1:])
+        reach = self._device_reach(snap)
+        if reach is None:
+            sums[1] = sums[0]
+        else:
+            known = (tg >= 0) & (tg < snap.num_live)
+            cnt[known & ~reach[np.where(known, tg, 0)]] = 0
+            np.cumsum(cnt, out=sums[1, 1:])
+        return sums
 
     def _frame_blocker(self, snap: GraphSnapshot, batch: QueryBatch) -> Optional[str]:
         """Why ``batch``'s framed records cannot be resolved as they are
@@ -802,18 +930,15 @@ class CheckDispatch:
             return None
         return interned.resolve_queries(buf, n)
 
-    def _resolve_bulk_native(
-        self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]
-    ):
+    def _raw_of_tuples(self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]):
         """Pack literal queries into the native wire format and resolve them
-        in one C++ pass; route the rest through the per-query Python path.
-        Returns None when the buffer framing is unsafe (separator bytes in
-        strings) — callers fall back to the pure host loop."""
+        to raw node ids in one C++ pass: ``(start_raw, sub_raw, special,
+        dead, no_target)``, the marks being the queries the records cannot
+        speak for. None when the buffer framing is unsafe (separator bytes
+        in strings): callers fall back to the pure host loop."""
         buf, *marked = self._frame_tuples(snap, tuples)
         raw = self._resolve_buffer(snap.interned, buf, len(tuples))
-        if raw is None:
-            return None
-        return self._resolve_records(snap, tuples, *raw, *marked)
+        return None if raw is None else (*raw, *marked)
 
     def _frame_tuples(self, snap: GraphSnapshot, tuples: Sequence[RelationTuple]):
         """The framing loop: ``tuples`` as query records, and the indices
@@ -1551,10 +1676,11 @@ class CheckDispatch:
         for s0 in range(0, n, cap_q):
             s1 = min(s0 + cap_q, n)
             clk.enter(RESOLVE)
-            sd, tg, multi = self._resolve_bulk(snap, tuples[s0:s1])
+            chunk = tuples[s0:s1]
+            sd, tg, multi, closure, sums, view = self._resolve_chunk(snap, chunk)
             oracle_ans = exp = None
             if snap.rewrites is not None:
-                oracle_ans, exp = self._rewrite_split(snap, tuples[s0:s1], sd, tg, multi, cap_q)
+                oracle_ans, exp = self._rewrite_split(snap, chunk, sd, tg, multi, cap_q, closure)
             clk.poll()
             if exp is None:
                 pieces = [(0, s1 - s0)]
@@ -1573,20 +1699,27 @@ class CheckDispatch:
                     pieces.append((int(ptr[c0]), int(ptr[c1])))
                     c0 = c1
             use_labels = self._labels_usable(snap)
-            cnt = self._entry_counts(snap, sd, tg, multi)
+            if sums is None:
+                # the numpy path's rows, or the positions under a plan with
+                # gates (the split may have unset targets, too)
+                if view is None:
+                    sums = self._entry_sums(snap, sd, tg, multi)
+                else:
+                    sums = native_pack.entry_sums(view, sd, tg)
             for q0, q1 in pieces:
                 yield from self._dispatch_piece(
-                    snap, tuples, s0, sd, tg, multi, cnt, q0, q1, exp, oracle_ans,
+                    snap, tuples, s0, sd, tg, multi, sums, q0, q1, exp, oracle_ans,
                     use_labels, it_cap,
                 )
 
     def _dispatch_piece(
-        self, snap, tuples, s0, sd, tg, multi, cnt, q0, q1, exp, oracle_ans,
+        self, snap, tuples, s0, sd, tg, multi, sums, q0, q1, exp, oracle_ans,
         use_labels, it_cap,
     ):
         """Positions ``[q0, q1)`` of a resolved chunk (at most a slice's
         width of them) as one slice, or as several where their entries pass
-        the budget (``_dispatch_slices``)."""
+        the budget (``_dispatch_slices``). ``sums``: the chunk's
+        ``_entry_sums``; a cut is a bisect over them."""
         nq = q1 - q0
         W = next(w for w in _WORD_WIDTHS if 32 * w >= nq)
         B = 32 * W
@@ -1607,27 +1740,22 @@ class CheckDispatch:
             budget = self.stream_ctrl.entry_budget()
             if budget is not None:
                 cap_e = min(cap_e, max(cap_e // 4, budget))
-        cnt = cnt[q0:q1]
-        total = int(cnt.sum())
+        csum = sums[0]
+        total = int(csum[q1] - csum[q0])
         if total > cap_e:
-            reach = self._device_reach(snap)
-            if reach is not None:
-                # a query whose target side has no row that a pull
-                # changes sends the device nothing (``device_part``):
-                # its entries do not count towards a split
-                t = tg[q0:q1]
-                known = (t >= 0) & (t < snap.num_live)
-                cnt = cnt.copy()
-                cnt[known & ~reach[np.where(known, t, 0)]] = 0
-                total = int(cnt.sum())
+            # a query whose target side has no row that a pull changes
+            # sends the device nothing (``device_part``): its entries do
+            # not count towards a split
+            csum = sums[1]
+            total = int(csum[q1] - csum[q0])
         if total <= cap_e:
             bounds = [(q0, q1)]
         else:
-            csum = np.concatenate([np.zeros(1, np.int64), np.cumsum(cnt)])
+            seg = csum[q0 : q1 + 1]
             bounds = []
             i0 = 0
             while i0 < nq:
-                i1 = int(np.searchsorted(csum, csum[i0] + cap_e, side="right")) - 1
+                i1 = int(np.searchsorted(seg, seg[i0] + cap_e, side="right")) - 1
                 i1 = max(i0 + 1, min(i1, nq))
                 if exp is not None and i1 < nq:
                     # between two checks: a check's positions land together
@@ -1661,11 +1789,12 @@ class CheckDispatch:
                 gate = exp.cut(ca, cb)
             yield [
                 dev, host_ans, b - a, tuples[s0 + ca : s0 + cb],
-                leases, int(cnt[a - q0 : b - q0].sum()), gate,
+                leases, int(csum[b] - csum[a]), gate,
             ]
 
     def _rewrite_split(
-        self, snap: GraphSnapshot, queries, sd, tg, multi, cap_q: Optional[int] = None
+        self, snap: GraphSnapshot, queries, sd, tg, multi, cap_q: Optional[int] = None,
+        closure: Optional[tuple] = None,
     ):
         """Under a rewrite schema: count a resolved batch by closure
         (``keto_check_rewrite_checks_total``) from the snapshot's one byte a
@@ -1676,7 +1805,27 @@ class CheckDispatch:
         then unset so that pack sends the device nothing for them). Returns
         ``(the oracle's answers as bool[n] or None, a gates.Expansion or
         None)``. A wildcard pattern (its starts in ``multi``) carries the
-        bits of every start and of the relation it names."""
+        bits of every start and of the relation it names. ``closure``: the
+        closure bytes and how many hold ``REWRITTEN``, where ``resolve``'s
+        native pass already read them (a chunk it took has no pattern and no
+        start past the plan's rows)."""
+        plan = snap.rewrites
+        n = sd.shape[0]
+        if closure is None:
+            f = self._closure_bytes(snap, queries, sd, multi)
+            closure = f, int(np.count_nonzero(f & REWRITTEN))
+        f, rewritten = closure
+        incr = self.maintenance.incr
+        incr("rewrite_checks_rewritten", by=rewritten)
+        incr("rewrite_checks_plain", by=n - rewritten)
+        if not plan.has_gated:
+            incr("rewrite_route_device", by=rewritten)
+            return None, None
+        return gates.split(self, snap, queries, sd, tg, multi, f, cap_q or self._slice_cap(snap))
+
+    def _closure_bytes(self, snap: GraphSnapshot, queries, sd, multi) -> np.ndarray:
+        """``uint8[n]``: the closure bits of each resolved query's start
+        (``RewritePlan.flags_of``), the numpy form."""
         plan = snap.rewrites
         n = sd.shape[0]
         flags = plan.flags_of(snap)
@@ -1702,14 +1851,7 @@ class CheckDispatch:
                     for dev in np.asarray(starts).tolist():
                         bits |= bits_of(int(dev))
                 f[i] = bits
-        rewritten = int(np.count_nonzero(f & REWRITTEN))
-        incr = self.maintenance.incr
-        incr("rewrite_checks_rewritten", by=rewritten)
-        incr("rewrite_checks_plain", by=n - rewritten)
-        if not plan.has_gated:
-            incr("rewrite_route_device", by=rewritten)
-            return None, None
-        return gates.split(self, snap, queries, sd, tg, multi, f, cap_q or self._slice_cap(snap))
+        return f
 
     @staticmethod
     def _decode_packed(f: np.ndarray, host_ans: np.ndarray, nq: int):

@@ -1,5 +1,18 @@
 """ctypes binding for the native pack (native/pack.cpp).
 
+Before a chunk is packed it is resolved, and that state of the dispatch
+thread is one GIL-released call too (``resolve_chunk``:
+``keto_resolve_chunk``, over a ``ResolveView`` of the snapshot alone): from
+the raw node ids the door or the thread's own probes gave, the device rows
+``sd`` / ``tg``, the closure byte a query under a rewrite plan, and the
+running sums of the per-query entry counts, plain and with the queries
+zeroed that no pull can change, which is all a cut of the chunk asks.
+``check/dispatch.py`` ``_resolve_chunk`` takes it wherever nothing declines
+it (``keto_check_resolve_chunks_total{path}``,
+``keto_check_resolve_declines_total{reason}``); ``_resolve_records``,
+``_entry_counts`` and the reach mask there are the contract it is fuzzed
+against (tests/test_resolve_fused.py) and the fallback.
+
 Three paths pack a chunk of a check slice, counted a chunk in ``COUNTERS``
 (``keto_native_pack_chunks_total{path}``):
 
@@ -36,7 +49,7 @@ insert-only-delta serving state keeps the native paths.
 
 Loading is opportunistic: ``load_library()`` returns None (and callers
 fall back to numpy) when the shared object is absent, stale
-(``keto_pack_version`` is not ABI 3) or ``KETO_TPU_NATIVE=0`` (every native
+(``keto_pack_version`` is not ABI 4) or ``KETO_TPU_NATIVE=0`` (every native
 off: a box without a compiler). Build with ``make native``.
 """
 
@@ -51,7 +64,7 @@ from typing import Optional
 
 import numpy as np
 
-_ABI_VERSION = 3
+_ABI_VERSION = 4
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_checked = False
@@ -78,6 +91,18 @@ class _KetoPackView(ctypes.Structure):
     ] + [
         (name, ctypes.c_int64)
         for name in ("n_base", "ni", "sb", "nl", "n_lab", "pair_cap")
+    ]
+
+
+class _KetoResolveView(ctypes.Structure):
+    """``KetoResolveView`` of native/pack.cpp, field for field."""
+
+    _fields_ = [
+        (name, ctypes.c_void_p)
+        for name in ("raw2dev", "fwd_indptr", "sink_indptr", "hub_ptr", "reach", "flags")
+    ] + [
+        (name, ctypes.c_int64)
+        for name in ("n_raw", "n_base", "ni", "sb", "nl", "n_flags", "rewritten")
     ]
 
 
@@ -131,6 +156,8 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.keto_pack_labeled_pairs.argtypes = [p, c]
         lib.keto_pack_labeled_riders.restype = None
         lib.keto_pack_labeled_riders.argtypes = [p, p, p, p, p, p, p, c]
+        lib.keto_resolve_chunk.restype = None
+        lib.keto_resolve_chunk.argtypes = [p, c, p, p, p, c, p, c, p, p, p, p, p, p]
         _lib = lib
         return _lib
     return None
@@ -350,3 +377,104 @@ def labeled_riders(counts: LabeledCounts, B: int):
     )
     load_library().keto_pack_labeled_riders(*(a.ctypes.data for a in arrays), B)
     return arrays
+
+
+class ResolveView:
+    """One snapshot's arrays as ``keto_resolve_chunk`` reads them
+    (``KetoResolveView``), made once a snapshot and kept by the caller, like
+    ``PackView`` but of the snapshot alone (no label index: a BFS-only
+    snapshot takes it too). ``hub``: a hub sink's answer counts its relay
+    rows (``pack.hub_usable``); ``reach``: ``bool[num_live]``, the targets a
+    pull can change, or None where every target counts (``csum_reach`` then
+    repeats ``csum``); ``flags``: the rewrite plan's closure byte a device
+    row, or None without a plan. ``of`` says whether it still describes the
+    snapshot a chunk is about to be resolved against."""
+
+    __slots__ = ("_snap", "_hub", "_reach", "_flags", "_arrays", "_struct", "ref", "has_flags")
+
+    def __init__(self, snap, hub: bool, reach, flags, rewritten: int):
+        self._snap = weakref.ref(snap)
+        self._hub, self._reach, self._flags = hub, reach, flags
+        self.has_flags = flags is not None
+        self._arrays = [
+            np.ascontiguousarray(snap.raw2dev, np.int64),
+            np.ascontiguousarray(snap.fwd_indptr, np.int64),
+            np.ascontiguousarray(snap.sink_indptr, np.int64),
+            np.ascontiguousarray(snap.hub_ptr, np.int64) if hub else None,
+            None if reach is None else np.ascontiguousarray(reach).view(np.uint8),
+            None if flags is None else np.ascontiguousarray(flags, np.uint8),
+        ]
+        self._struct = _KetoResolveView(
+            *(None if a is None else a.ctypes.data for a in self._arrays),
+            self._arrays[0].shape[0], snap.n_base_nodes, snap.num_int, snap.sink_base,
+            snap.num_live, 0 if flags is None else flags.shape[0], rewritten,
+        )
+        self.ref = ctypes.addressof(self._struct)
+
+    def of(self, snap, hub: bool, reach, flags) -> bool:
+        return (
+            self._snap() is snap and self._hub == hub
+            and self._reach is reach and self._flags is flags
+        )
+
+
+#: what ``keto_resolve_chunk`` hands back: ``sd`` / ``tg`` as
+#: ``_resolve_records`` defines them, ``flags`` (uint8[n], None without a
+#: plan), ``sums`` (int64[2, n + 1]: the running sums of ``_entry_counts``
+#: and of the same with the reach mask applied; None where the caller asked
+#: for no count), and its int64[4]: the queries other than the dead that miss
+#: a start or a target, the closure bytes with the REWRITTEN bit, the starts
+#: past the plan's flags, the inputs out of range
+ResolvedChunk = collections.namedtuple("ResolvedChunk", [
+    "sd", "tg", "flags", "sums", "misses", "rewritten", "overlay_starts", "bad_inputs",
+])
+
+
+_NO_MARKS = np.zeros(0, np.int64)
+
+
+def _resolve_call(view: ResolveView, n: int, ids, marks, sd, tg, flags, count: bool = True):
+    sums = np.empty((2, n + 1), np.int64) if count else None
+    counts = np.empty(4, np.int64)
+    load_library().keto_resolve_chunk(
+        view.ref, n, *(None if a is None else a.ctypes.data for a in ids),
+        marks[0].ctypes.data, marks[0].shape[0], marks[1].ctypes.data, marks[1].shape[0],
+        sd.ctypes.data, tg.ctypes.data, None if flags is None else flags.ctypes.data,
+        *((sums[0].ctypes.data, sums[1].ctypes.data) if count else (None, None)),
+        counts.ctypes.data,
+    )
+    return sums, counts.tolist()
+
+
+def resolve_chunk(
+    view: ResolveView, start_raw, sub_raw, dead=(), no_target=(), count: bool = True
+) -> ResolvedChunk:
+    """The ``resolve`` of one chunk in one call: the raw node ids of its
+    queries (``start_raw`` / ``sub_raw``, -1 where the tables hold none) and
+    the marks the records could not carry (``dead``: both rows forced to -1;
+    ``no_target``: the target) as a ``ResolvedChunk``. ``count=False``: no
+    running sums (the caller will count other rows: ``entry_sums``)."""
+    assert load_library() is not None, "resolve_chunk called without the native library"
+    a = np.ascontiguousarray(start_raw, np.int64)
+    b = np.ascontiguousarray(sub_raw, np.int64)
+    n = a.shape[0]
+    if b.shape[0] != n:
+        raise ValueError("start_raw and sub_raw differ in length")
+    marks = [np.asarray(m, np.int64) for m in (dead, no_target)]
+    sd, tg = np.empty(n, np.int64), np.empty(n, np.int64)
+    flags = np.empty(n, np.uint8) if view.has_flags else None
+    sums, counts = _resolve_call(view, n, (a, b), marks, sd, tg, flags, count)
+    return ResolvedChunk(sd, tg, flags, sums, *counts)
+
+
+def entry_sums(view: ResolveView, sd: np.ndarray, tg: np.ndarray) -> np.ndarray:
+    """``int64[2, n + 1]``, the ``sums`` of a ``ResolvedChunk``, of device
+    rows that are already resolved: the counting half of the same call, for
+    the positions a gate expansion made of a chunk."""
+    assert load_library() is not None, "entry_sums called without the native library"
+    sd = np.ascontiguousarray(sd, np.int64)
+    tg = np.ascontiguousarray(tg, np.int64)
+    n = sd.shape[0]
+    if tg.shape[0] != n:
+        raise ValueError("sd and tg differ in length")
+    return _resolve_call(view, n, (None, None), (_NO_MARKS, _NO_MARKS), sd, tg, None)[0]

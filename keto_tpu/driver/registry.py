@@ -1968,6 +1968,45 @@ class Registry:
             pack_declines, ("reason",),
         )
 
+        from keto_tpu.check.dispatch import RESOLVE_DECLINES, RESOLVE_PATHS
+
+        def resolve_chunks():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((path,), float(counters.get(f"resolve_chunks_{path}", 0)))
+                for path in RESOLVE_PATHS
+            ]
+
+        m.register_callback(
+            "keto_check_resolve_chunks_total", "counter",
+            "Chunks of check slices by how the dispatch thread resolved them "
+            "(raw node ids to device rows, closure bytes and entry counts): "
+            "native (one GIL-released pass, native/pack.cpp "
+            "keto_resolve_chunk) vs numpy (the pass declined: "
+            "keto_check_resolve_declines_total says why).",
+            resolve_chunks, ("path",),
+        )
+
+        def resolve_declines():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((reason,), float(counters.get(f"resolve_declines_{reason}", 0)))
+                for reason in RESOLVE_DECLINES
+            ]
+
+        m.register_callback(
+            "keto_check_resolve_declines_total", "counter",
+            "Chunks the native resolve pass did not take, once a chunk by the "
+            "first cause found: no_library (absent, stale or disabled, or no "
+            "native intern tables), special (a wildcard or pattern query in "
+            "the chunk, or records whose framing is unsafe), overlay (overlay "
+            "or extension nodes in the snapshot and a query that missed a "
+            "start or a target), overlay_start (a start row past the rewrite "
+            "plan's closure bytes). Such a chunk takes the numpy functions: "
+            "the same rows.",
+            resolve_declines, ("reason",),
+        )
+
         # what the slice controller, the stream and the dispatch clock
         # decided, declared by the modules that count it
         from keto_tpu.check import gates as check_gates

@@ -207,6 +207,17 @@ class DispatchClock:
         if self._tracing:
             self._gates = {"gated": gated, "positions": positions}
 
+    def resolving(self, path: str) -> None:
+        """The chunk a ``resolve`` works on is about to be resolved by
+        ``path`` (``native``: the one GIL-released pass of native/pack.cpp;
+        ``numpy``: ``_resolve_records`` and ``_entry_counts``): the
+        ``resolve`` span is opened anew, holds that call and what follows up
+        to the chunk's ``pack``, and carries ``path``. A chunk the native
+        pass hands back (an overlay miss) has a span of each. No clock is
+        read: the state does not change."""
+        if self._tracing:
+            self._annotate(RESOLVE, extra={"path": path})
+
     def round(
         self, tuples: int, lane_depth: int, overlapped: bool = False,
         cap: int = 0, cap_by: str = ROUND_CAP_BY[0],
@@ -226,7 +237,7 @@ class DispatchClock:
         self._cap, self._cap_by = cap, cap_by
         self._gates = None
 
-    def _annotate(self, state: int, note=None) -> None:
+    def _annotate(self, state: int, note=None, extra=None) -> None:
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
@@ -234,6 +245,8 @@ class DispatchClock:
             if state == LAUNCH:
                 self._slices += 1
             attrs = dict(self._gates) if self._gates is not None else {}
+            if extra is not None:
+                attrs.update(extra)
             if note is not None:
                 route, kernel, sizes, met = note
                 attrs.update(
@@ -277,6 +290,9 @@ class _NoClock:
         pass
 
     def gates(self, gated: int, positions: int) -> None:
+        pass
+
+    def resolving(self, path: str) -> None:
         pass
 
 

@@ -1,6 +1,9 @@
 // Native pack: the host side of the check hot path, behind a C ABI
 // (keto_tpu/check/native_pack.py binds it with ctypes, which releases the
-// GIL for every call). ABI version 3. Two generations live here:
+// GIL for every call). ABI version 4. Two generations of `pack` live here,
+// and since ABI 4 the `resolve` state before them (keto_resolve_chunk, at the
+// very end: a chunk's raw node ids to its device rows, closure bytes and the
+// running sums of its entry counts, one call):
 //
 //  1. keto_pack_walk / keto_sink_gather / keto_pairs_member: the pieces of
 //     keto_tpu/check/pack.py's pack_chunk and device_part that were worth a
@@ -219,10 +222,25 @@ struct KetoPackView {
     int64_t n_base, ni, sb, nl, n_lab, pair_cap;
 };
 
+// A snapshot's arrays as keto_resolve_chunk reads them: filled once a
+// snapshot by the binding (native_pack.ResolveView), which keeps the arrays
+// alive. Null where the snapshot has none: hub_ptr (a sink's answer names its
+// own rows), reach (every target's entries count towards a cut), flags (no
+// rewrite plan: no closure byte is written).
+struct KetoResolveView {
+    const int64_t* raw2dev;
+    const int64_t* fwd_indptr;
+    const int64_t* sink_indptr;
+    const int64_t* hub_ptr;
+    const uint8_t* reach;
+    const uint8_t* flags;
+    int64_t n_raw, n_base, ni, sb, nl, n_flags, rewritten;
+};
+
 extern "C" {
 
 // ABI version probe: the Python binding refuses a stale .so.
-int64_t keto_pack_version() { return 3; }
+int64_t keto_pack_version() { return 4; }
 
 void* keto_pack_walk(
     const int64_t* fwd_indptr, const int32_t* fwd_indices, int64_t n_base,
@@ -659,6 +677,133 @@ void keto_pack_labeled_riders(int32_t* e1r, int32_t* e1q, int32_t* e2r,
             *aq++ = (int32_t)li;
         }
     }
+}
+
+// The dispatch thread's `resolve` of one chunk of n queries, in one call
+// (check/dispatch.py _resolve_chunk; _resolve_records, _entry_counts, the
+// reach mask of _dispatch_piece and _rewrite_split's closure bytes are the
+// contract, array for array: tests/test_resolve_fused.py fuzzes it).
+//
+//  - sd, tg (int64[n]): the raw node ids start_raw / sub_raw (-1: the tables
+//    hold no such node) through raw2dev; a target only where the query has a
+//    start and the row is live; sd = tg = -1 at dead, tg = -1 at no_target.
+//    With start_raw null, sd and tg are the caller's and only read: the
+//    positions a gate expansion made of a chunk, counted anew;
+//  - csum, csum_reach (int64[n + 1], running sums from 0): a query's device
+//    entries (an interior start 1, a host-propagated start its base
+//    out-degree or 1 past the base, a sink target of a query with a start
+//    its rows or, where hub_ptr names some, its relay rows), and the same
+//    with the queries zeroed whose live target no pull can change (reach).
+//    Both null: nothing is counted (under a plan with gates the caller
+//    counts the positions the expansion makes of the chunk, not its queries);
+//  - flags_out (uint8[n], where the view has flags and flags_out is not
+//    null): flags[sd], 0 without a start row under n_flags.
+//
+// counts is int64[4]: 0 queries other than the dead that miss a start or a
+// target (what the overlay asks before it re-resolves anything), 1 closure
+// bytes with the view's `rewritten` bit, 2 starts at or past n_flags (an
+// overlay start: its byte is not the table's to give), 3 inputs out of range
+// (a raw id past n_raw, a mark outside the chunk): nothing to trust then.
+void keto_resolve_chunk(const KetoResolveView* v, int64_t n,
+                        const int64_t* start_raw, const int64_t* sub_raw,
+                        const int64_t* dead, int64_t n_dead,
+                        const int64_t* no_target, int64_t n_no_target,
+                        int64_t* sd, int64_t* tg, uint8_t* flags_out,
+                        int64_t* csum, int64_t* csum_reach, int64_t* counts) {
+    const int64_t ni = v->ni, sb = v->sb, nl = v->nl, n_base = v->n_base;
+    const int64_t* const r2d = v->raw2dev;
+    const int64_t* const ip = v->fwd_indptr;
+    const int64_t* const sp = v->sink_indptr;
+    const int64_t* const hub_ptr = v->hub_ptr;
+    const uint8_t* const reach = v->reach;
+    const uint8_t* const flags = flags_out ? v->flags : nullptr;
+    for (int k = 0; k < 4; ++k) counts[k] = 0;
+    constexpr int64_t kAhead = 16;
+    if (start_raw) {
+        // 2n independent reads of a table far larger than the cache
+        auto ask = [&](int64_t i) {
+            if (start_raw[i] >= 0) __builtin_prefetch(r2d + start_raw[i]);
+            if (sub_raw[i] >= 0) __builtin_prefetch(r2d + sub_raw[i]);
+        };
+        for (int64_t i = 0; i < n && i < kAhead; ++i) ask(i);
+        for (int64_t i = 0; i < n; ++i) {
+            if (i + kAhead < n) ask(i + kAhead);
+            const int64_t a = start_raw[i], b = sub_raw[i];
+            if (a >= v->n_raw || b >= v->n_raw) {
+                ++counts[3];
+                sd[i] = tg[i] = -1;
+                continue;
+            }
+            const int64_t s = a >= 0 ? r2d[a] : -1;
+            const int64_t t = b >= 0 ? r2d[b] : -1;
+            sd[i] = s;
+            // a target only matters when the query has starts
+            tg[i] = (t >= 0 && t < nl && s >= 0) ? t : -1;
+        }
+        for (int64_t k = 0; k < n_dead; ++k) {
+            const int64_t i = dead[k];
+            if (i < 0 || i >= n) { ++counts[3]; continue; }
+            sd[i] = tg[i] = -1;
+        }
+        for (int64_t k = 0; k < n_no_target; ++k) {
+            const int64_t i = no_target[k];
+            if (i < 0 || i >= n) { ++counts[3]; continue; }
+            tg[i] = -1;
+        }
+    }
+    const bool count = csum != nullptr;
+    auto ask_rows = [&](int64_t i) {
+        const int64_t s = sd[i], t = tg[i];
+        if (flags && s >= 0 && s < v->n_flags) __builtin_prefetch(flags + s);
+        if (!count) return;
+        if (s >= ni && s < n_base) __builtin_prefetch(ip + s);
+        if (t >= sb && t < nl) {
+            __builtin_prefetch(sp + (t - sb));
+            if (hub_ptr) __builtin_prefetch(hub_ptr + (t - sb));
+        }
+        if (reach && t >= 0 && t < nl) __builtin_prefetch(reach + t);
+    };
+    for (int64_t i = 0; i < n && i < kAhead; ++i) ask_rows(i);
+    int64_t total = 0, total_reach = 0, misses = 0, rewritten = 0;
+    if (count) csum[0] = csum_reach[0] = 0;
+    for (int64_t i = 0; i < n; ++i) {
+        if (i + kAhead < n) ask_rows(i + kAhead);
+        const int64_t s = sd[i], t = tg[i];
+        misses += (s == -1 || t == -1);
+        if (count) {
+            int64_t c = 0;
+            bool has_start = false;
+            if (s >= 0 && s < ni) {
+                has_start = true;
+                c = 1;
+            } else if ((s >= ni && s < sb) || s >= nl) {
+                has_start = true;
+                c = s < n_base ? ip[s + 1] - ip[s] : 1;  // overlay adjacency is small
+            }
+            if (has_start && t >= sb && t < nl) {
+                const int64_t k = t - sb;
+                int64_t rows = sp[k + 1] - sp[k];
+                // a hub sink sends its relay rows, not its rows
+                if (hub_ptr && hub_ptr[k + 1] > hub_ptr[k]) rows = hub_ptr[k + 1] - hub_ptr[k];
+                c += rows;
+            }
+            total += c;
+            csum[i + 1] = total;
+            // a query whose target side has no row that a pull changes sends
+            // the device nothing: its entries do not count towards a cut
+            if (!(reach && t >= 0 && t < nl && !reach[t])) total_reach += c;
+            csum_reach[i + 1] = total_reach;
+        }
+        if (flags) {
+            uint8_t f = 0;
+            if (s >= v->n_flags) ++counts[2];
+            else if (s >= 0) f = flags[s];
+            flags_out[i] = f;
+            rewritten += (f & v->rewritten) != 0;
+        }
+    }
+    counts[0] = misses - (start_raw ? n_dead : 0);
+    counts[1] = rewritten;
 }
 
 }  // extern "C"

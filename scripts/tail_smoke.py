@@ -16,7 +16,8 @@ machinery end to end:
    (sample rate 1.0) re-verifies served decisions with zero mismatches;
 3. native pack == numpy pack BYTE parity on the serving snapshot
    (every packed kernel array and host-decided grant), and the native
-   path actually ran (keto_native_pack_chunks_total, ``native`` or ``fused``);
+   path actually ran (keto_native_pack_chunks_total, ``native`` or ``fused``),
+   as did ``resolve``'s one native pass (keto_check_resolve_chunks_total);
 4. the staging ledger reconciles: the governor's ``staging`` tag equals
    the engine pool's own accounting, with zero outstanding leases after
    the workload drains;
@@ -194,6 +195,8 @@ def main() -> int:
         else:
             if native_pack.COUNTERS["native"] + native_pack.COUNTERS["fused"] == 0:
                 problems.append("native pack path never ran")
+            if not engine.maintenance.snapshot().get("resolve_chunks_native", 0):
+                problems.append("native resolve pass never ran")
             snap = engine.snapshot()
             qs = [RelationTuple(namespace=t["namespace"], object=t["object"],
                                 relation=t["relation"],
@@ -234,7 +237,7 @@ def main() -> int:
         with urllib.request.urlopen(f"{base}/metrics", timeout=30) as resp:
             families = parse_exposition(resp.read().decode())
         for fam in ("keto_stream_tail_ratio", "keto_stream_route_slices_total",
-                    "keto_native_pack_chunks_total"):
+                    "keto_native_pack_chunks_total", "keto_check_resolve_chunks_total"):
             if fam not in families:
                 problems.append(f"{fam} missing from the scrape")
 

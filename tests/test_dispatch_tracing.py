@@ -250,6 +250,33 @@ def test_a_rounds_spans_carry_its_room_and_what_set_it():
     assert clock.round_cap == {"batch_size": 1, "sub_slice": 0, "controller": 1}
 
 
+@pytest.mark.parametrize("paths", [("native",), ("numpy",), ("native", "numpy")])
+def test_the_resolve_span_that_holds_the_call_carries_its_path(paths):
+    """``resolving(path)`` opens the ``resolve`` span anew before the call
+    that makes the chunk's rows: that span says ``path``, the spans before it
+    and after it do not, and no clock is read (the state stays ``resolve``).
+    A chunk the native pass hands back has a span of each. Without a session
+    nothing is made."""
+    session = FakeSession()
+    session.open = True
+    clock = DispatchClock(session)
+    clock.round(4096, 0)
+    clock.enter(RESOLVE)
+    before = list(clock.seconds)
+    for path in paths:
+        clock.resolving(path)
+    assert [b for a, b in zip(clock.seconds, before) if a != b] == []
+    clock.enter(PACK)
+    made = [(name.rsplit(".", 1)[1], args.get("path")) for name, args in session.made]
+    assert made == [("resolve", None), *(("resolve", p) for p in paths), ("pack", None)]
+    assert session.events.count(("exit", "keto.dispatch.resolve")) == 1 + len(paths)
+    quiet = FakeSession()
+    clock = DispatchClock(quiet)
+    clock.enter(RESOLVE)
+    clock.resolving("native")
+    assert quiet.made == []
+
+
 def test_a_controller_event_is_a_zero_length_annotation_on_the_dispatch_thread():
     """``StreamSliceController.observe`` marks what moved it through the
     calling thread's clock: written while a session is open, on the dispatch

@@ -333,7 +333,9 @@ def test_overlay_ell_insert_blocks_then_compaction_restores():
     labels and the fast path resumes — bit-identically throughout."""
     p = deep_store(depth=6)
     on = quiet_engine(p)
-    on.snapshot()
+    # the overlapped label build has to have installed its index before the
+    # write: a snapshot without one blocks nothing and counts no invalidation
+    assert on.labels_settled()
     q = T("d", "doc", "view", SubjectID("alice"))
     assert on.subject_is_allowed(q)
     # new edge between existing active-interior rows → overlay ELL

@@ -284,10 +284,10 @@ def test_a_declined_chunk_takes_the_numpy_path_and_is_counted_once(make_persiste
 @needs_native
 def test_a_library_of_another_abi_version_loads_nothing(monkeypatch):
     """``load_library`` takes a library only at the binding's own ABI number:
-    an older build left beside newer Python (the parent's was 2) is passed
+    an older build left beside newer Python (the parent's was 3) is passed
     over and every chunk packs with numpy."""
-    assert native_pack.load_library().keto_pack_version() == native_pack._ABI_VERSION == 3
-    monkeypatch.setattr(native_pack, "_ABI_VERSION", 2)
+    assert native_pack.load_library().keto_pack_version() == native_pack._ABI_VERSION == 4
+    monkeypatch.setattr(native_pack, "_ABI_VERSION", 3)
     monkeypatch.setattr(native_pack, "_lib", None)
     monkeypatch.setattr(native_pack, "_lib_checked", False)
     assert native_pack.load_library() is None and not native_pack.available()

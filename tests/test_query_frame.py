@@ -258,7 +258,7 @@ def assert_frame_equals_decode(engine, body: bytes, got) -> None:
     assert off[1:].tolist() == ends
     framed = QueryFrame(buf, off, flags, body, MANAGER)
     sd, tg, multi = eng.dispatch._resolve_bulk(snap, QueryBatch([(framed, 0, n)]))
-    sd_o, tg_o, multi_o = eng.dispatch._resolve_bulk_native(snap, tuples)
+    sd_o, tg_o, multi_o = eng.dispatch._resolve_bulk(snap, tuples)
     assert np.array_equal(sd, sd_o) and np.array_equal(tg, tg_o)
     assert multi.keys() == multi_o.keys()
     for i in multi:

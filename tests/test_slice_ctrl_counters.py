@@ -252,12 +252,35 @@ def test_a_chunk_cut_by_the_entry_budget_alone_counts_budget_and_its_pieces(engi
     assert pieces == slices > 1
 
 
-def test_a_chunk_over_the_geometric_bound_counts_geometry_with_or_without_a_budget(engine, monkeypatch):
+@pytest.mark.parametrize("path", ["native", "numpy"])
+def test_a_chunk_over_the_geometric_bound_counts_geometry_with_or_without_a_budget(engine, monkeypatch, path):
+    """Whichever path resolved the chunk (``resolve``'s one native pass hands
+    the entry counts over as running sums; a chunk it declines takes
+    ``_entry_counts``), at least 20 entries a query are over the bound."""
+    from keto_tpu.check import native_pack
+
     engine, batch = engine
     d = engine.dispatch
-    real = d._entry_counts
     # 128 queries pad to B = 256: the geometric bound is 4 x B = 1,024 entries
-    monkeypatch.setattr(d, "_entry_counts", lambda *a: np.maximum(real(*a), 20))
+    if path == "numpy":
+        real = d._entry_counts
+        monkeypatch.setattr(d, "_resolve_decline", lambda snap, raw: "no_library")
+        monkeypatch.setattr(d, "_entry_counts", lambda *a: np.maximum(real(*a), 20))
+    else:
+        if not native_pack.available():
+            pytest.skip("native pack library not built")
+        # the pass takes no chunk with a pattern in it
+        patterns = set(d._raw_ids(engine.snapshot(), batch)[1][2])
+        batch = [q for i, q in enumerate(batch) if i not in patterns]
+        real = native_pack.resolve_chunk
+
+        def at_least_20(*a, **kw):
+            got = real(*a, **kw)
+            sums = np.zeros_like(got.sums)
+            np.cumsum(np.maximum(np.diff(got.sums), 20), axis=1, out=sums[:, 1:])
+            return got._replace(sums=sums)
+
+        monkeypatch.setattr(native_pack, "resolve_chunk", at_least_20)
     for budget in (None, 64):
         monkeypatch.setattr(d.stream_ctrl, "entry_budget", lambda: budget)
         cuts, pieces, slices = _dispatch(engine, batch)

@@ -247,8 +247,8 @@ def test_bulk_resolve_native_parity(make_persister, seed):
         queries.append(
             T(rng.choice(ns_names + ["nope"]), rng.choice(objects), rng.choice(relations), sub)
         )
-    got_n = tpu.dispatch._resolve_bulk_native(snap, queries)
-    assert got_n is not None
+    assert tpu.dispatch._raw_of_tuples(snap, queries) is not None  # the native resolver takes them
+    got_n = tpu.dispatch._resolve_bulk(snap, queries)
     sd_n, tg_n, multi_n = got_n
     sd_p, tg_p, multi_p = tpu.dispatch._resolve_bulk_py(snap, queries)
     assert np.array_equal(sd_n, sd_p)
@@ -285,8 +285,8 @@ def test_bulk_resolve_wild_subject_namespace_parity(make_persister):
     # (never the -2 multi sentinel) with a reachable target
     assert sd_p[0] >= 0 and tg_p[0] >= 0 and 0 not in multi_p
     if hasattr(snap.interned, "resolve_queries"):
-        got = tpu.dispatch._resolve_bulk_native(snap, queries)
-        assert got is not None
+        assert tpu.dispatch._raw_of_tuples(snap, queries) is not None
+        got = tpu.dispatch._resolve_bulk(snap, queries)
         sd_n, tg_n, multi_n = got
         assert np.array_equal(sd_n, sd_p)
         assert np.array_equal(tg_n, tg_p)
@@ -311,8 +311,8 @@ def test_bulk_resolve_wild_subject_no_empty_namespace(make_persister):
     sd_p, tg_p, _ = tpu.dispatch._resolve_bulk_py(snap, queries)
     assert sd_p[0] >= 0 and tg_p[0] == -1
     if hasattr(snap.interned, "resolve_queries"):
-        got = tpu.dispatch._resolve_bulk_native(snap, queries)
-        assert got is not None
+        assert tpu.dispatch._raw_of_tuples(snap, queries) is not None
+        got = tpu.dispatch._resolve_bulk(snap, queries)
         sd_n, tg_n, _ = got
         assert np.array_equal(sd_n, sd_p)
         assert np.array_equal(tg_n, tg_p)
