@@ -53,6 +53,7 @@ import contextlib
 import json
 import os
 import random
+import re
 import shutil
 import signal
 import socket
@@ -73,6 +74,8 @@ N_SINGLE = 200
 N_GRPC = 200
 N_ORACLE_SAMPLE = 2_000
 N_SPECIAL = 64  # per special shape
+N_JOINS = 300  # one-tuple writes ahead of the fold
+FOLD_LIMIT_MS = 5000.0  # a fold that compiles takes 13-19 s on the chip (PR 46)
 
 
 def _from_owner(rel: str) -> dict:
@@ -277,6 +280,7 @@ class Smoke:
         n_rbac_tuples = len(tuples)
         # queries over the special shapes; the CPU oracle decides them
         special_q = []
+        any_relation = set()  # leaf groups under an empty-relation subject set
         for i in range(N_SPECIAL):
             # wildcard subject
             tuples.append(T("docs", f"pub-{i}", "view", SubjectID("*")))
@@ -297,6 +301,7 @@ class Smoke:
             # empty-relation subject set over an existing leaf group
             u = rng.randrange(n_users)
             leaves = sorted(membership.get(u, ())) or [0]
+            any_relation.add(leaves[0])
             tuples.append(
                 T("docs", f"anyrel-{i}", "view", SubjectSet("groups", f"leaf-{leaves[0]}", ""))
             )
@@ -321,6 +326,12 @@ class Smoke:
         self.rbac_expected = [e for _, e in pairs]
         self.doc_grant = doc_grant
         self.user_reaches = user_reaches
+        # leaf groups that hold a member: one more member is an overlay edge,
+        # where a group's first member would change its node's class. Not
+        # the ones an empty-relation subject set names: an edge out of a
+        # wildcard node cannot be folded, only rebuilt around
+        self.joinable = sorted(
+            {g for groups in membership.values() for g in groups} - any_relation)
 
         # the write: cycle group 0-a gains cycle group 1-b as a member. An
         # edge between two interior rows the layout can never peel (cycle
@@ -644,6 +655,67 @@ class Smoke:
                 self.compare("post-write checks pinned to the snaptoken vs "
                              "CheckEngine", post, got, want)
                 counts["post_write"] = len(post)
+                self.overlay_after_write = self.metric(self.scrape(), "keto_overlay_edges")
+
+            # a store that is written to: N_JOINS one-tuple writes, then reads
+            # beside the fold that compact_after_s brings (5 s after the
+            # overlay was born); a fold sorts through programs the boot build
+            # compiled, or on the host, and compiles nothing
+            with self.phase("writes, a fold and reads beside it"):
+                before = self.scrape()
+                joins = [
+                    self.T("groups", f"leaf-{self.joinable[i % len(self.joinable)]}",
+                           "member", SubjectID(f"smoke-joiner-{i}"))
+                    for i in range(N_JOINS)
+                ]
+                for t in joins:
+                    status, _, _ = self.http(
+                        "PUT", f"{self.write_url}/relation-tuples", t.to_json())
+                    if status != 201:
+                        raise SmokeFailure(f"PUT {t} answered {status}")
+                asked, got = [], []
+                deadline = time.monotonic() + 90
+                while True:
+                    # unpinned singles, each sent after its write's acknowledgement
+                    qs = rng.sample(joins, 24) + rng.sample(self.rbac_q[:N_BATCHED], 8)
+                    asked += qs
+                    got += [client.check(q) for q in qs]
+                    fam = self.scrape()
+                    folds = (self.metric(fam, "keto_fold_runs_total")
+                             - self.metric(before, "keto_fold_runs_total"))
+                    if folds >= 1 and self.metric(fam, "keto_overlay_edges") == 0:
+                        break
+                    if time.monotonic() > deadline:
+                        raise SmokeFailure("no fold 90 s after the writes")
+                qs = joins + rng.sample(self.rbac_q[:N_BATCHED], 212)
+                asked += qs
+                got += client.batch_check(qs)  # the folded snapshot
+                want = [oracle.subject_is_allowed(q) for q in asked]
+                if not all(want[:24]):
+                    raise SmokeFailure("the oracle does not see the joins")
+                self.compare("reads beside and after the fold vs CheckEngine", asked, got, want)
+                counts["beside_fold"] = len(asked)
+                sorts = {
+                    f"{labels['backend']}/{labels['why']}": int(value)
+                    for _, labels, value in fam.get("keto_build_sort_total", {}).get("samples", ())
+                }
+                waits = {
+                    labels["site"]: round(value, 4)
+                    for _, labels, value in
+                    fam.get("keto_engine_lock_wait_seconds_total", {}).get("samples", ())
+                }
+                stale = self.metric(fam, "keto_snapshot_stale_serves_total")
+                fold_ms = [float(ms) for ms in re.findall(
+                    r"overlay compacted in ([0-9.]+) ms", (self.out / "daemon.log").read_text())]
+                self.say(f"{N_JOINS} writes, {int(folds)} fold(s) of {fold_ms} ms; sort batches "
+                         f"since boot by backend/why {json.dumps(sorts)}; lock waits by site "
+                         f"{json.dumps(waits)} s; stale serves {int(stale)}")
+                if stale:
+                    raise SmokeFailure(f"{int(stale)} rounds were served a stale snapshot")
+                if sorts.get("host/error"):
+                    raise SmokeFailure(f"{sorts['host/error']} sort batches failed on the device")
+                if not fold_ms or max(fold_ms) > FOLD_LIMIT_MS:
+                    raise SmokeFailure(f"a fold took over {FOLD_LIMIT_MS} ms: {fold_ms}")
         finally:
             store.close()
         self.counts = counts
@@ -738,7 +810,7 @@ class Smoke:
         for name in ("audit_mismatches", "degraded", "list_device_errors", "oom_events"):
             if gauges[name]:
                 problems.append(f"{name} = {gauges[name]}")
-        if gauges["overlay_edges"] <= 0:
+        if self.overlay_after_write <= 0:  # read then: a fold has emptied it since
             problems.append("the write did not land in the delta overlay")
         if gauges["list_device"] <= 0:
             problems.append("no reverse query ran on the device")

@@ -17,6 +17,7 @@ in one device program**. It is split at one seam:
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import logging
 import random
@@ -40,6 +41,7 @@ from keto_tpu.graph.snapshot import GraphSnapshot, _ceil_pow2
 from keto_tpu.relationtuple.model import RelationTuple
 from keto_tpu.x import faults
 from keto_tpu.x.errors import KetoError
+from keto_tpu.x.profiling import SESSION
 from keto_tpu.x.retry import retry_call
 from keto_tpu.x.supervise import SupervisedTask
 from keto_tpu.x.telemetry import MaintenanceStats
@@ -175,6 +177,11 @@ class TpuCheckEngine:
             # graph axis (leading dim), replicated over data
             self._shard_stack_sharding = NamedSharding(mesh, P(GRAPH_AXIS))
         self._lock = threading.Lock()
+        # what the lock's holder is doing, for those who find it taken:
+        # "delta" (catching up by overlay), "fold", "rebuild", or None.
+        # Written under the lock, read without it (a stale read sends a
+        # serving thread into one more short wait, no further)
+        self._lock_holder: Optional[str] = None
         self._snapshot: Optional[GraphSnapshot] = None
         # delta overlays beyond this edge count trigger COMPACTION — the
         # overlay folds into the base layout by segment
@@ -349,7 +356,12 @@ class TpuCheckEngine:
         self.build_progress = BuildProgress(stats=self.maintenance)
         self._build_chunk_rows = max(1, int(build_chunk_rows))
         self._build_sorter = (
-            GovernedSorter(hbm=self.hbm, stats=self.maintenance)
+            GovernedSorter(
+                hbm=self.hbm, stats=self.maintenance,
+                # after the first build every sort is asked under the lock
+                # with readers behind it: it may not wait for a compiler
+                serving=lambda: self._snapshot is not None,
+            )
             if device_build_enabled
             else None
         )
@@ -393,8 +405,58 @@ class TpuCheckEngine:
         ):
             self._kick_background_refresh()
             return snap
-        with self._lock:
+        with self._engine_lock("pinned" if at_least is not None else "latest"):
             return self._refresh_locked()
+
+    @contextlib.contextmanager
+    def _engine_lock(self, site: Optional[str] = None):
+        """Hold the engine's lock. A thread on the serving path names its
+        ``site`` (``latest``, ``pinned``) and what it waits where another
+        holds the lock is counted (``_lock_wait``); maintenance passes wait
+        uncounted."""
+        if site is None:
+            self._lock.acquire()
+        elif not self._lock.acquire(blocking=False):
+            with self._lock_wait(site):
+                self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._release_engine_lock()
+
+    def _release_engine_lock(self) -> None:
+        self._lock_holder = None
+        self._lock.release()
+
+    @contextlib.contextmanager
+    def _lock_wait(self, site: str):
+        """One ``perf_counter`` pair around a serving thread's wait for the
+        engine's lock: ``keto_engine_lock_wait_seconds_total{site}``, and a
+        ``keto.engine.lock_wait`` span while a profiler session is open,
+        so that what the dispatch thread waited for has a name."""
+        t0 = time.perf_counter()
+        try:
+            if not SESSION.open:
+                yield
+            else:
+                with SESSION.annotation(
+                    "keto.engine.lock_wait", site=site, holder=str(self._lock_holder)
+                ):
+                    yield
+        finally:
+            self.maintenance.observe_ms(
+                f"lock_wait_{site}", (time.perf_counter() - t0) * 1e3
+            )
+
+    def _serve_stale(self) -> GraphSnapshot:
+        """Hand out the snapshot there is although the store has moved on,
+        counted by what stands in the way
+        (``keto_snapshot_stale_serves_total{holder}``: only ever a full
+        rebuild, running or owed; a fold and a delta apply are waited
+        for), and have the supervised worker catch up."""
+        self.maintenance.incr("stale_serves_rebuild")
+        self._kick_background_refresh()
+        return self._snapshot
 
     def snapshot_serving(self) -> GraphSnapshot:
         """Serving-path snapshot: NEVER stalls the read plane on an
@@ -406,10 +468,16 @@ class TpuCheckEngine:
         - watermark advanced and a delta applies → synchronous catch-up
           (milliseconds: inserts extend the overlay, deletes tombstone —
           effectively read-your-writes);
-        - only a full rebuild can reach the watermark → if the last build
-          was cheap (≤ sync_rebuild_budget_s), just do it; otherwise serve
-          the current snapshot (bounded staleness, Zanzibar default) and
-          let the background refresh catch up.
+        - the lock is held by a delta apply or a fold → wait for it (both
+          are bounded work: fractions of a second, and a fold never
+          compiles, keto_tpu/graph/device_build.py), then catch up as
+          above: a read sent after a write's acknowledgement sees it;
+        - only a full rebuild can reach the watermark, or one holds the
+          lock → if the last build was cheap (≤ sync_rebuild_budget_s),
+          just do it; otherwise serve the current snapshot (bounded
+          staleness, Zanzibar default; counted,
+          ``keto_snapshot_stale_serves_total``) and let the background
+          refresh catch up.
 
         Callers needing hard read-your-writes use ``snapshot()`` /
         ``mode="latest"``; callers holding a write's snaptoken use
@@ -434,8 +502,7 @@ class TpuCheckEngine:
                 _log.warning(
                     "inline refresh failed; serving stale snapshot", exc_info=True
                 )
-                self._kick_background_refresh()
-                return self._snapshot
+                return self._serve_stale()
         wm = self._store.watermark()
         if snap.snapshot_id >= wm:
             # current — return it directly (NOT via snapshot(): a write
@@ -443,7 +510,7 @@ class TpuCheckEngine:
             # call into an inline rebuild), with the usual compaction kick
             self._maybe_kick_compaction(snap)
             return snap
-        if self._lock.acquire(blocking=False):
+        if self._lock.acquire(blocking=False) or self._wait_out_bounded_holder():
             try:
                 try:
                     got = self._refresh_locked(delta_only=True)
@@ -468,11 +535,23 @@ class TpuCheckEngine:
                         self._kick_background_refresh()
                     return got
             finally:
-                self._lock.release()
+                self._release_engine_lock()
         # rebuild territory (or a rebuild is already holding the lock):
         # serve stale, catch up off the serving path
-        self._kick_background_refresh()
-        return self._snapshot
+        return self._serve_stale()
+
+    def _wait_out_bounded_holder(self) -> bool:
+        """``snapshot_serving`` found the lock taken. A delta apply and a
+        fold end in a fraction of a second and leave a snapshot that holds
+        every acknowledged write: wait for them (counted under
+        ``site="serving"``). A full rebuild takes as long as a boot: not
+        for that one. True with the lock held; False while a rebuild
+        holds it."""
+        with self._lock_wait("serving"):
+            while self._lock_holder != "rebuild":
+                if self._lock.acquire(timeout=0.02):
+                    return True
+        return False
 
     def _schema_moved(self, snap: GraphSnapshot) -> bool:
         """Was ``snap`` built under another rewrite schema than the current
@@ -488,10 +567,18 @@ class TpuCheckEngine:
         return self._snapshot
 
     def _snapshot_for(self, at_least, mode: str) -> GraphSnapshot:
+        """The snapshot a round is answered from. ``serving`` with a floor
+        is a round that holds unpinned requests beside pinned ones
+        (keto_tpu/driver/batch.py ``_consistency_kw``): the unpinned ones
+        are owed the serving rules (every acknowledged write that a delta
+        reaches), the pinned ones their floor, so the round catches up as
+        an unpinned round would and only then looks at the floor."""
+        if mode == "serving":
+            snap = self.snapshot_serving()
+            if at_least is None or snap.snapshot_id >= at_least:
+                return snap
         if at_least is not None:
             return self.snapshot(at_least=at_least)
-        if mode == "serving":
-            return self.snapshot_serving()
         return self.snapshot()
 
     def _read_store(self, fn, *args):
@@ -583,6 +670,8 @@ class TpuCheckEngine:
         self._cache_task.stop()
         self._audit_task.stop()
         self._label_build_wait()
+        if self._build_sorter is not None:
+            self._build_sorter.close()
         self.dispatch.close()
 
     # -- HBM budget governor (keto_tpu/driver/hbm.py) ------------------------
@@ -996,7 +1085,7 @@ class TpuCheckEngine:
         force_full, self._refresh_force_full = self._refresh_force_full, False
         self._in_maintenance_pass = True
         try:
-            with self._lock:
+            with self._engine_lock():
                 self._refresh_locked(force_full=force_full)
         except Exception:
             if force_full:
@@ -1018,6 +1107,7 @@ class TpuCheckEngine:
         path's never-stall contract — snapshot_serving falls back to
         stale; oversized overlays still apply and compact off-path)."""
         snap = self._snapshot
+        self._lock_holder = "delta"
         wm = self._store.watermark()
         fold_failed = False
         rewrites = schema_of(self._nm())
@@ -1029,7 +1119,9 @@ class TpuCheckEngine:
             snap = None
             self._fold_base, self._seg_log, self._pending_seg = None, [], None
         if snap is None and self._cache_dir is not None and not delta_only:
+            self._lock_holder = "rebuild"
             snap = self._load_cache_locked(wm)
+            self._lock_holder = "delta"
         # an over-budget overlay owes a fold even when the snapshot is
         # already current: the maintenance pass falls through to the
         # delta path (an empty delta) so the fold below runs — serving
@@ -1087,6 +1179,7 @@ class TpuCheckEngine:
                         self._refresh_task.kick()
                     else:
                         try:
+                            self._lock_holder = "fold"
                             folded = self._fold_locked(new, full=force_full)
                         except Exception:
                             # a broken fold must not kill the refresh: log
@@ -1110,6 +1203,7 @@ class TpuCheckEngine:
                 return None
             from keto_tpu.graph.stream_build import full_build
 
+            self._lock_holder = "rebuild"
             t0 = time.monotonic()
             # streaming, overlapped, device-accelerated pipeline: chunked
             # store scan feeds the native intern pool, the layout's

@@ -641,6 +641,12 @@ class CheckBatcher:
             # read-your-writes dominates every floor in the batch
             return {"mode": "latest"}
         floors = [a for a in at_leasts if a is not None]
+        if floors and len(floors) == len(at_leasts):
+            # every request holds a snaptoken: any snapshot that fresh
+            # answers at once
+            return {"at_least": max(floors), "mode": "pinned"}
+        # an unpinned request in the round is owed the serving rules (catch
+        # up by delta), whatever floor rides beside it
         return {"at_least": max(floors) if floors else None, "mode": "serving"}
 
     def _dispatch(self, tuples, at_leasts, latests):

@@ -2894,6 +2894,86 @@ class Registry:
             fold_duration,
         )
 
+        # where a build's or a fold's sorts ran and why, and what the
+        # serving path waited for or went without while a pass held the
+        # engine's lock (keto_tpu/graph/device_build.py GovernedSorter,
+        # keto_tpu/check/tpu_engine.py)
+        from keto_tpu.graph.device_build import SORT_WHYS
+
+        def build_sorts():
+            counters, _, _ = maintenance_raw()
+            combos = [("device", "ok")] + [("host", why) for why in SORT_WHYS]
+            return [
+                ((backend, why), float(counters.get(f"build_sort_{backend}_{why}", 0)))
+                for backend, why in combos
+            ]
+
+        m.register_callback(
+            "keto_build_sort_total", "counter",
+            "Batches of stable argsorts a snapshot build, a fold or a cache "
+            "load asked for, by where they ran and why: device/ok, or the "
+            "host because the batch was small, the HBM plan did not fit "
+            "(pressure), a snapshot was serving and the batch's padded "
+            "program was not compiled in this process yet (cold: it is "
+            "compiled behind, off the engine's lock), or the device sort "
+            "failed or met a key equal to the padding sentinel (error). "
+            "The same permutations either way.",
+            build_sorts, ("backend", "why"),
+        )
+
+        def duration_seconds(prefix, values):
+            def read():
+                _, _, durations = maintenance_raw()
+                for value in values:
+                    d = durations.get(f"{prefix}_{value}")
+                    yield (value,), float(d["total_ms"]) * 1e-3 if d else 0.0
+
+            return read
+
+        m.register_callback(
+            "keto_build_sort_seconds_total", "counter",
+            "Wall time of those batches by backend: padding, transfers and "
+            "the sort on the device; numpy on the host.",
+            duration_seconds("build_sort", ("device", "host")), ("backend",),
+        )
+
+        def build_sort_rungs():
+            _, gauges, _ = maintenance_raw()
+            yield (), float(gauges.get("build_sort_rungs", 0))
+
+        m.register_callback(
+            "keto_build_sort_rungs", "gauge",
+            "Padded sort programs (one a rung and arity) compiled in this "
+            "process: what a fold can sort on the device without compiling.",
+            build_sort_rungs,
+        )
+
+        def stale_serves():
+            counters, _, _ = maintenance_raw()
+            return [
+                ((holder,), float(counters.get(f"stale_serves_{holder}", 0)))
+                for holder in ("fold", "delta", "rebuild")
+            ]
+
+        m.register_callback(
+            "keto_snapshot_stale_serves_total", "counter",
+            "Rounds snapshot_serving answered from a snapshot older than "
+            "the store's watermark, by what stood in the way. A fold or a "
+            "delta apply is waited for (these two stay 0); only a full "
+            "rebuild, running or owed, is not.",
+            stale_serves, ("holder",),
+        )
+        m.register_callback(
+            "keto_engine_lock_wait_seconds_total", "counter",
+            "Seconds serving threads waited for the engine's lock while a "
+            "refresh pass, a fold or a rebuild held it, by site: serving "
+            "(an unpinned round, waiting out a fold or a delta apply), "
+            "pinned (a round whose requests all hold snaptokens the "
+            "snapshot does not reach yet), latest. One dispatch thread: "
+            "what it waits here, every request behind it waits too.",
+            duration_seconds("lock_wait", ("serving", "pinned", "latest")), ("site",),
+        )
+
         # fleet control plane (keto_tpu/fleet/): lease epoch, promotion
         # and membership state, live-reshard state machine, and the
         # lag-aware routing weights — peek-only like every other bridge

@@ -398,7 +398,7 @@ def test_each_round_asks_for_the_consistency_of_its_own_riders():
     finally:
         b.stop()
     assert eng.kw == [
-        {"at_least": None, "mode": "serving"}, {"mode": "latest"}, {"at_least": 41, "mode": "serving"},
+        {"at_least": None, "mode": "serving"}, {"mode": "latest"}, {"at_least": 41, "mode": "pinned"},
     ]
     assert b.clock.overlapped == 2
     assert [res[k][1] for k in ("serving", "latest", "pinned")] == [100, 101, 102]
